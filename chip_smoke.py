@@ -1,0 +1,429 @@
+"""chip_smoke.py — the quickest proof that the system still starts on the chip.
+
+One process drives the two hot paths once, through the entry points a user
+calls, at the full width of the models the repository supports:
+
+* trainer — BERT-Large (24L / hidden 1024 / 16 heads / intermediate 4096 /
+  seq 512, 8 samples per chip, bf16 compute, Adam) through ``FFConfig`` ->
+  ``FFModel`` -> ``build_bert`` -> ``compile()`` -> ``fit()``: on one chip on
+  one, on a four-chip host data-parallel over all four;
+* server — GPT-2 small (12L / hidden 768 / 12 heads / vocab 50257, bf16)
+  through ``ServingEngine(ff, n_slots=8, max_decode_len=256).generate`` on the
+  default path (paged KV, prefix cache on, sync loop, fast decode).
+
+Weights are random, made from the config's seed. Before the two phases each
+Pallas kernel's numbers are checked on the chip once, and after each phase the
+compiled program's text must hold the kernel's Mosaic custom call — a run that
+routed to the einsum / gather path fails.
+
+The script refuses to run without a TPU whose ``device_kind`` is in the peak
+table: it never selects a smaller size or another backend. Any exception in
+any phase is a non-zero exit; the last line of standard output is the result
+JSON and is printed only when every phase passed. Lines tagged ``[info]`` are
+information for CHANGES.md (compile seconds, step and token times), not
+metrics.
+"""
+from __future__ import annotations
+
+import json
+import os
+import re
+import sys
+import time
+
+ROOT = os.path.dirname(os.path.abspath(__file__))
+
+TRAIN_STEPS = 8          # first fit(): step 0 compiles
+TRAIN_STEPS_AGAIN = 2    # second fit() on the same model (donated buffers)
+# Adam's first steps move every one of 335M weights by the learning rate: at
+# the 1e-4 bench.py times with, one step saturates the 2-class softmax and the
+# loss sits at the clip (0.75 -> 17.3 on the CPU, 1.15 -> 6.9 on the chip) —
+# the recipe, not the device. At 1e-6 the loss falls step by step.
+TRAIN_LR = 1e-6
+MAX_NEW_TOKENS = 24
+
+# Kernel tolerances, as the largest |difference| over the largest |reference|
+# value. bf16 keeps 8 mantissa bits, so one unit in the last place at the top
+# of the range is 2**-8 of the largest value.
+#
+# flash_attention vs mha_core, bf16 in and out: both round the probabilities
+# to bf16 before P.V and the result to bf16, and they accumulate the f32 sums
+# in a different tile order — a few last-place units.
+FLASH_FWD_TOL = 2.0 ** -6
+# ... and the backward adds the bf16 roundings of dS, P and dO on both sides.
+FLASH_BWD_TOL = 2.0 ** -5
+# flash_decode vs its masked-gather reference at f32 ("highest") matmul
+# precision: both compute in f32 from the same stored bf16 / dequantised int8
+# rows, so only the summation order and the final rounding to bf16 differ.
+FLASH_DECODE_TOL = 2.0 ** -7
+
+
+def info(msg: str) -> None:
+    print(f"[info] {msg}", flush=True)
+
+
+def check(ok: bool, what: str) -> None:
+    if not ok:
+        raise AssertionError(f"chip_smoke: {what}")
+    print(f"[ok] {what}", flush=True)
+
+
+def rel_err(got, ref) -> float:
+    import numpy as np
+
+    got = np.asarray(got, np.float32)
+    ref = np.asarray(ref, np.float32)
+    return float(np.max(np.abs(got - ref)) / np.max(np.abs(ref)))
+
+
+def mosaic_calls(compiled_text: str) -> set:
+    """Names of the Mosaic (``tpu_custom_call``) kernels in a compiled
+    program's text. The hot-path ``pl.pallas_call``s carry a ``name``, which
+    XLA keeps as the last scope of the call's ``op_name`` — bare, or inside
+    ``jvp(...)`` / ``transpose(jvp(...))`` for a custom_vjp's two halves."""
+    names = set()
+    for line in compiled_text.splitlines():
+        if 'custom_call_target="tpu_custom_call"' in line:
+            m = re.search(r"(\w+)\)*/pallas_call", line)
+            names.add(m.group(1) if m else "<unnamed>")
+    return names
+
+
+# ------------------------------------------------------------- environment
+def check_environment() -> dict:
+    """Fail before building anything unless JAX runs on a known TPU."""
+    import jax
+
+    devices = jax.devices()
+    dev = devices[0]
+    if dev.platform != "tpu":
+        sys.exit(f"chip_smoke: no TPU — jax.devices()[0].platform is "
+                 f"{dev.platform!r}; this check runs on the chip only")
+    sys.path.insert(0, ROOT)
+    from flexflow_tpu import native
+    from flexflow_tpu.obs.telemetry import detect_peak_flops
+    from flexflow_tpu.utils.compile_cache import ensure_compile_cache
+
+    peak = detect_peak_flops()  # a device_kind outside the table raises
+    cache_dir = ensure_compile_cache()
+    import importlib.metadata as md
+
+    info(f"device platform={dev.platform} kind={dev.device_kind!r} "
+         f"count={len(devices)} peak_bf16_flops={peak:.3g}")
+    info(f"jax {jax.__version__} jaxlib {md.version('jaxlib')} "
+         f"libtpu {md.version('libtpu')}")
+    info(f"compile cache dir: {cache_dir} "
+         f"({'from JAX_COMPILATION_CACHE_DIR' if os.environ.get('JAX_COMPILATION_CACHE_DIR') else 'checkout default'})")
+    info(f"native runtime core: {native.implementation()}")
+    return {"platform": dev.platform, "kind": dev.device_kind,
+            "count": len(devices)}
+
+
+# ------------------------------------------------------- kernel numerics
+def check_flash_attention() -> None:
+    import jax
+    import jax.numpy as jnp
+
+    from flexflow_tpu.kernels.flash_attention import flash_attention
+    from flexflow_tpu.ops.attention import _flash_blocks, mha_core
+
+    shape = (8, 16, 512, 64)
+    kq, kk, kv, kd = jax.random.split(jax.random.PRNGKey(0), 4)
+    q, k, v, do = (jax.random.normal(r, shape, jnp.bfloat16)
+                   for r in (kq, kk, kv, kd))
+    bq, bk = _flash_blocks(shape[2], shape[2])
+
+    def through(core):
+        def loss(q, k, v):
+            out = core(q, k, v)
+            return jnp.sum(out.astype(jnp.float32)
+                           * do.astype(jnp.float32)), out
+        return jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2),
+                                          has_aux=True))
+
+    flash = through(lambda q, k, v: flash_attention(q, k, v, False, bq, bk))
+    ref = through(lambda q, k, v: mha_core(q, k, v))
+    text = flash.lower(q, k, v).compile().as_text()
+    check({"flash_attention_fwd", "flash_attention_bwd_fused"}
+          <= mosaic_calls(text),
+          f"flash_attention {shape} blocks ({bq},{bk}) compiles to Mosaic "
+          f"kernels {sorted(mosaic_calls(text))}")
+    (_, out_f), grads_f = flash(q, k, v)
+    (_, out_r), grads_r = ref(q, k, v)
+    e = rel_err(out_f, out_r)
+    check(e <= FLASH_FWD_TOL,
+          f"flash_attention forward vs mha_core: {e:.2e} <= "
+          f"{FLASH_FWD_TOL:.2e}")
+    for name, gf, gr in zip(("dq", "dk", "dv"), grads_f, grads_r):
+        e = rel_err(gf, gr)
+        check(e <= FLASH_BWD_TOL,
+              f"flash_attention backward {name} vs mha_core: {e:.2e} <= "
+              f"{FLASH_BWD_TOL:.2e}")
+
+
+def check_flash_decode() -> None:
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from flexflow_tpu.kernels.flash_decode import (_reference_decode,
+                                                   flash_decode)
+    from flexflow_tpu.serving.kvcache import quantize_kv
+
+    slots, heads, hd, bs, mb = 8, 12, 64, 16, 16
+    n_blocks = slots * mb + 1
+    kq, kk, kv = jax.random.split(jax.random.PRNGKey(1), 3)
+    q = jax.random.normal(kq, (slots, heads, hd), jnp.bfloat16)
+    kpool = jax.random.normal(kk, (n_blocks, heads, bs, hd), jnp.bfloat16)
+    vpool = jax.random.normal(kv, (n_blocks, heads, bs, hd), jnp.bfloat16)
+    rng = np.random.default_rng(1)
+    # every slot its own shuffled run of blocks; lengths from 1 key to full
+    tables = jnp.asarray(
+        1 + rng.permutation(slots * mb).reshape(slots, mb), jnp.int32)
+    n_keys = jnp.asarray([1, 15, 16, 17, 100, 200, 255, 256], jnp.int32)
+    scale = 1.0 / np.sqrt(hd)
+    ref = _reference_decode()
+    pools = {"native": (kpool, vpool, None, None)}
+    k8, ks = quantize_kv(kpool)
+    v8, vs = quantize_kv(vpool)
+    pools["int8"] = (k8, v8, ks, vs)
+    for label, (kp, vp, ksc, vsc) in pools.items():
+        fn = jax.jit(lambda q, kp, vp, t, n, ksc=ksc, vsc=vsc: flash_decode(
+            q, kp, vp, t, n, sm_scale=scale, kscale=ksc, vscale=vsc))
+        text = fn.lower(q, kp, vp, tables, n_keys).compile().as_text()
+        check("flash_decode" in mosaic_calls(text),
+              f"flash_decode ({label} pool, {heads} heads x {hd}, block "
+              f"{bs}) compiles to a Mosaic kernel")
+        out = fn(q, kp, vp, tables, n_keys)
+        with jax.default_matmul_precision("highest"):
+            want = ref(q, kp, vp, tables, n_keys, scale, ksc, vsc)
+        e = rel_err(out, want)
+        check(e <= FLASH_DECODE_TOL,
+              f"flash_decode ({label} pool) vs masked-gather reference: "
+              f"{e:.2e} <= {FLASH_DECODE_TOL:.2e}")
+
+
+# ------------------------------------------------------------------ trainer
+def build_trainer(cfg, argv):
+    """FFConfig -> FFModel -> build_bert -> compile(), bf16 compute, Adam."""
+    from flexflow_tpu import AdamOptimizer, FFConfig, FFModel, LossType
+    from flexflow_tpu.models.bert import build_bert
+
+    config = FFConfig()
+    config.parse_args(["-b", str(cfg.batch_size), "--compute-dtype", "bf16"]
+                      + argv)
+    ff = FFModel(config)
+    build_bert(ff, cfg)
+    ff.compile(optimizer=AdamOptimizer(ff, alpha=TRAIN_LR),
+               loss_type=LossType.LOSS_SPARSE_CATEGORICAL_CROSSENTROPY)
+    return ff
+
+
+def synthetic_batch(cfg):
+    import numpy as np
+
+    rng = np.random.default_rng(0)
+    x1 = rng.normal(size=(cfg.batch_size, cfg.seq_len, cfg.hidden)
+                    ).astype(np.float32)
+    y1 = rng.integers(0, cfg.num_classes, size=(cfg.batch_size,)
+                      ).astype(np.int32)
+    return x1, y1
+
+
+def fit_repeated(ff, cfg, batch, steps: int):
+    """fit() ``steps`` steps on one repeated batch; per-step (losses,
+    walls) from fit()'s telemetry."""
+    import numpy as np
+
+    x1, y1 = batch
+    ff.fit(np.tile(x1, (steps, 1, 1)), np.tile(y1, steps),
+           batch_size=cfg.batch_size, epochs=1, shuffle=False)
+    tel = ff.get_telemetry()
+    return list(tel.loss_history), list(tel.step_wall_s)
+
+
+def device_batch(ff, batch):
+    """The batch as the train step takes it: sharded like fit() shards it."""
+    import jax
+
+    x1, y1 = batch
+    return ([jax.device_put(x1, ff.executor.batch_sharding(3))],
+            jax.device_put(y1[:, None], ff.executor.batch_sharding(2)))
+
+
+def train_step_text(ff, batch) -> str:
+    """Compiled text of the train step fit() ran."""
+    import jax
+
+    xd, yd = device_batch(ff, batch)
+    return ff.executor.make_train_step().lower(
+        ff.params, ff.opt_state, xd, yd, jax.random.PRNGKey(0)
+    ).compile().as_text()
+
+
+def on_distinct_devices(arrays) -> int:
+    """Fewest distinct devices any of ``arrays`` has shards on."""
+    return min(len({s.device for s in a.addressable_shards})
+               for a in arrays)
+
+
+def train_phase(cfg, n_chips: int, steps: int, steps_again: int):
+    """compile() + fit() twice on one repeated synthetic batch. Returns
+    (losses, compile seconds, compiled train-step text)."""
+    import jax
+    import numpy as np
+
+    t0 = time.perf_counter()
+    ff = build_trainer(cfg, ["--only-data-parallel"] if n_chips > 1 else [])
+    build_s = time.perf_counter() - t0
+    check(int(ff.mesh.devices.size) == n_chips,
+          f"trainer mesh {dict(ff.mesh.shape)} spans all {n_chips} chip(s)")
+
+    batch = synthetic_batch(cfg)
+    losses, walls = [], []
+    for n in (steps, steps_again):
+        # fit() a second time on the same model: the step donates params
+        # and optimizer state, so a stale reference would be a deleted array
+        more_losses, more_walls = fit_repeated(ff, cfg, batch, n)
+        losses += more_losses
+        walls += more_walls
+    if n_chips > 1:
+        check(on_distinct_devices(jax.tree_util.tree_leaves(ff.params)
+                                  + device_batch(ff, batch)[0]) == n_chips,
+              f"every parameter and the batch have shards on {n_chips} "
+              f"distinct devices")
+    info(f"trainer: build+init {build_s:.1f} s, first step (compile) "
+         f"{walls[0]:.1f} s, steady step "
+         f"{1e3 * float(np.median(walls[2:steps])):.1f} ms "
+         f"(median of steps 2..{steps - 1}, each synced for the loss)")
+    return losses, walls[0], train_step_text(ff, batch)
+
+
+# ------------------------------------------------------------------- server
+def serve_phase(cfg, max_new_tokens: int):
+    """ServingEngine.generate twice on one engine; the second wave repeats
+    the first wave's prompts, so it runs on prefix-cache hits. Returns
+    (first-wave streams, engine, first-wave wall seconds)."""
+    import numpy as np
+
+    from flexflow_tpu import FFConfig, FFModel, LossType
+    from flexflow_tpu.models.gpt2 import build_gpt2
+    from flexflow_tpu.serving import ServingEngine
+
+    config = FFConfig()
+    # one replica sits on one chip, also on a four-chip host (every replica
+    # of a ServingFleet shares its model's mesh; four one-chip replicas are
+    # ROADMAP R2)
+    config.parse_args(["-b", str(cfg.batch_size), "--compute-dtype", "bf16",
+                       "--only-data-parallel", "--mesh-shape", "1"])
+    ff = FFModel(config)
+    build_gpt2(ff, cfg)
+    ff.compile(loss_type=LossType.LOSS_SPARSE_CATEGORICAL_CROSSENTROPY)
+    check(int(ff.mesh.devices.size) == 1, "server replica sits on one chip")
+    eng = ServingEngine(ff, n_slots=8, max_decode_len=256)
+    check((eng.kv_cache, eng.serve_loop, eng.exact_decode,
+           eng._prefix is not None) == ("paged", "sync", False, True),
+          "server runs the default path: paged KV, prefix cache on, sync "
+          "loop, fast decode")
+
+    rng = np.random.default_rng(0)
+    lengths = [5, 12, 17, 30, 33, 48, 64, 70, 90, 100, 120]
+    prompts = [rng.integers(0, cfg.vocab_size, size=n).tolist()
+               for n in lengths]
+    prompts.append(list(prompts[4]))  # the same prompt twice
+
+    t0 = time.perf_counter()
+    first = eng.generate(prompts, max_new_tokens=max_new_tokens)
+    cold_s = time.perf_counter() - t0
+    _check_wave(eng, cfg, prompts, first, max_new_tokens, "first wave")
+    check(first[4] == first[-1],
+          "the two equal prompts give equal greedy streams")
+    p50 = eng.stats.p50_token_ms()
+
+    t0 = time.perf_counter()
+    again = eng.generate(prompts, max_new_tokens=max_new_tokens)
+    warm_s = time.perf_counter() - t0
+    _check_wave(eng, cfg, prompts, again, max_new_tokens, "second wave")
+    check(eng.stats.prefix_hits > 0,
+          f"second generate() on the same engine reuses cached prefixes "
+          f"({eng.stats.prefix_hits} hits, "
+          f"{eng.stats.prefix_tokens_reused} prompt tokens reused)")
+    agree = float(np.mean([a == b for a, b in zip(first, again)]))
+    info(f"server: first generate() {cold_s:.1f} s (compiles every prefill "
+         f"bucket and the decode step), second {warm_s:.2f} s; p50 token "
+         f"{p50:.2f} ms first wave, {eng.stats.p50_token_ms():.2f} ms "
+         f"second; {eng.stats.tokens_generated} tokens/wave; "
+         f"{agree:.0%} of streams identical across the cold and "
+         f"prefix-hit waves (bf16 fast decode promises no bitwise match)")
+    return first, eng, cold_s
+
+
+def _check_wave(eng, cfg, prompts, streams, max_new_tokens, label) -> None:
+    check(eng.stats.outcomes == {"ok": len(prompts)},
+          f"{label}: every request leaves with outcome ok "
+          f"({eng.stats.outcomes})")
+    check(all(len(s) == max_new_tokens for s in streams),
+          f"{label}: every stream has {max_new_tokens} tokens")
+    check(all(0 <= t < cfg.vocab_size for s in streams for t in s),
+          f"{label}: every token is inside the vocabulary")
+    check(eng.decode_compiles == 1,
+          f"{label}: decode_compiles == 1 (got {eng.decode_compiles})")
+
+
+def decode_step_text(eng) -> str:
+    """Compiled text of the decode step the engine just served with."""
+    import jax.numpy as jnp
+
+    fn = eng._decode_fn(guard=eng._last_guard)
+    tokens = jnp.zeros((eng.n_slots, 1), jnp.int32)
+    return fn.lower(eng.model.params, [tokens], eng.state
+                    ).compile().as_text()
+
+
+# --------------------------------------------------------------------- main
+def main() -> None:
+    t_start = time.perf_counter()
+    device = check_environment()
+    import numpy as np
+
+    from flexflow_tpu.models.bert import BertConfig
+    from flexflow_tpu.models.gpt2 import GPT2Config
+    from flexflow_tpu.obs import enable as obs_enable
+
+    obs_enable()  # per-step losses and walls come from fit()'s telemetry
+
+    check_flash_attention()
+    check_flash_decode()
+
+    n_chips = device["count"]
+    bert = BertConfig(batch_size=8 * n_chips, seq_len=512, hidden=1024,
+                      num_heads=16, num_layers=24, intermediate=4096)
+    losses, train_compile_s, text = train_phase(
+        bert, n_chips, TRAIN_STEPS, TRAIN_STEPS_AGAIN)
+    check(bool(np.all(np.isfinite(losses))),
+          f"trainer: loss finite at all {len(losses)} steps "
+          f"({', '.join(f'{v:.4f}' for v in losses)})")
+    check(losses[TRAIN_STEPS - 1] < losses[0],
+          f"trainer: loss fell over the first fit() "
+          f"({losses[0]:.4f} -> {losses[TRAIN_STEPS - 1]:.4f})")
+    check({"flash_attention_fwd", "flash_attention_bwd_fused"}
+          <= mosaic_calls(text),
+          f"train step runs the flash_attention Mosaic kernels, forward "
+          f"and backward ({sorted(mosaic_calls(text))})")
+
+    gpt2 = GPT2Config(batch_size=8, seq_len=256, hidden=768, num_heads=12,
+                      num_layers=12, intermediate=3072, vocab_size=50257)
+    _streams, eng, serve_cold_s = serve_phase(gpt2, MAX_NEW_TOKENS)
+    calls = mosaic_calls(decode_step_text(eng))
+    check("flash_decode" in calls,
+          f"decode step runs the flash_decode Mosaic kernel "
+          f"({sorted(calls)})")
+
+    info(f"compile seconds: trainer first step {train_compile_s:.1f}, "
+         f"server first generate() {serve_cold_s:.1f}; total wall "
+         f"{time.perf_counter() - t_start:.1f} s")
+    print(json.dumps({"ok": True, "device": device}), flush=True)
+
+
+if __name__ == "__main__":
+    main()

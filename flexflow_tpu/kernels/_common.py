@@ -9,11 +9,22 @@ import jax
 DEFAULT_BLOCK_ROWS = 8
 
 
+def on_tpu() -> bool:
+    """Is the process's first device a TPU? The one routing query behind
+    every kernel gate; a failing device query propagates."""
+    return jax.devices()[0].platform == "tpu"
+
+
 def resolve_interpret(interpret: Optional[bool]) -> bool:
-    """Default to interpret mode off-TPU (the CPU test mesh)."""
-    if interpret is not None:
-        return interpret
-    return jax.default_backend() != "tpu"
+    """Interpret mode defaults on for the CPU test mesh and is refused on
+    a TPU: on the chip every Pallas call compiles through Mosaic."""
+    if interpret is None:
+        return not on_tpu()
+    if interpret and on_tpu():
+        raise RuntimeError(
+            "Pallas interpret mode requested on a TPU backend; kernels "
+            "compile through Mosaic on the chip")
+    return interpret
 
 
 def pick_block_rows(rows: int, dim: int) -> int:

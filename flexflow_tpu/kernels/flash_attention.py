@@ -31,8 +31,9 @@ one extra bf16 rounding versus scaling the f32 score tile in-kernel. The
 error is bounded by one bf16 ulp per element ahead of the f32 accumulation
 and sits inside the parity tests' bf16 tolerances; see _flash_forward.
 
-Falls back transparently to the einsum core off-TPU (interpret mode is used in
-tests)."""
+Off-TPU the attention op routes to the einsum core instead
+(ops/attention._should_use_flash); tests run these kernels in interpret
+mode."""
 from __future__ import annotations
 
 import functools
@@ -40,6 +41,8 @@ from typing import Optional
 
 import jax
 import numpy as np
+
+from ._common import resolve_interpret as _resolve_interpret
 
 DEFAULT_BLOCK_Q = 128
 DEFAULT_BLOCK_K = 128
@@ -51,25 +54,13 @@ FUSED_BWD_RESIDENT_BUDGET = 5 * 2 ** 20
 # Unroll the fused backward's q loop with STATIC slices up to this many
 # tiles (dynamic-slice reads defeat the Mosaic vectorizer, ~10% on v5e).
 MAX_UNROLL_QB = 16
-# Per-core VMEM scope the backward schedules must fit inside a full train
-# step (v5e/v5p expose 16 MB to a Pallas kernel next to XLA's own buffers).
-VMEM_SCOPE_BYTES = 16 * 2 ** 20
+# Widest k tile of the fused one-pass backward. Measured on the v5e inside
+# the full train step (2026-09-26, jax 0.9.0 / libtpu 0.0.34): at s4096 d64 a
+# (512, 1024) tile runs out of VMEM — the resident Q/dO/O/dq and the
+# (seq_q, 1) lse block are lane-padded to 128 and double-buffered, which a
+# byte count of the unpadded arrays (the r18 estimate this replaces) missed.
+FUSED_BWD_MAX_BLOCK_K = 512
 NEG_INF = -1e30
-
-
-def _fused_bwd_vmem_bytes(seq_q: int, d: int, block_q: int,
-                          block_k: int) -> int:
-    """VMEM footprint of the fused one-pass backward at a given tiling:
-    the resident Q/dO/O/dq-out (bf16) plus the (seq_q, d) f32 dq scratch
-    (~10*seq_q*d bytes), three (block_q, block_k) f32 score-sized tiles in
-    flight (s, p, dp), and the streamed K/V bf16 tiles. Used to decide when
-    the k tile can be WIDER than the conservative 512 cap: short sequences
-    leave most of the scope unused, and wider k tiles amortize the resident
-    re-reads across fewer grid steps."""
-    resident = 10 * seq_q * d
-    score_tiles = 3 * block_q * block_k * 4
-    kv_tiles = 2 * block_k * d * 2
-    return resident + score_tiles + kv_tiles
 
 
 def dropout_keep_scale(seed, bh, q_start, k_start, block_q, block_k,
@@ -325,6 +316,7 @@ def _flash_forward(q, k, v, causal: bool, block_q: int, block_k: int,
         compiler_params=_compiler_params(
             interpret, ("parallel", "parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_attention_fwd",
     )(seed_arr, q, k, v)
     return out, lse.reshape(batch, heads, seq_q)
 
@@ -599,6 +591,7 @@ def _flash_backward(q, k, v, out, lse, do, causal: bool, block_q: int,
             compiler_params=_compiler_params(
                 interpret, ("parallel", "parallel", "arbitrary")),
             interpret=interpret,
+            name="flash_attention_bwd_fused",
         )(seed_arr, q, k, v, dor, lser, out)
         return dq, dk, dv
 
@@ -627,6 +620,7 @@ def _flash_backward(q, k, v, out, lse, do, causal: bool, block_q: int,
         compiler_params=_compiler_params(
             interpret, ("parallel", "parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_attention_bwd_dkv",
     )(seed_arr, q, k, v, dor, lser, delta)
 
     res_q = pl.BlockSpec((1, 1, block_q, d), lambda b, h, i, j: (b, h, i, 0))
@@ -648,6 +642,7 @@ def _flash_backward(q, k, v, out, lse, do, causal: bool, block_q: int,
         compiler_params=_compiler_params(
             interpret, ("parallel", "parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_attention_bwd_dq",
     )(seed_arr, q, k, v, dor, lser, delta)
 
     return dq, dk, dv
@@ -681,21 +676,19 @@ def _flash_attention_p(q, k, v, seed, causal, block_q, block_k, interpret,
 
 def _bwd_blocks(block_q: int, block_k: int, bwd_block_q, bwd_block_k,
                 seq_q: int, seq_k: int, head_dim: Optional[int] = None):
-    """Backward block defaults are SCHEDULE-AWARE (r18):
+    """Backward block defaults are SCHEDULE-AWARE:
 
     - Fused one-pass (seq_q*d*10 <= FUSED_BWD_RESIDENT_BUDGET): keeps three
       (block_q, block_k) f32 score-sized tiles in flight NEXT TO the
-      resident Q/dO/O/dq, so block_k defaults to the measured 512 cap —
-      (512, 512) timed the same 2.16 ms/layer as (512, 1024) on v5e —
-      UNLESS _fused_bwd_vmem_bytes says the forward-width tile still fits
-      the 16 MB scope (short sequences), in which case the wider forward
-      block wins back the resident re-read amortization.
+      resident Q/dO/O/dq, so block_k is capped at FUSED_BWD_MAX_BLOCK_K —
+      (512, 512) timed the same 2.16 ms/layer as (512, 1024) standalone on
+      v5e, and inside the train step the wider tile does not fit VMEM.
     - Two-pass streaming (past the residency budget): VMEM is O(block),
       so the k tile defaults to the full forward block — 1024-wide k tiles
       are the forward sweet spot and the long-context (8k-32k) backward
       spends its time streaming K/V, where wider tiles cut grid overhead.
 
-    Without head_dim (legacy callers) the conservative 512 cap applies.
+    Without head_dim (legacy callers) the fused cap applies.
 
     Divisibility is re-checked against the sequences: a default that no
     longer divides seq_k falls back to the (valid) forward block, and an
@@ -709,14 +702,9 @@ def _bwd_blocks(block_q: int, block_k: int, bwd_block_q, bwd_block_k,
                 f"sequence length {seq_q}")
         bq = block_q  # forward block divides by the public contract
 
-    k_default = min(block_k, 512)
-    if head_dim is not None:
-        fused = seq_q * head_dim * 10 <= FUSED_BWD_RESIDENT_BUDGET
-        if not fused:
-            k_default = block_k
-        elif _fused_bwd_vmem_bytes(seq_q, head_dim, min(bq, seq_q),
-                                   block_k) <= VMEM_SCOPE_BYTES:
-            k_default = block_k
+    fused = head_dim is None or \
+        seq_q * head_dim * 10 <= FUSED_BWD_RESIDENT_BUDGET
+    k_default = min(block_k, FUSED_BWD_MAX_BLOCK_K) if fused else block_k
 
     bk = bwd_block_k if bwd_block_k is not None else k_default
     if seq_k % min(bk, seq_k) != 0:
@@ -760,14 +748,6 @@ def _check_causal_shape(q, k, causal: bool) -> None:
         raise ValueError(
             f"flash_attention causal requires seq_q <= seq_k, got "
             f"{q.shape[-2]} > {k.shape[-2]}; use the einsum core instead")
-
-
-def _resolve_interpret(interpret: Optional[bool]) -> bool:
-    if interpret is not None:
-        return interpret
-    import jax
-
-    return jax.default_backend() != "tpu"
 
 
 def _fwd(q, k, v, seed, causal, block_q, block_k, interpret, dropout,

@@ -22,11 +22,14 @@ slot actually occupies, so per-token traffic is O(true_length):
   bytes plus the f32 scale vectors (the bandwidth the serving search's
   ``kv_dtype`` axis prices).
 
-Tile tuning rides the per-generation FLASH_TUNING machinery
-(``ops.attention._flash_tuning(kernel="flash_decode")`` at the routing
-site — an unmeasured generation warns once per kernel, ISSUE 12
-satellite). Off-TPU the op layer never routes here (the masked gather
-path keeps tier-1 CPU-green); tests run the kernel in interpret mode.
+Off-TPU the op layer never routes here (the masked gather path keeps
+tier-1 CPU-green); tests run the kernel in interpret mode.
+
+Mosaic wants a free (row) dimension on the left operand of a matmul, so
+the one-token query rides as a unit row: q is ``(heads, 1, head_dim)`` and
+the two products are ordinary head-batched matmuls ``hqd,hkd->hqk`` and
+``hqk,hkd->hqd`` — the (m, l, acc) state and the output block carry the
+same unit row.
 """
 from __future__ import annotations
 
@@ -39,16 +42,13 @@ NEG_INF = -1e30
 def use_flash_decode(head_dim: int, block_size: int) -> bool:
     """Routing gate for the serving attention op: real-TPU platform and
     MXU/VPU-friendly dims (lane-padded head_dim, whole-sublane blocks).
-    The CPU fallback (gather + masked einsum) is the correctness path —
+    The CPU path (gather + masked einsum) is the correctness path —
     this kernel is the bandwidth path."""
+    from ._common import on_tpu
+
     if block_size < 8 or block_size % 8 != 0 or head_dim % 64 != 0:
         return False
-    try:
-        import jax
-
-        return jax.devices()[0].platform == "tpu"
-    except Exception:
-        return False
+    return on_tpu()
 
 
 def _decode_kernel(tab_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
@@ -72,7 +72,7 @@ def _decode_kernel(tab_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
 
     @pl.when(j * block_size < n_keys)
     def _step():
-        q = q_ref[0].astype(jnp.float32)          # (h, hd), pre-scaled
+        q = q_ref[0].astype(jnp.float32)          # (h, 1, hd), pre-scaled
         k = k_ref[0]                              # (h, bs, kd)
         v = v_ref[0]                              # (h, bs, vd)
         if kv_dtype == "int8":
@@ -81,29 +81,27 @@ def _decode_kernel(tab_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
         else:
             k = k.astype(jnp.float32)
             v = v.astype(jnp.float32)
-        # (h, bs) score tile: per-head q row against the block's keys
-        s_tile = jax.lax.dot_general(
-            q, k, (((1,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)
+        # (h, 1, bs) score tile: per-head q row against the block's keys
+        s_tile = jnp.einsum("hqd,hkd->hqk", q, k,
+                            preferred_element_type=jnp.float32)
         kpos = j * block_size + jax.lax.broadcasted_iota(
-            jnp.int32, s_tile.shape, 1)
+            jnp.int32, s_tile.shape, 2)
         s_tile = jnp.where(kpos < n_keys, s_tile, NEG_INF)
-        m_prev = m_ref[:, :1]                     # (h, 1)
+        m_prev = m_ref[:, :, :1]                  # (h, 1, 1)
         m_new = jnp.maximum(m_prev, jnp.max(s_tile, axis=-1,
                                             keepdims=True))
-        p = jnp.exp(s_tile - m_new)               # (h, bs)
-        corr = jnp.exp(m_prev - m_new)            # (h, 1)
-        pv = jax.lax.dot_general(
-            p, v, (((1,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)   # (h, vd)
+        p = jnp.exp(s_tile - m_new)               # (h, 1, bs)
+        corr = jnp.exp(m_prev - m_new)            # (h, 1, 1)
+        pv = jnp.einsum("hqk,hkd->hqd", p, v,
+                        preferred_element_type=jnp.float32)  # (h, 1, vd)
         acc_ref[:] = acc_ref[:] * corr + pv
-        l_new = l_ref[:, :1] * corr + jnp.sum(p, axis=-1, keepdims=True)
+        l_new = l_ref[:, :, :1] * corr + jnp.sum(p, axis=-1, keepdims=True)
         m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
         l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
 
     @pl.when(j == n_blocks_grid - 1)
     def _finish():
-        o_ref[0] = (acc_ref[:] / l_ref[:, :1]).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[:] / l_ref[:, :, :1]).astype(o_ref.dtype)
 
 
 def flash_decode(q, kpool, vpool, block_tables, n_keys, *,
@@ -119,12 +117,14 @@ def flash_decode(q, kpool, vpool, block_tables, n_keys, *,
     n_keys       (n_slots,) int32 — keys each slot attends (position + 1)
 
     Returns (n_slots, heads, vd) in q's dtype. ``interpret=True`` runs
-    the Mosaic interpreter (the CPU test path)."""
+    the Mosaic interpreter (the CPU test path; refused on a TPU)."""
     import jax
     import jax.numpy as jnp
     import numpy as np
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
+
+    from ._common import resolve_interpret
 
     n_slots, heads, head_dim = q.shape
     n_blocks, _h, block_size, kd = kpool.shape
@@ -135,7 +135,7 @@ def flash_decode(q, kpool, vpool, block_tables, n_keys, *,
         raise ValueError("flash_decode: int8 pools need kscale/vscale")
     scale = sm_scale if sm_scale is not None else 1.0 / np.sqrt(head_dim)
     out_dtype = q.dtype
-    q = (q.astype(jnp.float32) * jnp.float32(scale))
+    q = (q.astype(jnp.float32) * jnp.float32(scale))[:, :, None, :]
     tables = block_tables.astype(jnp.int32)
     n_keys = n_keys.astype(jnp.int32)
 
@@ -151,7 +151,8 @@ def flash_decode(q, kpool, vpool, block_tables, n_keys, *,
         return block_index(s, j, tab_ref, len_ref)[:3]
 
     in_specs = [
-        pl.BlockSpec((1, heads, head_dim), lambda s, j, t, n: (s, 0, 0)),
+        pl.BlockSpec((1, heads, 1, head_dim),
+                     lambda s, j, t, n: (s, 0, 0, 0)),
         pl.BlockSpec((1, heads, block_size, kd), block_index),
         pl.BlockSpec((1, heads, block_size, vd), block_index),
     ]
@@ -178,20 +179,22 @@ def flash_decode(q, kpool, vpool, block_tables, n_keys, *,
         num_scalar_prefetch=2,
         grid=(n_slots, mb),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, heads, vd), lambda s, j, t, n: (s, 0, 0)),
+        out_specs=pl.BlockSpec((1, heads, 1, vd),
+                               lambda s, j, t, n: (s, 0, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((heads, 128), jnp.float32),  # m
-            pltpu.VMEM((heads, 128), jnp.float32),  # l
-            pltpu.VMEM((heads, vd), jnp.float32),   # acc
+            pltpu.VMEM((heads, 1, 128), jnp.float32),  # m
+            pltpu.VMEM((heads, 1, 128), jnp.float32),  # l
+            pltpu.VMEM((heads, 1, vd), jnp.float32),   # acc
         ],
     )
     fn = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n_slots, heads, vd), out_dtype),
-        interpret=interpret,
+        out_shape=jax.ShapeDtypeStruct((n_slots, heads, 1, vd), out_dtype),
+        interpret=resolve_interpret(interpret),
+        name="flash_decode",
     )
-    return fn(tables, n_keys, *args)
+    return fn(tables, n_keys, *args)[:, :, 0, :]
 
 
 @functools.lru_cache(maxsize=1)

@@ -22,7 +22,8 @@ from typing import Optional
 import jax
 import numpy as np
 
-from ._common import (pick_block_rows as _pick_block_rows,
+from ._common import (on_tpu as _on_tpu,
+                      pick_block_rows as _pick_block_rows,
                       resolve_interpret as _resolve_interpret)
 
 
@@ -103,7 +104,4 @@ def should_use_pallas_softmax(x, axis: int, opt_in: bool = False) -> bool:
     rows = int(np.prod(x.shape[:-1])) if x.ndim > 1 else 1
     if rows == 0 or x.shape[-1] == 0:
         return False
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:
-        return False
+    return _on_tpu()

@@ -25,7 +25,8 @@ from typing import Optional, Tuple
 import jax
 import numpy as np
 
-from ._common import (pick_block_rows as _pick_block_rows,
+from ._common import (on_tpu as _on_tpu,
+                      pick_block_rows as _pick_block_rows,
                       resolve_interpret as _resolve_interpret)
 
 MAX_PALLAS_K = 8  # the unrolled-sweep formulation only pays off for small k
@@ -124,7 +125,4 @@ def should_use_pallas_topk(x, k: int, opt_in: bool = False) -> bool:
     if not jnp.issubdtype(x.dtype, jnp.floating) or \
             jnp.dtype(x.dtype).itemsize > 4:
         return False
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:
-        return False
+    return _on_tpu()

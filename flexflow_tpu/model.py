@@ -555,6 +555,9 @@ class FFModel:
         from .ops.base import op_class_for
         from .resilience.preflight import (preflight_config,
                                            preflight_strategy)
+        from .utils.compile_cache import ensure_compile_cache
+
+        ensure_compile_cache()
 
         # flag-combination sanity before any expensive work (ISSUE 5;
         # parse-time single-flag checks live in FFConfig.parse_args, this
@@ -765,11 +768,8 @@ class FFModel:
 
     def _run_search(self, pcg, n_dev):
         from .parallel.strategy import data_parallel_strategy
+        from .search.unity import SearchResult, unity_search
 
-        try:
-            from .search.unity import unity_search
-        except ImportError:
-            return data_parallel_strategy(pcg, n_dev)
         # --search-num-nodes/--search-num-workers: search for a TARGET
         # machine that may differ from the one we are running on (reference:
         # graph.cc:1892-1897 overrides numNodes/workersPerNode for the
@@ -827,8 +827,6 @@ class FFModel:
         # its sink the same way via the output-shape contract).
         # _search_sim: an elastic restart hands the previous search's warm
         # Simulator in so the re-plan reuses its memoized delta-cost tables
-        from .search.unity import SearchResult
-
         res = unity_search(pcg, self.config, n_dev,
                            protected_guids=(self.final_guid,),
                            return_result=True,

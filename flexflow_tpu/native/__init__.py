@@ -1,41 +1,61 @@
 """Native (C++) runtime core, loaded via ctypes.
 
-Builds lazily with g++ on first use (no pybind11 in the image; plain C ABI).
-Every entry point has a pure-Python fallback so the framework works without a
-compiler — but the native path is the default where it matters (dataloader
-gather, search-time task-graph simulation).
+Builds lazily with g++ on first use (no pybind11 in the image; plain C ABI)
+from ``ffnative.cpp`` and nothing else: the shared object is named by the
+hash of that source, so an object built from other source is never loaded.
+Every entry point has a pure-Python twin (the tests' reference); when the
+build fails the twins take over and a warning says so, once. The native
+path is the default where it matters (dataloader gather, search-time
+task-graph simulation).
 """
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
+import warnings
 from typing import Optional
 
 import numpy as np
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_HERE, "ffnative.cpp")
-_SO = os.path.join(_HERE, "libffnative.so")
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 _build_failed = False
 
 
-def _build() -> bool:
+def _so_path() -> str:
+    with open(_SRC, "rb") as f:
+        tag = hashlib.sha256(f.read()).hexdigest()[:12]
+    return os.path.join(_HERE, f"libffnative-{tag}.so")
+
+
+def _build(so: str) -> None:
+    """Compile ``ffnative.cpp`` to ``so``; atomic, so concurrent first
+    uses (pytest workers, replicas) never load a half-written object."""
+    tmp = f"{so}.{os.getpid()}.tmp"
     try:
         subprocess.run(
             ["g++", "-O3", "-shared", "-fPIC", "-pthread", "-std=c++17",
-             _SRC, "-o", _SO],
+             _SRC, "-o", tmp],
             check=True, capture_output=True, timeout=120)
-        return True
-    except Exception:
-        return False
+        os.replace(tmp, so)
+    finally:
+        if os.path.exists(tmp):
+            os.remove(tmp)
+
+
+def implementation() -> str:
+    """Which implementation serves this process: "native" or "python"."""
+    return "native" if get_lib() is not None else "python"
 
 
 def get_lib() -> Optional[ctypes.CDLL]:
-    """Load (building if needed) the native library; None if unavailable."""
+    """Load (building if needed) the native library; None if the build
+    failed (said once, as a warning)."""
     global _lib, _build_failed
     if _lib is not None:
         return _lib
@@ -44,16 +64,22 @@ def get_lib() -> Optional[ctypes.CDLL]:
     with _lock:
         if _lib is not None:
             return _lib
-        if not os.path.exists(_SO) or (
-                os.path.exists(_SRC)
-                and os.path.getmtime(_SRC) > os.path.getmtime(_SO)):
-            if not _build():
-                _build_failed = True
-                return None
+        if _build_failed:
+            return None
+        so = _so_path()
         try:
-            lib = ctypes.CDLL(_SO)
-        except OSError:
+            if not os.path.exists(so):
+                _build(so)
+            lib = ctypes.CDLL(so)
+        except (OSError, subprocess.CalledProcessError,
+                subprocess.TimeoutExpired) as e:
             _build_failed = True
+            detail = getattr(e, "stderr", b"") or b""
+            warnings.warn(
+                f"flexflow_tpu.native: building {os.path.basename(_SRC)} "
+                f"failed ({type(e).__name__}: {e}); the pure-Python "
+                f"implementations are in use. "
+                f"{detail.decode(errors='replace')[-400:]}")
             return None
         lib.gather_rows.restype = ctypes.c_int
         lib.gather_rows.argtypes = [

@@ -133,11 +133,8 @@ class MultiHeadAttentionOp(Op):
                                      dropout=live_dropout, seed=seed)
         elif _should_use_flash(use_flash, q, k, causal) \
                 and _flash_blocks(q.shape[-2], k.shape[-2]) is not None:
-            from ..kernels.flash_attention import flash_attention
-
-            bq, bk = _flash_blocks(q.shape[-2], k.shape[-2])
-            out = flash_attention(q, k, v, causal, bq, bk,
-                                  dropout=live_dropout, seed=seed)
+            out = _flash_on_mesh(q, k, v, causal, live_dropout, seed,
+                                 ctx.mesh)
         else:
             # the already-resolved live_dropout is the single gate (the r5
             # warning path); rng only rides along when dropout is live, so
@@ -443,9 +440,7 @@ def _chunk_prefill_attention(name: str, q, k, v, sv):
 def _maybe_flash_decode(q, entry, tables, sv, sm_scale):
     """Route one paged decode read through the Pallas flash-decode kernel
     when eligible (on-TPU, non-exact numerics, MXU-friendly dims) —
-    returns the (S, h, 1, hd) output or None for the gather fallback.
-    Consults ``_flash_tuning(kernel="flash_decode")`` so an unmeasured
-    chip generation warns once for THIS kernel (ISSUE 12 satellite)."""
+    returns the (S, h, 1, hd) output or None for the gather path."""
     from ..kernels.flash_decode import flash_decode, use_flash_decode
 
     if (sv.exact or sv.seq_shards > 1
@@ -454,7 +449,6 @@ def _maybe_flash_decode(q, entry, tables, sv, sm_scale):
         # per segment over the gathered extent (_seqpar_decode); the
         # single whole-extent kernel launch would bypass the combine
         return None
-    _flash_tuning(kernel="flash_decode")  # per-(generation, kernel) warn
     n_keys = sv.positions + 1
     if sv.kv_dtype == "int8":
         kq, ks, vq, vs = entry
@@ -495,76 +489,37 @@ def _resolve_live_dropout(dropout, ctx) -> float:
     return float(dropout)
 
 
-# Flash crossover/tile constants, keyed by TPU generation (VERDICT r4
-# weak #7: these are hardware-generation-specific). ONLY the v5e row is
-# MEASURED (the chip of this image, round-5 streaming kernels, b1 h16
-# s4096 d64 bf16 sweep: (block_q 512, block_k 1024) fwd 1.72 ms /
-# fwd+fused-bwd 3.58 ms vs 4.6 ms at (512,512) and 7.8 ms at (256,256);
-# wider k tiles amortize the per-grid-step scratch round-trip, block_k >
-# 1024 overflows VMEM in the fused backward's score tile; min_block 256:
-# at 128-wide tiles — e.g. seq 640's only divisor — the einsum core wins).
-# Other generations inherit the v5e numbers as UNMEASURED estimates;
-# re-measure recipe: on the target chip, time
+# Flash crossover/tile constants, keyed by TPU generation (these are
+# hardware-generation-specific). ONLY the v5e row is MEASURED (the chip of
+# this installation, round-5 streaming kernels, b1 h16 s4096 d64 bf16
+# sweep: (block_q 512, block_k 1024) fwd 1.72 ms / fwd+fused-bwd 3.58 ms vs
+# 4.6 ms at (512,512) and 7.8 ms at (256,256); wider k tiles amortize the
+# per-grid-step scratch round-trip, block_k > 1024 overflows VMEM in the
+# fused backward's score tile; min_block 256: at 128-wide tiles — e.g. seq
+# 640's only divisor — the einsum core wins). A TPU generation without a
+# row is an error (_flash_tuning): add its row after running this recipe on
+# that chip — time
 # jax.jit(jax.grad(lambda q,k,v: flash_attention(q,k,v,False,bq,bk).sum()))
 # at b1 h16 s4096 d64 bf16 over (bq, bk) in {128,256,512}x{256,512,1024}
-# and vs mha_core at seq 640, then update the row.
+# and vs mha_core at seq 640.
 FLASH_TUNING = {
-    # v5e is the only MEASURED row; _flash_tuning() falls back to it for
-    # every other generation (v4/v5p/v6e: add a measured row here after
-    # running the recipe above on that chip)
     "v5e": {"block_q_cap": 512, "block_k_cap": 1024, "min_block": 256},
 }
-_tuning_cache: dict = {}
 
 
-def _detect_tpu_generation():
-    """(on_tpu, generation) of the process's first device — one probe,
-    cached; the shared detection behind every kernel's tuning lookup
-    (monkeypatch point for the warn-once tests)."""
-    gen = None
-    on_tpu = False
-    try:
-        import jax
+def _flash_tuning() -> dict:
+    """The FLASH_TUNING row for the current chip. A TPU whose generation
+    has no measured row raises; the CPU mesh (interpret-mode tests) tiles
+    by the v5e row."""
+    from ..search.machine_model import local_tpu_generation
 
-        from ..search.machine_model import detect_generation
-
-        dev = jax.devices()[0]
-        on_tpu = dev.platform == "tpu"
-        gen = detect_generation(dev.device_kind)
-    except Exception:
-        pass
-    return on_tpu, gen
-
-
-def _flash_tuning(kernel: str = "flash_attention") -> dict:
-    """The FLASH_TUNING row for the current chip (device_kind normalized by
-    machine_model.detect_generation — the one shared matcher; v5e's
-    measured row is the default for unknown kinds). When an UNMEASURED TPU
-    generation inherits the v5e row, warn once PER (generation, kernel) —
-    not once per process (ISSUE 12 satellite: the old module-level
-    warn-once meant a v5e-tuned tile row inherited by another generation
-    was silenced for the flash-DECODE kernel after the first training
-    warning): if a flash kernel regresses on that chip, the trace must
-    point at the tuning table, not the kernels (ADVICE r5)."""
-    if "probe" not in _tuning_cache:
-        _tuning_cache["probe"] = _detect_tpu_generation()
-        _tuning_cache["warned"] = set()
-    on_tpu, gen = _tuning_cache["probe"]
-    if on_tpu and gen not in FLASH_TUNING and \
-            (gen, kernel) not in _tuning_cache["warned"]:
-        import warnings
-
-        _tuning_cache["warned"].add((gen, kernel))
-        warnings.warn(
-            f"{kernel}: flash tile table has no MEASURED row for TPU "
-            f"generation {gen!r}; inheriting the v5e tiling (block_q "
-            f"{FLASH_TUNING['v5e']['block_q_cap']} / block_k "
-            f"{FLASH_TUNING['v5e']['block_k_cap']} / min_block "
-            f"{FLASH_TUNING['v5e']['min_block']}) as an unmeasured "
-            f"estimate — on-chip regressions are traceable here; "
-            f"re-measure per the FLASH_TUNING recipe and add a row.",
-            stacklevel=2)
-    return FLASH_TUNING.get(gen, FLASH_TUNING["v5e"])
+    gen = local_tpu_generation() or "v5e"
+    if gen not in FLASH_TUNING:
+        raise RuntimeError(
+            f"flash tile table has no measured row for TPU generation "
+            f"{gen!r}; measure one per the FLASH_TUNING recipe in "
+            f"ops/attention.py")
+    return FLASH_TUNING[gen]
 
 
 def _flash_blocks(seq_q: int, seq_k: int):
@@ -593,13 +548,9 @@ def _should_use_flash(use_flash, q, k, causal) -> bool:
     if use_flash is True:
         return True
     if use_flash == "auto":
-        import jax
+        from ..kernels._common import on_tpu
 
-        try:
-            on_tpu = jax.devices()[0].platform == "tpu"
-        except Exception:
-            on_tpu = False
-        if not on_tpu or q.shape[-1] % 64 != 0:
+        if not on_tpu() or q.shape[-1] % 64 != 0:
             return False
         # head_dim 64 is fine on the MXU (the (block_q, d) tiles pad lanes
         # to 128). Only take flash when both sequences admit blocks >= the
@@ -609,6 +560,53 @@ def _should_use_flash(use_flash, q, k, causal) -> bool:
         return blocks is not None and \
             min(blocks) >= _flash_tuning()["min_block"]
     return False
+
+
+def _flash_on_mesh(q, k, v, causal, dropout, seed, mesh):
+    """The flash kernel on whatever mesh the step runs over. Mosaic kernels
+    cannot be partitioned automatically (lowering one with a sharded operand
+    raises), so on more than one device the call rides a ``shard_map``.
+    Attention is independent per (batch, head): batch goes over the ``data``
+    axis and heads over every other mesh axis, wherever the sizes divide —
+    XLA reshards q/k/v to that layout if the plan holds them otherwise, and
+    an axis that divides neither dimension computes redundantly. Each shard
+    folds its mesh position into the dropout seed, so shards draw different
+    masks."""
+    import jax
+    import jax.numpy as jnp
+
+    from ..kernels.flash_attention import flash_attention
+
+    bq, bk = _flash_blocks(q.shape[-2], k.shape[-2])
+    if mesh is None or mesh.devices.size == 1:
+        return flash_attention(q, k, v, causal, bq, bk, dropout=dropout,
+                               seed=seed)
+    from jax.sharding import PartitionSpec as P
+
+    batch_axes, head_axes = [], []
+    b, h = q.shape[0], q.shape[1]
+    for axis, size in mesh.shape.items():
+        if axis == "data" and b % size == 0:
+            batch_axes.append(axis)
+            b //= size
+        elif h % size == 0:
+            head_axes.append(axis)
+            h //= size
+    spec = P(tuple(batch_axes) or None, tuple(head_axes) or None, None, None)
+
+    def local(q, k, v, seed):
+        if dropout:
+            shard = jnp.uint32(0)
+            for axis in batch_axes + head_axes:
+                shard = shard * jnp.uint32(mesh.shape[axis]) + \
+                    jax.lax.axis_index(axis).astype(jnp.uint32)
+            seed = seed + shard * jnp.uint32(0x9E3779B1)
+        return flash_attention(q, k, v, causal, bq, bk, dropout=dropout,
+                               seed=seed if dropout else None)
+
+    return jax.shard_map(
+        local, mesh=mesh, in_specs=(spec, spec, spec, P()), out_specs=spec,
+        check_vma=False)(q, k, v, seed if dropout else jnp.uint32(0))
 
 
 @register_op(OperatorType.OP_SDPA)
@@ -637,12 +635,9 @@ class SDPAOp(Op):
                 and _should_use_flash(
                     self.attrs.get("use_flash", "auto"), q, k, causal) \
                 and _flash_blocks(q.shape[-2], k.shape[-2]) is not None:
-            from ..kernels.flash_attention import flash_attention
-
-            bq, bk = _flash_blocks(q.shape[-2], k.shape[-2])
             seed = _dropout_seed(ctx.rng) if live_dropout else None
-            return [flash_attention(q, k, v, causal, bq, bk,
-                                    dropout=live_dropout, seed=seed)]
+            return [_flash_on_mesh(q, k, v, causal, live_dropout, seed,
+                                   ctx.mesh)]
         # same single-gate rule as MultiHeadAttentionOp: pass the resolved
         # live_dropout, rng only when it is live
         return [mha_core(q, k, v, causal=causal, dropout=live_dropout,

@@ -113,12 +113,7 @@ def _sp_mesh():
     return Mesh(devs, ("data", "seq"))
 
 
-@pytest.mark.parametrize("which", [
-    pytest.param("ring", marks=pytest.mark.xfail(
-        reason="jax 0.4.37 shard_map rejects the ring dropout scan with a "
-               "carry replication-type mismatch (env regression, present on "
-               "the pristine seed; passes on newer jax)", strict=False)),
-    "ulysses"])
+@pytest.mark.parametrize("which", ["ring", "ulysses"])
 def test_sp_dropout_mean_field_and_grads(which):
     """Ring/Ulysses with dropout: mean over seeds converges to the
     undropped output; gradients flow; dropout=0 is bit-identical to the

@@ -122,8 +122,6 @@ def test_memory_model_within_2x_of_xla_peak():
     yd = jax.device_put(y, ff.executor.batch_sharding(2))
     ma = ff.executor.train_step_memory_analysis(ff.params, ff.opt_state,
                                                 xd, yd)
-    # version-compat accessor: older jaxlibs don't expose
-    # peak_memory_in_bytes and need the component-sum reconstruction
     from flexflow_tpu.obs.telemetry import peak_memory_bytes
 
     xla_peak = peak_memory_bytes(ma)

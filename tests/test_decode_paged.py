@@ -406,30 +406,7 @@ def test_flash_decode_gate_off_tpu():
     assert not use_flash_decode(64, 3)
 
 
-# ------------------------------------------------- satellites: warn + FF006
-def test_flash_tuning_warns_once_per_generation_and_kernel(monkeypatch):
-    """ISSUE 12 satellite: the unmeasured-generation tile warning fires
-    once per (generation, KERNEL) — flash_decode gets its own warning
-    even after flash_attention already warned."""
-    import warnings
-
-    from flexflow_tpu.ops import attention
-
-    monkeypatch.setattr(attention, "_tuning_cache", {})
-    monkeypatch.setattr(attention, "_detect_tpu_generation",
-                        lambda: (True, "v99"))
-    with pytest.warns(UserWarning, match="flash_attention.*no MEASURED"):
-        attention._flash_tuning("flash_attention")
-    with warnings.catch_warnings():
-        warnings.simplefilter("error")
-        attention._flash_tuning("flash_attention")  # silenced
-    with pytest.warns(UserWarning, match="flash_decode.*no MEASURED"):
-        attention._flash_tuning("flash_decode")
-    with warnings.catch_warnings():
-        warnings.simplefilter("error")
-        attention._flash_tuning("flash_decode")
-
-
+# ------------------------------------------------------ satellite: FF006
 def test_check_paged_kv_shape_laws(gpt2):
     """FF006 paged extension: misconfigured block tables/pools are
     rejected statically with the rule ID; a clean config passes."""

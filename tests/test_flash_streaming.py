@@ -136,26 +136,3 @@ def test_fwd_streams_k_grid():
                               -1)) + jnp.max(s, -1)
     np.testing.assert_allclose(np.asarray(lse), np.asarray(ref_lse),
                                rtol=1e-4, atol=1e-4)
-
-
-@pytest.mark.skipif("jax.default_backend() != 'tpu'")
-def test_smoke_8k_seq_tpu():
-    """>= 8k-sequence smoke on real hardware (VERDICT r4 item 1 Done
-    criterion): causal fwd+bwd at seq 8192 (fused schedule boundary) and
-    16384 (two-pass streaming) compile and produce finite gradients."""
-    import jax
-    import jax.numpy as jnp
-
-    rng = np.random.default_rng(3)
-    for s in (8192, 16384):
-        q = jnp.asarray(rng.normal(size=(1, 2, s, 64)), jnp.bfloat16)
-        k = jnp.asarray(rng.normal(size=(1, 2, s, 64)), jnp.bfloat16)
-        v = jnp.asarray(rng.normal(size=(1, 2, s, 64)), jnp.bfloat16)
-
-        def loss(q, k, v):
-            o = fa.flash_attention(q, k, v, True, 512, 1024)
-            return jnp.sum(o.astype(jnp.float32) ** 2)
-
-        g = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
-        for t in g:
-            assert bool(jnp.all(jnp.isfinite(t.astype(jnp.float32))))

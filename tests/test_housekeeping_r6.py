@@ -1,7 +1,6 @@
 """Round-6 satellite fixes (ADVICE r5): TASO loader dst-side PM_* policy,
-attention's single live-dropout gate, flash tuning-table warn-once."""
+attention's single live-dropout gate."""
 import json
-import warnings
 
 import numpy as np
 import pytest
@@ -157,37 +156,3 @@ def test_einsum_fallback_passes_resolved_live_dropout(monkeypatch):
                OpContext(training=False, rng=jax.random.PRNGKey(1)))
     assert seen["dropout"] == 0.0
     assert seen["rng"] is None
-
-
-# ---------------------------------------------- flash tuning warn-once
-def test_flash_tuning_warns_once_for_unmeasured_tpu_generation(monkeypatch):
-    """ops/attention.py:200 — an unmeasured TPU generation inheriting the
-    v5e tile table must warn ONCE (traceable on-chip regressions), and the
-    cached row must silence later calls."""
-    import jax
-
-    from flexflow_tpu.ops import attention
-
-    class FakeDev:
-        platform = "tpu"
-        device_kind = "TPU v99"
-
-    monkeypatch.setattr(attention, "_tuning_cache", {})
-    monkeypatch.setattr(jax, "devices", lambda *a, **k: [FakeDev()])
-    with pytest.warns(UserWarning, match="no MEASURED row"):
-        row = attention._flash_tuning()
-    assert row == attention.FLASH_TUNING["v5e"]
-    with warnings.catch_warnings():
-        warnings.simplefilter("error")
-        assert attention._flash_tuning() == row  # cached: no second warning
-
-
-def test_flash_tuning_no_warning_off_tpu(monkeypatch):
-    """CPU/interpret runs (every CI test) must stay silent — the fallback
-    row is only a concern when real flash kernels will run."""
-    from flexflow_tpu.ops import attention
-
-    monkeypatch.setattr(attention, "_tuning_cache", {})
-    with warnings.catch_warnings():
-        warnings.simplefilter("error")
-        assert attention._flash_tuning() == attention.FLASH_TUNING["v5e"]
