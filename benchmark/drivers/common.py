@@ -1,0 +1,85 @@
+"""What both drivers share: the compile counter, the profiler's start and
+stop, the compile cache's thresholds, the kernel names in a compiled
+program's text, and the one door to members of the program that no public
+entry point gives."""
+from __future__ import annotations
+
+import glob
+import os
+import re
+import shutil
+
+
+def mosaic_calls(compiled_text: str) -> set:
+    """Names of the Mosaic (``tpu_custom_call``) kernels in a compiled
+    program's text (a copy of ``chip_smoke.mosaic_calls``)."""
+    names = set()
+    for line in compiled_text.splitlines():
+        if 'custom_call_target="tpu_custom_call"' in line:
+            m = re.search(r"(\w+)\)*/pallas_call", line)
+            names.add(m.group(1) if m else "<unnamed>")
+    return names
+
+
+def program_member(obj, name: str, what_for: str):
+    """A member of the program that the benchmark reads although no public
+    entry point hands it out (PERF.md, Open questions, lists every one for
+    the ``tracing`` issue to replace by a hook). Where a later PR has
+    renamed or removed it, the run stops with a line that says what is
+    missing and what for, and not with an AttributeError."""
+    if not hasattr(obj, name):
+        raise SystemExit(
+            f"benchmark: the program's {type(obj).__name__} has no "
+            f"{name!r} any more; the benchmark needs it for {what_for}. "
+            f"Give the program a public hook for that and point the "
+            f"benchmark's driver at it in a benchmark PR (PERF.md, Open "
+            f"questions).")
+    return getattr(obj, name)
+
+
+class CompileCounter:
+    """Counts what JAX lowers and compiles, so that a window can show it
+    compiled nothing."""
+
+    KEYS = ("/jax/core/compile/jaxpr_to_mlir_module_duration",
+            "/jax/core/compile/backend_compile_duration")
+
+    def __init__(self):
+        import jax.monitoring
+
+        self.n = 0
+        jax.monitoring.register_event_duration_secs_listener(self._on)
+
+    def _on(self, event, duration, **kwargs):
+        if event in self.KEYS:
+            self.n += 1
+
+
+def start_trace(ctx) -> None:
+    import jax
+
+    shutil.rmtree(ctx.trace_dir, ignore_errors=True)
+    os.makedirs(ctx.trace_dir, exist_ok=True)
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0  # the Python tracer is most of the overhead
+    opts.host_tracer_level = 2
+    jax.profiler.start_trace(ctx.trace_dir, profiler_options=opts)
+
+
+def stop_trace(ctx) -> str:
+    import jax
+
+    jax.profiler.stop_trace()
+    found = glob.glob(os.path.join(ctx.trace_dir, "plugins", "profile", "*",
+                                   "*.xplane.pb"))
+    return found[0] if found else ""
+
+
+def place_cache() -> None:
+    """Every program goes to the persistent cache, also the small ones that
+    compile in under a second (JAX's default leaves those out), so that only
+    a checkout's first run compiles."""
+    import jax
+
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)

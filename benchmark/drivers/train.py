@@ -1,0 +1,345 @@
+"""Driver of the training cells: ``FFModel.compile`` + ``fit`` with its
+dataloader and prefetch running, telemetry off inside the window.
+
+Set-up (counted in ``setup_s``): build, ``compile()`` (with the search where
+the cell's flags ask for one), weights on the device from the seed, the
+synthetic set from the seed, one step on the check batch (it compiles the
+train step and gives the loss and gradients the plain reference is compared
+with), one short warm ``fit``. Then the window: whole passes over the
+synthetic set (``fit(epochs=1)``, each ending in ``fit``'s own
+``block_until_ready``) until ``--seconds`` have gone; ``train_tokens_per_s``
+is the median over those passes.
+
+The driver names no configuration. What belongs to one — the builder and its
+fields (``configs/<config>.json``), the plain loss and gradients, the
+parameter groups they are compared on, the model's FLOPs per token and its
+attention calls (``reference/<config>.py``), the inputs
+(``traffic/<generator>.py``) — it finds through the cell's files. The
+builder named in the configuration's file leaves the tensor the loss is
+taken on as the graph's last.
+"""
+from __future__ import annotations
+
+import json
+import os
+import time
+
+import numpy as np
+
+from benchmark import spans
+from benchmark.drivers.common import (CompileCounter, mosaic_calls,
+                                      place_cache, program_member,
+                                      start_trace, stop_trace)
+
+# Tolerances of the comparison with the plain reference (f32, matmul
+# precision "highest") on the check batch. The system computes in bf16
+# (8 mantissa bits: one rounding is 2^-9 relative) through 24 post-LN blocks.
+# Loss: the log-probability of a 2-class softmax over mean-pooled features.
+# An error d of the logit difference moves it by about 0.7 d relative, and
+# bf16 leaves d near 0.005-0.01: measured 0.01-0.75% over 16 runs on one
+# check sequence on the chip (PERF.md, correct), so 1% was under three
+# standard deviations and would have failed a correct run in a hundred. A
+# dropped term moves the loss by tenths; the gradients below are the tight
+# check.
+LOSS_TOL = 3e-2
+# Gradients: relative L2 error of every weight's gradient in the parameter
+# groups the reference names, on one check sequence. Every element carries
+# ~sqrt(depth) bf16 roundings of activations and cotangents; measured
+# 0.4-2.1% on the chip wherever the check ran on one sequence, largest in
+# the first layer's first dense kernel (PERF.md, correct). fp8 (3 mantissa
+# bits, 2^-4 a rounding) would give 30%+, a dropped term (a bias, a
+# residual) 100%.
+GRAD_TOL = 8e-2
+
+
+def run(ctx) -> dict:
+    import jax
+
+    from flexflow_tpu import AdamOptimizer, FFConfig, FFModel, LossType
+    from flexflow_tpu.ffconst import MetricsType
+
+    place_cache()
+    counter = CompileCounter()
+    cell, job = ctx.cell, ctx.traffic
+    chips = int(cell["chips"])
+    batch = int(cell["batch_per_chip"]) * chips
+    seq = int(job["seq_len"])
+    info, checks = {}, {}
+    ref = ctx.reference()
+
+    # ---- build + compile() (search included) + weights on the device
+    t = time.perf_counter()
+    model_cfg, build = ctx.model_config(batch_size=batch, seq_len=seq)
+    search_log = ctx.side_file("search.jsonl")
+    config = FFConfig()
+    config.parse_args(["-b", str(batch), "--seed", str(ctx.seed),
+                       "--search-log-file", search_log]
+                      + list(ctx.config.get("compile_flags", []))
+                      + list(cell.get("compile_flags", [])))
+    ff = FFModel(config)
+    build(ff, model_cfg)
+    opt, asks = cell["optimizer"], ctx.config["train"]
+    with spans.span("compile"):
+        ff.compile(optimizer=AdamOptimizer(ff, alpha=float(opt["lr"])),
+                   loss_type=getattr(LossType, asks["loss_type"]),
+                   metrics=[getattr(MetricsType, m)
+                            for m in asks["metrics"]])
+    jax.block_until_ready(ff.params)
+    ff_compile_s = time.perf_counter() - t
+    info["mesh"] = dict(ff.mesh.shape)
+    info["plan"] = ff.strategy.describe()
+    search = search_result(search_log)
+    search_s = search.get("search_wall_s")
+    # every parameter finite: one small program, warmed here; a step whose
+    # loss or gradient is not finite leaves Adam's update, and so the
+    # parameters, not finite for good
+    finite = jax.jit(lambda p: jax.numpy.stack(
+        [jax.numpy.isfinite(leaf).all()
+         for leaf in jax.tree_util.tree_leaves(p)]).all())
+    checks["parameters_finite_at_start"] = bool(finite(ff.params))
+    checks["mesh_spans_all_chips"] = int(ff.mesh.devices.size) == chips
+    # the parameters as they are before the first step, for the reference
+    params0 = jax.device_get(ff.params)
+
+    # ---- data from the seed
+    t = time.perf_counter()
+    gen = ctx.generator()
+    x, y = gen.generate(job, ctx.seed, batch, ctx.config)
+    cx, cy, cx_tiled, cy_tiled = gen.check_batch(job, ctx.seed, batch,
+                                                 ctx.config)
+    data_s = time.perf_counter() - t
+
+    # ---- first step, on the check batch: compiles the train step
+    t = time.perf_counter()
+    sys_loss = fit_with_losses(ctx, ff, cx_tiled, cy_tiled, batch)[0]
+    first_step_s = time.perf_counter() - t
+
+    # ---- compare with the plain reference (outside the window)
+    t = time.perf_counter()
+    checks.update(reference_check(ctx, ref, ff, params0, cx, cy, sys_loss,
+                                  float(opt.get("beta1", 0.9)), info,
+                                  pipelined=bool(search.get("pipeline"))))
+    del params0
+    check_s = time.perf_counter() - t
+
+    # ---- warm fit() over two batches: the dataloader path, no new program
+    t = time.perf_counter()
+    ff.fit(x[:2 * batch], y[:2 * batch], batch_size=batch, epochs=1,
+           shuffle=False)
+    warm_s = time.perf_counter() - t
+
+    # ---- the compiled step's text: the kernels that should run are in it
+    # (after the warm fit this lowering finds the program already compiled:
+    # 0.7 s; before it, it compiled a second time)
+    t = time.perf_counter()
+    text, batch_devices = train_step_text(ff, x[:batch], y[:batch])
+    if chips > 1:
+        # every parameter, every gradient moment and the batch (placed as
+        # fit() places it) on all the chips
+        leaves = jax.tree_util.tree_leaves((ff.params, ff.opt_state))
+        checks["on_distinct_devices"] = min(
+            [batch_devices] + [len({s.device for s in a.addressable_shards})
+                               for a in leaves
+                               if hasattr(a, "addressable_shards")]) == chips
+        del leaves
+    kernels = mosaic_calls(text)
+    info["mosaic_kernels"] = sorted(kernels)
+    if ctx.devices[0].platform == "tpu":
+        checks["flash_kernels_in_step"] = (
+            "flash_attention_fwd" in kernels
+            and any(k.startswith("flash_attention_bwd") for k in kernels))
+    text_s = time.perf_counter() - t
+
+    steps_per_pass = int(job["batches_per_epoch"])
+    facts = {"info": info, "checks": checks}
+    if ctx.trace:
+        # the loss after 32 steps from the seed, read with telemetry on (it
+        # syncs every step, so never inside a measured window)
+        losses = []
+        while len(losses) < 32:
+            losses += fit_with_losses(ctx, ff, x, y, batch)
+        facts["loss_after_32_steps"] = float(losses[31])
+        from benchmark.reduce import xplane
+
+        # op_name metadata gives the breakdown its node scopes
+        facts["scopes"] = xplane.scope_map(text)
+        facts["sim_step_s"] = search.get("cost_ms", 0.0) / 1e3 \
+            or analytic_step_s(ff)
+    del text
+    if ctx.trace:
+        start_trace(ctx)
+
+    # ---- the window
+    seconds = min(ctx.seconds, float(cell.get("trace_seconds", 6.0))) \
+        if ctx.trace else ctx.seconds
+    setup_s = ctx.since_start()
+    n_compiles0 = counter.n
+    passes, pass_ok, pass_loss = [], [], []
+    loss_key = asks.get("pass_loss")
+    t_open = time.perf_counter()
+    with spans.span(spans.WINDOW):
+        while time.perf_counter() - t_open < seconds:
+            t = time.perf_counter()
+            with spans.span("fit_epoch"):
+                ff.fit(x, y, batch_size=batch, epochs=1, shuffle=False)
+            passes.append(time.perf_counter() - t)
+            pass_ok.append(bool(finite(ff.params)))
+            if loss_key:
+                pass_loss.append(ff.get_perf_metrics().mean(loss_key))
+    window_s = time.perf_counter() - t_open
+    compiles_in_window = counter.n - n_compiles0
+    if ctx.trace:
+        facts["trace_file"] = stop_trace(ctx)
+
+    tokens_per_pass = steps_per_pass * batch * seq
+    rates = [tokens_per_pass / p for p in passes]
+    tokens_per_s = float(np.median(rates))
+    bad_passes = sum(1 for ok, v in zip(pass_ok, pass_loss or pass_ok)
+                     if not (ok and np.isfinite(v)))
+    checks["losses_finite"] = bad_passes == 0
+    checks["no_compile_in_window"] = compiles_in_window == 0
+    info.update({
+        "passes": len(passes), "steps_per_pass": steps_per_pass,
+        "pass_s": [round(p, 4) for p in passes],
+        "pass_loss": [round(float(v), 5) for v in pass_loss],
+        "window_s": round(window_s, 3),
+        "setup_split_s": {"build_compile_init": round(ff_compile_s, 2),
+                          "search": search_s, "data": round(data_s, 2),
+                          "first_step": round(first_step_s, 2),
+                          "reference_check": round(check_s, 2),
+                          "step_text": round(text_s, 2),
+                          "warm_fit": round(warm_s, 2)},
+        "compiles_in_window": compiles_in_window})
+    facts.update({
+        "kind": "train", "correct": all(checks.values()),
+        "attempted": len(passes) * steps_per_pass,
+        "failed": bad_passes * steps_per_pass,
+        "end_to_end": {"train_tokens_per_s": (tokens_per_s, "tokens/s"),
+                       "setup_s": (setup_s, "s")},
+        "steps": len(passes) * steps_per_pass, "chips": chips,
+        "batch": batch, "seq": seq,
+        "tokens_per_s": tokens_per_s, "step_s": batch * seq / tokens_per_s,
+        "flops_per_token": float(ref.train_flops_per_token(ctx.config, seq)),
+        "attention_calls": ref.attention_calls(ctx.config, batch // chips,
+                                               seq),
+        "peaks": ctx.peaks,
+        "compile_s": ff_compile_s - (search_s or 0.0) + first_step_s,
+        "search_s": search_s, "step_module": "jit_step"})
+    return facts
+
+
+def fit_with_losses(ctx, ff, x, y, batch) -> list:
+    """One pass of ``fit`` with the program's telemetry on (``--telemetry-file``
+    as a deployment sets it: every step is synced for its loss), and the
+    losses of its steps. Off again afterwards, so that no later ``fit``
+    syncs."""
+    ff.config.telemetry_file = ctx.side_file("telemetry.json")
+    try:
+        ff.fit(x, y, batch_size=batch, epochs=1, shuffle=False)
+    finally:
+        ff.config.telemetry_file = ""
+    return [float(v) for v in ff.get_telemetry().loss_history]
+
+
+def search_result(path: str) -> dict:
+    """The last ``result`` record of the program's search log (``cost_ms``:
+    the simulator's step time for the plan it chose; ``search_wall_s``);
+    empty where ``compile()`` did not search (one chip)."""
+    out = {}
+    if os.path.exists(path):
+        with open(path) as f:
+            for line in f:
+                rec = json.loads(line)
+                if rec.get("event") == "result":
+                    out = rec
+    return out
+
+
+def train_step_text(ff, x1, y1):
+    """(the compiled train step's text, the number of devices its batch sits
+    on). No public entry point gives the text: the step is lowered again
+    through the executor's step builder, for the batch as ``fit`` places it
+    (labels as ``fit`` shapes them)."""
+    import jax
+
+    executor = program_member(ff, "executor", "the compiled train step's text")
+    sharding = program_member(executor, "batch_sharding",
+                              "placing a batch as fit() does")
+    step = program_member(executor, "make_train_step",
+                          "the compiled train step's text")()
+    y1 = y1[:, None] if y1.ndim == 1 else y1
+    xd = [jax.device_put(x1, sharding(x1.ndim))]
+    yd = jax.device_put(y1, sharding(y1.ndim))
+    text = step.lower(ff.params, ff.opt_state, xd, yd,
+                      jax.random.PRNGKey(0)).compile().as_text()
+    return text, len({s.device for s in xd[0].addressable_shards})
+
+
+def reference_check(ctx, ref, ff, params0, cx, cy, sys_loss, beta1, info,
+                    pipelined=False):
+    """Loss and gradients of the first step against the plain reference, on
+    the parameter groups the configuration's reference file names. Adam's
+    first moment after one step from zero is (1 - beta1) * g, so the
+    gradients are read from the optimizer state the real train step wrote —
+    no second program. Where the search log says the plan is a pipeline,
+    ``fit`` trains through a trainer whose optimizer state the model does
+    not hand out: the loss is compared, the gradients are not reachable."""
+    import jax
+
+    names = [] if pipelined else list(ref.checked_params(ff.params,
+                                                          ctx.config))
+    moments = ff.opt_state.get("m", {}) if isinstance(ff.opt_state, dict) \
+        else {}
+    if pipelined:
+        info["reference"] = "pipelined plan: loss compared, gradients not"
+    elif not names or any(k not in moments for k in names):
+        raise SystemExit(
+            f"benchmark: nothing to compare gradients on — the reference "
+            f"names {names}, the optimizer state after one step holds first "
+            f"moments for {sorted(moments)[:6]}... A run that checks no "
+            f"gradient does not pass.")
+    sys_grads = {k: {w: np.asarray(jax.device_get(v), np.float32)
+                     / (1.0 - beta1) for w, v in moments[k].items()}
+                 for k in names}
+    # the reference lives on one device; parameters are gathered to it
+    dev = ctx.devices[0]
+    ref_loss, ref_grads = ref.loss_and_grads(
+        jax.device_put(params0, dev), jax.device_put(cx, dev),
+        jax.device_put(cy, dev), ctx.config, wanted=names)
+    ref_loss = float(ref_loss)
+    worst = 0.0
+    for k in names:
+        for w, g in ref_grads[k].items():
+            g = np.asarray(jax.device_get(g), np.float32)
+            err = float(np.linalg.norm(sys_grads[k][w] - g)
+                        / max(np.linalg.norm(g), 1e-30))
+            info[f"grad_rel_err {k}.{w}"] = round(err, 5)
+            worst = max(worst, err)
+    loss_err = abs(sys_loss - ref_loss) / max(abs(ref_loss), 1e-12)
+    info["first_step_loss system/reference"] = (round(sys_loss, 6),
+                                                round(ref_loss, 6))
+    out = {"loss_matches_reference": loss_err <= LOSS_TOL}
+    if not pipelined:
+        out["grads_match_reference"] = worst <= GRAD_TOL
+    return out
+
+
+def analytic_step_s(ff):
+    """Where ``compile()`` did not search (one chip): the program's analytic
+    (uncalibrated) machine model of the devices, the one a search would have
+    used, on the data-parallel plan that runs. The program's simulator, not
+    the yardstick: where it gives nothing, ``sim_vs_measured`` is left out."""
+    try:
+        from flexflow_tpu.search.machine_model import TPUMachineModel
+        from flexflow_tpu.search.simulator import OpSharding, Simulator
+        from flexflow_tpu.search.unity import simulate_best
+
+        n = int(ff.mesh.devices.size)
+        sim = Simulator(TPUMachineModel.detect(n))
+        plan = {node.guid: OpSharding(dp=n)
+                for node in ff.pcg.compute_nodes()}
+        return float(simulate_best(sim, ff.pcg, plan, {}))
+    except Exception as e:
+        print(f"[bench] sim_vs_measured: simulator gave nothing ({e!r})",
+              flush=True)
+        return None
