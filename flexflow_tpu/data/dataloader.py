@@ -11,10 +11,13 @@ transfer with the previous step (replacing zero-copy staging).
 from __future__ import annotations
 
 import threading
+import time
 from queue import Queue
-from typing import Any, Iterator, List, Optional, Sequence
+from typing import Any, Dict, Iterator, List, Optional, Sequence
 
 import numpy as np
+
+from ..obs.trace import span
 
 
 class SingleDataLoader:
@@ -93,8 +96,22 @@ def device_put_batch(arrays: List[np.ndarray], shardings: List[Any]):
     return [jax.device_put(a) for a in arrays]
 
 
-def prefetch_iterator(it: Iterator, shardings: List[Any], depth: int = 2):
+def new_input_stats() -> Dict[str, Any]:
+    """The input pipeline's always-on counters (``FFModel.input_stats``):
+    seconds the consumer waited for a batch, batches handed over, seconds
+    the producer gathered and shipped. Each key has one writing thread."""
+    return {"wait_s": 0.0, "batches": 0, "gather_s": 0.0, "put_s": 0.0}
+
+
+def prefetch_iterator(it: Iterator, shardings: List[Any], depth: int = 2,
+                      stats: Optional[Dict[str, Any]] = None):
     """Background-thread prefetch of device batches (double buffering).
+
+    Every region is a span on the profiler's clock (obs/trace.SPANS):
+    ``batch_gather`` / ``batch_put`` / ``prefetch_backpressure`` on the
+    producer's thread, ``dataloader_wait`` around each ``q.get()`` of the
+    consumer; their sums go into ``stats`` (``new_input_stats``), plain
+    adds, no profiler needed.
 
     Abandoning the generator early (e.g. fit breaking out on a dynamic
     recompile) stops the producer promptly and JOINS it — without the stop
@@ -107,6 +124,9 @@ def prefetch_iterator(it: Iterator, shardings: List[Any], depth: int = 2):
     sentinel and the error included, gives up once the consumer is gone."""
     from queue import Empty, Full
 
+    if stats is None:
+        stats = new_input_stats()
+    clock = time.perf_counter
     q: Queue = Queue(maxsize=depth)
     stop = threading.Event()
     _END = object()
@@ -124,23 +144,41 @@ def prefetch_iterator(it: Iterator, shardings: List[Any], depth: int = 2):
 
     def producer():
         try:
-            for batch in it:
-                staged = device_put_batch(batch, shardings)
-                if not put_or_stop(staged):
+            source = iter(it)
+            while True:
+                t0 = clock()
+                with span("batch_gather"):
+                    batch = next(source, _END)
+                t1 = clock()
+                stats["gather_s"] += t1 - t0
+                if batch is _END:
+                    break
+                with span("batch_put",
+                          bytes=sum(getattr(a, "nbytes", 0) for a in batch)):
+                    staged = device_put_batch(batch, shardings)
+                stats["put_s"] += clock() - t1
+                with span("prefetch_backpressure"):
+                    landed = put_or_stop(staged)
+                if not landed:
                     return
             put_or_stop(_END)
         except BaseException as e:  # propagate to the consumer, don't swallow
             put_or_stop(e)
 
-    t = threading.Thread(target=producer, daemon=True)
-    t.start()
+    with span("fit_epoch_setup"):
+        t = threading.Thread(target=producer, daemon=True)
+        t.start()
     try:
         while True:
-            item = q.get()
+            t0 = clock()
+            with span("dataloader_wait", batch=stats["batches"]):
+                item = q.get()
+            stats["wait_s"] += clock() - t0
             if item is _END:
                 break
             if isinstance(item, BaseException):
                 raise item
+            stats["batches"] += 1
             yield item
     finally:
         stop.set()
@@ -151,10 +189,8 @@ def prefetch_iterator(it: Iterator, shardings: List[Any], depth: int = 2):
         # (short: this sits on fit's recompile path) and fall back to
         # leaking the daemon thread (the pre-fix behavior) rather than
         # stalling the training process in generator close
-        import time as _time
-
-        deadline = _time.monotonic() + 1.0
-        while t.is_alive() and _time.monotonic() < deadline:
+        deadline = time.monotonic() + 1.0
+        while t.is_alive() and time.monotonic() < deadline:
             try:
                 while True:
                     q.get_nowait()

@@ -19,6 +19,24 @@ from ..parallel.strategy import Strategy
 from .losses import loss_value
 from .metrics import Metrics
 
+#: The jitted programs a device trace is read by: a launch shows on the
+#: chip's ``XLA Modules`` line as ``jit_<name>``, and the benchmark's
+#: reduction finds the train step and the serving programs under these
+#: names. Renaming one renames the source of a metric
+#: (tests/test_program_spans.py lowers each and reads the module's name).
+PROGRAM_NAMES = ("step", "decode", "prefill", "prefill_chunk", "write")
+
+
+def named_jit(name: str, fn, **jit_kwargs):
+    """``jax.jit(fn)`` under the program name ``name`` (one of
+    ``PROGRAM_NAMES``), whatever the inner function is called."""
+    import jax
+
+    if name not in PROGRAM_NAMES:
+        raise KeyError(name)
+    fn.__name__ = fn.__qualname__ = name
+    return jax.jit(fn, **jit_kwargs)
+
 
 class Executor:
     def __init__(self, pcg: PCG, mesh, strategy: Strategy, loss_type,
@@ -549,10 +567,11 @@ class Executor:
                                               self._bind_inputs(xs), ctx)
                 raw = values[self.final_guid][self.final_out_idx]
             logits = self._logits_f32(raw)
-            loss = loss_value(self.loss_type, logits, labels,
-                              self.repl_labels)
-            for aux in ctx.aux_losses:
-                loss = loss + aux
+            with jax.named_scope("loss"):
+                loss = loss_value(self.loss_type, logits, labels,
+                                  self.repl_labels)
+                for aux in ctx.aux_losses:
+                    loss = loss + aux
             return loss, (logits, cache_out)
 
         def step(params, opt_state, xs, labels, rng, cache=None):
@@ -573,13 +592,17 @@ class Executor:
                 gsq = (sum(jnp.vdot(g, g) for g in leaves)
                        if leaves else jnp.zeros((), jnp.float32))
                 ok = jnp.logical_and(jnp.isfinite(loss), jnp.isfinite(gsq))
-                new_params, new_state = jax.lax.cond(
-                    ok,
-                    lambda: opt.update(params, grads, opt_state),
-                    lambda: (params, opt_state))
+                with jax.named_scope("optimizer_update"):
+                    new_params, new_state = jax.lax.cond(
+                        ok,
+                        lambda: opt.update(params, grads, opt_state),
+                        lambda: (params, opt_state))
             else:
-                new_params, new_state = opt.update(params, grads, opt_state)
-            m = self._compute_metrics(logits, labels)
+                with jax.named_scope("optimizer_update"):
+                    new_params, new_state = opt.update(params, grads,
+                                                       opt_state)
+            with jax.named_scope("metrics"):
+                m = self._compute_metrics(logits, labels)
             out = (new_params, new_state, loss, m)
             if has_cache:
                 out = out + (cache_out,)
@@ -587,8 +610,7 @@ class Executor:
                 out = out + (ok,)
             return out
 
-        jit_kwargs = {"donate_argnums": (0, 1)}
-        fn = jax.jit(step, **jit_kwargs)
+        fn = named_jit("step", step, donate_argnums=(0, 1))
         if guard:
             self._guarded_train_step = fn
         else:
@@ -885,7 +907,7 @@ class Executor:
                 logits, idx[:, None, None], axis=1)[:, 0]
             return logits, last, sv.cache_out
 
-        fn = jax.jit(prefill)
+        fn = named_jit("prefill", prefill)
         self._serving_jits[key] = fn
         return fn
 
@@ -969,7 +991,7 @@ class Executor:
                                     block_tables=state.block_tables)
             return last, new_state
 
-        fn = jax.jit(chunk, donate_argnums=(2,))
+        fn = named_jit("prefill_chunk", chunk, donate_argnums=(2,))
         self._serving_jits[key] = fn
         return fn
 
@@ -1046,6 +1068,6 @@ class Executor:
                 return logits, new_state, ok
             return logits, new_state
 
-        fn = jax.jit(decode, donate_argnums=(2,))
+        fn = named_jit("decode", decode, donate_argnums=(2,))
         self._serving_jits[key] = fn
         return fn

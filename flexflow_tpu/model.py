@@ -478,6 +478,18 @@ class FFModel:
         was disabled for that run)."""
         return getattr(self, "_telemetry", None)
 
+    def input_stats(self) -> Dict[str, Any]:
+        """The input pipeline's counters over the most recent ``fit()``
+        (always on, plain adds; reset per fit): ``wait_s`` the steps waited
+        for their batch (the ``dataloader_wait`` spans), ``batches`` handed
+        over, ``gather_s`` / ``put_s`` the producer thread spent in the
+        source iterator and in ``device_put`` (``batch_gather`` /
+        ``batch_put``). wait_s near gather_s + put_s: the producer is the
+        limit; wait_s near zero: it keeps up."""
+        from .data.dataloader import new_input_stats
+
+        return dict(getattr(self, "_input_stats", None) or new_input_stats())
+
     def _make_telemetry(self, tracer, batch_size: int, phase: str):
         """A StepTelemetry when either sink wants one, else None — the
         None-ness is the hot loop's single instrumentation gate.
@@ -940,8 +952,11 @@ class FFModel:
         # variant); step_fn is the unguarded path's handle only
         step_fn = (None if guard is not None
                    else self.executor.make_train_step())
-        from .data.dataloader import batch_iterator, prefetch_iterator
+        from .data.dataloader import (batch_iterator, new_input_stats,
+                                      prefetch_iterator)
+        from .obs.trace import span, step_span
 
+        self._input_stats = new_input_stats()
         in_shardings = [self.executor.batch_sharding(a.ndim) for a in xs]
         label_sharding = self.executor.batch_sharding(y.ndim)
 
@@ -1000,122 +1015,138 @@ class FFModel:
             epoch = epoch0
             preempted = False
             while epoch < epochs:
-                # shuffled epochs by default (the reference's loaders shuffle);
-                # the shuffled path stages batches through the native C++
-                # double-buffered BatchPipeline (data/dataloader.py).
-                # start_batch replays an interrupted epoch's tail: the same
-                # seed reproduces the shuffle, the cursor skips what the
-                # restored checkpoint already consumed
-                it = batch_iterator(xs + [y], batch_size, shuffle=shuffle,
-                                    seed=self.config.numpy_seed() + epoch,
-                                    start_batch=skip_batches)
-                batch_in_epoch = skip_batches
-                skip_batches = 0
-                epoch_metrics = []  # device-side; folded at epoch end (async)
-                recompiled = False
-                rolled_back = False
-                t_epoch = time.perf_counter()
-                for batch in prefetch_iterator(
-                        it, in_shardings + [label_sharding]):
-                    bx, by = batch[:-1], batch[-1]
-                    if session is not None and session.chaos is not None:
-                        bx = session.chaos.poison_batch(step_count, bx)
-                        session.chaos.maybe_preempt(step_count)
-                    if telemetry is not None:
-                        t_step = time.perf_counter()
-                    step_ok = True
-                    if guard is not None:
-                        rng = self._next_rng()
-                        if cache is not None:
-                            outs, step_ok = guard(self.params, self.opt_state,
-                                                  bx, by, rng, cache)
-                            (self.params, self.opt_state, loss_val, m,
-                             fresh) = outs
-                        else:
-                            outs, step_ok = guard(self.params, self.opt_state,
-                                                  bx, by, rng)
-                            self.params, self.opt_state, loss_val, m = outs
-                            fresh = None
-                    elif cache is not None:
-                        (self.params, self.opt_state, loss_val, m,
-                         fresh) = step_fn(self.params, self.opt_state, bx, by,
-                                          self._next_rng(), cache)
-                    else:
-                        self.params, self.opt_state, loss_val, m = step_fn(
-                            self.params, self.opt_state, bx, by,
-                            self._next_rng())
-                        fresh = None
-                    if cache is not None and step_ok:
-                        self._score_caches(cache, fresh, step_count)
-                        cache.update(fresh)
-                    step_count += 1
-                    batch_in_epoch += 1
-                    executed_steps += 1
-                    if step_ok:
-                        # a guarded bad step left params untouched; its
-                        # NaN metrics must not poison the epoch fold
-                        epoch_metrics.append(m)
-                    loss_f = None
-                    if telemetry is not None:
-                        # observability is opt-in: the per-step sync it costs
-                        # is what buys true step walls + the compile split
-                        jax.block_until_ready(loss_val)
-                        wall = time.perf_counter() - t_step
-                        loss_f = float(loss_val) if step_ok else None
-                        telemetry.record_step(wall, loss_f)
-                        tracer.complete("train_step", wall, step=step_count,
-                                        loss=loss_f)
-                        last_batch = (bx, by)
-                    if not step_ok:
-                        session.record_fault(step_count - 1)
-                        if guard.should_rollback:
-                            step_count, epoch, skip_batches = \
-                                session.rollback()
+                with span("epoch", tracer=tracer, index=epoch) as ep:
+                    # shuffled epochs by default (the reference's loaders shuffle);
+                    # the shuffled path stages batches through the native C++
+                    # double-buffered BatchPipeline (data/dataloader.py).
+                    # start_batch replays an interrupted epoch's tail: the same
+                    # seed reproduces the shuffle, the cursor skips what the
+                    # restored checkpoint already consumed
+                    with span("fit_epoch_setup", tracer=tracer):
+                        it = batch_iterator(xs + [y], batch_size, shuffle=shuffle,
+                                            seed=self.config.numpy_seed() + epoch,
+                                            start_batch=skip_batches)
+                        batch_in_epoch = skip_batches
+                        skip_batches = 0
+                        epoch_metrics = []  # device-side; folded at epoch end
+                        recompiled = False
+                        rolled_back = False
+                    for batch in prefetch_iterator(
+                            it, in_shardings + [label_sharding],
+                            stats=self._input_stats):
+                        bx, by = batch[:-1], batch[-1]
+                        if session is not None and session.chaos is not None:
+                            bx = session.chaos.poison_batch(step_count, bx)
+                            session.chaos.maybe_preempt(step_count)
+                        if telemetry is not None:
+                            t_step = time.perf_counter()
+                        step_ok = True
+                        with step_span("train_step", step_count,
+                                       tracer=tracer) as step_sp:
+                            if guard is not None:
+                                rng = self._next_rng()
+                                if cache is not None:
+                                    outs, step_ok = guard(
+                                        self.params, self.opt_state, bx, by, rng,
+                                        cache)
+                                    (self.params, self.opt_state, loss_val, m,
+                                     fresh) = outs
+                                else:
+                                    outs, step_ok = guard(
+                                        self.params, self.opt_state, bx, by, rng)
+                                    (self.params, self.opt_state, loss_val,
+                                     m) = outs
+                                    fresh = None
+                            elif cache is not None:
+                                (self.params, self.opt_state, loss_val, m,
+                                 fresh) = step_fn(self.params, self.opt_state, bx,
+                                                  by, self._next_rng(), cache)
+                            else:
+                                (self.params, self.opt_state, loss_val,
+                                 m) = step_fn(self.params, self.opt_state, bx, by,
+                                              self._next_rng())
+                                fresh = None
+                            if cache is not None and step_ok:
+                                self._score_caches(cache, fresh, step_count)
+                                cache.update(fresh)
+                            step_count += 1
+                            batch_in_epoch += 1
+                            executed_steps += 1
+                            if step_ok:
+                                # a guarded bad step left params untouched; its
+                                # NaN metrics must not poison the epoch fold
+                                epoch_metrics.append(m)
+                            loss_f = None
+                            if telemetry is not None:
+                                # observability is opt-in: the per-step sync it
+                                # costs is what buys true step walls + the
+                                # compile split; the sync sits inside the span
+                                jax.block_until_ready(loss_val)
+                                wall = time.perf_counter() - t_step
+                                loss_f = float(loss_val) if step_ok else None
+                                telemetry.record_step(wall, loss_f)
+                                step_sp.set_metadata(
+                                    step=step_count,
+                                    **({} if loss_f is None else {"loss": loss_f}))
+                                last_batch = (bx, by)
+                        if not step_ok:
+                            session.record_fault(step_count - 1)
+                            if guard.should_rollback:
+                                step_count, epoch, skip_batches = \
+                                    session.rollback()
+                                cache = (self.executor.init_cache()
+                                         if self.executor.cache_nodes else None)
+                                epoch_metrics = []  # poisoned partials discarded
+                                rolled_back = True
+                                break
+                        if session is not None:
+                            session.on_step(step_count, epoch, batch_in_epoch,
+                                            steps_per_epoch)
+                            if session.preempted:
+                                # preemption grace window: flush a final
+                                # committed checkpoint, then stop cleanly
+                                self._preempted_at_step = step_count
+                                session.note_preemption(step_count)
+                                session.final_checkpoint(step_count, epoch,
+                                                         batch_in_epoch,
+                                                         steps_per_epoch)
+                                preempted = True
+                                break
+                        if self._recompile_state is not None and \
+                                self.recompile_on_condition(self._recompile_state):
+                            # executor rebuilt: refresh the jitted step and cache,
+                            # then RE-RUN this epoch on the new shardings (the break
+                            # abandons the rest of its batches)
+                            if guard is not None:
+                                guard.executor = self.executor
+                                guard.rebuild()
+                            else:
+                                step_fn = self.executor.make_train_step()
                             cache = (self.executor.init_cache()
                                      if self.executor.cache_nodes else None)
-                            epoch_metrics = []  # poisoned partials discarded
-                            rolled_back = True
+                            recompiled = True
                             break
-                    if session is not None:
-                        session.on_step(step_count, epoch, batch_in_epoch,
-                                        steps_per_epoch)
-                        if session.preempted:
-                            # preemption grace window: flush a final
-                            # committed checkpoint, then stop cleanly
-                            self._preempted_at_step = step_count
-                            session.note_preemption(step_count)
-                            session.final_checkpoint(step_count, epoch,
-                                                     batch_in_epoch,
-                                                     steps_per_epoch)
-                            preempted = True
-                            break
-                    if self._recompile_state is not None and \
-                            self.recompile_on_condition(self._recompile_state):
-                        # executor rebuilt: refresh the jitted step and cache,
-                        # then RE-RUN this epoch on the new shardings (the break
-                        # abandons the rest of its batches)
-                        if guard is not None:
-                            guard.executor = self.executor
-                            guard.rebuild()
-                        else:
-                            step_fn = self.executor.make_train_step()
-                        cache = (self.executor.init_cache()
-                                 if self.executor.cache_nodes else None)
-                        recompiled = True
-                        break
-                    if self.config.profiling and \
-                            step_count % max(self.config.print_freq, 1) == 0:
-                        # legacy stdout line, byte-identical to the pre-obs
-                        # print so existing scripts keep parsing it
-                        print(f"step {step_count}: loss="
-                              f"{float(loss_val) if loss_f is None else loss_f:.4f}")
-                # fold whatever the epoch produced (also the partial pre-recompile
-                # batches — their steps trained the old graph but still count);
-                # ONE host transfer for the whole epoch instead of a blocking
-                # int()/float() per scalar per step
-                if epoch_metrics:
-                    for m in jax.device_get(epoch_metrics):
-                        self._perf.update(m)
+                        if self.config.profiling and \
+                                step_count % max(self.config.print_freq, 1) == 0:
+                            # legacy stdout line, byte-identical to the pre-obs
+                            # print so existing scripts keep parsing it
+                            print(f"step {step_count}: loss="
+                                  f"{float(loss_val) if loss_f is None else loss_f:.4f}")
+                    # fold whatever the epoch produced (also the partial pre-recompile
+                    # batches — their steps trained the old graph but still count);
+                    # ONE host transfer for the whole epoch instead of a blocking
+                    # int()/float() per scalar per step
+                    if epoch_metrics:
+                        with span("epoch_fold", tracer=tracer):
+                            for m in jax.device_get(epoch_metrics):
+                                self._perf.update(m)
+                    if telemetry is not None and not (rolled_back or preempted
+                                                      or recompiled):
+                        loss_f = (float(loss_val) if loss_val is not None
+                                  else None)
+                        telemetry.record_epoch(loss_f)
+                        if loss_f is not None:
+                            ep.set_metadata(loss=loss_f)
                 if rolled_back:
                     continue  # re-enter at the restored epoch/batch cursor
                 if preempted:
@@ -1125,17 +1156,12 @@ class FFModel:
                                     for a in xs]
                     label_sharding = self.executor.batch_sharding(y.ndim)
                     continue  # restart the SAME epoch
-                if telemetry is not None:
-                    loss_f = (float(loss_val) if loss_val is not None
-                              else None)
-                    telemetry.record_epoch(loss_f)
-                    tracer.complete("epoch", time.perf_counter() - t_epoch,
-                                    index=epoch, loss=loss_f)
                 if self.config.profiling:
                     print(f"epoch {epoch}: loss={float(loss_val):.4f}")
                 epoch += 1
             if loss_val is not None:
-                jax.block_until_ready(loss_val)
+                with span("fit_sync", tracer=tracer):
+                    jax.block_until_ready(loss_val)
         finally:
             if tracing:
                 jax.profiler.stop_trace()
@@ -1187,6 +1213,7 @@ class FFModel:
         import jax
 
         from .data.dataloader import batch_iterator
+        from .obs.trace import span, step_span
 
         tr = self._pipeline_trainer
         # seed from the CURRENT executor params when they changed since the
@@ -1229,31 +1256,30 @@ class FFModel:
         for epoch in range(epochs):
             it = batch_iterator(xs + [y], batch_size, shuffle=shuffle,
                                 seed=self.config.numpy_seed() + epoch)
-            t_epoch = time.perf_counter()
-            for batch in it:
-                bx, by = batch[:-1], batch[-1]
-                t_step = time.perf_counter()
-                loss = tr.train_step(list(bx), by, rng_seed=step)
-                step += 1
-                # loss-only metrics: train_step returns the scalar loss
-                # (accuracy-style metrics need the eval path)
-                loss_f = float(loss)
+            with span("epoch", tracer=tracer, index=epoch):
+                for batch in it:
+                    bx, by = batch[:-1], batch[-1]
+                    t_step = time.perf_counter()
+                    with step_span("train_step", step,
+                                   tracer=tracer) as step_sp:
+                        loss = tr.train_step(list(bx), by, rng_seed=step)
+                        step += 1
+                        # loss-only metrics: train_step returns the scalar
+                        # loss (accuracy-style metrics need the eval path)
+                        loss_f = float(loss)
+                        if telemetry is not None:
+                            wall = time.perf_counter() - t_step
+                            telemetry.record_step(wall, loss_f)
+                            step_sp.set_metadata(step=step, loss=loss_f)
+                    self._perf.update({
+                        "train_all": by.shape[0],
+                        loss_key: loss_f * by.shape[0]})
+                    if self.config.profiling and \
+                            step % max(self.config.print_freq, 1) == 0:
+                        print(f"step {step}: loss={loss_f:.4f}")
                 if telemetry is not None:
-                    wall = time.perf_counter() - t_step
-                    telemetry.record_step(wall, loss_f)
-                    tracer.complete("train_step", wall, step=step,
-                                    loss=loss_f)
-                self._perf.update({
-                    "train_all": by.shape[0],
-                    loss_key: loss_f * by.shape[0]})
-                if self.config.profiling and \
-                        step % max(self.config.print_freq, 1) == 0:
-                    print(f"step {step}: loss={loss_f:.4f}")
-            if telemetry is not None:
-                telemetry.record_epoch(float(loss) if loss is not None
-                                       else None)
-                tracer.complete("epoch", time.perf_counter() - t_epoch,
-                                index=epoch)
+                    telemetry.record_epoch(float(loss) if loss is not None
+                                           else None)
         for lname, ws in tr.export_params().items():
             for wname, arr in ws.items():
                 cur = self.params[lname][wname]

@@ -5,7 +5,10 @@ analog, SURVEY §1 L0):
 
 * ``trace``: thread-safe span/event tracer with Chrome trace-event JSON
   export (Perfetto-loadable) and a JSONL event sink. Disabled by default via
-  a no-op singleton — ``enable()`` swaps in a live tracer.
+  a no-op singleton — ``enable()`` swaps in a live tracer. ``span`` /
+  ``step_span`` / ``SPANS``: the hot loops' spans (``fit`` with its input
+  pipeline, the serve tick) as ``jax.profiler`` annotations on the
+  profiler's clock; the profiler session is their switch.
 * ``telemetry``: per-step training telemetry (wall times, loss history,
   compile-vs-steady split, samples/sec, estimated MFU, XLA peak memory) and
   the Unity/MCMC per-iteration search log.
@@ -22,8 +25,9 @@ analog, SURVEY §1 L0):
 Nothing in this package allocates in the jitted path; all instrumentation is
 host-side and gated on ``get_tracer().enabled``.
 """
-from .trace import (NoopTracer, Tracer, atomic_write_json,  # noqa: F401
-                    disable, enable, get_tracer, set_tracer)
+from .trace import (SPANS, NoopTracer, Tracer,  # noqa: F401
+                    atomic_write_json, disable, enable, get_tracer,
+                    set_tracer, span, step_span)
 from .reqtrace import (FleetTimeSeries, NoopRequestTrace,  # noqa: F401
                        RequestTrace, disable_reqtrace, enable_reqtrace,
                        get_reqtrace, set_reqtrace)

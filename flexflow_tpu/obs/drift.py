@@ -132,7 +132,7 @@ class DriftSentinel:
                         tolerance=self.tolerance)
         agg = ev["aggregate_ratio"]
         if tracer.enabled and agg is not None:
-            tracer.gauge("calibration_aggregate_ratio", round(agg, 4))
+            tracer.counter("calibration_aggregate_ratio", round(agg, 4))
         return {
             "profiled_keys": len(ev["per_key"]),
             "aggregate_ratio": agg,

@@ -1,11 +1,20 @@
-"""Structured span/event tracer with Chrome trace-event export.
+"""Structured span/event tracer with Chrome trace-event export, and the
+program's hot-loop spans on the profiler's clock.
 
 The observability analog of the reference's Legion Prof integration
 (``-lg:prof``) plus the per-op ``--profiling`` kernel-timing prints: nested
 spans for compile / train-step / epoch / eval / search phases, instant
-events, counters and gauges, exported as Chrome trace-event JSON
+events and counters, exported as Chrome trace-event JSON
 (Perfetto-loadable, ``chrome://tracing``) and optionally streamed to a JSONL
 event sink as spans complete.
+
+``span`` / ``step_span`` (below, with the ``SPANS`` registry) are what the two
+hot host loops — ``FFModel.fit`` with its input pipeline, and the serve tick —
+are instrumented with: ``jax.profiler`` annotations, so they land in the
+profiler's trace on the device's clock whenever a profiler session runs
+(``--profiler-trace-dir``, ``obs.start_trace``) and cost about a microsecond
+when none does; with the Chrome ``Tracer`` enabled the same call also records
+its complete event.
 
 Disabled-by-default design: the module-level singleton starts as a
 ``NoopTracer`` whose ``span()`` returns one shared, reusable null context
@@ -20,7 +29,8 @@ import json
 import os
 import threading
 import time
-from typing import Any, Dict, Optional
+from types import MappingProxyType
+from typing import Any, Dict, Mapping, Optional
 
 
 def atomic_write_json(path: str, obj) -> str:
@@ -80,9 +90,6 @@ class NoopTracer:
     def counter(self, name: str, value) -> None:
         pass
 
-    def gauge(self, name: str, value) -> None:
-        pass
-
     def to_chrome_trace(self) -> Dict[str, Any]:
         return {"traceEvents": []}
 
@@ -129,8 +136,7 @@ class Tracer:
 
     * ``span(name, **args)``: context manager; emits a complete ('X') event.
     * ``event(name, **args)``: instant ('i') event.
-    * ``counter(name, value)`` / ``gauge``: 'C' events Perfetto plots as
-      time series.
+    * ``counter(name, value)``: 'C' event Perfetto plots as a time series.
     * ``to_chrome_trace()`` / ``write(path)``: Chrome trace-event JSON.
     * ``jsonl_file``: when set, every emitted event is also appended to this
       file as one JSON object per line (the machine-readable event sink).
@@ -239,8 +245,6 @@ class Tracer:
                     "tid": threading.get_ident(),
                     "args": {name: value}})
 
-    gauge = counter  # same Chrome event shape; kept as a semantic alias
-
     # -- export ------------------------------------------------------------
     def to_chrome_trace(self) -> Dict[str, Any]:
         with self._lock:
@@ -304,3 +308,99 @@ def disable():
                 prev._jsonl_fh = None
     _TRACER = NoopTracer()
     return prev
+
+
+# ------------------------------------------- hot-loop spans, profiler's clock
+#: Every span of the two hot host loops: name -> what it brackets. ``span()``
+#: refuses a name that is not here, so the registry, docs/observability.md
+#: (scripts/check_trace_events.py reads this mapping) and the benchmark's
+#: readers (benchmark/reduce/program_spans.py imports it) cannot drift apart.
+SPANS: Mapping[str, str] = MappingProxyType({
+    # FFModel.fit, main thread
+    "epoch": "one pass of fit's epoch loop, set-up to fold",
+    "fit_epoch_setup": "top of the epoch loop to the first q.get(): "
+                       "batch_iterator(), producer thread start",
+    "dataloader_wait": "one q.get() of prefetch_iterator's consumer: the "
+                       "step waited for its batch",
+    "train_step": "the step_fn/guard call and the cache update after it "
+                  "(the dispatch; with telemetry on also its sync)",
+    "epoch_fold": "device_get(epoch_metrics) + PerfMetrics.update at the "
+                  "end of an epoch",
+    "fit_sync": "fit's final block_until_ready(loss)",
+    # prefetch_iterator's producer thread
+    "batch_gather": "one next() of the source iterator: the numpy gather "
+                    "of one batch",
+    "batch_put": "one device_put_batch(): host -> device, as sharded",
+    "prefetch_backpressure": "the producer blocked on a full queue: it is "
+                             "keeping up",
+    # the serve tick (_ServeLoop / _AsyncServeLoop)
+    "serve_tick": "one tick(); kind = prefill | prefill_chunk | decode | "
+                  "idle; pipelined=1 when a step was in flight at entry",
+    "tick_dispatch": "tick entry -> the device call is issued: deadline "
+                     "sweep, next_action(), building ids",
+    "prefill": "the bucket's prefill call up to and including the "
+               "sampler's device_get",
+    "prefill_chunk": "one chunk's call (and, on the last chunk, the "
+                     "sampler's device_get)",
+    "slot_write": "_write_slot / _set_slot_meta: placing a prompt's KV "
+                  "rows and arming the slot",
+    "decode_dispatch": "_dispatch_decode + _sample: the decode step and "
+                       "the sampler are issued",
+    "fetch_tokens": "the host blocked on a decode step's tokens",
+    "tick_bookkeep": "device return -> end of tick: commits, recycling, "
+                     "trie insert",
+    "tick_overlap": "async loop: host work after a decode step was issued, "
+                    "hidden behind it (sampler, the previous step's commit)",
+})
+
+
+class _TracedSpan:
+    """A profiler annotation and the Chrome ``Tracer``'s span of the same
+    name, entered and left together (only built while a Tracer is on)."""
+
+    __slots__ = ("_ann", "_span")
+
+    def __init__(self, ann, span: _Span):
+        self._ann = ann
+        self._span = span
+
+    def __enter__(self):
+        self._ann.__enter__()
+        self._span.__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        self._span.__exit__(*exc)
+        return self._ann.__exit__(*exc)
+
+    def set_metadata(self, **args) -> None:
+        self._ann.set_metadata(**args)
+        self._span.args.update(args)
+
+
+def _program_span(cls_name: str, name: str, tracer, args):
+    SPANS[name]  # KeyError: register the span (and document it) first
+    import jax.profiler  # loaded with jax; nothing new at start-up
+
+    ann = getattr(jax.profiler, cls_name)(name, **args)
+    t = _TRACER if tracer is None else tracer
+    return _TracedSpan(ann, t.span(name, **args)) if t.enabled else ann
+
+
+def span(name: str, tracer=None, **args):
+    """Context manager over ``jax.profiler.TraceAnnotation(name, **args)``:
+    a span on the profiler's clock, beside the device's ops, while a profiler
+    session runs — the session is the switch — and a no-op of about a
+    microsecond otherwise. With a Chrome ``Tracer`` enabled (``tracer``, or
+    the process singleton) it records that tracer's complete event too.
+    ``name`` must be in ``SPANS``; ``args`` are ints and short strings. What
+    is known only inside the span goes in through ``set_metadata(**args)``
+    of the entered object."""
+    return _program_span("TraceAnnotation", name, tracer, args)
+
+
+def step_span(name: str, step_num: int, tracer=None, **args):
+    """``span`` over ``jax.profiler.StepTraceAnnotation``: xprof's step-time
+    view groups device time by these."""
+    return _program_span("StepTraceAnnotation", name, tracer,
+                         dict(args, step_num=step_num))

@@ -225,14 +225,17 @@ def _serving_attention(name: str, q, k, v, sv, *, causal: bool):
         tables, bs = sv.block_tables, sv.block_size
         if sv.kv_dtype == "int8":
             kq, ks, vq, vs = sv.cache_in[name]
-            k_new, ks_new = quantize_kv(k)   # (S,h,1,hd) -> scale (S,h,1)
-            v_new, vs_new = quantize_kv(v)
-            kq = write_token_kv_paged(kq, k_new, sv.positions, tables, bs)
-            ks = write_token_scale_paged(ks, ks_new, sv.positions, tables,
-                                         bs)
-            vq = write_token_kv_paged(vq, v_new, sv.positions, tables, bs)
-            vs = write_token_scale_paged(vs, vs_new, sv.positions, tables,
-                                         bs)
+            with jax.named_scope("kv_update"):
+                k_new, ks_new = quantize_kv(k)  # (S,h,1,hd), scale (S,h,1)
+                v_new, vs_new = quantize_kv(v)
+                kq = write_token_kv_paged(kq, k_new, sv.positions, tables,
+                                          bs)
+                ks = write_token_scale_paged(ks, ks_new, sv.positions,
+                                             tables, bs)
+                vq = write_token_kv_paged(vq, v_new, sv.positions, tables,
+                                          bs)
+                vs = write_token_scale_paged(vs, vs_new, sv.positions,
+                                             tables, bs)
             sv.cache_out[name] = (kq, ks, vq, vs)
             kernel_out = _maybe_flash_decode(
                 q, (kq, ks, vq, vs), tables, sv, scale)
@@ -244,8 +247,9 @@ def _serving_attention(name: str, q, k, v, sv, *, causal: bool):
                                gather_paged_scales(vs, tables), v.dtype)
         else:
             kp, vp = sv.cache_in[name]
-            kp = write_token_kv_paged(kp, k, sv.positions, tables, bs)
-            vp = write_token_kv_paged(vp, v, sv.positions, tables, bs)
+            with jax.named_scope("kv_update"):
+                kp = write_token_kv_paged(kp, k, sv.positions, tables, bs)
+                vp = write_token_kv_paged(vp, v, sv.positions, tables, bs)
             sv.cache_out[name] = (kp, vp)
             kernel_out = _maybe_flash_decode(q, (kp, vp), tables, sv,
                                              scale)
@@ -255,8 +259,9 @@ def _serving_attention(name: str, q, k, v, sv, *, causal: bool):
             vc = gather_paged_kv(vp, tables)
     else:
         kc, vc = sv.cache_in[name]
-        kc = write_token_kv(kc, k, sv.positions)
-        vc = write_token_kv(vc, v, sv.positions)
+        with jax.named_scope("kv_update"):
+            kc = write_token_kv(kc, k, sv.positions)
+            vc = write_token_kv(vc, v, sv.positions)
         sv.cache_out[name] = (kc, vc)
     extent = kc.shape[2]  # max_len (ring) | blocks * block_size (paged)
     if sv.seq_shards > 1:

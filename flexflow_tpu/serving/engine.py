@@ -29,6 +29,7 @@ from typing import Any, Deque, Dict, List, Optional, Sequence
 import numpy as np
 
 from ..ffconst import OperatorType
+from ..obs.trace import span, step_span
 from .kvcache import DecodeState, update_slot_entry
 from .scheduler import (ContinuousBatchScheduler, Request, ServingRejection,
                         bucket_for, default_buckets)
@@ -58,6 +59,8 @@ def position_context_bound(executor, max_len: int) -> int:
 # window of the most recent TOKEN_WALL_WINDOW walls instead (plenty for a
 # stable tail estimate; the summary fields are unchanged).
 TOKEN_WALL_WINDOW = 8192
+# what one tick() can do: the ``kind`` of its ``serve_tick`` span
+TICK_KINDS = ("prefill", "prefill_chunk", "decode", "idle")
 
 
 @dataclasses.dataclass
@@ -130,6 +133,14 @@ class ServingStats:
     # steady-state contract is <= 1 per committed decode step
     host_overlap_s: float = 0.0
     host_syncs: int = 0
+    # the wall of every tick() by what the tick did (the ``kind`` of its
+    # ``serve_tick`` span: prefill | prefill_chunk | decode | idle) and how
+    # many there were — which kind of tick the time between two tokens went
+    # to; plain adds, read as deltas over a window like the buckets above
+    tick_wall_s_by_kind: Dict[str, float] = dataclasses.field(
+        default_factory=lambda: dict.fromkeys(TICK_KINDS, 0.0))
+    ticks_by_kind: Dict[str, int] = dataclasses.field(
+        default_factory=lambda: dict.fromkeys(TICK_KINDS, 0))
     # sequence-parallel decode (ISSUE 18): mean per-step occupied KV
     # bytes ONE shard chip holds — pool bytes at measured fill divided
     # by seq_shards. This is the recorded number behind the "KV provably
@@ -651,7 +662,10 @@ class ServingEngine:
                 return DecodeState(caches=caches, lengths=lengths,
                                    block_tables=tables), last
 
-            self._write_slot_fn = jax.jit(write, donate_argnums=(0, 1))
+            from ..execution.executor import named_jit
+
+            self._write_slot_fn = named_jit("write", write,
+                                            donate_argnums=(0, 1))
         if table_row is None:
             table_row = np.zeros(
                 (getattr(self, "max_blocks_per_slot", 1),), np.int32)
@@ -876,7 +890,8 @@ class ServingEngine:
             return fn
         if greedy:
             def sample(logits, base_rng, tag_counts):
-                return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                with jax.named_scope("sample"):
+                    return jnp.argmax(logits, axis=-1).astype(jnp.int32)
         else:
             temp = float(temperature)
             k = int(top_k)
@@ -886,6 +901,10 @@ class ServingEngine:
                     jax.random.fold_in(base_rng, tc[0]), tc[1])
 
             def sample(logits, base_rng, tag_counts):
+                with jax.named_scope("sample"):
+                    return draw(logits, base_rng, tag_counts)
+
+            def draw(logits, base_rng, tag_counts):
                 rngs = jax.vmap(lambda tc: row_rng(base_rng, tc))(
                     tag_counts)
                 if k > 0:
@@ -1344,6 +1363,34 @@ def _state_lost(state) -> bool:
     return state_buffers_lost(state)
 
 
+class _TickPhase:
+    """The region of a tick that is open right now: ``to(name)`` ends it
+    and begins the next (flat, back to back, as ``_acct_tick``'s buckets
+    are), ``close()`` ends it. Each is a ``span`` of obs/trace.SPANS.
+    ``tick_args`` is what the tick learns about itself on the way, for
+    its ``serve_tick`` span."""
+
+    __slots__ = ("tracer", "cur", "tick_args")
+
+    def __init__(self, tracer):
+        self.tracer = tracer
+        self.cur = None
+        self.tick_args: Dict[str, Any] = {}
+
+    def to(self, name: str, **args) -> None:
+        self.close()
+        self.cur = span(name, tracer=self.tracer, **args)
+        self.cur.__enter__()
+
+    def set_metadata(self, **args) -> None:
+        self.cur.set_metadata(**args)
+
+    def close(self) -> None:
+        cur, self.cur = self.cur, None
+        if cur is not None:
+            cur.__exit__(None, None, None)
+
+
 class _ServeLoop:
     """One serve() run's loop state, advanced one scheduler action at a
     time (ISSUE 11 refactor: the monolithic serve loop became
@@ -1505,8 +1552,30 @@ class _ServeLoop:
     def tick(self) -> bool:
         """Perform ONE scheduler action. Returns False when there is
         nothing to do right now (queue empty + no live slot, or the
-        drain grace just expired and evicted the stragglers)."""
-        t_tick = time.perf_counter()
+        drain grace just expired and evicted the stragglers).
+
+        One ``serve_tick`` span (obs/trace.SPANS) with the tick's
+        ``kind``; inside it the regions ``_acct_tick`` accounts for are
+        spans that begin and end where its clock is read, so the buckets
+        and the spans cannot disagree."""
+        phase = _TickPhase(self.tracer)
+        with step_span("serve_tick", self.step_no,
+                       tracer=self.tracer) as tick_sp:
+            phase.to("tick_dispatch")
+            t_tick = time.perf_counter()
+            try:
+                kind, worked = self._tick(t_tick, phase)
+            finally:
+                phase.close()
+            tick_sp.set_metadata(kind=kind, **phase.tick_args)
+            st = self.stats
+            st.tick_wall_s_by_kind[kind] += time.perf_counter() - t_tick
+            st.ticks_by_kind[kind] += 1
+        return worked
+
+    def _tick(self, t_tick: float, phase: "_TickPhase"):
+        """``tick``'s body, entered inside ``tick_dispatch``: (the tick's
+        kind, whether it did anything)."""
         import jax
         import jax.numpy as jnp
 
@@ -1522,12 +1591,13 @@ class _ServeLoop:
             for slot, r in enumerate(list(sched.slots)):
                 if r is not None:
                     sched.evict(slot, "preempted")
-            return False
+            return "idle", False
         if self.res_active and res.deadlines_armed:
             eng._sweep_deadlines(sched, res, tracer)
         action = sched.next_action()
         if action is None:
-            return self._idle()
+            phase.close()
+            return "idle", self._idle()
         if action[0] == "prefill":
             _, req, slot, bucket = action
             if self.res_active and req.expired(res.clock()):
@@ -1535,12 +1605,14 @@ class _ServeLoop:
                 # iteration: evict before paying prefill
                 res.deadline_misses += 1
                 sched.evict(slot, "deadline_exceeded")
-                return True
+                return "idle", True
             t_p = time.perf_counter()
             # effective prompt = prompt + committed tokens: empty suffix
             # for a fresh request, the full committed stream for a
             # decode-fault retry (or cross-replica migration) re-prefill
             eff = req.effective_len
+            phase.to("prefill", rid=req.rid, bucket=bucket, slot=slot,
+                     prompt_len=eff)
             cur = req.current_prompt()
             ids = np.zeros((1, bucket), np.int32)
             ids[0, :eff] = cur
@@ -1558,6 +1630,7 @@ class _ServeLoop:
                              np.asarray([[tag, len(req.generated)]],
                                         np.int32))[0]))
             wall = time.perf_counter() - t_p
+            phase.to("tick_bookkeep")
             stats.prefills += 1
             stats.prefill_tokens_computed += eff
             stats.record_token(wall)
@@ -1567,13 +1640,11 @@ class _ServeLoop:
             # site every first-commit path passes through
             if req.first_token_step is None:
                 req.first_token_step = self.step_no
-            if tracer.enabled:
-                tracer.complete("prefill", wall, rid=req.rid,
-                                bucket=bucket, slot=slot, prompt_len=eff)
             if not sched.commit_token(slot, tok):
-                eng._write_slot(cache, slot, eff, tok,
-                                table_row=(eng._table_row_for(req)
-                                           if eng._paged else None))
+                with span("slot_write", tracer=tracer):
+                    eng._write_slot(cache, slot, eff, tok,
+                                    table_row=(eng._table_row_for(req)
+                                               if eng._paged else None))
                 # mark completion (the pool holds the prompt's KV now)
                 # and eagerly cache the FULL prompt blocks so same-batch
                 # shared-prefix admissions already hit; the partial tail
@@ -1586,7 +1657,7 @@ class _ServeLoop:
                         eng._prefix.insert(cur[:full * eng.kv_block_size],
                                            req.kv_blocks[:full])
             self._acct_tick(t_tick, t_p, wall)
-            return True
+            return "prefill", True
         if action[0] == "prefill_chunk":
             # chunked prefill / prefix-suffix prefill (ISSUE 14): one
             # fixed-width chunk of ONE slot's prompt, co-scheduled with
@@ -1598,8 +1669,10 @@ class _ServeLoop:
                 res.deadline_misses += 1
                 sched.evict(slot, "deadline_exceeded")
                 self._chunk_walls.pop(req.rid, None)
-                return True
+                return "idle", True
             t_p = time.perf_counter()
+            phase.to("prefill_chunk", rid=req.rid, slot=slot, start=start,
+                     tokens=n, hit=req.prefix_hit_tokens)
             eng._ensure_state_bootstrap()
             if req.pending_cow is not None:
                 # first divergent write into a shared partial tail
@@ -1622,25 +1695,24 @@ class _ServeLoop:
             stats.chunked_prefills += 1
             done = sched.chunk_done(slot, n)
             wall = time.perf_counter() - t_p
+            phase.set_metadata(done=int(done))
             self._chunk_walls[req.rid] = \
                 self._chunk_walls.get(req.rid, 0.0) + wall
-            if tracer.enabled:
-                tracer.complete("prefill_chunk", wall, rid=req.rid,
-                                slot=slot, start=start, tokens=n,
-                                hit=req.prefix_hit_tokens, done=done)
             if sched.rt.enabled:
                 sched.rt.note(req.rid, "chunk", float(res.clock()),
                               start=start, tokens=n,
                               replica=sched.replica_idx)
             if not done:
+                phase.to("tick_bookkeep")
                 self._acct_tick(t_tick, t_p, wall)
-                return True
+                return "prefill_chunk", True
             eff = req.prefill_target
             tag = req.rng_tag if req.rng_tag is not None else req.rid
             tok = int(jax.device_get(
                 self.sampler(last, self.base_rng,
                              np.asarray([[tag, len(req.generated)]],
                                         np.int32))[0]))
+            phase.to("tick_bookkeep")
             stats.prefills += 1
             stats.record_token(self._chunk_walls.pop(req.rid, wall))
             stats.tokens_generated += 1
@@ -1659,12 +1731,13 @@ class _ServeLoop:
                 # decode steps running between chunks wrote this slot's
                 # discarded tokens into the garbage block, never into
                 # its real blocks)
-                eng._set_slot_meta(slot, eff, tok, row)
+                with span("slot_write", tracer=tracer):
+                    eng._set_slot_meta(slot, eff, tok, row)
             self._acct_tick(t_tick, t_p, wall)
-            return True
+            return "prefill_chunk", True
         # decode: one token for every live slot — through the sync
         # (reference) or async (double-buffered) _tick_decode variant
-        return self._tick_decode(t_tick, action[1])
+        return "decode", self._tick_decode(t_tick, action[1], phase)
 
     def _idle(self) -> bool:
         """No scheduler action is available right now. The async loop
@@ -1809,7 +1882,7 @@ class _ServeLoop:
             tracer.complete("decode_step", wall, step=self.step_no,
                             live_slots=len(live))
 
-    def _tick_decode(self, t_tick: float, live) -> bool:
+    def _tick_decode(self, t_tick: float, live, phase) -> bool:
         """One decode step, fully synchronous — the reference
         implementation the async runtime must match stream-for-stream:
         dispatch, BLOCK on the host transfer, commit."""
@@ -1819,16 +1892,20 @@ class _ServeLoop:
         k = self.stats.decode_steps  # the chaos-script step index
         self._chaos_hooks(k)
         t_d = time.perf_counter()
+        phase.to("decode_dispatch")
         try:
             logits, ok_vec = eng._dispatch_decode(
                 self.params, res, self.chaos, k, self.guard, self.tracer)
         except DecodeStateLostError:
+            phase.to("tick_bookkeep")
             self._rebuild_lost_state(k)
             self._acct_tick(t_tick, t_d, 0.0)
             return True
         toks = self._sample(live, logits)
+        phase.to("fetch_tokens")
         toks_host, ok_host = self._fetch(toks, ok_vec)
         wall = time.perf_counter() - t_d
+        phase.to("tick_bookkeep")
         self._commit_arrival(live, None, toks_host, ok_host, wall)
         self._acct_tick(t_tick, t_d, wall)
         return True
@@ -1970,16 +2047,21 @@ class _AsyncServeLoop(_ServeLoop):
         self.dispatch_no = 0
 
     # ---------------------------------------------------------- settling
-    def _settle_step(self, p: _PendingStep) -> float:
-        """Block until ``p``'s transfer lands, then run the commit
-        point. Returns the seconds actually spent BLOCKED (the only
-        part of the settle that is device wait, not host work)."""
+    def _settle_step(self, p: _PendingStep, commit_span: str) -> float:
+        """Block until ``p``'s transfer lands (``fetch_tokens``), then
+        run the commit point under the span of the bucket its time is
+        accounted to (``commit_span``). Returns the seconds actually
+        spent BLOCKED (the only part of the settle that is device wait,
+        not host work)."""
         t_s = time.perf_counter()
-        toks_host, ok_host = self._fetch(p.toks, p.ok_vec)
+        with span("fetch_tokens", tracer=self.tracer):
+            toks_host, ok_host = self._fetch(p.toks, p.ok_vec)
         blocked = time.perf_counter() - t_s
         self.stats.host_device_s += blocked
         wall = time.perf_counter() - p.t_d
-        self._commit_arrival(p.live, p.epochs, toks_host, ok_host, wall)
+        with span(commit_span, tracer=self.tracer):
+            self._commit_arrival(p.live, p.epochs, toks_host, ok_host,
+                                 wall)
         return blocked
 
     def _settle_pending(self) -> None:
@@ -1990,7 +2072,7 @@ class _AsyncServeLoop(_ServeLoop):
         if p is None:
             return
         t0 = time.perf_counter()
-        blocked = self._settle_step(p)
+        blocked = self._settle_step(p, "tick_bookkeep")
         self.stats.host_bookkeep_s += max(
             time.perf_counter() - t0 - blocked, 0.0)
 
@@ -2004,11 +2086,18 @@ class _AsyncServeLoop(_ServeLoop):
         return True
 
     # ------------------------------------------------------------- decode
-    def _tick_decode(self, t_tick: float, live) -> bool:
+    def _tick_decode(self, t_tick: float, live, phase) -> bool:
         """One double-buffered decode step: dispatch k+1 FIRST (device
         starts immediately), then settle k's pending transfer and do
         its commit bookkeeping while k+1 executes. Steady state: one
-        blocking host sync (the settle fetch) per committed step."""
+        blocking host sync (the settle fetch) per committed step.
+
+        Spans: tick entry -> step issued is ``tick_dispatch``
+        (``decode_dispatch`` nested in it); behind a step in flight the
+        tick says ``pipelined=1`` and that wall is counted in
+        ``host_overlap_s``, not ``host_dispatch_s``. Everything after
+        the issue is ``tick_overlap`` except the settle's blocking
+        ``fetch_tokens``."""
         from .resilience import DecodeStateLostError
 
         eng, res, stats = self.engine, self.res, self.stats
@@ -2016,12 +2105,16 @@ class _AsyncServeLoop(_ServeLoop):
         # this tick's prework — host work only hits the critical path
         # when the pipeline is empty (first step of a burst)
         pipelined = self._pending is not None
+        if pipelined:
+            phase.tick_args["pipelined"] = 1
         k = self.dispatch_no  # chaos keys on dispatch order
         self._chaos_hooks(k)
         t_d = time.perf_counter()
         try:
-            logits, ok_vec = eng._dispatch_decode(
-                self.params, res, self.chaos, k, self.guard, self.tracer)
+            with span("decode_dispatch", tracer=self.tracer):
+                logits, ok_vec = eng._dispatch_decode(
+                    self.params, res, self.chaos, k, self.guard,
+                    self.tracer)
         except DecodeStateLostError:
             # settle FIRST: at this logical point the sync loop had
             # already committed step k-1's tokens — the rebuild's
@@ -2030,6 +2123,7 @@ class _AsyncServeLoop(_ServeLoop):
             # loss that killed them too loses that step's tokens (the
             # requests re-prefill one token earlier — still a valid
             # stream position)
+            phase.close()
             try:
                 self._settle_pending()
             except Exception:
@@ -2039,6 +2133,7 @@ class _AsyncServeLoop(_ServeLoop):
             stats.host_ticks += 1
             return True
         issued = time.perf_counter()
+        phase.to("tick_overlap")
         if pipelined:
             stats.host_overlap_s += max(issued - t_tick, 0.0)
         else:
@@ -2048,7 +2143,8 @@ class _AsyncServeLoop(_ServeLoop):
         # entire commit bookkeeping all overlap its execution — that is
         # the double buffer. Only the settle's blocking fetch counts as
         # device wait
-        toks = self._sample(live, logits, pending=self._pending)
+        with span("decode_dispatch", tracer=self.tracer):
+            toks = self._sample(live, logits, pending=self._pending)
         ok_arr = (ok_vec,) if ok_vec is not None else ()
         for arr in (toks,) + ok_arr:
             try:
@@ -2059,7 +2155,9 @@ class _AsyncServeLoop(_ServeLoop):
             toks=toks, ok_vec=ok_vec, live=list(live),
             epochs=[self.sched.slot_epoch[s] for s, _ in live], t_d=t_d)
         self.dispatch_no += 1
-        blocked = self._settle_step(prev) if prev is not None else 0.0
+        phase.close()
+        blocked = (self._settle_step(prev, "tick_overlap")
+                   if prev is not None else 0.0)
         stats.host_overlap_s += max(
             time.perf_counter() - issued - blocked, 0.0)
         stats.host_ticks += 1

@@ -9,8 +9,9 @@ extracts every name literal passed to a tracer emission method
 (``span`` / ``span_at`` / ``event`` / ``event_at`` / ``complete`` /
 ``counter``) across the whole ``flexflow_tpu/`` package — plus the
 request-trace phase-span names registered in ``reqtrace._PHASE_SPANS``
-— and requires each to appear verbatim (whole-token) in the
-observability doc. Wired into tier-1 via tests/test_housekeeping_r16.py
+and the hot-loop span registry ``obs.trace.SPANS`` (the profiler-clock
+spans of ``fit`` and the serve tick) — and requires each to appear
+verbatim (whole-token) in the observability doc. Wired into tier-1 via tests/test_housekeeping_r16.py
 so drift fails CI.
 
 A few call sites build names dynamically (f-strings); those cannot be
@@ -44,6 +45,10 @@ _EMIT_RE = re.compile(
 _PHASE_MAP_RE = re.compile(r"_PHASE_SPANS\s*=\s*\{(.*?)\}", re.S)
 _PHASE_VAL_RE = re.compile(r':\s*"([a-z_][a-z0-9_]*)"')
 
+# obs/trace.py's hot-loop span registry: the names are the mapping's keys
+_SPANS_RE = re.compile(r"^SPANS\b[^\n]*\{(.*?)^\}", re.S | re.M)
+_SPANS_KEY_RE = re.compile(r'^\s*"([a-z_][a-z0-9_]*)":', re.M)
+
 #: dynamically-built names (f-string call sites) -> the substring that
 #: must still appear in the source, so the pin cannot outlive the code
 DYNAMIC_NAMES = {
@@ -67,6 +72,8 @@ def emitted_names(pkg_dir: str) -> "tuple[set, list]":
             names.update(_EMIT_RE.findall(src))
             for m in _PHASE_MAP_RE.finditer(src):
                 names.update(_PHASE_VAL_RE.findall(m.group(1)))
+            for m in _SPANS_RE.finditer(src):
+                names.update(_SPANS_KEY_RE.findall(m.group(1)))
     blob = "\n".join(sources)
     stale = []
     for name, marker in DYNAMIC_NAMES.items():
