@@ -4,11 +4,13 @@ every request that is due (``engine.admit``) and ``loop.tick()``. One process
 holds the chip; no second process, no server that outlives the run.
 
 Set-up (counted in ``setup_s``): build, ``compile()``, weights on the device
-from the seed, the engine with its KV pool, a warm wave that compiles the
-decode step and every prefill bucket the mix's clipped prompt range can hit
-(its streams are what the plain reference is compared with), and the
-pre-roll: arrivals begin ``pre_roll_s`` before the window so that the window
-opens at steady occupancy.
+from ``CHECK_SEED``, the engine with its KV pool, a warm wave (its prompts
+from ``CHECK_SEED`` too) that compiles the decode step and every prefill
+bucket the mix's clipped prompt range can hit (its streams are what the
+plain reference is compared with: one value per program and cell, whatever
+the run's seed, which draws the window's arrivals, lengths and prompts), and
+the pre-roll: arrivals begin ``pre_roll_s`` before the window so that the
+window opens at steady occupancy.
 
 Times are taken by the benchmark: a request's clock starts when it was
 **due**, which only the generator knows. ``first_token_ms``
@@ -30,9 +32,9 @@ import time
 import numpy as np
 
 from benchmark import spans
-from benchmark.drivers.common import (CompileCounter, mosaic_calls,
-                                      place_cache, program_member,
-                                      start_trace, stop_trace)
+from benchmark.drivers.common import (CHECK_SEED, CompileCounter,
+                                      mosaic_calls, place_cache,
+                                      program_member, start_trace, stop_trace)
 
 # A generated token is accepted where its logit under the plain reference
 # (f32, "highest", full forward over prompt + generated tokens) lies within
@@ -41,8 +43,10 @@ from benchmark.drivers.common import (CompileCounter, mosaic_calls,
 # computes in bf16 through 48 pre-LN blocks: its logits carry an error of
 # about 2^-8 of the logit scale per rounding, accumulated over the depth;
 # measured on the chip the worst gap was 0.015 at a logit spread
-# (max - median) of 1.04 (PERF.md Findings PR 22). fp8 rounds sixteen times
-# as coarsely as bf16 and would move logits by tenths; a dropped bias more.
+# (max - median) of 1.04 (PERF.md Findings PR 22; 0.002-0.011 over five more
+# seeds of weights and prompts in PR 29, before both came from CHECK_SEED).
+# fp8 rounds sixteen times as coarsely as bf16 and would move logits by
+# tenths; a dropped bias more.
 LOGIT_GAP_TOL = 0.06
 # Output tokens of a checked request: enough positions for the comparison,
 # few enough that the warm wave stays short (a decode step is ~0.2 s).
@@ -80,7 +84,7 @@ def build_engine(ctx, info):
     eng_cfg = ctx.cell["engine"]
     model_cfg, build = ctx.model_config(batch_size=8)
     config = FFConfig()
-    config.parse_args(["-b", "8", "--seed", str(ctx.seed)]
+    config.parse_args(["-b", "8", "--seed", str(CHECK_SEED)]
                       + list(ctx.config.get("compile_flags", []))
                       + list(ctx.cell.get("compile_flags", [])))
     ff = FFModel(config)
@@ -122,9 +126,9 @@ def make_request(arrival, tag: int):
 def warm_wave(ctx, eng, gen, mix, vocab):
     """Compile what the window will use, and produce the streams the
     reference is compared with: one prompt at the top of every bucket the
-    mix can hit, four requests of the mix's own distribution, and one of
-    those twice (equal prompts must give equal streams)."""
-    rng = np.random.default_rng([ctx.seed, 0xA11])
+    mix can hit, four requests of the mix's own distribution, and each of
+    those a second time (equal prompts must give equal streams)."""
+    rng = np.random.default_rng([CHECK_SEED, 0xA11])
     lo, hi = int(mix["prompt_len"]["min"]), int(mix["prompt_len"]["max"])
     cap = int(mix["max_total_tokens"])
     reqs = []
@@ -132,15 +136,15 @@ def warm_wave(ctx, eng, gen, mix, vocab):
         n = min(b, hi, cap - 4)
         reqs.append(gen.Arrival(len(reqs), 0.0, rng.integers(
             0, vocab, size=n).astype(np.int32), 4))
-    sample = gen.generate(mix, 1.0, ctx.seed + 7919, 64.0, vocab)[:4]
+    sample = gen.generate(mix, 1.0, CHECK_SEED + 7919, 64.0, vocab)[:4]
     for a in sample:
         a.max_new_tokens = min(a.max_new_tokens, CHECK_TOKENS)
-    checked = list(range(len(reqs), len(reqs) + len(sample) + 1))
-    reqs += sample
-    # the same prompt again: it is admitted after its twin's prefill, so it
-    # takes the prefix-hit (chunk) path and is checked like the others
-    reqs.append(sample[0])
-    twin = (checked[0], len(reqs) - 1)
+    checked = list(range(len(reqs), len(reqs) + 2 * len(sample)))
+    # the same prompts again: each is admitted after its twin's prefill, so
+    # it takes the prefix-hit (chunk) path (one chunk shape for all: the
+    # suffix left to compute is under a block) and is checked like the others
+    reqs += sample + sample
+    twins = [(i, i + len(sample)) for i in checked[:len(sample)]]
     sched = new_scheduler(eng)
     loop = eng.start_serve(sched)
     live = [make_request(a, i) for i, a in enumerate(reqs)]
@@ -149,10 +153,12 @@ def warm_wave(ctx, eng, gen, mix, vocab):
     while loop.tick():
         pass
     loop.finish()
-    return live, checked, twin
+    return live, checked, twins
 
 
-def reference_check(ctx, ff, live, checked, twin, info):
+def reference_check(ctx, ff, live, checked, twins, info):
+    """Fetch what the comparison needs — the plain reference's logits at
+    every generated position of the checked requests — and compare."""
     import jax
 
     ref = ctx.reference().Reference(ff.params, ctx.config)
@@ -160,7 +166,7 @@ def reference_check(ctx, ff, live, checked, twin, info):
     # shape and compiles once per checkout, not once per drawn length; under
     # the causal mask the padding cannot reach an earlier position
     pad_to = int(ctx.traffic["max_total_tokens"])
-    worst_gap, spread, rows_of = 0.0, [], {}
+    rows_of, streams = {}, {}
     for i in checked:
         r = live[i]
         ids = np.concatenate([r.prompt, np.asarray(r.generated, np.int32)])
@@ -168,35 +174,71 @@ def reference_check(ctx, ff, live, checked, twin, info):
         padded_ids[:len(ids) - 1] = ids[:-1]
         logits = np.asarray(jax.device_get(ref.logits(padded_ids)),
                             np.float32)[:len(ids) - 1]
-        rows = logits[len(r.prompt) - 1:]           # one per generated token
-        rows_of[i] = rows
-        chosen = rows[np.arange(len(r.generated)), np.asarray(r.generated)]
+        rows_of[i] = logits[len(r.prompt) - 1:]     # one per generated token
+        streams[i] = list(r.generated)
+    stats, verdicts = compare(rows_of, streams, twins)
+    info["reference: worst logit gap of a chosen token"] = round(
+        stats["logit_gap_max"], 5)
+    info["reference: logit spread (max - median), median"] = round(
+        stats["logit_spread"], 4)
+    info["equal prompts: tokens equal before the streams part, of"] = [
+        f"{j}/{len(streams[a])}"
+        for j, (a, _) in zip(stats["twin_tokens_equal"], twins)]
+    if "twin_tie_gap" in stats:
+        info["equal prompts: widest reference gap where streams part"] = \
+            round(stats["twin_tie_gap"], 5)
+    verdicts["warm_wave_all_ok"] = all(
+        r.outcome == "ok" and len(r.generated) == r.max_new_tokens
+        for r in live)
+    return stats, verdicts
+
+
+def compare(rows_of, streams, twins):
+    """The comparison itself, arrays in: (the statistics, the verdicts).
+    ``rows_of[i]`` holds the reference's logits at every generated position
+    of checked request ``i`` (tokens, vocabulary), ``streams[i]`` its tokens,
+    ``twins`` the pairs of requests with equal prompts. ``logit_gap_max`` is
+    what the guard metric ``check_logit_gap_max`` reports."""
+    worst_gap, spread = 0.0, []
+    for i, rows in rows_of.items():
+        tokens = np.asarray(streams[i])
+        chosen = rows[np.arange(len(tokens)), tokens]
         worst_gap = max(worst_gap, float(np.max(rows.max(axis=1) - chosen)))
         spread.append(float(np.median(rows.max(axis=1)
                                       - np.median(rows, axis=1))))
-    info["reference: worst logit gap of a chosen token"] = round(worst_gap, 5)
-    info["reference: logit spread (max - median), median"] = round(
-        float(np.median(spread)), 4)
     # Two equal prompts: one takes the bucket path, its twin the prefix-hit
-    # path, and in bf16 the two round a near-tie differently in about half of
-    # the runs (PERF.md, correct). So the streams are equal up to the first
-    # position where the reference itself all but ties the two tokens chosen.
-    a, b = twin
-    ga, gb = list(live[a].generated), list(live[b].generated)
-    same = ga == gb
-    info["equal prompts gave equal streams"] = same
-    if not same:
-        j = next(k for k in range(min(len(ga), len(gb))) if ga[k] != gb[k])
-        tie = abs(float(rows_of[a][j][ga[j]] - rows_of[a][j][gb[j]]))
-        info["equal prompts: reference gap where the streams part"] = \
-            (j, round(tie, 5))
-        same = len(ga) == len(gb) and tie <= LOGIT_GAP_TOL
-    return {
+    # path, and in bf16 the two may round a near-tie differently (PERF.md,
+    # correct). So a pair's streams are equal up to the first position where
+    # the reference itself all but ties the two tokens chosen; after it the
+    # two continue other texts, each held to the reference by the gap above.
+    equal_before, ties, same = [], [], True
+    for a, b in twins:
+        ga, gb = streams[a], streams[b]
+        j = next((k for k in range(min(len(ga), len(gb))) if ga[k] != gb[k]),
+                 min(len(ga), len(gb)))
+        equal_before.append(j)
+        same = same and len(ga) == len(gb)
+        if j < min(len(ga), len(gb)):
+            ties.append(abs(float(rows_of[a][j][ga[j]]
+                                  - rows_of[a][j][gb[j]])))
+    stats = {"logit_gap_max": worst_gap,
+             "logit_spread": float(np.median(spread)),
+             "twin_tokens_equal": equal_before}
+    if ties:
+        stats["twin_tie_gap"] = max(ties)
+        same = same and max(ties) <= LOGIT_GAP_TOL
+    return stats, {
         "tokens_within_reference_gap": worst_gap <= LOGIT_GAP_TOL,
-        "equal_prompts_equal_streams_up_to_a_tie": same,
-        "warm_wave_all_ok": all(r.outcome == "ok" and
-                                len(r.generated) == r.max_new_tokens
-                                for r in live)}
+        "equal_prompts_equal_streams_up_to_a_tie": same}
+
+
+def compared(stats) -> list:
+    """(name, value, limit) of every number the comparison holds to a
+    limit, for the run's last lines."""
+    out = [("check_logit_gap_max", stats["logit_gap_max"], LOGIT_GAP_TOL)]
+    if "twin_tie_gap" in stats:
+        out.append(("twin_tie_gap", stats["twin_tie_gap"], LOGIT_GAP_TOL))
+    return out
 
 
 def decode_step_text(eng) -> str:
@@ -274,11 +316,13 @@ def run(ctx) -> dict:
     gen = ctx.generator()
 
     t = time.perf_counter()
-    live, checked, twin = warm_wave(ctx, eng, gen, mix, vocab)
+    live, checked, twins = warm_wave(ctx, eng, gen, mix, vocab)
     warm_s = time.perf_counter() - t
     checks["decode_compiles_once"] = eng.decode_compiles == 1
     t = time.perf_counter()
-    checks.update(reference_check(ctx, ff, live, checked, twin, info))
+    check_stats, verdicts = reference_check(ctx, ff, live, checked, twins,
+                                            info)
+    checks.update(verdicts)
     check_s = time.perf_counter() - t
     del live
 
@@ -362,10 +406,10 @@ def run(ctx) -> dict:
                 window_cm.__enter__()
                 now = clock_ms()
             snap_open = (now, snapshot(stats), sched.active + sched.queued,
-                         counter.n)
+                         counter.n, len(counter.names))
         if snap_close is None and now >= w_close:
             snap_close = (now, snapshot(stats), sched.active + sched.queued,
-                          counter.n, sched.queued)
+                          counter.n, sched.queued, len(counter.names))
             if window_cm is not None:
                 window_cm.__exit__(None, None, None)
                 trace_file = stop_trace(ctx)
@@ -432,8 +476,11 @@ def run(ctx) -> dict:
         "compiles_in_window": compiles_in_window,
         "prefix_hits": stats.prefix_hits,
         "outcomes": dict(stats.outcomes)})
+    if compiles_in_window:
+        info["lowered_in_window"] = counter.names[snap_open[4]:snap_close[5]]
     facts = {
         "kind": "serve", "info": info, "checks": checks,
+        "check_stats": check_stats, "compared": compared(check_stats),
         "correct": all(checks.values()),
         "attempted": len(in_window), "failed": failed,
         "end_to_end": {

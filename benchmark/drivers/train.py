@@ -2,13 +2,15 @@
 dataloader and prefetch running, telemetry off inside the window.
 
 Set-up (counted in ``setup_s``): build, ``compile()`` (with the search where
-the cell's flags ask for one), weights on the device from the seed, the
-synthetic set from the seed, one step on the check batch (it compiles the
-train step and gives the loss and gradients the plain reference is compared
-with), one short warm ``fit``. Then the window: whole passes over the
-synthetic set (``fit(epochs=1)``, each ending in ``fit``'s own
-``block_until_ready``) until ``--seconds`` have gone; ``train_tokens_per_s``
-is the median over those passes.
+the cell's flags ask for one), weights on the device from ``CHECK_SEED``, the
+synthetic set from the run's seed, one step on the check batch (from
+``CHECK_SEED``; it compiles the train step and gives the loss and gradients
+the plain reference is compared with), one short warm ``fit``. So the
+comparison with the reference is one value per program and cell, whatever
+the run's seed; the seed draws the set that is timed. Then the window: whole
+passes over the synthetic set (``fit(epochs=1)``, each ending in ``fit``'s
+own ``block_until_ready``) until ``--seconds`` have gone;
+``train_tokens_per_s`` is the median over those passes.
 
 The driver names no configuration. What belongs to one — the builder and its
 fields (``configs/<config>.json``), the plain loss and gradients, the
@@ -27,36 +29,47 @@ import time
 import numpy as np
 
 from benchmark import spans
-from benchmark.drivers.common import (CompileCounter, mosaic_calls,
-                                      place_cache, program_member,
-                                      start_trace, stop_trace)
+from benchmark.drivers.common import (CHECK_SEED, CompileCounter,
+                                      mosaic_calls, place_cache,
+                                      program_member, start_trace, stop_trace)
 
 # Tolerances of the comparison with the plain reference (f32, matmul
 # precision "highest") on the check batch. The system computes in bf16
-# (8 mantissa bits: one rounding is 2^-9 relative) through 24 post-LN blocks.
+# (8 mantissa bits: one rounding is 2^-9 relative) through 24 post-LN blocks,
+# and hands out its softmax in bf16.
 # Loss: the log-probability of a 2-class softmax over mean-pooled features.
-# An error d of the logit difference moves it by about 0.7 d relative, and
-# bf16 leaves d near 0.005-0.01: measured 0.01-0.75% over 16 runs on one
-# check sequence on the chip (PERF.md, correct), so 1% was under three
-# standard deviations and would have failed a correct run in a hundred. A
-# dropped term moves the loss by tenths; the gradients below are the tight
-# check.
-LOSS_TOL = 3e-2
+# The system hands out its softmax in bf16, so its p(label) is off by a
+# fraction of the bf16 step of p, and the loss by that over p: 0.42 steps
+# (median), 2.1 (99th percentile), 2.40 at most over 254 seeds of weights
+# and sequence on the chip (PERF.md, correct; PR 29), whatever the p.
+# The *relative* loss error divides by a loss that goes to 0 as p(label)
+# nears 1 (28% at p 0.988: the tail that printed `correct: false` on the
+# accepted tree), so it is a statistic of the draw: the draw is CHECK_SEED's,
+# p(label) 0.128 at 512 tokens and 0.118 at 4096, where one bf16 step of p
+# is 0.37% / 0.19% of the loss. There the tree reads 0.058% / 0.113%, and
+# the 37 of those seeds with p(label) under 0.2 (what a sound program whose
+# rounding falls otherwise would read) 0.13% (median) to 0.76%. The limit is
+# twice that largest, and above it by more than its distance to the median:
+# four steps at 512 tokens. It is there for a part of the batch left out of
+# the loss: one row of 32 left out of the sum reads 3.1%
+# (tests/test_check_seed.py: refused by this check alone), a chip's share of
+# four 25%. The control below moves the loss by 1.0-3.4% and is not this
+# check's to refuse.
+LOSS_TOL = 1.5e-2
 # Gradients: relative L2 error of every weight's gradient in the parameter
 # groups the reference names, on one check sequence. Every element carries
-# ~sqrt(depth) bf16 roundings of activations and cotangents; measured
-# 0.4-2.1% on the chip wherever the check ran on one sequence, largest in
-# the first layer's first dense kernel (PERF.md, correct). fp8 (3 mantissa
-# bits, 2^-4 a rounding) would give 30%+, a dropped term (a bias, a
+# ~sqrt(depth) bf16 roundings of activations and cotangents: over the same
+# 220 seeds the worst weight (`l0_fc1.kernel` in 182) reads 2.3% (median),
+# 3.8% at most where the loss passed, 23% in every weight at once where
+# p(label) = 0.988 (the cotangent p - onehot cancels in bf16). The control,
+# the reference with operands and cotangents in three mantissa bits (fp8) in
+# the program's place, reads 18% on the chip; a dropped term (a bias, a
 # residual) 100%.
 GRAD_TOL = 8e-2
 
 
 def run(ctx) -> dict:
     import jax
-
-    from flexflow_tpu import AdamOptimizer, FFConfig, FFModel, LossType
-    from flexflow_tpu.ffconst import MetricsType
 
     place_cache()
     counter = CompileCounter()
@@ -69,21 +82,8 @@ def run(ctx) -> dict:
 
     # ---- build + compile() (search included) + weights on the device
     t = time.perf_counter()
-    model_cfg, build = ctx.model_config(batch_size=batch, seq_len=seq)
     search_log = ctx.side_file("search.jsonl")
-    config = FFConfig()
-    config.parse_args(["-b", str(batch), "--seed", str(ctx.seed),
-                       "--search-log-file", search_log]
-                      + list(ctx.config.get("compile_flags", []))
-                      + list(cell.get("compile_flags", [])))
-    ff = FFModel(config)
-    build(ff, model_cfg)
-    opt, asks = cell["optimizer"], ctx.config["train"]
-    with spans.span("compile"):
-        ff.compile(optimizer=AdamOptimizer(ff, alpha=float(opt["lr"])),
-                   loss_type=getattr(LossType, asks["loss_type"]),
-                   metrics=[getattr(MetricsType, m)
-                            for m in asks["metrics"]])
+    ff = compiled_model(ctx, batch, seq, search_log)
     jax.block_until_ready(ff.params)
     ff_compile_s = time.perf_counter() - t
     info["mesh"] = dict(ff.mesh.shape)
@@ -105,7 +105,7 @@ def run(ctx) -> dict:
     t = time.perf_counter()
     gen = ctx.generator()
     x, y = gen.generate(job, ctx.seed, batch, ctx.config)
-    cx, cy, cx_tiled, cy_tiled = gen.check_batch(job, ctx.seed, batch,
+    cx, cy, cx_tiled, cy_tiled = gen.check_batch(job, CHECK_SEED, batch,
                                                  ctx.config)
     data_s = time.perf_counter() - t
 
@@ -116,9 +116,11 @@ def run(ctx) -> dict:
 
     # ---- compare with the plain reference (outside the window)
     t = time.perf_counter()
-    checks.update(reference_check(ctx, ref, ff, params0, cx, cy, sys_loss,
-                                  float(opt.get("beta1", 0.9)), info,
-                                  pipelined=bool(search.get("pipeline"))))
+    check_stats, verdicts = reference_check(
+        ctx, ref, ff, params0, cx, cy, sys_loss,
+        float(cell["optimizer"].get("beta1", 0.9)), info,
+        pipelined=bool(search.get("pipeline")))
+    checks.update(verdicts)
     del params0
     check_s = time.perf_counter() - t
 
@@ -151,7 +153,8 @@ def run(ctx) -> dict:
     text_s = time.perf_counter() - t
 
     steps_per_pass = int(job["batches_per_epoch"])
-    facts = {"info": info, "checks": checks}
+    facts = {"info": info, "checks": checks, "check_stats": check_stats,
+             "compared": compared(check_stats)}
     if ctx.trace:
         # the loss after 32 steps from the seed, read with telemetry on (it
         # syncs every step, so never inside a measured window)
@@ -173,9 +176,9 @@ def run(ctx) -> dict:
     seconds = min(ctx.seconds, float(cell.get("trace_seconds", 6.0))) \
         if ctx.trace else ctx.seconds
     setup_s = ctx.since_start()
-    n_compiles0 = counter.n
+    n_compiles0, n_lowered0 = counter.n, len(counter.names)
     passes, pass_ok, pass_loss = [], [], []
-    loss_key = asks.get("pass_loss")
+    loss_key = ctx.config["train"].get("pass_loss")
     t_open = time.perf_counter()
     with spans.span(spans.WINDOW):
         while time.perf_counter() - t_open < seconds:
@@ -210,6 +213,8 @@ def run(ctx) -> dict:
                           "step_text": round(text_s, 2),
                           "warm_fit": round(warm_s, 2)},
         "compiles_in_window": compiles_in_window})
+    if compiles_in_window:
+        info["lowered_in_window"] = counter.names[n_lowered0:]
     facts.update({
         "kind": "train", "correct": all(checks.values()),
         "attempted": len(passes) * steps_per_pass,
@@ -226,6 +231,30 @@ def run(ctx) -> dict:
         "compile_s": ff_compile_s - (search_s or 0.0) + first_step_s,
         "search_s": search_s, "step_module": "jit_step"})
     return facts
+
+
+def compiled_model(ctx, batch: int, seq: int, search_log: str):
+    """The cell's model, built and through ``compile()`` (with the search
+    where the cell's flags ask for one), its weights on the device from
+    ``CHECK_SEED``."""
+    from flexflow_tpu import AdamOptimizer, FFConfig, FFModel, LossType
+    from flexflow_tpu.ffconst import MetricsType
+
+    model_cfg, build = ctx.model_config(batch_size=batch, seq_len=seq)
+    config = FFConfig()
+    config.parse_args(["-b", str(batch), "--seed", str(CHECK_SEED),
+                       "--search-log-file", search_log]
+                      + list(ctx.config.get("compile_flags", []))
+                      + list(ctx.cell.get("compile_flags", [])))
+    ff = FFModel(config)
+    build(ff, model_cfg)
+    opt, asks = ctx.cell["optimizer"], ctx.config["train"]
+    with spans.span("compile"):
+        ff.compile(optimizer=AdamOptimizer(ff, alpha=float(opt["lr"])),
+                   loss_type=getattr(LossType, asks["loss_type"]),
+                   metrics=[getattr(MetricsType, m)
+                            for m in asks["metrics"]])
+    return ff
 
 
 def fit_with_losses(ctx, ff, x, y, batch) -> list:
@@ -277,50 +306,102 @@ def train_step_text(ff, x1, y1):
 
 def reference_check(ctx, ref, ff, params0, cx, cy, sys_loss, beta1, info,
                     pipelined=False):
-    """Loss and gradients of the first step against the plain reference, on
-    the parameter groups the configuration's reference file names. Adam's
-    first moment after one step from zero is (1 - beta1) * g, so the
-    gradients are read from the optimizer state the real train step wrote —
-    no second program. Where the search log says the plan is a pipeline,
-    ``fit`` trains through a trainer whose optimizer state the model does
-    not hand out: the loss is compared, the gradients are not reachable."""
+    """Loss and gradients of the first step against the plain reference:
+    fetch the arrays, compare them, log every statistic."""
+    if pipelined:
+        info["reference"] = "pipelined plan: loss compared, gradients not"
+    ref_loss, sys_grads, ref_grads = reference_arrays(
+        ctx, ref, ff, params0, cx, cy, beta1, pipelined)
+    stats, verdicts = compare(sys_loss, ref_loss, sys_grads, ref_grads)
+    for name, err in stats.get("grad_rel_err", {}).items():
+        info[f"grad_rel_err {name}"] = round(err, 5)
+    info["first_step_loss system/reference"] = (round(sys_loss, 6),
+                                                round(ref_loss, 6))
+    info["p_label of the check sequence"] = round(stats["p_label"], 4)
+    info["first_step_loss error in bf16 steps of p_label"] = round(
+        float(stats["loss_err_p_steps"]), 3)
+    return stats, verdicts
+
+
+def reference_arrays(ctx, ref, ff, params0, cx, cy, beta1, pipelined=False):
+    """(the reference's loss, the system's gradients, the reference's) on
+    the parameter groups the configuration's reference file names, as
+    ``{group: {weight: float32 array}}``. Adam's first moment after one step
+    from zero is (1 - beta1) * g, so the system's gradients are read from
+    the optimizer state the real train step wrote — no second program. Where
+    the search log says the plan is a pipeline, ``fit`` trains through a
+    trainer whose optimizer state the model does not hand out: the loss is
+    compared, the gradients are not reachable (both None)."""
     import jax
 
     names = [] if pipelined else list(ref.checked_params(ff.params,
                                                           ctx.config))
     moments = ff.opt_state.get("m", {}) if isinstance(ff.opt_state, dict) \
         else {}
-    if pipelined:
-        info["reference"] = "pipelined plan: loss compared, gradients not"
-    elif not names or any(k not in moments for k in names):
+    if not pipelined and (not names or any(k not in moments for k in names)):
         raise SystemExit(
             f"benchmark: nothing to compare gradients on — the reference "
             f"names {names}, the optimizer state after one step holds first "
             f"moments for {sorted(moments)[:6]}... A run that checks no "
             f"gradient does not pass.")
-    sys_grads = {k: {w: np.asarray(jax.device_get(v), np.float32)
-                     / (1.0 - beta1) for w, v in moments[k].items()}
-                 for k in names}
     # the reference lives on one device; parameters are gathered to it
     dev = ctx.devices[0]
     ref_loss, ref_grads = ref.loss_and_grads(
         jax.device_put(params0, dev), jax.device_put(cx, dev),
         jax.device_put(cy, dev), ctx.config, wanted=names)
-    ref_loss = float(ref_loss)
-    worst = 0.0
-    for k in names:
-        for w, g in ref_grads[k].items():
-            g = np.asarray(jax.device_get(g), np.float32)
-            err = float(np.linalg.norm(sys_grads[k][w] - g)
-                        / max(np.linalg.norm(g), 1e-30))
-            info[f"grad_rel_err {k}.{w}"] = round(err, 5)
-            worst = max(worst, err)
-    loss_err = abs(sys_loss - ref_loss) / max(abs(ref_loss), 1e-12)
-    info["first_step_loss system/reference"] = (round(sys_loss, 6),
-                                                round(ref_loss, 6))
-    out = {"loss_matches_reference": loss_err <= LOSS_TOL}
-    if not pipelined:
-        out["grads_match_reference"] = worst <= GRAD_TOL
+    if pipelined:
+        return float(ref_loss), None, None
+
+    def host(tree, scale=1.0):
+        return {k: {w: np.asarray(jax.device_get(v), np.float32) * scale
+                    for w, v in tree[k].items()} for k in names}
+
+    return float(ref_loss), host(moments, 1.0 / (1.0 - beta1)), \
+        host(ref_grads)
+
+
+def compare(sys_loss, ref_loss, sys_grads, ref_grads):
+    """The comparison itself, arrays in: (the statistics, the verdicts).
+    ``sys_grads`` / ``ref_grads`` are ``{group: {weight: array}}`` or None
+    (a pipelined plan: the loss alone is compared). The statistics are what
+    the guard metrics ``check_loss_rel_err`` and ``check_grad_rel_err_max``
+    report: |system - reference| / reference of the first step's loss; the
+    relative L2 error of every compared weight's gradient, the largest of
+    them and the weight that holds it; and ``p_label`` = exp(-reference
+    loss), the probability the reference gives the check sequence's label
+    (the loss cotangent p - onehot cancels in bf16 as it nears 1, and every
+    gradient's error rises with it: PERF.md, correct)."""
+    p_label = float(np.exp(-ref_loss))
+    stats = {"loss_rel_err": abs(sys_loss - ref_loss)
+             / max(abs(ref_loss), 1e-12),
+             "p_label": p_label,
+             # the same error in bf16 steps of p(label): steady from draw
+             # to draw, where the relative error is not
+             "loss_err_p_steps": abs(float(np.exp(-sys_loss)) - p_label)
+             / 2.0 ** (np.floor(np.log2(max(p_label, 1e-30))) - 7)}
+    verdicts = {"loss_matches_reference": stats["loss_rel_err"] <= LOSS_TOL}
+    if sys_grads is not None:
+        errs = {}
+        for k, group in ref_grads.items():
+            for w, g in group.items():
+                g = np.asarray(g, np.float32)
+                d = np.asarray(sys_grads[k][w], np.float32) - g
+                errs[f"{k}.{w}"] = float(np.linalg.norm(d)
+                                         / max(np.linalg.norm(g), 1e-30))
+        worst = max(errs, key=errs.get)
+        stats.update({"grad_rel_err": errs, "grad_rel_err_max": errs[worst],
+                      "grad_rel_err_worst": worst})
+        verdicts["grads_match_reference"] = errs[worst] <= GRAD_TOL
+    return stats, verdicts
+
+
+def compared(stats) -> list:
+    """(name, value, limit) of every number the comparison holds to a
+    limit, for the run's last lines."""
+    out = [("check_loss_rel_err", stats["loss_rel_err"], LOSS_TOL)]
+    if "grad_rel_err_max" in stats:
+        out.append((f"check_grad_rel_err_max ({stats['grad_rel_err_worst']})",
+                    stats["grad_rel_err_max"], GRAD_TOL))
     return out
 
 

@@ -12,8 +12,9 @@ per-layer metric as new files and edits nothing here.
 
 The last line of standard output is the result: one JSON object with
 ``correct``, ``attempted``, ``failed``, ``metrics``, ``device`` and, traced,
-``breakdown``. Everything else goes to earlier lines (``[bench] ...``) and,
-where ``chiprun_out/`` exists, to
+``breakdown``. Everything else goes to earlier lines (``[bench] ...``; the
+checks' verdicts and every compared number beside its limit also to the last
+lines of standard error) and, where ``chiprun_out/`` exists, to
 ``chiprun_out/bench/<cell>.seed<n>.trace<t>.json``.
 
 It measures on the platform the cell's file names (``tpu`` unless the file
@@ -225,8 +226,13 @@ def main() -> None:
     if ctx.trace and tr and tr.get("n_devices"):
         result["breakdown"] = {"device_ops": tr["device_ops"][:10],
                                "idle_gaps": tr["idle_gaps"][:10]}
-    for name, ok in facts.get("checks", {}).items():
-        log(f"check {name}: {ok}")
+    verdict_lines = [f"check {name}: {ok}"
+                     for name, ok in facts.get("checks", {}).items()]
+    # every number the comparison with the reference holds to a limit
+    verdict_lines += [f"compared {name}: {value:.6g} (limit {limit:g})"
+                      for name, value, limit in facts.get("compared", [])]
+    for line in verdict_lines:
+        log(line)
     for k, v in facts.get("info", {}).items():
         log(f"{k}: {v}")
     side = os.path.join(ROOT, "chiprun_out")
@@ -240,6 +246,12 @@ def main() -> None:
                                f"trace{args.trace}.json"), "w") as f:
             json.dump(keep, f, indent=1, default=str)
     print(json.dumps(result), flush=True)
+    # the driver's record of a run that is not correct keeps the end of
+    # standard error, and of standard output the last line's keys alone
+    # (the benchmark's contract; PERF.md, correct): so the verdicts and each
+    # compared number beside its limit are standard error's last lines
+    print("\n".join(f"[bench] {line}" for line in verdict_lines),
+          file=sys.stderr, flush=True)
 
 
 if __name__ == "__main__":

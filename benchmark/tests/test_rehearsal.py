@@ -12,7 +12,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)
 CELLS = os.path.join("benchmark", "tests", "cells")
 
 
-def run(workload, trace, cells=CELLS, devices=1, seconds="1.5"):
+def run(workload, trace, cells=CELLS, devices=1, seconds="1.5", seed=3):
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
     # the rehearsal leaves no compile cache behind in the checkout
@@ -20,7 +20,7 @@ def run(workload, trace, cells=CELLS, devices=1, seconds="1.5"):
                                                     "rehearsal_cache")
     return subprocess.run(
         [sys.executable, "benchmark/run.py", "--workload", workload, "--seed",
-         "3", "--seconds", seconds, "--trace", str(trace), "--cells", cells],
+         str(seed), "--seconds", seconds, "--trace", str(trace), "--cells", cells],
         cwd=ROOT, env=env, capture_output=True, text=True, timeout=600)
 
 

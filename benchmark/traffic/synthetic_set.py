@@ -35,8 +35,9 @@ def generate(job: dict, seed: int, batch: int, config: dict):
 
 
 def check_batch(job: dict, seed: int, batch: int, config: dict):
-    """One seeded sequence with label 0, alone and repeated to a batch of
-    ``batch`` rows: the batch's mean loss and gradients are those of the one
+    """One seeded sequence with label 0 (the train driver passes its
+    ``CHECK_SEED``, never the run's seed: the comparison with the reference
+    is about the program), alone and repeated to a batch of ``batch`` rows: the batch's mean loss and gradients are those of the one
     sequence, which is what the plain reference computes. One sequence and
     not two: the gradients of two sequences with different labels largely
     cancel in the last layers and in every bias, and the relative error of
