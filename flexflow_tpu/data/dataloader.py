@@ -56,36 +56,45 @@ def batch_iterator(arrays: Sequence[np.ndarray], batch_size: int,
     """``start_batch`` skips the first k batches of the (seed-determined)
     stream without materializing them — the exact-resume path: a run
     restored mid-epoch replays the same shuffle and continues at the batch
-    cursor the checkpoint recorded (resilience/session.py)."""
+    cursor the checkpoint recorded (resilience/session.py).
+
+    Unshuffled batches are views of ``arrays`` (basic slices, nothing is
+    copied on the host); shuffled batches are gathered copies."""
     n = arrays[0].shape[0]
+    if not shuffle:
+        # consecutive rows: the basic slice a[lo:hi] is the rows of
+        # a[idx[lo:hi]] as a view — an index array makes numpy copy the
+        # batch on this one thread, about 0.9 GB/s, slower than four chips
+        # train on it (PERF.md §6, PR 30). The batch aliases its source
+        # until its transfer ends; no consumer writes into a batch.
+        lo0 = start_batch * batch_size
+        m = max(n - lo0, 0)
+        for b in range(m // batch_size if drop_remainder
+                       else -(-m // batch_size)):
+            lo = lo0 + b * batch_size
+            yield [a[lo:min(lo + batch_size, n)] for a in arrays]
+        return
     idx = np.arange(n)
-    if shuffle:
-        np.random.default_rng(seed).shuffle(idx)
+    np.random.default_rng(seed).shuffle(idx)
     if start_batch > 0:
         # trim AFTER the shuffle: the remaining stream is identical to the
         # tail of an uninterrupted epoch at the same seed
         idx = idx[start_batch * batch_size:]
     m = len(idx)
-    if shuffle:
-        # native double-buffered staging: C++ gathers batch b+1 while batch b
-        # ships to the device (flexflow_tpu/native BatchPipeline; falls back
-        # to synchronous gather without the library)
-        from ..native import BatchPipeline
+    # native double-buffered staging: C++ gathers batch b+1 while batch b
+    # ships to the device (flexflow_tpu/native BatchPipeline; falls back
+    # to synchronous gather without the library)
+    from ..native import BatchPipeline
 
-        if drop_remainder or m % batch_size == 0:
-            yield from BatchPipeline(arrays, idx, batch_size)
-            return
-        from ..native import gather_rows
+    if drop_remainder or m % batch_size == 0:
+        yield from BatchPipeline(arrays, idx, batch_size)
+        return
+    from ..native import gather_rows
 
-        arrays = [np.ascontiguousarray(a) for a in arrays]
-        take = gather_rows
-    else:
-        def take(a, sl):
-            return a[sl]
-    nb = m // batch_size if drop_remainder else -(-m // batch_size)
-    for b in range(nb):
+    arrays = [np.ascontiguousarray(a) for a in arrays]
+    for b in range(-(-m // batch_size)):
         sl = idx[b * batch_size:(b + 1) * batch_size]
-        yield [take(a, sl) for a in arrays]
+        yield [gather_rows(a, sl) for a in arrays]
 
 
 def device_put_batch(arrays: List[np.ndarray], shardings: List[Any]):
@@ -99,8 +108,11 @@ def device_put_batch(arrays: List[np.ndarray], shardings: List[Any]):
 def new_input_stats() -> Dict[str, Any]:
     """The input pipeline's always-on counters (``FFModel.input_stats``):
     seconds the consumer waited for a batch, batches handed over, seconds
-    the producer gathered and shipped. Each key has one writing thread."""
-    return {"wait_s": 0.0, "batches": 0, "gather_s": 0.0, "put_s": 0.0}
+    the producer gathered and shipped, and the bytes it received as host
+    copies (batch arrays that own their data; a view of the set counts 0).
+    Each key has one writing thread."""
+    return {"wait_s": 0.0, "batches": 0, "gather_s": 0.0, "put_s": 0.0,
+            "copied_bytes": 0}
 
 
 def prefetch_iterator(it: Iterator, shardings: List[Any], depth: int = 2,
@@ -153,6 +165,9 @@ def prefetch_iterator(it: Iterator, shardings: List[Any], depth: int = 2,
                 stats["gather_s"] += t1 - t0
                 if batch is _END:
                     break
+                stats["copied_bytes"] += sum(
+                    a.nbytes for a in batch
+                    if isinstance(a, np.ndarray) and a.flags.owndata)
                 with span("batch_put",
                           bytes=sum(getattr(a, "nbytes", 0) for a in batch)):
                     staged = device_put_batch(batch, shardings)

@@ -481,11 +481,11 @@ class FFModel:
     def input_stats(self) -> Dict[str, Any]:
         """The input pipeline's counters over the most recent ``fit()``
         (always on, plain adds; reset per fit): ``wait_s`` the steps waited
-        for their batch (the ``dataloader_wait`` spans), ``batches`` handed
-        over, ``gather_s`` / ``put_s`` the producer thread spent in the
-        source iterator and in ``device_put`` (``batch_gather`` /
-        ``batch_put``). wait_s near gather_s + put_s: the producer is the
-        limit; wait_s near zero: it keeps up."""
+        for their batch (``dataloader_wait``), ``batches`` handed over,
+        ``gather_s`` / ``put_s`` the producer's time in the source iterator
+        and in ``device_put`` (``batch_gather`` / ``batch_put``),
+        ``copied_bytes`` that reached it as host copies (views count 0).
+        wait_s near gather_s + put_s: the producer is the limit; else not."""
         from .data.dataloader import new_input_stats
 
         return dict(getattr(self, "_input_stats", None) or new_input_stats())
@@ -888,14 +888,15 @@ class FFModel:
             chaos=None) -> PerfMetrics:
         """Training loop (reference: flexflow_cffi.py:2058-2100 — per batch:
         next_batch -> forward -> zero_gradients -> backward -> update inside a
-        Legion trace; here one fused jitted step per batch).
+        Legion trace; here one fused jitted step per batch). ``x`` and ``y``
+        are READ IN PLACE while fit runs: with ``shuffle=False`` a batch is a
+        view of the caller's array until its transfer ends, never a host copy.
 
         CacheOps in the graph are threaded as a device-side cache pytree;
-        their ``score_fn`` runs host-side every ``num_batches`` steps and the
-        scores land in ``self.cache_scores`` — the signal the MoE
-        cache/recompile pairing consumes (reference: cache.cc:291 +
-        moe.cc:180,204). ``recompile_state`` hooks the per-iteration dynamic
-        recompile check (FFModel::recompile_on_condition, model.cc:2422).
+        their ``score_fn`` runs host-side every ``num_batches`` steps; the
+        scores land in ``self.cache_scores`` (the MoE cache/recompile signal;
+        reference: cache.cc:291 + moe.cc:180,204). ``recompile_state`` hooks
+        the dynamic recompile check (recompile_on_condition, model.cc:2422).
 
         Fault tolerance (ISSUE 4, docs/fault_tolerance.md): when the config
         asks for it (``--checkpoint-dir``/``--checkpoint-every``/
@@ -903,10 +904,9 @@ class FFModel:
         loop — periodic async atomic checkpoints, SIGTERM/SIGINT preemption
         flush, exact resume of the data-pipeline cursor, and the divergence
         sentinel that skips non-finite steps and rolls back to the last
-        committed checkpoint. ``chaos`` takes a
-        ``resilience.ChaosPlan`` for deterministic fault injection (tests).
-        All of this is scoped to the SPMD path; the GPipe pipeline trainer
-        checkpoints only via explicit ``save_checkpoint`` calls."""
+        committed checkpoint. ``chaos`` takes a ``resilience.ChaosPlan`` for
+        deterministic fault injection (tests). SPMD path only: the GPipe
+        pipeline trainer checkpoints only via explicit ``save_checkpoint``."""
         import jax
 
         assert self.executor is not None, "call compile() first"

@@ -328,8 +328,9 @@ SPANS: Mapping[str, str] = MappingProxyType({
                   "end of an epoch",
     "fit_sync": "fit's final block_until_ready(loss)",
     # prefetch_iterator's producer thread
-    "batch_gather": "one next() of the source iterator: the numpy gather "
-                    "of one batch",
+    "batch_gather": "one next() of the source iterator: shuffled, the "
+                    "gather of one batch's rows into a copy; unshuffled, "
+                    "slicing views of the set (copies nothing)",
     "batch_put": "one device_put_batch(): host -> device, as sharded",
     "prefetch_backpressure": "the producer blocked on a full queue: it is "
                              "keeping up",
