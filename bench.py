@@ -1839,8 +1839,7 @@ def seqpar_decode_leg() -> dict:
             ff.compile(optimizer=SGDOptimizer(ff),
                        loss_type=LossType.LOSS_SPARSE_CATEGORICAL_CROSSENTROPY)
             eng = ServingEngine(ff, n_slots=2, max_decode_len=32,
-                                exact_decode=True, kv_block_size=8,
-                                seq_shards=shards)
+                                kv_block_size=8, seq_shards=shards)
             eng.generate([prompts[0]], max_new_tokens=4)  # warm the jits
             t0 = time.perf_counter()
             toks = eng.generate(prompts, max_new_tokens=12)

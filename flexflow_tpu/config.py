@@ -208,7 +208,7 @@ class FFConfig:
     # The reference's only inference artifact is an incomplete Triton
     # prototype — these knobs drive the JAX serving path instead.
     serve: bool = False          # run the examples' serve mode after compile
-    # decode-state ring-buffer capacity per slot: prompt + generated tokens
+    # decode-state capacity per slot: prompt + generated tokens
     # must fit; also the largest prefill bucket
     max_decode_len: int = 128
     # continuous-batching decode slots (the in-flight request ceiling);
@@ -218,13 +218,11 @@ class FFConfig:
     # search_all(objective="serving"); 0 = throughput-only
     slo_p99_ms: float = 0.0
     # paged KV cache (flexflow_tpu/serving/kvcache.py, docs/serving.md
-    # "Paged KV cache" + docs/decode_perf.md; ISSUE 12).
-    # KV-cache layout: "paged" (block pool + per-slot block tables —
-    # slot recycling is pointer bookkeeping, decode attention reads
-    # O(true_length) through the flash-decode kernel) or "ring" (the
-    # legacy per-slot max_len buffers)
-    kv_cache: str = "paged"
-    # tokens per KV block of the paged layout
+    # "Paged KV cache" + docs/decode_perf.md; ISSUE 12): a block pool +
+    # per-slot block tables — slot recycling is pointer bookkeeping,
+    # decode attention reads O(true_length) through the flash-decode
+    # kernel.
+    # tokens per KV block
     kv_block_size: int = 16
     # paged pool size in blocks (incl. the reserved garbage block);
     # 0 = auto (every slot can hold max_decode_len). Setting it smaller
@@ -234,7 +232,7 @@ class FFConfig:
     # KV storage dtype: "native" (model dtype; also lets the serving
     # search sweep the int8 axis) or "int8" (pin symmetric per-(token,
     # head) int8 with f32 scales — ~1/el the decode KV bandwidth, judged
-    # against a pinned tolerance band instead of the bitwise contract)
+    # against a pinned tolerance band)
     kv_dtype: str = "native"
     # prefix cache + chunked prefill (flexflow_tpu/serving/prefix.py,
     # docs/serving.md "Prefix cache & chunked prefill"; ISSUE 14).
@@ -242,8 +240,8 @@ class FFConfig:
     # cached prompt prefix (>= one full KV block) map its blocks into
     # their block table with zero prefill compute and prefill only the
     # suffix. "on" (default; paged, attention-only graphs) or "off".
-    # The hit path is bitwise the cold path, so enabling it changes no
-    # emitted token.
+    # In tier-1 the hit path emits the cold path's token streams; on
+    # the chip equal prompts may part at a reference tie (PERF.md §7).
     prefix_cache: str = "on"
     # chunked prefill: prompts/suffixes longer than this many tokens
     # prefill in fixed chunks co-scheduled with decode iterations, so a
@@ -276,8 +274,8 @@ class FFConfig:
     # serve-loop runtime (ISSUE 17, docs/serving.md "Async runtime"):
     # "sync" (reference: block on step k's tokens before dispatching
     # k+1) or "async" (double-buffered: dispatch k+1 while k's transfer
-    # is in flight, commit at arrival — bitwise the sync streams under
-    # exact decode, at a lower host_overhead_fraction)
+    # is in flight, commit at arrival — the sync loop's token streams,
+    # at a lower host_overhead_fraction)
     serve_loop: str = "sync"
     # sequence-parallel decode (flexflow_tpu/kernels/seqpar_decode.py,
     # docs/decode_perf.md "Sequence-parallel decode"; ISSUE 18): number
@@ -532,12 +530,6 @@ class FFConfig:
                 self.max_inflight = int(_next())
             elif a == "--slo-p99-ms":
                 self.slo_p99_ms = float(_next())
-            elif a == "--kv-cache":
-                v = _next()
-                if v not in ("paged", "ring"):
-                    raise ValueError(
-                        f"--kv-cache expects paged|ring, got {v!r}")
-                self.kv_cache = v
             elif a == "--kv-block-size":
                 self.kv_block_size = int(_next())
             elif a == "--kv-pool-blocks":
@@ -689,7 +681,7 @@ class FFConfig:
         if "--max-decode-len" in seen and self.max_decode_len < 1:
             raise ValueError(
                 f"--max-decode-len must be >= 1 (got "
-                f"{self.max_decode_len}): it is the decode ring-buffer "
+                f"{self.max_decode_len}): it is the per-slot decode "
                 "capacity every prompt + generation must fit")
         if "--max-inflight" in seen and self.max_inflight < 1:
             raise ValueError(
@@ -709,31 +701,12 @@ class FFConfig:
                 f"--kv-pool-blocks must be >= 0 (got "
                 f"{self.kv_pool_blocks}); 0 sizes the pool automatically "
                 "(every slot can hold max_decode_len)")
-        if "--kv-pool-blocks" in seen and self.kv_cache == "ring":
-            raise ValueError(
-                "--kv-pool-blocks is only meaningful with --kv-cache "
-                "paged; drop it or switch the layout")
-        if "--kv-dtype" in seen and self.kv_dtype != "native" and \
-                self.kv_cache == "ring":
-            raise ValueError(
-                "--kv-dtype int8 requires --kv-cache paged (the ring "
-                "layout stores the model dtype only)")
-        if "--prefix-cache" in seen and self.prefix_cache == "on" and \
-                self.kv_cache == "ring":
-            raise ValueError(
-                "--prefix-cache on requires --kv-cache paged (the ring "
-                "layout has no shared block pool to map a cached prefix "
-                "into)")
         if "--prefill-chunk-tokens" in seen:
             if self.prefill_chunk_tokens < 0:
                 raise ValueError(
                     f"--prefill-chunk-tokens must be >= 0 (got "
                     f"{self.prefill_chunk_tokens}); 0 disables chunked "
                     "prefill (one-shot prompts)")
-            if self.prefill_chunk_tokens and self.kv_cache == "ring":
-                raise ValueError(
-                    "--prefill-chunk-tokens requires --kv-cache paged "
-                    "(chunks write into the block pool)")
             if self.prefill_chunk_tokens % max(self.kv_block_size, 1):
                 raise ValueError(
                     f"--prefill-chunk-tokens ({self.prefill_chunk_tokens}"
@@ -766,18 +739,6 @@ class FFConfig:
                 f"--decode-retry-budget must be >= 0 (got "
                 f"{self.decode_retry_budget}); 0 aborts a poisoned "
                 "request on its first quarantined decode")
-        if "--seq-shards" in seen and self.seq_shards > 1 and \
-                self.kv_cache == "ring":
-            raise ValueError(
-                "--seq-shards > 1 requires --kv-cache paged (the ring "
-                "layout has no block tables to partition into per-shard "
-                "contiguous runs)")
-        if "--context-buckets" in seen and self.context_buckets and \
-                self.kv_cache == "ring":
-            raise ValueError(
-                "--context-buckets requires --kv-cache paged (buckets "
-                "route requests to sequence-sharded block-table "
-                "partitions)")
         if "--fleet-replicas" in seen and self.fleet_replicas < 0:
             raise ValueError(
                 f"--fleet-replicas must be >= 0 (got "

@@ -931,9 +931,9 @@ class Executor:
         (earlier chunks' logits are discarded). One compile per chunk
         shape (``chunk_len``), like the prefill buckets; ``start`` /
         ``n_new`` / the table row are traced, so chunk position and
-        block choice never recompile. Numerics are bitwise the one-shot
-        prefill's in every mode — see
-        ``ops.attention._chunk_prefill_attention`` for the argument.
+        block choice never recompile. Numerics follow the one-shot
+        prefill's (same streams, logits within the stated tolerance in
+        tier-1) — see ``ops.attention._chunk_prefill_attention``.
         ``state`` is donated: the pool updates in place; lengths and
         block tables pass through untouched (the engine arms the slot's
         device-side row and cursor only at prefill completion, so decode
@@ -995,19 +995,16 @@ class Executor:
         self._serving_jits[key] = fn
         return fn
 
-    def make_decode_step(self, max_decode_len: int, exact: bool = False,
-                         guard: bool = False, block_size: int = 0,
-                         kv_dtype: str = "native", seq_shards: int = 1):
+    def make_decode_step(self, max_decode_len: int, block_size: int,
+                         guard: bool = False, kv_dtype: str = "native",
+                         seq_shards: int = 1):
         """Jitted ``(params, xs, state) -> (logits, new_state)``: ONE token
         per slot through the graph, consuming and extending the
-        ``DecodeState`` ring buffers at each slot's ``lengths`` cursor.
+        ``DecodeState`` block pool at each slot's ``lengths`` cursor.
         Static shapes throughout — after the single warmup compile the
         decode loop never recompiles (the engine asserts this via the jit
-        cache size). The state argument is donated: the ring buffers
-        update in place on device. ``exact=True`` selects the
-        bitwise-vs-full-forward attention numerics (ServingState.exact) at
-        a max_len-x score-compute premium — the verification mode the
-        equivalence tests run. ``guard=True`` is the decode-health
+        cache size). The state argument is donated: the pool updates in
+        place on device. ``guard=True`` is the decode-health
         sentinel (ISSUE 9, mirroring ``make_train_step(guard=True)``): the
         step additionally returns ``ok`` — ``isfinite`` of each slot's
         logits reduced to a (n_slots,) bool vector — fused into the same
@@ -1017,11 +1014,10 @@ class Executor:
         every healthy slot's values stay bitwise-identical to the
         unguarded step's.
 
-        Paged KV (ISSUE 12): when the carried ``DecodeState`` has block
-        tables, ``block_size``/``kv_dtype`` select the paged layout —
+        Paged KV (ISSUE 12): the carried ``DecodeState`` holds the block
+        tables, ``block_size``/``kv_dtype`` name the pool's layout —
         the tables ride the jitted signature as one more int32 array, so
-        the single-compile contract is unchanged (ring and paged are
-        distinct programs, each compiled once).
+        the single-compile contract is unchanged.
 
         ``seq_shards`` (ISSUE 18) selects the sequence-parallel decode
         decomposition (ServingState.seq_shards): the gathered extent is
@@ -1030,7 +1026,7 @@ class Executor:
         jit key and keeps the single-compile contract."""
         import jax
 
-        key = ("decode", int(max_decode_len), bool(exact), bool(guard),
+        key = ("decode", int(max_decode_len), bool(guard),
                int(block_size), str(kv_dtype), int(seq_shards))
         cached = self._serving_jits.get(key)
         if cached is not None:
@@ -1047,7 +1043,7 @@ class Executor:
             params, xs = self._cast_for_compute(params, xs)
             sv = ServingState(mode="decode", max_len=max_decode_len,
                               positions=state.lengths,
-                              cache_in=state.caches, exact=exact,
+                              cache_in=state.caches,
                               block_tables=state.block_tables,
                               block_size=int(block_size),
                               kv_dtype=str(kv_dtype),

@@ -18,7 +18,8 @@ partials:
 
 — exactly the flash-attention segment-merge identity, so the combined
 result equals the unsharded online softmax up to fp reassociation
-(~1 ulp, the same order as the engine's fast-vs-exact decode delta).
+(~1 ulp, inside the band tier-1 holds decode logits to against the
+whole-sequence forward: 64 ulp of the largest logit, measured 3-8).
 A shard whose entire segment is masked (the slot's write cursor has not
 reached its block range) contributes ``m_s = -1e30``; its combine
 weight ``exp(m_s - m*)`` underflows to exactly 0.0, so never-written
@@ -27,14 +28,11 @@ whole shards.
 
 On the CPU tier (and on a single chip) the shards are emulated locally:
 the decomposition is a compute-path reshape of the one gathered extent,
-which is what lets tier-1 pin the seq-parallel exact path BITWISE
-against the single-shard reference (ops/attention.py routes exact mode
-through per-shard full-extent score GEMMs whose concatenation feeds the
-single unsharded softmax — the key axis is never reduced by the score
-product, so per-shard score columns are elementwise the unsharded
-ones). On a real mesh the per-shard partials are chip-local and only
-``(m, l, acc)`` crosses ICI; ``combine_bytes_per_step`` below is the
-closed form ``serving_search`` prices that traffic with, next to
+and tier-1 pins the sharded token stream equal to the single-shard
+stream (shards 2 and 4, solo, co-batched, through prefix hits and
+chunked prefill). On a real mesh the per-shard partials are chip-local
+and only ``(m, l, acc)`` crosses ICI; ``combine_bytes_per_step`` below
+is the closed form ``serving_search`` prices that traffic with, next to
 kv_fill/prefill_reuse.
 """
 from __future__ import annotations

@@ -113,7 +113,7 @@ class MultiHeadAttentionOp(Op):
         live_dropout = _resolve_live_dropout(dropout, ctx)
         seed = _dropout_seed(ctx.rng) if live_dropout else None
         if ctx.serving is not None:
-            # serving engine prefill/decode (ISSUE 6): the KV ring buffer is
+            # serving engine prefill/decode (ISSUE 6): the KV pool is
             # the execution path, selected before any kernel routing —
             # decode shapes (seq 1) must never reach flash/ring
             out = _serving_attention(self.name, q, k, v, ctx.serving,
@@ -167,33 +167,30 @@ class MultiHeadAttentionOp(Op):
 
 
 def _serving_attention(name: str, q, k, v, sv, *, causal: bool):
-    """Prefill/decode attention over the serving KV ring buffer
-    (serving/kvcache.py; ISSUE 6). Numerics are kept IDENTICAL to
+    """Prefill/decode attention over the serving KV pool
+    (serving/kvcache.py; ISSUE 6, paged since ISSUE 12). Numerics follow
     ``mha_core``'s einsum path — same scale, same ``-1e30`` additive mask,
-    same f32-accumulating einsums — so prefill+decode logits bitwise-match
-    the whole-sequence forward (tests/test_serving.py's equivalence gate):
-    masked lanes contribute exp(-1e30-max) == 0.0 exactly, and the ring
-    buffer's unwritten tail is zeros, so the wider reduction adds exact
-    zeros only.
+    same f32-accumulating einsums — so prefill and decode logits match the
+    whole-sequence forward within float32 rounding of the one-token score
+    product (tests/serving_oracle.py states the tolerance; tier-1 reads
+    3-8 ulp of the largest logit): masked lanes contribute
+    exp(-1e30-max) == 0.0 exactly, and unwritten rows are finite, so the
+    wider reduction adds exact zeros only.
 
     * prefill: q/k/v carry the whole padded prompt; the causal core runs
-      unchanged and k/v land at position 0 of a fresh ``max_len`` buffer.
-    * decode: q/k/v carry ONE token per slot; k/v are written at
-      ``positions[slot]`` (per-slot dynamic_update_slice — static shapes,
-      no recompile) and q attends over the full buffer under the mask
-      ``key_pos <= position``.
-
-    Paged decode (ISSUE 12, ``sv.paged``): the per-slot ring becomes a
-    block pool + per-slot block tables (serving/kvcache.py). The token
-    write is a pool scatter at (table[pos // bs], pos % bs); the read is
-    either the Pallas flash-decode kernel (TPU fast path — O(true
-    length) HBM traffic, kernels/flash_decode.py) or a pure gather back
-    to position order followed by EXACTLY the ring math below — gathered
-    rows are bitwise the stored rows and garbage-block rows are masked
-    to exact zeros, so paged fp decode stays bitwise-identical to the
-    ring (and, under ``sv.exact``, to the whole-sequence forward). The
-    int8 layout dequantizes per-(token, head) rows on read and is judged
-    against a pinned tolerance band instead.
+      unchanged and k/v land at position 0 of one request's contiguous
+      ``max_len`` buffer, which the engine's slot writer scatters into
+      the pool (``scatter_prefill_paged``).
+    * decode: q/k/v carry ONE token per slot; k/v are written into the
+      block pool at (table[pos // bs], pos % bs) — a scatter with static
+      shapes, no recompile — and q attends under the mask
+      ``key_pos <= position``. The read is either the Pallas flash-decode
+      kernel (TPU fast path — O(true length) HBM traffic,
+      kernels/flash_decode.py) or a pure gather back to position order
+      followed by the masked einsums below — gathered rows are bitwise
+      the stored rows and garbage-block rows are masked to exact zeros.
+      The int8 layout dequantizes per-(token, head) rows on read and is
+      judged against a pinned tolerance band instead.
     """
     import jax
     import jax.numpy as jnp
@@ -201,7 +198,7 @@ def _serving_attention(name: str, q, k, v, sv, *, causal: bool):
 
     from ..serving.kvcache import (dequantize_kv, gather_paged_kv,
                                    gather_paged_scales, quantize_kv,
-                                   write_token_kv, write_token_kv_paged,
+                                   write_token_kv_paged,
                                    write_token_scale_paged)
 
     if not causal:
@@ -221,66 +218,43 @@ def _serving_attention(name: str, q, k, v, sv, *, causal: bool):
         sv.cache_out[name] = (kbuf, vbuf)
         return mha_core(q, k, v, causal=True)
     scale = 1.0 / np.sqrt(q.shape[-1])
-    if sv.paged:
-        tables, bs = sv.block_tables, sv.block_size
-        if sv.kv_dtype == "int8":
-            kq, ks, vq, vs = sv.cache_in[name]
-            with jax.named_scope("kv_update"):
-                k_new, ks_new = quantize_kv(k)  # (S,h,1,hd), scale (S,h,1)
-                v_new, vs_new = quantize_kv(v)
-                kq = write_token_kv_paged(kq, k_new, sv.positions, tables,
-                                          bs)
-                ks = write_token_scale_paged(ks, ks_new, sv.positions,
-                                             tables, bs)
-                vq = write_token_kv_paged(vq, v_new, sv.positions, tables,
-                                          bs)
-                vs = write_token_scale_paged(vs, vs_new, sv.positions,
-                                             tables, bs)
-            sv.cache_out[name] = (kq, ks, vq, vs)
-            kernel_out = _maybe_flash_decode(
-                q, (kq, ks, vq, vs), tables, sv, scale)
-            if kernel_out is not None:
-                return kernel_out
-            kc = dequantize_kv(gather_paged_kv(kq, tables),
-                               gather_paged_scales(ks, tables), k.dtype)
-            vc = dequantize_kv(gather_paged_kv(vq, tables),
-                               gather_paged_scales(vs, tables), v.dtype)
-        else:
-            kp, vp = sv.cache_in[name]
-            with jax.named_scope("kv_update"):
-                kp = write_token_kv_paged(kp, k, sv.positions, tables, bs)
-                vp = write_token_kv_paged(vp, v, sv.positions, tables, bs)
-            sv.cache_out[name] = (kp, vp)
-            kernel_out = _maybe_flash_decode(q, (kp, vp), tables, sv,
-                                             scale)
-            if kernel_out is not None:
-                return kernel_out
-            kc = gather_paged_kv(kp, tables)
-            vc = gather_paged_kv(vp, tables)
-    else:
-        kc, vc = sv.cache_in[name]
+    tables, bs = sv.block_tables, sv.block_size
+    if sv.kv_dtype == "int8":
+        kq, ks, vq, vs = sv.cache_in[name]
         with jax.named_scope("kv_update"):
-            kc = write_token_kv(kc, k, sv.positions)
-            vc = write_token_kv(vc, v, sv.positions)
-        sv.cache_out[name] = (kc, vc)
-    extent = kc.shape[2]  # max_len (ring) | blocks * block_size (paged)
+            k_new, ks_new = quantize_kv(k)  # (S,h,1,hd), scale (S,h,1)
+            v_new, vs_new = quantize_kv(v)
+            kq = write_token_kv_paged(kq, k_new, sv.positions, tables, bs)
+            ks = write_token_scale_paged(ks, ks_new, sv.positions,
+                                         tables, bs)
+            vq = write_token_kv_paged(vq, v_new, sv.positions, tables, bs)
+            vs = write_token_scale_paged(vs, vs_new, sv.positions,
+                                         tables, bs)
+        sv.cache_out[name] = (kq, ks, vq, vs)
+        kernel_out = _maybe_flash_decode(
+            q, (kq, ks, vq, vs), tables, sv, scale)
+        if kernel_out is not None:
+            return kernel_out
+        kc = dequantize_kv(gather_paged_kv(kq, tables),
+                           gather_paged_scales(ks, tables), k.dtype)
+        vc = dequantize_kv(gather_paged_kv(vq, tables),
+                           gather_paged_scales(vs, tables), v.dtype)
+    else:
+        kp, vp = sv.cache_in[name]
+        with jax.named_scope("kv_update"):
+            kp = write_token_kv_paged(kp, k, sv.positions, tables, bs)
+            vp = write_token_kv_paged(vp, v, sv.positions, tables, bs)
+        sv.cache_out[name] = (kp, vp)
+        kernel_out = _maybe_flash_decode(q, (kp, vp), tables, sv, scale)
+        if kernel_out is not None:
+            return kernel_out
+        kc = gather_paged_kv(kp, tables)
+        vc = gather_paged_kv(vp, tables)
+    extent = kc.shape[2]  # blocks_per_slot * block_size
     if sv.seq_shards > 1:
         return _seqpar_decode(q, kc, vc, sv, scale, extent)
-    if sv.exact:
-        # bitwise mode: the 1-token q rides a full-extent score GEMM (its
-        # row is extracted afterwards) so the d-axis accumulation order
-        # matches the whole-sequence forward exactly; the fast path below
-        # lowers to a matvec that differs by ~1 ulp
-        qpad = write_token_kv(
-            jnp.zeros(kc.shape[:2] + (extent, q.shape[-1]), q.dtype),
-            q, sv.positions)
-        full = jnp.einsum("bhqd,bhkd->bhqk", qpad, kc,
-                          preferred_element_type=jnp.float32) * scale
-        logits = jnp.take_along_axis(
-            full, sv.positions[:, None, None, None], axis=2)
-    else:
-        logits = jnp.einsum("bhqd,bhkd->bhqk", q, kc,
-                            preferred_element_type=jnp.float32) * scale
+    logits = jnp.einsum("bhqd,bhkd->bhqk", q, kc,
+                        preferred_element_type=jnp.float32) * scale
     kpos = jnp.arange(extent)
     mask = kpos[None, None, None, :] <= sv.positions[:, None, None, None]
     logits = jnp.where(mask, logits, -1e30)
@@ -295,53 +269,24 @@ def _seqpar_decode(q, kc, vc, sv, scale, extent):
     partitioned into ``sv.seq_shards`` contiguous key segments — on a
     mesh each segment is one chip's run of pool blocks; on a single
     device the same decomposition runs locally, which is what tier-1
-    pins.
-
-    ``exact`` keeps the bitwise contract against the single-shard
-    reference: every shard scores the SAME full-extent padded q against
-    its key segment, and the score einsum never reduces over the key
-    axis — shard s's columns are elementwise the unsharded GEMM's
-    columns ``[s*seg, (s+1)*seg)``, so concatenating in position order
-    reproduces the single-shard logits bit-for-bit and one unsharded
-    softmax/PV finishes the step (the combine collective carries raw
-    score columns instead of (m, l, acc) in this audit mode).
-
-    The fast path is the deployable layout: each shard folds its
-    segment through the flash-decode online-softmax recurrence into a
-    partial ``(m, l, acc)`` and the priced segment-merge combines them
-    (kernels/seqpar_decode.py) — ~1 ulp from the single-shard fast
-    matvec, the same band the fast-vs-exact delta already occupies.
-    Fully-masked segments (write cursor below the shard's range)
-    contribute exact zeros via ``exp(-1e30 - m*)``."""
-    import jax
+    pins. Each shard folds its segment through the flash-decode
+    online-softmax recurrence into a partial ``(m, l, acc)`` and the
+    priced segment-merge combines them (kernels/seqpar_decode.py) —
+    ~1 ulp from the single-shard matvec, and the sharded token stream
+    equals the single-shard stream in tier-1. Fully-masked segments
+    (write cursor below the shard's range) contribute exact zeros via
+    ``exp(-1e30 - m*)``."""
     import jax.numpy as jnp
     from jax import lax
 
     from ..kernels.seqpar_decode import (combine_partials,
                                          decode_shard_partial,
                                          shard_segment)
-    from ..serving.kvcache import write_token_kv
 
     S = int(sv.seq_shards)
     seg = shard_segment(extent, S)
     kpos = jnp.arange(extent)
     mask = kpos[None, None, None, :] <= sv.positions[:, None, None, None]
-    if sv.exact:
-        qpad = write_token_kv(
-            jnp.zeros(kc.shape[:2] + (extent, q.shape[-1]), q.dtype),
-            q, sv.positions)
-        cols = []
-        for s in range(S):
-            kseg = lax.slice_in_dim(kc, s * seg, (s + 1) * seg, axis=2)
-            full = jnp.einsum("bhqd,bhkd->bhqk", qpad, kseg,
-                              preferred_element_type=jnp.float32) * scale
-            cols.append(jnp.take_along_axis(
-                full, sv.positions[:, None, None, None], axis=2))
-        logits = jnp.where(mask, jnp.concatenate(cols, axis=-1), -1e30)
-        probs = jax.nn.softmax(logits, axis=-1)
-        out = jnp.einsum("bhqk,bhkd->bhqd", probs.astype(vc.dtype), vc,
-                         preferred_element_type=jnp.float32)
-        return out.astype(vc.dtype)
     partials = []
     for s in range(S):
         lo, hi = s * seg, (s + 1) * seg
@@ -362,25 +307,24 @@ def _chunk_prefill_attention(name: str, q, k, v, sv):
     extent — the already-written prefix (a cached trie hit or earlier
     chunks) plus this chunk — under the mask ``key_pos <= row_pos``.
 
-    Numerics are BITWISE the one-shot prefill's, by construction, in
-    every engine mode (not just ``exact``): the chunk's score product
-    always rides a full-extent GEMM (chunk rows scattered into a
-    zero-padded extent-row q, the decode-``exact`` idiom) so the d-axis
-    accumulation order matches the whole-sequence forward's; masked
-    lanes — the stale rows of freshly-recycled blocks included — are
-    finite and contribute exp(-1e30 - max) == 0.0 exactly; and the
-    row-wise projections run at the chunk program's fixed compiled
-    width (floor 2 — a 1-row matvec is the one lowering that breaks
-    per-row equality). This is what lets the prefix cache default ON
-    without perturbing a single token of any cold stream: a trie-hit
-    admission's suffix chunk, a chunked long prompt and a cold one-shot
-    prefill all commit identical KV rows and identical next-token
-    logits. The extent-wide score pad is the price (one chunk pays
-    O(extent^2) score FLOPs instead of O(chunk x extent)); chunks run
-    once per admitted prompt, decode runs per token, so the trade
-    follows the decode-``exact`` precedent. int8 pools quantize the
-    chunk rows per-(token, head) on write — band-judged like every
-    int8 path, never bitwise."""
+    Numerics are built to follow the one-shot prefill's: the chunk's
+    score product always rides a full-extent GEMM (chunk rows scattered
+    into a zero-padded extent-row q) so the d-axis accumulation order
+    matches the whole-sequence forward's; masked lanes — the stale rows
+    of freshly-recycled blocks included — are finite and contribute
+    exp(-1e30 - max) == 0.0 exactly; and the row-wise projections run at
+    the chunk program's fixed compiled width (floor 2). What tier-1 holds
+    of it: a trie-hit admission's suffix chunk, a chunked long prompt
+    and a cold one-shot prefill give the same token streams, and the
+    chunk's next-token logits are within the stated tolerance of the
+    one-shot prefill's (tests/serving_oracle.py; not bitwise under
+    jax 0.9.0, and on the chip equal prompts may part at a reference
+    tie, PERF.md §7). The extent-wide score pad is the price (one chunk
+    pays O(extent^2) score FLOPs instead of O(chunk x extent)) and a
+    named debt (ROADMAP.md D14): removing it moves the benchmark's
+    compared numbers, so it waits for a PR that measures. int8 pools
+    quantize the chunk rows per-(token, head) on write — band-judged
+    like every int8 path."""
     import jax
     import jax.numpy as jnp
 
@@ -389,11 +333,6 @@ def _chunk_prefill_attention(name: str, q, k, v, sv):
                                    write_chunk_kv_paged,
                                    write_chunk_scale_paged)
 
-    if not sv.paged:
-        raise NotImplementedError(
-            f"{name}: chunked prefill requires the paged KV layout "
-            "(kv_cache='paged'); the ring layout has no block pool to "
-            "write chunks into")
     tables, bs = sv.block_tables, sv.block_size  # tables: (1, mb)
     row = tables[0]
     start = sv.positions[0]
@@ -425,7 +364,7 @@ def _chunk_prefill_attention(name: str, q, k, v, sv):
     scale = 1.0 / np.sqrt(q.shape[-1])
     # full-extent score GEMM: chunk q rows scattered at their positions
     # into a zero extent-row buffer (pad rows dropped out of bounds),
-    # rows re-extracted after the product — the decode-exact idiom
+    # rows re-extracted after the product
     safe = jnp.where(valid, pos, extent + 1)
     qpad = jnp.zeros((1, q.shape[1], extent, q.shape[-1]), q.dtype)
     qpad = qpad.at[0, :, safe].set(jnp.swapaxes(q[0], 0, 1), mode="drop")
@@ -444,11 +383,11 @@ def _chunk_prefill_attention(name: str, q, k, v, sv):
 
 def _maybe_flash_decode(q, entry, tables, sv, sm_scale):
     """Route one paged decode read through the Pallas flash-decode kernel
-    when eligible (on-TPU, non-exact numerics, MXU-friendly dims) —
+    when eligible (on-TPU, MXU-friendly dims) —
     returns the (S, h, 1, hd) output or None for the gather path."""
     from ..kernels.flash_decode import flash_decode, use_flash_decode
 
-    if (sv.exact or sv.seq_shards > 1
+    if (sv.seq_shards > 1
             or not use_flash_decode(q.shape[-1], sv.block_size)):
         # seq_shards > 1: the shard decomposition runs the split-K math
         # per segment over the gathered extent (_seqpar_decode); the

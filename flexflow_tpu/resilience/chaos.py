@@ -284,7 +284,7 @@ def poison_decode_state(state, slot: int):
     degrade (a sustained poison *rate* on one replica, ISSUE 11).
     Floating leaves only; every other slot stays bitwise-untouched.
 
-    Paged layout (ISSUE 12): the victim's rows live in POOL blocks, so
+    The victim's rows live in POOL blocks (ISSUE 12), so
     the poison targets exactly the blocks its block-table row occupies
     (``tables[slot, :ceil(len/bs)]``) — never the shared GARBAGE block,
     whose contents must stay finite (a NaN there would leak into every
@@ -296,58 +296,48 @@ def poison_decode_state(state, slot: int):
 
     from ..serving.kvcache import DecodeState
 
-    if getattr(state, "block_tables", None) is not None:
-        tables = np.asarray(state.block_tables)
-        length = int(np.asarray(state.lengths)[slot])
-        caches = dict(state.caches)
-        # block_size from any pool leaf (n_blocks, h, bs, hd); a slot
-        # with no occupied block (never prefilled) has nothing to poison
-        for name, entry in state.caches.items():
-            leaves = jax.tree_util.tree_leaves(entry)
-            pool_like = [lf for lf in leaves if lf.ndim == 4]
-            if not pool_like:
-                # slot-major entries (LSTM carry): the ring rule applies
-                caches[name] = jax.tree.map(
-                    lambda lf: lf.at[slot].set(
-                        jnp.asarray(float("nan"), lf.dtype))
-                    if jnp.issubdtype(lf.dtype, jnp.floating) else lf,
-                    entry)
-                continue
-            if length < 1:
-                # never-admitted slot: it occupies NO pool block, so
-                # there is nothing to poison — indexing by the slot
-                # number here would NaN pool block == slot, which may
-                # belong to a LIVE request in another slot
-                continue
-            bs = int(pool_like[0].shape[2])
-            used = -(-length // bs)
-            row = tables[slot, :used]
-            # never the GARBAGE block (index 0): every co-batched slot's
-            # masked-out reads touch it, and a freed slot's cleared row
-            # points entirely at it
-            row = row[row != 0]
-            if row.size == 0:
-                continue
-            blocks = jnp.asarray(row, jnp.int32)
+    tables = np.asarray(state.block_tables)
+    length = int(np.asarray(state.lengths)[slot])
+    caches = dict(state.caches)
+    # block_size from any pool leaf (n_blocks, h, bs, hd); a slot
+    # with no occupied block (never prefilled) has nothing to poison
+    for name, entry in state.caches.items():
+        leaves = jax.tree_util.tree_leaves(entry)
+        pool_like = [lf for lf in leaves if lf.ndim == 4]
+        if not pool_like:
+            # slot-major entries (LSTM carry): NaN the slot's own row
+            caches[name] = jax.tree.map(
+                lambda lf: lf.at[slot].set(
+                    jnp.asarray(float("nan"), lf.dtype))
+                if jnp.issubdtype(lf.dtype, jnp.floating) else lf,
+                entry)
+            continue
+        if length < 1:
+            # never-admitted slot: it occupies NO pool block, so
+            # there is nothing to poison — indexing by the slot
+            # number here would NaN pool block == slot, which may
+            # belong to a LIVE request in another slot
+            continue
+        bs = int(pool_like[0].shape[2])
+        used = -(-length // bs)
+        row = tables[slot, :used]
+        # never the GARBAGE block (index 0): every co-batched slot's
+        # masked-out reads touch it, and a freed slot's cleared row
+        # points entirely at it
+        row = row[row != 0]
+        if row.size == 0:
+            continue
+        blocks = jnp.asarray(row, jnp.int32)
 
-            def nanify(leaf):
-                if not jnp.issubdtype(leaf.dtype, jnp.floating):
-                    return leaf
-                return leaf.at[blocks].set(
-                    jnp.asarray(float("nan"), leaf.dtype))
+        def nanify(leaf):
+            if not jnp.issubdtype(leaf.dtype, jnp.floating):
+                return leaf
+            return leaf.at[blocks].set(
+                jnp.asarray(float("nan"), leaf.dtype))
 
-            caches[name] = jax.tree.map(nanify, entry)
-        return DecodeState(caches=caches, lengths=state.lengths,
-                           block_tables=state.block_tables)
-
-    def nanify(leaf):
-        if not jnp.issubdtype(leaf.dtype, jnp.floating):
-            return leaf
-        return leaf.at[slot].set(jnp.asarray(float("nan"), leaf.dtype))
-
-    caches = {name: jax.tree.map(nanify, entry)
-              for name, entry in state.caches.items()}
-    return DecodeState(caches=caches, lengths=state.lengths)
+        caches[name] = jax.tree.map(nanify, entry)
+    return DecodeState(caches=caches, lengths=state.lengths,
+                       block_tables=state.block_tables)
 
 
 class FleetChaosPlan(ChaosPlan):

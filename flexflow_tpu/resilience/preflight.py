@@ -137,7 +137,7 @@ def preflight_config(config) -> None:
         raise PreflightError(
             f"--serve-loop expects sync|async, got {sl!r}: sync is the "
             "blocking reference loop, async the double-buffered runtime "
-            "(bitwise-identical streams under exact decode)")
+            "(the same token streams)")
     raw_ss = getattr(config, "seq_shards", 1)
     ss = int(raw_ss if raw_ss is not None else 1)
     if ss < 1:
@@ -145,11 +145,6 @@ def preflight_config(config) -> None:
             f"--seq-shards must be >= 1 (got {ss}): it is the number of "
             "contiguous block-table shards a decode step scores across "
             "(1 = unsharded)")
-    if ss > 1 and getattr(config, "kv_cache", "paged") == "ring":
-        raise PreflightError(
-            "--seq-shards > 1 requires --kv-cache paged: the ring "
-            "layout has no block tables to partition into per-shard "
-            "contiguous runs")
     cb = getattr(config, "context_buckets", "") or ""
     if cb:
         from ..serving.kvcache import parse_context_buckets
@@ -158,11 +153,6 @@ def preflight_config(config) -> None:
             parse_context_buckets(cb)
         except ValueError as e:
             raise PreflightError(str(e))
-        if getattr(config, "kv_cache", "paged") == "ring":
-            raise PreflightError(
-                "--context-buckets requires --kv-cache paged: buckets "
-                "route requests to sequence-sharded block-table "
-                "partitions")
     asc = (getattr(config, "autoscale", "off") or "off")
     if asc not in ("on", "off"):
         raise PreflightError(

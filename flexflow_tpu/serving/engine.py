@@ -91,10 +91,8 @@ class ServingStats:
     replans: int = 0
     drained_returned: int = 0
     # decode HBM traffic accounting (ISSUE 12): analytic KV bytes the
-    # decode attention reads, accumulated per step host-side — paged
-    # engines charge each live slot's OCCUPIED blocks, ring engines the
-    # full n_slots * max_len extent (the O(max_len) bill the paged
-    # refactor removes); bench's bytes-read/token column
+    # decode attention reads, accumulated per step host-side: each live
+    # slot's OCCUPIED blocks; bench's bytes-read/token column
     kv_bytes_read: int = 0
     # prefix cache + chunked prefill ledger (ISSUE 14,
     # serving/prefix.py): admissions that mapped a cached prefix, the
@@ -270,13 +268,17 @@ class ServingEngine:
     (incremental decode is undefined for them, and the engine says so).
     """
 
+    # the one KV layout and the one decode numerics path, kept as
+    # constants because benchmark/drivers/serve.py records both in a
+    # run's info (ROADMAP.md D12: drop them with that reader)
+    kv_cache = "paged"
+    exact_decode = False
+
     def __init__(self, model, n_slots: Optional[int] = None,
                  max_decode_len: Optional[int] = None,
                  buckets: Optional[Sequence[int]] = None,
                  max_queue: int = 64,
                  eos_id: Optional[int] = None,
-                 exact_decode: bool = False,
-                 kv_cache: Optional[str] = None,
                  kv_block_size: Optional[int] = None,
                  kv_pool_blocks: Optional[int] = None,
                  kv_dtype: Optional[str] = None,
@@ -298,46 +300,33 @@ class ServingEngine:
         self.requested_max_decode_len = self.max_decode_len
         self.max_queue = max_queue
         self.eos_id = eos_id
-        # bitwise-vs-full-forward decode numerics (ServingState.exact) —
-        # the verification mode; default is the fast matvec score path
-        self.exact_decode = bool(exact_decode)
-        # paged KV cache (ISSUE 12, docs/serving.md "Paged KV cache"):
-        # "paged" (default) = block pool + per-slot tables, "ring" = the
-        # legacy per-slot max_len buffers (the bitwise reference layout)
         # serve-loop runtime (ISSUE 17, docs/serving.md "Async
         # runtime"): "sync" (default) blocks on each decode step's host
         # transfer before dispatching the next; "async" double-buffers —
         # step k+1 is enqueued on-device while step k's (tokens, ok)
         # transfer is in flight, commits land at transfer ARRIVAL. Both
         # run the same device programs; async must match sync
-        # stream-for-stream bitwise under exact decode (tier-1 pins it)
+        # stream for stream (tier-1 pins it)
         self.serve_loop = str(serve_loop or
                               getattr(cfg, "serve_loop", "sync") or "sync")
         if self.serve_loop not in ("sync", "async"):
             raise ValueError(
                 f"serve_loop must be 'sync' or 'async', got "
                 f"{self.serve_loop!r}")
-        self.kv_cache = str(kv_cache or getattr(cfg, "kv_cache", "paged"))
+        # paged KV cache (ISSUE 12, docs/serving.md "Paged KV cache"):
+        # one block pool per KV entry + per-slot block tables
         self.kv_block_size = int(kv_block_size or
                                  getattr(cfg, "kv_block_size", 16))
         self.kv_dtype = str(kv_dtype or getattr(cfg, "kv_dtype", "native"))
         kv_pool_blocks = int(kv_pool_blocks if kv_pool_blocks is not None
                              else getattr(cfg, "kv_pool_blocks", 0))
-        if self.kv_cache not in ("paged", "ring"):
-            raise ValueError(
-                f"kv_cache must be 'paged' or 'ring', got "
-                f"{self.kv_cache!r}")
-        from .kvcache import (KV_DTYPES, SeqShardsError, blocks_per_slot,
+        from .kvcache import (KV_DTYPES, blocks_per_slot,
                               parse_context_buckets)
 
         if self.kv_dtype not in KV_DTYPES:
             raise ValueError(
                 f"kv_dtype must be one of {KV_DTYPES}, got "
                 f"{self.kv_dtype!r}")
-        if self.kv_cache == "ring" and self.kv_dtype != "native":
-            raise ValueError(
-                "kv_dtype='int8' requires the paged KV layout "
-                "(kv_cache='paged')")
         # sequence-parallel decode (ISSUE 18, docs/decode_perf.md
         # "Sequence-parallel decode"): the gathered extent is scored as
         # seq_shards contiguous key segments merged by the flash segment
@@ -350,24 +339,14 @@ class ServingEngine:
         if self.seq_shards < 1:
             raise ValueError(
                 f"seq_shards must be >= 1, got {self.seq_shards}")
-        if self.seq_shards > 1 and self.kv_cache == "ring":
-            raise SeqShardsError(
-                "--seq-shards > 1 requires the paged KV layout "
-                "(kv_cache='paged'): the ring layout has no block tables "
-                "to partition into per-shard contiguous runs")
         self.context_buckets = parse_context_buckets(
             context_buckets if context_buckets is not None
             else getattr(cfg, "context_buckets", "") or "")
-        if self.context_buckets and self.kv_cache == "ring":
-            raise ValueError(
-                "--context-buckets requires the paged KV layout "
-                "(kv_cache='paged'): buckets route requests to "
-                "sequence-sharded block-table partitions")
         # prefix cache + chunked prefill (ISSUE 14, serving/prefix.py,
         # docs/serving.md "Prefix cache & chunked prefill"): the radix
-        # trie defaults ON for paged attention-only graphs — its hit
-        # path is bitwise the cold path, so enabling it changes no
-        # stream; chunking is opt-in via --prefill-chunk-tokens
+        # trie defaults ON for attention-only graphs — in tier-1 its hit
+        # path gives the cold path's streams; chunking is opt-in via
+        # --prefill-chunk-tokens
         self.prefill_chunk_tokens = int(
             prefill_chunk_tokens if prefill_chunk_tokens is not None
             else getattr(cfg, "prefill_chunk_tokens", 0) or 0)
@@ -376,20 +355,8 @@ class ServingEngine:
         if prefix_mode not in ("on", "off"):
             raise ValueError(
                 f"prefix_cache must be 'on' or 'off', got {prefix_mode!r}")
-        if self.kv_cache == "ring":
-            if prefix_cache == "on":
-                raise ValueError(
-                    "prefix_cache='on' requires the paged KV layout "
-                    "(kv_cache='paged'): the ring layout has no shared "
-                    "block pool to map a cached prefix into")
-            if self.prefill_chunk_tokens:
-                raise ValueError(
-                    "prefill_chunk_tokens requires the paged KV layout "
-                    "(kv_cache='paged'): chunks write into the block "
-                    "pool")
-            prefix_mode = "off"
         # max supported context: bounded by the position-embedding table
-        # when it is shorter than the ring/pool capacity; admission
+        # when it is shorter than the pool capacity; admission
         # REJECTS beyond it (the old warn-and-clamp is gone, ISSUE 12
         # satellite)
         self._validate_graph()
@@ -413,54 +380,52 @@ class ServingEngine:
             prefix_mode = "off"
         self.max_context = position_context_bound(self.executor,
                                                   self.max_decode_len)
-        self.block_allocator = None
         self._prefix = None
-        if self.kv_cache == "paged":
-            from .scheduler import BlockAllocator
+        from .scheduler import BlockAllocator
 
-            mb = blocks_per_slot(self.max_decode_len, self.kv_block_size)
-            self.max_blocks_per_slot = mb
-            # auto pool: full capacity (every slot at max_len) + the
-            # garbage block — --kv-pool-blocks decouples occupancy from
-            # max_len (admission then waits on FREE BLOCKS, not slots).
-            # Chunked prefill adds one live chunk's worth of headroom
-            # (the FF006 law: one max-context request PLUS one chunk)
-            chunk_blocks = (-(-self.prefill_chunk_tokens //
-                              self.kv_block_size)
-                            if self.prefill_chunk_tokens else 0)
-            self.kv_pool_blocks = kv_pool_blocks or (
-                self.n_slots * mb + 1 + chunk_blocks)
-            # ShardLint FF006 paged shape laws — statically, zero compile
-            from ..analysis import (AnalysisReport, StaticAnalysisError,
-                                    check_paged_kv)
+        mb = blocks_per_slot(self.max_decode_len, self.kv_block_size)
+        self.max_blocks_per_slot = mb
+        # auto pool: full capacity (every slot at max_len) + the
+        # garbage block — --kv-pool-blocks decouples occupancy from
+        # max_len (admission then waits on FREE BLOCKS, not slots).
+        # Chunked prefill adds one live chunk's worth of headroom
+        # (the FF006 law: one max-context request PLUS one chunk)
+        chunk_blocks = (-(-self.prefill_chunk_tokens //
+                          self.kv_block_size)
+                        if self.prefill_chunk_tokens else 0)
+        self.kv_pool_blocks = kv_pool_blocks or (
+            self.n_slots * mb + 1 + chunk_blocks)
+        # ShardLint FF006 paged shape laws — statically, zero compile
+        from ..analysis import (AnalysisReport, StaticAnalysisError,
+                                check_paged_kv)
 
-            import jax
+        import jax
 
-            diags = check_paged_kv(
-                self.executor.pcg,
-                block_size=self.kv_block_size,
-                pool_blocks=self.kv_pool_blocks,
-                max_blocks_per_slot=mb,
-                max_context=self.max_context,
-                prefill_chunk_tokens=self.prefill_chunk_tokens,
-                seq_shards=self.seq_shards,
-                n_devices=jax.device_count(),
-                context_buckets=self.context_buckets)
-            if diags:
-                raise StaticAnalysisError(
-                    AnalysisReport(diagnostics=diags, checked=("FF006",)),
-                    context="paged KV configuration")
-            self.block_allocator = BlockAllocator(self.kv_pool_blocks,
-                                                  self.kv_block_size)
-            if prefix_mode == "on":
-                from .prefix import PrefixCache
+        diags = check_paged_kv(
+            self.executor.pcg,
+            block_size=self.kv_block_size,
+            pool_blocks=self.kv_pool_blocks,
+            max_blocks_per_slot=mb,
+            max_context=self.max_context,
+            prefill_chunk_tokens=self.prefill_chunk_tokens,
+            seq_shards=self.seq_shards,
+            n_devices=jax.device_count(),
+            context_buckets=self.context_buckets)
+        if diags:
+            raise StaticAnalysisError(
+                AnalysisReport(diagnostics=diags, checked=("FF006",)),
+                context="paged KV configuration")
+        self.block_allocator = BlockAllocator(self.kv_pool_blocks,
+                                              self.kv_block_size)
+        if prefix_mode == "on":
+            from .prefix import PrefixCache
 
-                self._prefix = PrefixCache(
-                    self.block_allocator, self.kv_block_size,
-                    max_blocks=int(
-                        prefix_cache_blocks
-                        if prefix_cache_blocks is not None
-                        else getattr(cfg, "prefix_cache_blocks", 0) or 0))
+            self._prefix = PrefixCache(
+                self.block_allocator, self.kv_block_size,
+                max_blocks=int(
+                    prefix_cache_blocks
+                    if prefix_cache_blocks is not None
+                    else getattr(cfg, "prefix_cache_blocks", 0) or 0))
         self.buckets = tuple(buckets) if buckets else \
             default_buckets(self.max_decode_len)
         self.state: Optional[DecodeState] = None
@@ -564,10 +529,6 @@ class ServingEngine:
         return self.model._obs_tracer()
 
     @property
-    def _paged(self) -> bool:
-        return self.kv_cache == "paged"
-
-    @property
     def decode_compiles(self) -> Optional[int]:
         """Entries in the decode step's jit cache — the recompile-free
         contract is exactly ``== 1`` after warmup (asserted in tier-1).
@@ -575,10 +536,8 @@ class ServingEngine:
         unguarded decode are distinct programs, each with its own
         one-entry contract)."""
         fn = self.executor._serving_jits.get(
-            ("decode", self.max_decode_len, self.exact_decode,
-             self._last_guard,
-             self.kv_block_size if self._paged else 0, self.kv_dtype,
-             self.seq_shards))
+            ("decode", self.max_decode_len, self._last_guard,
+             self.kv_block_size, self.kv_dtype, self.seq_shards))
         if fn is None:
             return None
         try:
@@ -589,8 +548,7 @@ class ServingEngine:
     # ------------------------------------------------------------ device fns
     def _decode_fn(self, guard: bool = False):
         return self.executor.make_decode_step(
-            self.max_decode_len, exact=self.exact_decode, guard=guard,
-            block_size=self.kv_block_size if self._paged else 0,
+            self.max_decode_len, self.kv_block_size, guard=guard,
             kv_dtype=self.kv_dtype, seq_shards=self.seq_shards)
 
     def _prefill_fn(self, bucket: int):
@@ -598,7 +556,7 @@ class ServingEngine:
 
     @staticmethod
     def _is_kv_entry(entry) -> bool:
-        """Attention KV entries are (k, v) tuples of 4-D per-request ring
+        """Attention KV entries are (k, v) tuples of 4-D per-request
         buffers ``(1, h, max_len, hd)`` — the pageable kind; everything
         else (the LSTM carry ``(1, 2h)``) stays slot-major."""
         import jax
@@ -608,33 +566,32 @@ class ServingEngine:
             getattr(leaf, "ndim", 0) == 4 for leaf in leaves)
 
     def _write_slot(self, cache, slot: int, length: int, token,
-                    table_row=None) -> None:
+                    table_row) -> None:
         """Insert one prefilled request into the decode batch: cache rows,
         length cursor and the pending first token — one jitted scatter,
-        slot/length/token traced (no per-slot recompiles). Paged engines
-        additionally scatter the request's ring cache into its table
-        row's pool blocks (quantizing for int8 layouts) and set the
-        slot's block-table row — ``table_row`` is a traced int32 array,
-        so block choice never recompiles either."""
+        slot/length/token traced (no per-slot recompiles). KV entries
+        are scattered into the table row's pool blocks (quantizing for
+        int8 layouts), other stateful entries land slot-major, and the
+        slot's block-table row is set — ``table_row`` is a traced int32
+        array, so block choice never recompiles either."""
         import jax
         import jax.numpy as jnp
 
         from .kvcache import scatter_prefill_paged
 
         if self._write_slot_fn is None:
-            paged = self._paged
             bs = self.kv_block_size
             int8 = self.kv_dtype == "int8"
             # the ONE pagedness decision: the entry-name set recorded by
             # _ensure_state when it built the pool (a second structural
             # classifier here could silently disagree for a future
             # stateful op's cache shape)
-            kv_names = self._paged_entry_names if paged else set()
+            kv_names = self._paged_entry_names
 
             def write(state, last, cache, slot, length, token, table_row):
                 caches = {}
                 for name in state.caches:
-                    if paged and name in kv_names:
+                    if name in kv_names:
                         if int8:
                             kq, ks, vq, vs = state.caches[name]
                             kc, vc = cache[name]
@@ -655,9 +612,7 @@ class ServingEngine:
                         caches[name] = update_slot_entry(
                             state.caches[name], cache[name], slot)
                 lengths = state.lengths.at[slot].set(length)
-                tables = state.block_tables
-                if tables is not None:
-                    tables = tables.at[slot].set(table_row)
+                tables = state.block_tables.at[slot].set(table_row)
                 last = last.at[slot, 0].set(token)
                 return DecodeState(caches=caches, lengths=lengths,
                                    block_tables=tables), last
@@ -666,9 +621,6 @@ class ServingEngine:
 
             self._write_slot_fn = named_jit("write", write,
                                             donate_argnums=(0, 1))
-        if table_row is None:
-            table_row = np.zeros(
-                (getattr(self, "max_blocks_per_slot", 1),), np.int32)
         self.state, self._last_tokens = self._write_slot_fn(
             self.state, self._last_tokens, cache,
             jnp.int32(slot), jnp.int32(length), jnp.int32(token),
@@ -688,8 +640,7 @@ class ServingEngine:
 
         from .resilience import state_buffers_lost
 
-        if self.state is None or self.state.block_tables is None or \
-                state_buffers_lost(self.state):
+        if self.state is None or state_buffers_lost(self.state):
             return  # no pool (or a dead one about to be rebuilt)
         if self._clear_slot_fn is None:
             def clear(state, slot):
@@ -751,20 +702,18 @@ class ServingEngine:
         """Arm a chunk-prefilled slot for decode: set its device-side
         length cursor, block-table row and pending first token — the
         pool rows were already written by the chunks, so this is the
-        ``_write_slot`` tail without the ring scatter. Traced indices:
+        ``_write_slot`` tail without the pool scatter. Traced indices:
         no recompiles."""
         import jax
         import jax.numpy as jnp
 
         if getattr(self, "_set_slot_meta_fn", None) is None:
             def meta(state, last, slot, length, token, table_row):
-                tables = state.block_tables
-                if tables is not None:
-                    tables = tables.at[slot].set(table_row)
                 return (DecodeState(caches=state.caches,
                                     lengths=state.lengths.at[slot].set(
                                         length),
-                                    block_tables=tables),
+                                    block_tables=state.block_tables.at[
+                                        slot].set(table_row)),
                         last.at[slot, 0].set(token))
 
             self._set_slot_meta_fn = jax.jit(meta, donate_argnums=(0, 1))
@@ -816,10 +765,10 @@ class ServingEngine:
     def _ensure_state(self, prefill_cache) -> None:
         """Allocate the slot-pool DecodeState lazily from the first
         prefill's cache structure (zeros; every slot's rows are fully
-        overwritten by its admission prefill before any read). Paged
-        engines build the block POOL per KV entry — ``(kv_pool_blocks,
-        h, block_size, hd)`` (+ f32 scale arrays for int8) — instead of
-        per-slot rings, plus the all-garbage block tables."""
+        overwritten by its admission prefill before any read): the
+        block POOL per KV entry — ``(kv_pool_blocks, h, block_size,
+        hd)`` (+ f32 scale arrays for int8) — a slot-major entry for
+        every other stateful op, and the all-garbage block tables."""
         import jax
         import jax.numpy as jnp
 
@@ -834,37 +783,31 @@ class ServingEngine:
             # anything can match stale pointers
             self._prefix.clear(free=True)
         n = self.n_slots
-        tables = None
-        if self._paged:
-            caches = {}
-            self._paged_entry_names = set()
-            for name, entry in prefill_cache.items():
-                if self._is_kv_entry(entry):
-                    self._paged_entry_names.add(name)
-                    kc, vc = entry
-                    if self.kv_dtype == "int8":
-                        kq, ks = paged_pool_entry(
-                            kc, self.kv_pool_blocks, self.kv_block_size,
-                            "int8")
-                        vq, vs = paged_pool_entry(
-                            vc, self.kv_pool_blocks, self.kv_block_size,
-                            "int8")
-                        caches[name] = (kq, ks, vq, vs)
-                    else:
-                        caches[name] = (
-                            paged_pool_entry(kc, self.kv_pool_blocks,
-                                             self.kv_block_size, "native"),
-                            paged_pool_entry(vc, self.kv_pool_blocks,
-                                             self.kv_block_size, "native"))
+        caches = {}
+        self._paged_entry_names = set()
+        for name, entry in prefill_cache.items():
+            if self._is_kv_entry(entry):
+                self._paged_entry_names.add(name)
+                kc, vc = entry
+                if self.kv_dtype == "int8":
+                    kq, ks = paged_pool_entry(
+                        kc, self.kv_pool_blocks, self.kv_block_size,
+                        "int8")
+                    vq, vs = paged_pool_entry(
+                        vc, self.kv_pool_blocks, self.kv_block_size,
+                        "int8")
+                    caches[name] = (kq, ks, vq, vs)
                 else:
-                    caches[name] = jax.tree.map(
-                        lambda leaf: jnp.zeros((n,) + leaf.shape[1:],
-                                               leaf.dtype), entry)
-            tables = jnp.zeros((n, self.max_blocks_per_slot), jnp.int32)
-        else:
-            caches = jax.tree.map(
-                lambda leaf: jnp.zeros((n,) + leaf.shape[1:], leaf.dtype),
-                prefill_cache)
+                    caches[name] = (
+                        paged_pool_entry(kc, self.kv_pool_blocks,
+                                         self.kv_block_size, "native"),
+                        paged_pool_entry(vc, self.kv_pool_blocks,
+                                         self.kv_block_size, "native"))
+            else:
+                caches[name] = jax.tree.map(
+                    lambda leaf: jnp.zeros((n,) + leaf.shape[1:],
+                                           leaf.dtype), entry)
+        tables = jnp.zeros((n, self.max_blocks_per_slot), jnp.int32)
         self.state = DecodeState(caches=caches,
                                  lengths=jnp.zeros((n,), jnp.int32),
                                  block_tables=tables)
@@ -941,15 +884,13 @@ class ServingEngine:
         """Bind the engine's paged-KV bookkeeping to a scheduler: the
         block allocator (admission allocates, recycling frees) and the
         max supported context (admission rejects beyond the position
-        table, ISSUE 12 satellite). Idempotent; a ring engine only sets
-        the context bound when the table is the binding constraint."""
-        if self.block_allocator is not None:
-            sched.allocator = self.block_allocator
-            sched.on_slot_freed = self._clear_slot_tables
-            # prefix cache + chunked prefill (ISSUE 14): admission walks
-            # the trie and long suffixes/prompts take the chunk path
-            sched.prefix = self._prefix
-            sched.chunk_tokens = self.prefill_chunk_tokens
+        table, ISSUE 12 satellite). Idempotent."""
+        sched.allocator = self.block_allocator
+        sched.on_slot_freed = self._clear_slot_tables
+        # prefix cache + chunked prefill (ISSUE 14): admission walks
+        # the trie and long suffixes/prompts take the chunk path
+        sched.prefix = self._prefix
+        sched.chunk_tokens = self.prefill_chunk_tokens
         if self.max_context < sched.max_len:
             sched.max_context = self.max_context
 
@@ -1121,9 +1062,9 @@ class ServingEngine:
         """Drop the slot-pool DecodeState (replica kill / rejoin in the
         fleet): the next admission prefill rebuilds it from scratch via
         ``_ensure_state`` — committed tokens live host-side on each
-        Request, so nothing user-visible is lost. Paged engines also
-        reset the block allocator (no block of the discarded pool is
-        live anymore; survivors' re-prefills allocate fresh tables)."""
+        Request, so nothing user-visible is lost. The block allocator
+        is reset with it (no block of the discarded pool is live
+        anymore; survivors' re-prefills allocate fresh tables)."""
         self.state = None
         self._last_tokens = None
         if self._prefix is not None:
@@ -1131,8 +1072,7 @@ class ServingEngine:
             # reset below forgets refcounts wholesale, so the trie just
             # drops its nodes without per-block decrements
             self._prefix.clear(free=False)
-        if self.block_allocator is not None:
-            self.block_allocator.reset()
+        self.block_allocator.reset()
 
     # ------------------------------------------------------ KV accounting
     def _kv_row_bytes(self) -> int:
@@ -1160,13 +1100,10 @@ class ServingEngine:
         return self._kv_row_bytes_cache
 
     def _decode_kv_bytes(self, live) -> int:
-        """Analytic KV bytes this decode step's attention reads: paged —
-        each live slot's OCCUPIED blocks (the flash-decode kernel's
-        actual traffic, O(true_length)); ring — every slot's full
-        ``max_len`` ring (the O(max_len) bill paged decode removes)."""
+        """Analytic KV bytes this decode step's attention reads: each
+        live slot's OCCUPIED blocks (the flash-decode kernel's actual
+        traffic, O(true_length))."""
         row = self._kv_row_bytes()
-        if not self._paged:
-            return self.n_slots * self.max_decode_len * row
         bs = self.kv_block_size
         toks = 0
         for _slot, req in live:
@@ -1643,8 +1580,7 @@ class _ServeLoop:
             if not sched.commit_token(slot, tok):
                 with span("slot_write", tracer=tracer):
                     eng._write_slot(cache, slot, eff, tok,
-                                    table_row=(eng._table_row_for(req)
-                                               if eng._paged else None))
+                                    table_row=eng._table_row_for(req))
                 # mark completion (the pool holds the prompt's KV now)
                 # and eagerly cache the FULL prompt blocks so same-batch
                 # shared-prefix admissions already hit; the partial tail

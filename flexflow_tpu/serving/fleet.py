@@ -39,8 +39,8 @@ ISSUE 11 engine refactor exposed. On top of that loop:
   in-flight streams harvested (no terminal outcome) and re-submitted to
   survivors, re-prefilled from host-side committed tokens (the PR 9
   ``DecodeStateLostError`` rebuild path, now crossing replica
-  boundaries): continuations are bitwise-unchanged under exact decode,
-  rng resuming at ``(tag, tokens_emitted)``. Its queued requests
+  boundaries): the migrated token stream equals the uninterrupted one
+  (tier-1), rng resuming at ``(tag, tokens_emitted)``. Its queued requests
   re-route through the fleet queue.
 * **hedged retries** — a request whose replica blows
   ``--hedge-after-pctl`` percent of its EWMA-predicted service time gets
@@ -492,7 +492,6 @@ class ServingFleet:
                  n_slots: Optional[int] = None,
                  max_decode_len: Optional[int] = None,
                  max_queue: int = 64, eos_id: Optional[int] = None,
-                 exact_decode: bool = False,
                  plans: Optional[Sequence] = None,
                  buckets: Optional[Sequence[int]] = None,
                  clock=None, serve_loop: Optional[str] = None,
@@ -525,7 +524,7 @@ class ServingFleet:
             FleetReplica(i, ServingEngine(
                 model, n_slots=n_slots, max_decode_len=max_decode_len,
                 buckets=buckets, max_queue=max_queue, eos_id=eos_id,
-                exact_decode=exact_decode, serve_loop=serve_loop),
+                serve_loop=serve_loop),
                 plan=(plans[i] if plans else None),
                 open_after=open_after)
             for i in range(n)]
@@ -1028,8 +1027,8 @@ class ServingFleet:
             # stop feeding the sick replica AND rescue what was already
             # fed: its queued requests (including engine-level quarantine
             # retries parked at its queue front) re-route through the
-            # fleet queue to a healthy replica — exact-decode streams
-            # continue bitwise wherever they land. In-flight slots stay:
+            # fleet queue to a healthy replica — their token streams
+            # are the same wherever they land. In-flight slots stay:
             # they are mid-stream and the replica may still finish them.
             if rep.sched is not None and rep.sched.queued:
                 rescued = list(rep.sched.queue)
@@ -1208,7 +1207,6 @@ class ServingFleet:
             self.model, n_slots=ref.n_slots,
             max_decode_len=ref.max_decode_len, buckets=ref.buckets,
             max_queue=ref.max_queue, eos_id=self.eos_id,
-            exact_decode=ref.exact_decode,
             serve_loop=getattr(ref, "serve_loop", None))
         warmest = max((r.engine.admission for r in self.replicas),
                       key=lambda a: a.observed_steps)
@@ -1803,10 +1801,11 @@ class ServingFleet:
         outcome record through the REAL fleet door — WFQ, tenancy,
         quota and shed policies all apply to replayed traffic, and a
         progress-journaled stream re-enters carrying its committed
-        tokens (the PR 11 re-prefill path resumes it bitwise under
-        exact decode). Returns the fleet with the backlog queued; call
-        :meth:`run` to serve it. The relative deadline budget restarts
-        at recovery — monotonic clocks do not survive a process."""
+        tokens (the PR 11 re-prefill path resumes it: recovered and
+        uninterrupted streams are equal in tier-1). Returns the fleet
+        with the backlog queued; call :meth:`run` to serve it. The
+        relative deadline budget restarts at recovery — monotonic
+        clocks do not survive a process."""
         config = model.config
         root = journal_dir or getattr(config, "request_journal", "") \
             or ""

@@ -34,8 +34,8 @@ idioms PR 4 proved for training checkpoints (shared via
   rid with a submit but no outcome through the REAL door — WFQ,
   tenancy, quota and shed policies intact — rid-keyed dedupe against
   client retries, journaled progress resuming via the PR 11
-  re-prefill path so recovered continuations are bitwise-identical
-  under exact decode.
+  re-prefill path so the recovered token stream equals the
+  uninterrupted one (tier-1 pins the two streams equal).
 
 Journal off (the default) is the PR 16 noop-singleton contract:
 :data:`NOOP_JOURNAL` — one shared, slotted, allocation-free no-op the
@@ -394,7 +394,8 @@ class RequestJournal:
         """Reconstruct every journaled-but-unfinished request, in rid
         order: prompt + sampling params from the submit record, the
         committed-token prefix from its progress records (the PR 11
-        re-prefill path resumes it bitwise under exact decode). The
+        re-prefill path resumes it; tier-1 pins the recovered stream
+        equal to the uninterrupted one). The
         deadline budget restarts at re-submission — monotonic clocks do
         not survive a process, so the pre-crash wait cannot be
         charged."""

@@ -6,48 +6,48 @@ cache.cc) threads one cached tensor per op through the step. This module
 generalizes that pattern into the serving engine's decode state (ISSUE 6):
 
 * ``ServingState`` — the per-forward context ops see (``OpContext.serving``):
-  mode ("prefill" | "decode"), the static ring-buffer capacity, per-slot
-  write positions, and the cache_in/cache_out dicts keyed by op name.
-  Stateful ops (causal ``MultiHeadAttentionOp``, ``LSTMOp``) read and
-  extend it; everything else is oblivious.
+  mode ("prefill" | "decode" | "chunk"), the static per-slot capacity,
+  per-slot write positions, the block tables, and the
+  cache_in/cache_out dicts keyed by op name. Stateful ops (causal
+  ``MultiHeadAttentionOp``, ``LSTMOp``) read and extend it; everything
+  else is oblivious.
 
 * ``DecodeState`` — the jit-carried pytree between decode steps: one cache
-  entry per stateful node plus the per-slot ``lengths`` cursor. Registered
-  as a pytree node so it flows through ``jax.jit`` donation like any other
-  train-state argument.
+  entry per stateful node, the per-slot ``lengths`` cursor and the block
+  tables. Registered as a pytree node so it flows through ``jax.jit``
+  donation like any other train-state argument.
 
-Static shapes are the design rule (no per-token recompiles): the KV cache
-is a ring buffer of capacity ``max_len`` per slot — prefill writes the
-prompt at position 0, each decode step writes ONE token at
-``lengths[slot]`` via a per-slot dynamic_update_slice, and attention masks
-key positions ``> position``. Pad garbage beyond a prompt's true length is
-never read: the write cursor overwrites it before the mask ever exposes it.
-
-Paged layout (ISSUE 12, vLLM-style PagedAttention adapted to JAX/TPU):
-the per-slot ``max_len`` ring buffers become ONE pool of fixed-size KV
-blocks per stateful node — ``(n_blocks, heads, block_size, head_dim)`` —
-plus a per-slot **block table** ``(n_slots, max_blocks_per_slot)`` int32
-mapping each slot's logical positions onto pool blocks. Slot recycling
-and prefix sharing are pointer bookkeeping in the host-side
-:class:`~flexflow_tpu.serving.scheduler.BlockAllocator` (prefix sharing
-delivered by ISSUE 14's radix-tree cache, serving/prefix.py: shared
-blocks are refcounted, divergent writes clone first — copy-on-write);
-pool occupancy
-decouples from ``max_len`` (a short request holds few blocks); and the
-single-compile decode contract survives — block tables are just another
-int32 array in the jitted signature. Block index 0 is the reserved
-GARBAGE block: every unused table entry points at it, free slots write
-their (discarded) tokens into it, and the attention mask guarantees it
-is never read — so its contents only ever need to stay FINITE (``0 *
-garbage`` must be exactly ``0.0`` for the paged/ring bitwise-equality
-contract; the chaos poisoner deliberately never NaNs it).
+Static shapes are the design rule (no per-token recompiles). The KV
+layout is paged (ISSUE 12, vLLM-style PagedAttention adapted to
+JAX/TPU): ONE pool of fixed-size KV blocks per stateful node —
+``(n_blocks, heads, block_size, head_dim)`` — plus a per-slot **block
+table** ``(n_slots, max_blocks_per_slot)`` int32 mapping each slot's
+logical positions onto pool blocks. Prefill computes one request's
+contiguous ``(1, heads, max_len, head_dim)`` cache and the slot writer
+scatters it into the request's blocks; each decode step writes ONE token
+at ``lengths[slot]``, and attention masks key positions ``> position``.
+Pad garbage beyond a prompt's true length is never read: the write
+cursor overwrites it before the mask ever exposes it.
+Slot recycling and prefix sharing are pointer bookkeeping in the
+host-side :class:`~flexflow_tpu.serving.scheduler.BlockAllocator`
+(prefix sharing delivered by ISSUE 14's radix-tree cache,
+serving/prefix.py: shared blocks are refcounted, divergent writes clone
+first — copy-on-write); pool occupancy decouples from ``max_len`` (a
+short request holds few blocks); and the single-compile decode contract
+holds — block tables are just another int32 array in the jitted
+signature. Block index 0 is the reserved GARBAGE block: every unused
+table entry points at it, free slots write their (discarded) tokens into
+it, and the attention mask guarantees it is never read — so its contents
+only ever need to stay FINITE (``0 * garbage`` must be exactly ``0.0``;
+the chaos poisoner deliberately never NaNs it).
 
 Quantized layout (``kv_dtype="int8"``): pool blocks store symmetric
 per-(token, head) int8 rows with float32 scales in block-paged scale
 arrays ``(n_blocks, heads, block_size)`` — scale = amax/127 over the
-head_dim row, written once with the row and folded back on read. The
-exact-decode bitwise contract applies to fp layouts only; int8 is judged
-against a pinned tolerance band (tests/test_decode_paged.py).
+head_dim row, written once with the row and folded back on read. fp
+layouts are held to the whole-sequence forward within the stated
+tolerance (tests/serving_oracle.py); int8 is judged against a pinned
+tolerance band (tests/test_decode_paged.py).
 """
 from __future__ import annotations
 
@@ -68,9 +68,9 @@ INT8_QMAX = 127.0
 
 class SeqShardsError(ValueError):
     """A configuration asked for sequence-parallel decode
-    (``--seq-shards`` > 1) in a mode that cannot honor it — the ring KV
-    layout (no block tables to partition) or speculative decoding (the
-    greedy verify contract assumes the single-shard score path). Raised
+    (``--seq-shards`` > 1) in a mode that cannot honor it — speculative
+    decoding (the greedy verify contract assumes the single-shard score
+    path). Raised
     loudly at plan/engine construction instead of decoding garbage."""
 
 
@@ -83,8 +83,8 @@ class ServingState:
                SINGLE slot — batch 1 — writing its k/v rows into the
                slot's pool blocks and attending over the slot's gathered
                extent; the chunked-prefill and prefix-suffix program)
-    max_len:   ring-buffer capacity — the static sequence axis of every
-               cache entry (``--max-decode-len``)
+    max_len:   per-slot capacity — the static sequence axis of the
+               prefill's cache entry (``--max-decode-len``)
     positions: (batch,) int32 — the first position this call writes
                (zeros for prefill; ``DecodeState.lengths`` for decode;
                the chunk's start position for chunk mode)
@@ -95,18 +95,10 @@ class ServingState:
                it for the chunk's REAL token count (rows beyond are pad).
     cache_in:  {node_name: state pytree} consumed by decode
     cache_out: {node_name: state pytree} every stateful op fills
-    exact:     decode-numerics mode: True routes the attention score
-               through a full-extent GEMM (the new token's q padded to
-               max_len rows) so decode logits are BITWISE-identical to the
-               whole-sequence forward — XLA lowers a 1-row score product
-               as a matvec whose d-axis accumulation order differs from
-               the GEMM's by ~1 ulp otherwise. Default False (the fast
-               matvec); the equivalence tests and audits flip it on.
     block_tables: (n_slots, max_blocks_per_slot) int32 — the paged-KV
-               block tables (None selects the legacy ring layout; the
-               branch is static at trace time, so ring and paged decode
-               are distinct compiles, each recompile-free)
-    block_size: tokens per KV block (paged layout only)
+               block tables (decode and chunk; prefill has none: it
+               hands one request's contiguous cache to the slot writer)
+    block_size: tokens per KV block
     kv_dtype:  "native" (store k/v at the model dtype) or "int8"
                (symmetric per-(token, head) quantization with f32 scales)
     seq_shards: sequence-parallel decode width (ISSUE 18) — the gathered
@@ -115,7 +107,7 @@ class ServingState:
                per shard owning that run of pool blocks; on one device:
                an emulated compute-path decomposition of the same
                arrays) and merged by the flash segment combine. 1 is
-               the unsharded reference path. Paged decode only; chunk
+               the unsharded reference path. Decode only; chunk
                prefill writes are layout-identical at any width.
     """
 
@@ -125,40 +117,31 @@ class ServingState:
     lengths: Any = None
     cache_in: Optional[Dict[str, Any]] = None
     cache_out: Dict[str, Any] = dataclasses.field(default_factory=dict)
-    exact: bool = False
     block_tables: Any = None
     block_size: int = 0
     kv_dtype: str = "native"
     seq_shards: int = 1
-
-    @property
-    def paged(self) -> bool:
-        return self.block_tables is not None
 
 
 @dataclasses.dataclass
 class DecodeState:
     """The decode loop's carried state: {node_name: cache pytree} plus the
     per-slot length cursor. A pytree node — ``jax.jit`` donates and returns
-    it whole, so the ring buffers (or the paged pool) update in place on
-    device (the decode loop never copies the cache host-side).
+    it whole, so the pool updates in place on device (the decode loop
+    never copies the cache host-side).
 
-    ``block_tables`` is None for the ring layout; for the paged layout it
-    is the (n_slots, max_blocks_per_slot) int32 table mapping each slot's
-    positions onto pool blocks — it only changes at admission (the slot
-    writer sets the row), so decode steps carry it through untouched."""
+    ``block_tables`` is the (n_slots, max_blocks_per_slot) int32 table
+    mapping each slot's positions onto pool blocks — it only changes at
+    admission (the slot writer sets the row), so decode steps carry it
+    through untouched."""
 
     caches: Dict[str, Any]
     lengths: Any  # (n_slots,) int32
-    block_tables: Any = None  # (n_slots, max_blocks_per_slot) int32 | None
+    block_tables: Any  # (n_slots, max_blocks_per_slot) int32
 
     @property
     def n_slots(self) -> int:
         return int(self.lengths.shape[0])
-
-    @property
-    def paged(self) -> bool:
-        return self.block_tables is not None
 
 
 def _decode_state_flatten(s: "DecodeState"):
@@ -246,19 +229,6 @@ def update_slot_entry(cache_entry, prefill_entry, slot):
                                         tuple(jnp.asarray(s) for s in start))
 
     return jax.tree.map(ins, cache_entry, prefill_entry)
-
-
-def write_token_kv(buf, new, positions):
-    """Scatter one token's k or v (b, h, 1, hd) into the ring buffer
-    (b, h, max_len, hd) at per-slot ``positions`` — vmapped
-    dynamic_update_slice, exact (no arithmetic on the stored values)."""
-    import jax
-    import jax.lax as lax
-
-    def one(dst, src, p):  # (h, L, hd), (h, 1, hd), scalar
-        return lax.dynamic_update_slice(dst, src, (0, p, 0))
-
-    return jax.vmap(one)(buf, new, positions)
 
 
 # ----------------------------------------------------------- paged layout
@@ -368,7 +338,7 @@ def gather_paged_kv(pool, block_tables):
     """Materialize each slot's logical KV extent from the pool:
     ``(n_blocks, h, bs, hd)`` gathered through ``(n_slots, mb)`` tables →
     ``(n_slots, h, mb * bs, hd)`` in position order. This is the
-    CPU/exact fallback read (O(mb * bs) rows like the ring layout — the
+    CPU fallback read (O(mb * bs) rows — the
     Pallas flash-decode kernel is the O(true_length) path); a pure
     gather, so the materialized rows are bitwise the stored rows."""
     import jax.numpy as jnp
@@ -387,32 +357,32 @@ def gather_paged_scales(scales, block_tables):
     return g.reshape(g.shape[0], g.shape[1], -1)
 
 
-def paged_pool_entry(ring_leaf, n_blocks: int, block_size: int,
+def paged_pool_entry(prefill_leaf, n_blocks: int, block_size: int,
                      kv_dtype: str):
     """Zeros-initialized pool (+ scales for int8) for one KV leaf whose
-    per-request ring shape is ``(1, h, max_len, hd)``. Returns the pool
+    per-request prefill shape is ``(1, h, max_len, hd)``. Returns the pool
     array for "native", ``(pool int8, scales f32)`` for "int8"."""
     import jax.numpy as jnp
 
-    _, h, _L, hd = ring_leaf.shape
+    _, h, _L, hd = prefill_leaf.shape
     if kv_dtype == "int8":
         return (jnp.zeros((n_blocks, h, block_size, hd), jnp.int8),
                 jnp.zeros((n_blocks, h, block_size), jnp.float32))
-    return jnp.zeros((n_blocks, h, block_size, hd), ring_leaf.dtype)
+    return jnp.zeros((n_blocks, h, block_size, hd), prefill_leaf.dtype)
 
 
-def scatter_prefill_paged(pool, ring_leaf, table_row, block_size: int,
+def scatter_prefill_paged(pool, prefill_leaf, table_row, block_size: int,
                           scales=None):
-    """Insert one prefilled request's ring cache ``(1, h, max_len, hd)``
-    into its table row's pool blocks: the ring is padded to whole blocks,
+    """Insert one prefilled request's contiguous cache ``(1, h, max_len,
+    hd)`` into its table row's pool blocks: it is padded to whole blocks,
     reshaped block-major and scattered at ``table_row`` (mb,) int32.
-    Unused table entries point at GARBAGE_BLOCK and receive the ring's
+    Unused table entries point at GARBAGE_BLOCK and receive the cache's
     zero pad — harmless, never read. For int8 pools the rows are
     quantized here (``scales`` must be the matching scale array); fp
     pools store the rows bit-unchanged."""
     import jax.numpy as jnp
 
-    x = ring_leaf[0]                       # (h, L, hd)
+    x = prefill_leaf[0]                       # (h, L, hd)
     h, L, hd = x.shape
     mb = int(table_row.shape[0])
     pad = mb * block_size - L

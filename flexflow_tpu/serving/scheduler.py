@@ -262,7 +262,7 @@ class Request:
     retries_used: int = 0
     # paged KV (ISSUE 12): pool block ids this request holds while it
     # occupies a slot (allocated at admission, freed on recycle) — empty
-    # for ring-layout engines and while queued
+    # while queued, and under a scheduler driven without an allocator
     kv_blocks: List[int] = dataclasses.field(default_factory=list)
     # prefix cache + chunked prefill (ISSUE 14, serving/prefix.py /
     # docs/serving.md "Prefix cache & chunked prefill"):
@@ -405,7 +405,8 @@ class ContinuousBatchScheduler:
         self.evicted = 0
         # paged KV (ISSUE 12): the engine attaches its BlockAllocator and
         # max supported context (position-table bound) before driving the
-        # loop; None = ring layout / no context bound below max_len.
+        # loop; None = a scheduler driven alone (no pool to account) / no
+        # context bound below max_len.
         # on_slot_freed fires on EVERY slot-freeing path (finish, evict,
         # quarantine, hedge cancel) — the paged engine resets the freed
         # slot's device-side block-table row and length cursor there: a
@@ -587,9 +588,8 @@ class ContinuousBatchScheduler:
             # program — chunk_tokens-wide steps when chunking is on, one
             # bucket-shaped chunk otherwise. Compiled shape floor 2: a
             # 1-row projection lowers as a matvec whose accumulation
-            # differs from the GEMM's by ~1 ulp (the same lowering fact
-            # behind ServingState.exact), breaking the cached-vs-cold
-            # bitwise contract.
+            # differs from the GEMM's by ~1 ulp, one more way for a
+            # cached stream to part from the cold one at a near tie.
             req.chunk_shape = max(
                 2, self.chunk_tokens or bucket_for(suffix, self.buckets))
             self._chunk_turn = True

@@ -417,14 +417,11 @@ def serving_search(pcg: PCG, config, n_dev: int, machine=None,
     max_len = int(max_decode_len or getattr(config, "max_decode_len", 128))
     slo = slo_p99_ms if slo_p99_ms is not None else \
         float(getattr(config, "slo_p99_ms", 0.0) or 0.0)
-    # --kv-dtype pins the axis; the default ("native" config value with
-    # a paged cache) searches both storage dtypes
+    # --kv-dtype pins the axis; the default ("native" config value)
+    # searches both storage dtypes
     pinned_dtype = str(getattr(config, "kv_dtype", "native") or "native")
-    paged = str(getattr(config, "kv_cache", "paged") or "paged") == "paged"
     kv_dtypes: Tuple[str, ...]
-    if not paged:
-        kv_dtypes = ("native",)   # int8 is a paged-layout feature
-    elif pinned_dtype != "native":
+    if pinned_dtype != "native":
         kv_dtypes = (pinned_dtype,)
     else:
         kv_dtypes = ("native", "int8")

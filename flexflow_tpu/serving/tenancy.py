@@ -11,9 +11,9 @@ string accepted by ``--tenant-tiers`` overrides or extends the registry:
 
 Scheduling law: the queue is deterministic in the submission sequence —
 virtual clocks advance only on append/popleft, never from wall time — so
-replaying the same submissions yields the same service order, and under
-exact decode every stream is bitwise-identical whether co-scheduled with
-other tenants or run solo (tier-1 pins both properties).
+replaying the same submissions yields the same service order, and
+every token stream is the same whether co-scheduled with other tenants
+or run solo (tier-1 pins both properties).
 """
 from __future__ import annotations
 

@@ -12,9 +12,6 @@ def test_kv_flags_parse():
     from flexflow_tpu.config import FFConfig
 
     c = FFConfig()
-    c.parse_args(["--kv-cache", "ring", "--max-decode-len", "64"])
-    assert c.kv_cache == "ring"
-    c = FFConfig()
     c.parse_args(["--kv-block-size", "32", "--kv-pool-blocks", "9",
                   "--kv-dtype", "int8"])
     assert (c.kv_block_size, c.kv_pool_blocks, c.kv_dtype) == \
@@ -22,12 +19,9 @@ def test_kv_flags_parse():
 
 
 @pytest.mark.parametrize("argv,match", [
-    (["--kv-cache", "circular"], "paged|ring"),
     (["--kv-dtype", "fp8"], "native|int8"),
     (["--kv-block-size", "0"], "kv-block-size"),
     (["--kv-pool-blocks", "-1"], "kv-pool-blocks"),
-    (["--kv-cache", "ring", "--kv-pool-blocks", "8"], "only meaningful"),
-    (["--kv-cache", "ring", "--kv-dtype", "int8"], "requires"),
 ])
 def test_kv_flag_validation_fails_fast(argv, match):
     from flexflow_tpu.config import FFConfig
@@ -36,25 +30,48 @@ def test_kv_flag_validation_fails_fast(argv, match):
         FFConfig().parse_args(argv)
 
 
-def test_engine_kv_validation():
-    """Engine-level validation mirrors the flags for programmatic use."""
-    from flexflow_tpu import FFConfig, FFModel, LossType, SGDOptimizer
+def _tiny_gpt2(config):
+    from flexflow_tpu import FFModel, LossType, SGDOptimizer
     from flexflow_tpu.models.gpt2 import GPT2Config, build_gpt2
-    from flexflow_tpu.serving import ServingEngine
 
     cfg = GPT2Config.tiny(batch_size=2)
-    config = FFConfig()
     config.batch_size = 2
     ff = FFModel(config)
     build_gpt2(ff, cfg)
     ff.compile(optimizer=SGDOptimizer(ff),
                loss_type=LossType.LOSS_SPARSE_CATEGORICAL_CROSSENTROPY)
-    with pytest.raises(ValueError, match="paged.*ring|ring.*paged"):
-        ServingEngine(ff, kv_cache="circular")
+    return ff, cfg
+
+
+def test_engine_kv_validation():
+    """Engine-level validation mirrors the flags for programmatic use."""
+    from flexflow_tpu import FFConfig
+    from flexflow_tpu.serving import ServingEngine
+
+    ff, _cfg = _tiny_gpt2(FFConfig())
     with pytest.raises(ValueError, match="kv_dtype"):
         ServingEngine(ff, kv_dtype="fp8")
-    with pytest.raises(ValueError, match="paged"):
-        ServingEngine(ff, kv_cache="ring", kv_dtype="int8")
+
+
+def test_removed_kv_cache_flag_is_ignored():
+    """``--kv-cache`` left with the ring layout (ISSUE 31). The parser
+    keeps no message for a removed flag: like every unrecognised flag it
+    is ignored, the flags around it still land, and the engine built
+    from that config is the paged one."""
+    from flexflow_tpu import FFConfig
+    from flexflow_tpu.serving import ServingEngine
+
+    config = FFConfig()
+    config.parse_args(["--kv-cache", "ring", "--max-decode-len", "16",
+                       "--kv-block-size", "8"])
+    assert not hasattr(config, "kv_cache")
+    assert (config.max_decode_len, config.kv_block_size) == (16, 8)
+    ff, _cfg = _tiny_gpt2(config)
+    eng = ServingEngine(ff, n_slots=2)
+    assert eng.kv_cache == "paged" and eng.block_allocator is not None
+    out = eng.generate([[5, 6, 7], [1, 2]], max_new_tokens=3)
+    assert [len(o) for o in out] == [3, 3]
+    assert eng.state.block_tables.shape == (2, 2)
 
 
 # ---------------------------------------------------------- stats + ewma

@@ -96,33 +96,6 @@ def test_prefix_block_absent_without_activity():
     assert blk["reuse_rate"] == 0.25
 
 
-def test_ring_engine_keeps_prefix_off_quietly():
-    """The config default 'on' degrades silently for ring engines (the
-    legacy layout has no pool); only an EXPLICIT opt-in raises."""
-    import pytest
-
-    from flexflow_tpu import FFConfig, FFModel, LossType, SGDOptimizer
-    from flexflow_tpu.models.gpt2 import GPT2Config, build_gpt2
-    from flexflow_tpu.serving import ServingEngine
-
-    cfg = GPT2Config.tiny(batch_size=2)
-    config = FFConfig()
-    config.batch_size = cfg.batch_size
-    ff = FFModel(config)
-    build_gpt2(ff, cfg)
-    ff.compile(optimizer=SGDOptimizer(ff),
-               loss_type=LossType.LOSS_SPARSE_CATEGORICAL_CROSSENTROPY)
-    eng = ServingEngine(ff, n_slots=2, max_decode_len=cfg.seq_len,
-                        kv_cache="ring")
-    assert eng._prefix is None
-    with pytest.raises(ValueError, match="paged"):
-        ServingEngine(ff, n_slots=2, max_decode_len=cfg.seq_len,
-                      kv_cache="ring", prefix_cache="on")
-    with pytest.raises(ValueError, match="paged"):
-        ServingEngine(ff, n_slots=2, max_decode_len=cfg.seq_len,
-                      kv_cache="ring", prefill_chunk_tokens=16)
-
-
 def test_bench_serving_leg_has_prefix_subleg_keys():
     """The bench source wires the new sub-legs (static pin — the full
     leg is too heavy for tier-1)."""

@@ -39,16 +39,11 @@ def test_seq_shards_flag_parse_and_combos():
     assert cfg.seq_shards == 4
     with pytest.raises(ValueError, match=">= 1"):
         FFConfig().parse_args(["--seq-shards", "0"])
-    with pytest.raises(ValueError, match="paged"):
-        FFConfig().parse_args(["--seq-shards", "2", "--kv-cache", "ring"])
     cfg2 = FFConfig()
     cfg2.parse_args(["--context-buckets", "1024,8192"])
     assert cfg2.context_buckets == "1024,8192"
     with pytest.raises(ValueError):
         FFConfig().parse_args(["--context-buckets", "8192,1024"])
-    with pytest.raises(ValueError, match="paged"):
-        FFConfig().parse_args(["--context-buckets", "64",
-                               "--kv-cache", "ring"])
 
 
 def test_seq_shards_preflight_programmatic_assignment():
@@ -64,11 +59,6 @@ def test_seq_shards_preflight_programmatic_assignment():
     bad.seq_shards = 0
     with pytest.raises(PreflightError, match="seq-shards"):
         preflight_config(bad)
-    ring = FFConfig()
-    ring.seq_shards = 2
-    ring.kv_cache = "ring"
-    with pytest.raises(PreflightError):
-        preflight_config(ring)
     garbled = FFConfig()
     garbled.context_buckets = "10,ten"
     with pytest.raises(PreflightError):

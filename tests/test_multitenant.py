@@ -55,7 +55,6 @@ def _fleet(ff, cfg, **kw):
     kw.setdefault("n_replicas", 2)
     kw.setdefault("n_slots", 2)
     kw.setdefault("max_decode_len", cfg.seq_len)
-    kw.setdefault("exact_decode", True)
     return ServingFleet(ff, **kw)
 
 
@@ -180,8 +179,8 @@ def test_wfq_deque_compat_rescue_lane_first():
 
 # ------------------------------------------------- bitwise isolation law
 def test_bitwise_isolation_under_batch_flood(gpt2):
-    """THE tier-1 isolation law (ISSUE 19 acceptance): under exact
-    decode, an interactive stream is bitwise identical with and without
+    """THE tier-1 isolation law (ISSUE 19 acceptance): an
+    interactive stream is token for token the same with and without
     a batch-tier flood co-scheduled through the WFQ door — tenancy
     changes WHEN a stream decodes, never WHAT it decodes. The per-tenant
     exactly-one-outcome ledger closes on both sides."""

@@ -78,7 +78,7 @@ def gpt2():
 
 def _engine(ff, loop):
     return ServingEngine(ff, serve_loop=loop, n_slots=3, max_decode_len=64,
-                         exact_decode=True, kv_block_size=8)
+                         kv_block_size=8)
 
 
 def _prompts(n=6, seed=0):

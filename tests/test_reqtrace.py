@@ -59,7 +59,6 @@ def _fleet(ff, cfg, **kw):
     kw.setdefault("n_replicas", 2)
     kw.setdefault("n_slots", 2)
     kw.setdefault("max_decode_len", cfg.seq_len)
-    kw.setdefault("exact_decode", True)
     return ServingFleet(ff, **kw)
 
 
@@ -268,8 +267,7 @@ def test_tracing_off_is_bitwise_identical(gpt2):
     disabled produces bitwise-identical streams — the request path only
     ever branches on `rt.enabled`."""
     ff, cfg = gpt2
-    eng = ServingEngine(ff, n_slots=2, max_decode_len=cfg.seq_len,
-                        exact_decode=True)
+    eng = ServingEngine(ff, n_slots=2, max_decode_len=cfg.seq_len)
     prompts = _prompts(4, seed=5)
     base = eng.generate(prompts, max_new_tokens=5)
     live = enable_reqtrace()

@@ -5,7 +5,7 @@ churn over random corruption), group commit, compaction, the NOOP_JOURNAL
 off-contract, rid-keyed client-retry dedupe, and the end-to-end loop:
 crash mid-serve (FleetChaosPlan.crash_at) -> ServingFleet.recover() ->
 every journaled rid under exactly one outcome, progress-journaled streams
-resuming bitwise under exact decode — all deterministic on CPU."""
+resuming with unchanged tokens — all deterministic on CPU."""
 import json
 import os
 
@@ -42,8 +42,7 @@ def _prompts(n, seed=0, lo=3, hi=6):
 
 
 def _baseline(ff, cfg, prompts, max_new):
-    return ServingEngine(ff, n_slots=2, max_decode_len=cfg.seq_len,
-                         exact_decode=True).generate(
+    return ServingEngine(ff, n_slots=2, max_decode_len=cfg.seq_len).generate(
                              prompts, max_new_tokens=max_new)
 
 
@@ -51,7 +50,6 @@ def _fleet(ff, cfg, **kw):
     kw.setdefault("n_replicas", 2)
     kw.setdefault("n_slots", 2)
     kw.setdefault("max_decode_len", cfg.seq_len)
-    kw.setdefault("exact_decode", True)
     return ServingFleet(ff, **kw)
 
 
@@ -259,7 +257,7 @@ def test_crash_recover_exactly_one_outcome_bitwise(gpt2, tmp_path):
     the unfinished backlog through the real door, and after the recovery
     run every journaled rid has exactly one outcome on disk — with
     progress-journaled streams resumed BITWISE vs an undisturbed
-    single-engine run under exact decode."""
+    single-engine run."""
     ff, cfg = gpt2
     config = ff.config
     prompts = _prompts(8, seed=4)
@@ -286,8 +284,7 @@ def test_crash_recover_exactly_one_outcome_bitwise(gpt2, tmp_path):
             "crash tick never reached mid-stream decode"
 
         fleet2 = ServingFleet.recover(ff, n_replicas=2, n_slots=2,
-                                      max_decode_len=cfg.seq_len,
-                                      exact_decode=True)
+                                      max_decode_len=cfg.seq_len)
         jr = fleet2.journal
         assert jr.replayed == len(backlog)
         assert jr.recovery_wall_s > 0
@@ -353,8 +350,7 @@ def test_drain_crash_recover_exactly_once(gpt2, tmp_path):
         # the drain's outcome records are already durable: recovery on
         # the same directory finds zero unfinished rids
         fleet2 = ServingFleet.recover(ff, n_replicas=2, n_slots=2,
-                                      max_decode_len=cfg.seq_len,
-                                      exact_decode=True)
+                                      max_decode_len=cfg.seq_len)
         assert fleet2.journal.replayed == 0
         assert fleet2.journal.pending_rids() == []
         fleet2.journal.close()
@@ -391,7 +387,7 @@ ff.compile(optimizer=SGDOptimizer(ff),
            loss_type=LossType.LOSS_SPARSE_CATEGORICAL_CROSSENTROPY)
 rng = np.random.default_rng(6)
 fleet = ServingFleet(ff, n_replicas=2, n_slots=2,
-                     max_decode_len=cfg.seq_len, exact_decode=True)
+                     max_decode_len=cfg.seq_len)
 for i in range(6):
     p = rng.integers(0, 100, size=int(rng.integers(3, 6)))
     fleet.submit(Request(prompt=p.astype(np.int32), max_new_tokens=6,
@@ -411,8 +407,7 @@ raise SystemExit("still alive after SIGKILL tick")
     _journal_config(config, jdir, commit_every=1)
     try:
         fleet = ServingFleet.recover(ff, n_replicas=2, n_slots=2,
-                                     max_decode_len=cfg.seq_len,
-                                     exact_decode=True)
+                                     max_decode_len=cfg.seq_len)
         assert fleet.journal.replayed >= 1
         fleet.run()
         assert set(fleet.stats.outcomes) == {"ok"}

@@ -19,6 +19,8 @@ from flexflow_tpu.models.transformer import (TransformerConfig,
 from flexflow_tpu.serving import (ContinuousBatchScheduler, QueueFullError,
                                   Request, ServingEngine, bucket_for)
 from flexflow_tpu.serving.kvcache import DecodeState
+from serving_oracle import (assert_matches_reference, logit_gap,
+                            logit_tolerance)
 
 
 def _compile_gpt2(batch=8):
@@ -37,10 +39,48 @@ def gpt2():
     return _compile_gpt2()
 
 
-def _teacher_forced_decode(ff, seq, prompt_len, max_len, bucket):
-    """Prefill ``prompt_len`` tokens, then decode with the TRUE next token
-    fed back each step (teacher forcing) — returns per-position decode
-    logits aligned with the full forward's rows."""
+KV_BLOCK = 8
+
+
+def _pool_state(cache, prompt_len, max_len):
+    """One request's prefill cache on the paged pool, built from the
+    public pieces the engine's slot writer uses: a pool of ``mb`` blocks
+    plus the garbage block per KV leaf, the one-row table ``[1 .. mb]``,
+    the prompt's rows scattered through it. A stateful entry that is no
+    KV pair (the LSTM carry) stays slot-major, and a model without KV
+    entries carries an empty pool under an all-garbage table — what
+    ``ServingEngine._ensure_state`` builds for such a model."""
+    import jax.numpy as jnp
+
+    from flexflow_tpu.serving.kvcache import (blocks_per_slot,
+                                              paged_pool_entry,
+                                              scatter_prefill_paged)
+
+    mb = blocks_per_slot(max_len, KV_BLOCK)
+    row = jnp.arange(1, mb + 1, dtype=jnp.int32)
+    caches, paged = {}, False
+    for name, entry in cache.items():
+        if isinstance(entry, tuple) and all(x.ndim == 4 for x in entry):
+            paged = True
+            caches[name] = tuple(
+                scatter_prefill_paged(
+                    paged_pool_entry(leaf, mb + 1, KV_BLOCK, "native"),
+                    leaf, row, KV_BLOCK)[0] for leaf in entry)
+        else:
+            caches[name] = entry
+    tables = row[None] if paged else jnp.zeros((1, mb), jnp.int32)
+    return DecodeState(caches=caches,
+                       lengths=jnp.asarray([prompt_len], jnp.int32),
+                       block_tables=tables)
+
+
+def _teacher_forced_decode(ff, seq, prompt_len, max_len, bucket,
+                           fault=None):
+    """Prefill ``prompt_len`` tokens, then decode over the paged pool
+    with the TRUE next token fed back each step (teacher forcing) —
+    returns per-position decode logits aligned with the full forward's
+    rows. ``fault`` (the control's) edits the decode state this harness
+    built, never the product."""
     import jax.numpy as jnp
 
     pre = ff.executor.make_prefill_step(bucket_len=bucket,
@@ -49,9 +89,10 @@ def _teacher_forced_decode(ff, seq, prompt_len, max_len, bucket):
     padded[0, :prompt_len] = seq[0, :prompt_len]
     logits_p, last, cache = pre(ff.params, [jnp.asarray(padded)],
                                 jnp.asarray([prompt_len], np.int32))
-    state = DecodeState(caches=cache,
-                        lengths=jnp.asarray([prompt_len], jnp.int32))
-    dec = ff.executor.make_decode_step(max_len, exact=True)
+    state = _pool_state(cache, prompt_len, max_len)
+    if fault is not None:
+        state = fault(state)
+    dec = ff.executor.make_decode_step(max_len, KV_BLOCK)
     rows = {}
     for t in range(prompt_len, seq.shape[1]):
         lg, state = dec(ff.params, [jnp.asarray(seq[:, t:t + 1])], state)
@@ -64,10 +105,16 @@ def _full_forward_logits(ff, seq, batch):
     return np.asarray(fwd(ff.params, [np.repeat(seq, batch, axis=0)]))[0]
 
 
-def test_prefill_decode_bitwise_gpt2(gpt2):
-    """Acceptance gate: prefill+decode logits BITWISE-match the
-    whole-sequence forward (exact decode mode routes the 1-token score
-    product through the same-shape GEMM)."""
+def _stack(rows):
+    """(positions, logits) of a ``{position: row}`` dict, in order."""
+    ts = sorted(rows)
+    return ts, np.stack([rows[t] for t in ts])
+
+
+def test_prefill_decode_matches_full_forward_gpt2(gpt2):
+    """Acceptance gate: prefill and decode logits match the
+    whole-sequence forward within the stated tolerance and choose the
+    same greedy token at every position (tests/serving_oracle.py)."""
     ff, cfg = gpt2
     rng = np.random.default_rng(0)
     seq = rng.integers(0, cfg.vocab_size,
@@ -76,16 +123,16 @@ def test_prefill_decode_bitwise_gpt2(gpt2):
     L, bucket = 5, 8
     logits_p, last, rows = _teacher_forced_decode(
         ff, seq, L, cfg.seq_len, bucket)
-    # prefill rows [0, L) match the full forward bitwise
-    assert np.array_equal(logits_p[0, :L], full[:L])
+    # prefill rows [0, L) match the full forward
+    assert_matches_reference(logits_p[0, :L], full[:L], "prefill rows")
     # the prefill's next-token logits are the row at L-1
-    assert np.array_equal(last[0], full[L - 1])
-    # every decoded position matches bitwise
-    for t, row in rows.items():
-        assert np.array_equal(row, full[t]), f"decode row {t} diverged"
+    assert_matches_reference(last[0], full[L - 1], "prefill last row")
+    # every decoded position matches
+    ts, got = _stack(rows)
+    assert_matches_reference(got, full[ts], "decode rows")
 
 
-def test_prefill_decode_bitwise_transformer_decoder():
+def test_prefill_decode_matches_full_forward_transformer_decoder():
     cfg = TransformerConfig.tiny(batch_size=4)
     config = FFConfig()
     config.batch_size = cfg.batch_size
@@ -98,16 +145,16 @@ def test_prefill_decode_bitwise_transformer_decoder():
     full = _full_forward_logits(ff, seq, cfg.batch_size)
     logits_p, last, rows = _teacher_forced_decode(
         ff, seq, 4, cfg.seq_len, 4)
-    assert np.array_equal(logits_p[0, :4], full[:4])
-    for t, row in rows.items():
-        assert np.array_equal(row, full[t]), f"decode row {t} diverged"
+    assert_matches_reference(logits_p[0, :4], full[:4], "prefill rows")
+    ts, got = _stack(rows)
+    assert_matches_reference(got, full[ts], "decode rows")
 
 
 def test_lstm_decode_state():
     """The NMT-family building block: the LSTM's recurrent carry is its
     decode state. Prefill gathers the carry at the TRUE prompt length
-    (not the padded tail); decode continues within float32 ulp noise of
-    the whole-sequence forward and greedy tokens agree exactly."""
+    (not the padded tail); decode continues within the stated tolerance
+    of the whole-sequence forward and greedy tokens agree exactly."""
     config = FFConfig()
     config.batch_size = 4
     ff = FFModel(config)
@@ -125,9 +172,88 @@ def test_lstm_decode_state():
     # prefill's next-token logits come from the carry at length-1 — the
     # padded tail the scan marched through must not leak in
     assert np.array_equal(last[0], full[L - 1])
-    for t_, row in rows.items():
-        np.testing.assert_allclose(row, full[t_], rtol=1e-5, atol=1e-5)
-        assert int(np.argmax(row)) == int(np.argmax(full[t_]))
+    ts, got = _stack(rows)
+    assert_matches_reference(got, full[ts], "decode rows")
+
+
+def _chunked_next_token_logits(ff, prompt, max_len, chunk, late=0):
+    """The prompt through the chunk-prefill program, ``chunk`` tokens at
+    a time into an empty pool; returns the final chunk's next-token
+    logits. ``late`` (the control's) shifts where the LAST chunk is
+    written."""
+    import jax
+    import jax.numpy as jnp
+
+    pre = ff.executor.make_prefill_step(bucket_len=chunk,
+                                        max_decode_len=max_len)
+    _lg, _last, cache = pre(ff.params, [jnp.zeros((1, chunk), jnp.int32)],
+                            jnp.asarray([1], np.int32))
+    state = _pool_state(jax.tree.map(jnp.zeros_like, cache), 0, max_len)
+    fn = ff.executor.make_chunk_prefill_step(chunk, max_len, KV_BLOCK)
+    row = state.block_tables[0]
+    n_all = len(prompt)
+    for start in range(0, n_all, chunk):
+        n = min(chunk, n_all - start)
+        ids = np.zeros((1, chunk), np.int32)
+        ids[0, :n] = prompt[start:start + n]
+        at = start + (late if start + n == n_all else 0)
+        last, state = fn(ff.params, [jnp.asarray(ids)], state, row,
+                         jnp.int32(at), jnp.int32(n))
+    return np.asarray(last)[0]
+
+
+def _zero_one_k_row(state):
+    """Fault (a): the cached K row of prompt position 2 reads zero in
+    the first attention entry (block ``table[0]``, offset 2)."""
+    name = sorted(n for n, e in state.caches.items()
+                  if isinstance(e, tuple))[0]
+    kp, vp = state.caches[name]
+    caches = dict(state.caches)
+    caches[name] = (kp.at[1, :, 2].set(0.0), vp)
+    return DecodeState(caches=caches, lengths=state.lengths,
+                       block_tables=state.block_tables)
+
+
+def _lengths_one_short(state):
+    """Fault (b): the write cursor — and with it the mask and the
+    position id — is off by one."""
+    return DecodeState(caches=state.caches, lengths=state.lengths - 1,
+                       block_tables=state.block_tables)
+
+
+@pytest.mark.parametrize("fault", ["k_row_zeroed", "lengths_one_short",
+                                   "chunk_one_late"])
+def test_oracle_tolerance_refuses_planted_faults(gpt2, fault):
+    """The control: the tolerance admits the product (the same harness
+    without the fault passes) and refuses a fault a reader would call a
+    bug, planted in the TEST's copy of the inputs — each reads at least
+    100 times the tolerance."""
+    ff, cfg = gpt2
+    rng = np.random.default_rng(0)
+    seq = rng.integers(0, cfg.vocab_size,
+                       size=(1, cfg.seq_len)).astype(np.int32)
+    full = _full_forward_logits(ff, seq, cfg.batch_size)
+    if fault == "chunk_one_late":
+        L = 12
+        ref = full[L - 1]
+        good = _chunked_next_token_logits(ff, seq[0, :L], cfg.seq_len, 8)
+        bad = _chunked_next_token_logits(ff, seq[0, :L], cfg.seq_len, 8,
+                                         late=1)
+    else:
+        plant = {"k_row_zeroed": _zero_one_k_row,
+                 "lengths_one_short": _lengths_one_short}[fault]
+        _p, _l, rows = _teacher_forced_decode(ff, seq, 5, cfg.seq_len, 8)
+        ts, good = _stack(rows)
+        _p, _l, rows = _teacher_forced_decode(ff, seq, 5, cfg.seq_len, 8,
+                                              fault=plant)
+        _ts, bad = _stack(rows)
+        ref = full[ts]
+    assert_matches_reference(good, ref, "unfaulted")
+    gap, tol = logit_gap(bad, ref), logit_tolerance(ref)
+    assert gap >= 100 * tol, \
+        f"{fault} reads {gap:.3e}, under 100x the tolerance {tol:.3e}"
+    with pytest.raises(AssertionError, match="differ from the reference"):
+        assert_matches_reference(bad, ref, fault)
 
 
 def test_decode_recompile_free(gpt2):

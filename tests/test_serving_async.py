@@ -3,8 +3,8 @@ flexflow_tpu/serving/engine.py `_AsyncServeLoop`, docs/serving.md
 "Async runtime"): `--serve-loop async` dispatches decode step k+1 while
 step k's (tokens, ok_vec) transfer is in flight and commits at arrival,
 one step behind dispatch. The sync loop is the reference
-implementation; under exact decode the async loop must match it
-stream-for-stream BITWISE — solo, co-batched, prefix-hit, chunked
+implementation; the async loop must match it
+stream for stream, token for token — solo, co-batched, prefix-hit, chunked
 prefill, speculative — including under the chaos harness (poison
 quarantine, mid-decode kill + migration, SIGTERM drain, fleet hedge),
 with at most one blocking host transfer per committed decode step
@@ -52,7 +52,6 @@ def _prompts(n, seed=0, lo=3, hi=8):
 def _engine(ff, loop, **kw):
     kw.setdefault("n_slots", 3)
     kw.setdefault("max_decode_len", 64)
-    kw.setdefault("exact_decode", True)
     kw.setdefault("kv_block_size", 8)
     return ServingEngine(ff, serve_loop=loop, **kw)
 
@@ -117,7 +116,7 @@ def test_async_matches_sync_chunked_prefill(gpt2):
 def test_speculative_matches_both_loops(gpt2):
     """The speculative decoder (device-side argmax scoring, ISSUE 17
     satellite) keeps its token-identity contract against BOTH loops'
-    greedy exact decode."""
+    greedy decode."""
     from flexflow_tpu.serving import SpeculativeDecoder
 
     ff, _ = gpt2
@@ -198,8 +197,7 @@ def test_fleet_kill_migration_parity(gpt2):
                                                    max_new_tokens=6)
     for loop in ("sync", "async"):
         fleet = ServingFleet(ff, n_replicas=2, n_slots=2,
-                             max_decode_len=64, exact_decode=True,
-                             serve_loop=loop)
+                             max_decode_len=64, serve_loop=loop)
         outs = fleet.generate(
             prompts, max_new_tokens=6,
             chaos=FleetChaosPlan(kill_replica_at={4: 0}))
@@ -221,8 +219,7 @@ def test_fleet_hedge_parity(gpt2):
     try:
         for loop in ("sync", "async"):
             fleet = ServingFleet(ff, n_replicas=2, n_slots=2,
-                                 max_decode_len=64, exact_decode=True,
-                                 serve_loop=loop)
+                                 max_decode_len=64, serve_loop=loop)
             for r in fleet.replicas:
                 r.engine.admission.force_token_cost_ms = 1e-6
             outs = fleet.generate(

@@ -1,7 +1,6 @@
 """Fleet of fault domains (ISSUE 11, flexflow_tpu/serving/fleet.py,
 docs/fleet.md): multi-replica routing with health-checked failover,
-cross-replica request migration (bitwise continuations under exact
-decode), hedged retries that never double-count, fleet-level shedding
+cross-replica request migration (unchanged token streams), hedged retries that never double-count, fleet-level shedding
 with a floored retry_after_ms, rolling drain/rejoin, per-replica plan
 lint, and the fleet-wide exactly-one-outcome ledger under scripted
 chaos — all deterministic on CPU."""
@@ -36,8 +35,7 @@ def _prompts(n, seed=0, lo=3, hi=6):
 
 
 def _baseline(ff, cfg, prompts, max_new):
-    return ServingEngine(ff, n_slots=2, max_decode_len=cfg.seq_len,
-                         exact_decode=True).generate(
+    return ServingEngine(ff, n_slots=2, max_decode_len=cfg.seq_len).generate(
                              prompts, max_new_tokens=max_new)
 
 
@@ -45,7 +43,6 @@ def _fleet(ff, cfg, **kw):
     kw.setdefault("n_replicas", 2)
     kw.setdefault("n_slots", 2)
     kw.setdefault("max_decode_len", cfg.seq_len)
-    kw.setdefault("exact_decode", True)
     return ServingFleet(ff, **kw)
 
 

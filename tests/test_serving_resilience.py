@@ -200,12 +200,12 @@ def test_decode_poison_quarantined_retried_neighbors_bitwise(gpt2):
     """A NaN-poisoned decode slot is quarantined ALONE: co-batched
     streams continue bit-identically, and the poisoned request is retried
     on a fresh slot, resuming its stream exactly where the quarantine cut
-    it (bitwise under exact decode numerics)."""
+    it (the same tokens)."""
     ff, cfg = gpt2
     prompts = _prompts(4, seed=3)
-    base = _engine(ff, cfg, exact_decode=True).generate(prompts,
+    base = _engine(ff, cfg).generate(prompts,
                                                         max_new_tokens=5)
-    eng = _engine(ff, cfg, exact_decode=True)
+    eng = _engine(ff, cfg)
     chaos = ChaosPlan(poison_decode_at={2: 0})
     outs = eng.generate(prompts, max_new_tokens=5, chaos=chaos)
     assert chaos.poisoned_decode_steps == [2]
@@ -222,9 +222,9 @@ def test_repeated_poison_aborts_decode_fault(gpt2):
     while neighbors still finish bit-identically."""
     ff, cfg = gpt2
     prompts = _prompts(2, seed=4)
-    base = _engine(ff, cfg, exact_decode=True).generate(prompts,
+    base = _engine(ff, cfg).generate(prompts,
                                                         max_new_tokens=6)
-    eng = _engine(ff, cfg, exact_decode=True)
+    eng = _engine(ff, cfg)
     # slot 0 poisoned at step 1; the retry re-prefills into the only free
     # slot (0 again) and is poisoned again at step 3 — budget 1 exhausted
     chaos = ChaosPlan(poison_decode_at={1: 0, 3: 0})
@@ -330,14 +330,14 @@ def test_real_loss_with_dead_state_reprefills_bitwise(gpt2):
     donated DecodeState. The engine must not retry into 'Array has been
     deleted': it replans, rebuilds the pool, and re-prefills every live
     stream from its host-side committed tokens — continuations stay
-    bit-identical (exact decode) and every request still ends ok."""
+    token-identical and every request still ends ok."""
     import jax
 
     ff, cfg = gpt2
     prompts = _prompts(3, seed=11)
-    base = _engine(ff, cfg, exact_decode=True).generate(prompts,
+    base = _engine(ff, cfg).generate(prompts,
                                                         max_new_tokens=5)
-    eng = _engine(ff, cfg, exact_decode=True)
+    eng = _engine(ff, cfg)
     real = eng._decode_fn
     fired = []
 
@@ -425,7 +425,7 @@ def test_chaos_end_to_end_every_request_accounted(gpt2):
     ff, cfg = gpt2
     config = ff.config
     prompts = _prompts(4, seed=9)
-    base = _engine(ff, cfg, exact_decode=True).generate(prompts,
+    base = _engine(ff, cfg).generate(prompts,
                                                         max_new_tokens=6)
     storm = {4: [[7, 8, 9]] * 6}
     config.shed_policy = "queue"
@@ -433,7 +433,7 @@ def test_chaos_end_to_end_every_request_accounted(gpt2):
         # max_queue 8 -> 'queue' policy high-water 4: part of the storm
         # is accepted, the rest shed; SIGTERM lands while storm work is
         # still queued so the drain has something to hand back
-        eng = _engine(ff, cfg, exact_decode=True, max_queue=8)
+        eng = _engine(ff, cfg, max_queue=8)
         chaos = ChaosPlan(poison_decode_at={3: 1},
                           storm_queue=storm,
                           storm_max_new_tokens=3,
