@@ -4,10 +4,14 @@ thresholds, the kernel names in a compiled program's text, and the one door
 to members of the program that no public entry point gives."""
 from __future__ import annotations
 
+import contextlib
 import glob
+import json
 import os
 import re
 import shutil
+import sys
+import time
 
 # The comparison with the plain reference is about the program, not about the
 # run: the weights and the inputs it is made on come from this constant, so
@@ -18,6 +22,45 @@ import shutil
 # (PERF.md, correct) refused PRs on their parent's runs. Never another value
 # "because it passes": a tolerance is set from many seeds' samples (PERF.md).
 CHECK_SEED = 0
+
+
+class Walls:
+    """Where a run's seconds went. Every phase prints one line to standard
+    error as it ends (``[bench] wall: <phase> <s> (since start <s>)``,
+    flushed), so that a run stopped at a time limit shows, in what is kept
+    of its standard error, the last phase it finished; ``line()`` is the
+    whole table, for the run's last lines. A phase that recurs is summed.
+    Nothing here is read by a metric: the walls say what a run cost, not
+    what the program did."""
+
+    def __init__(self, t_start: float):
+        self.t_start = t_start
+        self.seconds = {}
+
+    def add(self, phase: str, seconds: float) -> None:
+        self.seconds[phase] = self.seconds.get(phase, 0.0) + seconds
+        print(f"[bench] wall: {phase} {seconds:.2f} (since start "
+              f"{time.perf_counter() - self.t_start:.2f})", file=sys.stderr,
+              flush=True)
+
+    @contextlib.contextmanager
+    def phase(self, name: str):
+        t = time.perf_counter()
+        try:
+            yield
+        finally:
+            self.add(name, time.perf_counter() - t)
+
+    def table(self) -> dict:
+        """Seconds by phase in the order they first ended, and the whole
+        process so far (``run_wall_s``; the phases leave out what lies
+        between them)."""
+        out = {k: round(v, 2) for k, v in self.seconds.items()}
+        out["run_wall_s"] = round(time.perf_counter() - self.t_start, 2)
+        return out
+
+    def line(self) -> str:
+        return "walls: " + json.dumps(self.table())
 
 
 def mosaic_calls(compiled_text: str) -> set:

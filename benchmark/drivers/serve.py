@@ -24,6 +24,13 @@ between two of its tokens or since its newest. One that misses either is
 that makes the judged median better by admitting later, by running fewer
 slots or by letting a stream stall is refused, without a bound on a tail
 that some sixty requests cannot carry.
+
+The traced run (``--trace 1``) takes the profiler's trace of a shorter window
+of its own: ``trace_seconds`` of the cell's file or, where the file gives
+``trace_steps``, that many decode steps, whichever comes first. Stopping the
+profiler and reducing the trace cost in proportion to the device events, and
+those follow the steps: bounded by steps, a traced run costs the same
+whatever the program's speed.
 """
 from __future__ import annotations
 
@@ -309,21 +316,25 @@ def run(ctx) -> dict:
     counter = CompileCounter()
     cell, mix = ctx.cell, ctx.traffic
     info, checks = {}, {}
+    walls = ctx.walls
     t = time.perf_counter()
     ff, eng = build_engine(ctx, info)
     build_s = time.perf_counter() - t
+    walls.add("build_compile_init", build_s)
     vocab = int(ctx.config["vocab_size"])
     gen = ctx.generator()
 
     t = time.perf_counter()
     live, checked, twins = warm_wave(ctx, eng, gen, mix, vocab)
     warm_s = time.perf_counter() - t
+    walls.add("warm_wave", warm_s)
     checks["decode_compiles_once"] = eng.decode_compiles == 1
     t = time.perf_counter()
     check_stats, verdicts = reference_check(ctx, ff, live, checked, twins,
                                             info)
     checks.update(verdicts)
     check_s = time.perf_counter() - t
+    walls.add("reference_check", check_s)
     del live
 
     # the compiled decode step's text: the kernel that should run is in it
@@ -345,8 +356,10 @@ def run(ctx) -> dict:
         rt = enable_reqtrace()
     del text
     text_s = time.perf_counter() - t
+    walls.add("step_text", text_s)
     seconds = min(ctx.seconds, float(cell.get("trace_seconds", 5.0))) \
         if ctx.trace else ctx.seconds
+    trace_steps = int(cell.get("trace_steps", 0)) if ctx.trace else 0
     pre_roll = float(mix["pre_roll_s"])
     # after the window: until every request due in it has its first token
     # (at most drain_grace_s); the traced run waits for them to finish, so
@@ -401,18 +414,32 @@ def run(ctx) -> dict:
         now = clock_ms()
         if snap_open is None and now >= w_open:
             if ctx.trace:
-                start_trace(ctx)
+                with walls.phase("start_trace"):
+                    start_trace(ctx)
                 window_cm = spans.span(spans.WINDOW)
                 window_cm.__enter__()
                 now = clock_ms()
             snap_open = (now, snapshot(stats), sched.active + sched.queued,
                          counter.n, len(counter.names))
+        if (trace_steps and snap_open is not None and now < w_close
+                and stats.decode_steps - snap_open[1]["decode_steps"]
+                >= trace_steps):
+            # closed by its steps: the window ends here, and what was due
+            # after it is not offered (as nothing arrives after a window that
+            # closes by the clock)
+            w_close = now
+            n = max(i, int(np.searchsorted(due_ms, w_close, side="left")))
+            in_window = [k for k in range(n) if w_open <= due_ms[k]]
         if snap_close is None and now >= w_close:
             snap_close = (now, snapshot(stats), sched.active + sched.queued,
                           counter.n, sched.queued, len(counter.names))
+            walls.add("pre_roll", (snap_open[0] - t0) / 1e3)
+            walls.add("window", (now - snap_open[0]) / 1e3)
             if window_cm is not None:
                 window_cm.__exit__(None, None, None)
-                trace_file = stop_trace(ctx)
+                with walls.phase("stop_trace"):
+                    trace_file = stop_trace(ctx)
+            drain_from = clock_ms()
         if i < n and due_ms[i] <= now:
             with spans.span("admit"):
                 while i < n and due_ms[i] <= now:
@@ -435,8 +462,10 @@ def run(ctx) -> dict:
             with spans.span("generator_sleep"):
                 time.sleep(min(max(nxt - clock_ms(), 0.2), 5.0) / 1e3)
     end_ms = clock_ms()
+    walls.add("drain", (end_ms - drain_from) / 1e3)
     still_running = sched.active + sched.queued
-    loop.finish()
+    with walls.phase("loop_finish"):
+        loop.finish()
 
     # ---- reduce: requests due inside the window
     ttft, tpot, failed = latencies(
@@ -478,6 +507,10 @@ def run(ctx) -> dict:
         "outcomes": dict(stats.outcomes)})
     if compiles_in_window:
         info["lowered_in_window"] = counter.names[snap_open[4]:snap_close[5]]
+    records = None
+    if rt is not None:
+        with walls.phase("request_records"):
+            records = rt.records()
     facts = {
         "kind": "serve", "info": info, "checks": checks,
         "check_stats": check_stats, "compared": compared(check_stats),
@@ -494,7 +527,7 @@ def run(ctx) -> dict:
         "due_ms": {k: float(due_ms[k]) for k in in_window},
         "submit_ms": {k: admitted_ms[k] for k in in_window},
         "token_times_ms": token_times, "trace_file": trace_file,
-        "request_records": rt.records() if rt is not None else None,
+        "request_records": records,
         "rids": {reqs[k].rid: k for k in in_window},
         "scopes": scopes,
     }

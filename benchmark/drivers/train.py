@@ -78,6 +78,7 @@ def run(ctx) -> dict:
     batch = int(cell["batch_per_chip"]) * chips
     seq = int(job["seq_len"])
     info, checks = {}, {}
+    walls = ctx.walls
     ref = ctx.reference()
 
     # ---- build + compile() (search included) + weights on the device
@@ -86,6 +87,7 @@ def run(ctx) -> dict:
     ff = compiled_model(ctx, batch, seq, search_log)
     jax.block_until_ready(ff.params)
     ff_compile_s = time.perf_counter() - t
+    walls.add("build_compile_init", ff_compile_s)
     info["mesh"] = dict(ff.mesh.shape)
     info["plan"] = ff.strategy.describe()
     search = search_result(search_log)
@@ -108,11 +110,13 @@ def run(ctx) -> dict:
     cx, cy, cx_tiled, cy_tiled = gen.check_batch(job, CHECK_SEED, batch,
                                                  ctx.config)
     data_s = time.perf_counter() - t
+    walls.add("data", data_s)
 
     # ---- first step, on the check batch: compiles the train step
     t = time.perf_counter()
     sys_loss = fit_with_losses(ctx, ff, cx_tiled, cy_tiled, batch)[0]
     first_step_s = time.perf_counter() - t
+    walls.add("first_step", first_step_s)
 
     # ---- compare with the plain reference (outside the window)
     t = time.perf_counter()
@@ -123,12 +127,14 @@ def run(ctx) -> dict:
     checks.update(verdicts)
     del params0
     check_s = time.perf_counter() - t
+    walls.add("reference_check", check_s)
 
     # ---- warm fit() over two batches: the dataloader path, no new program
     t = time.perf_counter()
     ff.fit(x[:2 * batch], y[:2 * batch], batch_size=batch, epochs=1,
            shuffle=False)
     warm_s = time.perf_counter() - t
+    walls.add("warm_fit", warm_s)
 
     # ---- the compiled step's text: the kernels that should run are in it
     # (after the warm fit this lowering finds the program already compiled:
@@ -151,6 +157,7 @@ def run(ctx) -> dict:
             "flash_attention_fwd" in kernels
             and any(k.startswith("flash_attention_bwd") for k in kernels))
     text_s = time.perf_counter() - t
+    walls.add("step_text", text_s)
 
     steps_per_pass = int(job["batches_per_epoch"])
     facts = {"info": info, "checks": checks, "check_stats": check_stats,
@@ -159,8 +166,9 @@ def run(ctx) -> dict:
         # the loss after 32 steps from the seed, read with telemetry on (it
         # syncs every step, so never inside a measured window)
         losses = []
-        while len(losses) < 32:
-            losses += fit_with_losses(ctx, ff, x, y, batch)
+        with walls.phase("loss_after_32_steps"):
+            while len(losses) < 32:
+                losses += fit_with_losses(ctx, ff, x, y, batch)
         facts["loss_after_32_steps"] = float(losses[31])
         from benchmark.reduce import xplane
 
@@ -170,7 +178,8 @@ def run(ctx) -> dict:
             or analytic_step_s(ff)
     del text
     if ctx.trace:
-        start_trace(ctx)
+        with walls.phase("start_trace"):
+            start_trace(ctx)
 
     # ---- the window
     seconds = min(ctx.seconds, float(cell.get("trace_seconds", 6.0))) \
@@ -191,8 +200,10 @@ def run(ctx) -> dict:
                 pass_loss.append(ff.get_perf_metrics().mean(loss_key))
     window_s = time.perf_counter() - t_open
     compiles_in_window = counter.n - n_compiles0
+    walls.add("window", window_s)
     if ctx.trace:
-        facts["trace_file"] = stop_trace(ctx)
+        with walls.phase("stop_trace"):
+            facts["trace_file"] = stop_trace(ctx)
 
     tokens_per_pass = steps_per_pass * batch * seq
     rates = [tokens_per_pass / p for p in passes]

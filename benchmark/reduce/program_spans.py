@@ -6,7 +6,9 @@ pipeline, and the serve tick — with ``flexflow_tpu.obs.span`` /
 ``step_span``: ``jax.profiler`` annotations whose names are the registry
 ``flexflow_tpu.obs.SPANS``. In a ``--trace 1`` run they sit on the host
 threads' lines of the ``.xplane.pb`` (``run['trace_file']``), on the clock
-of the device's ``XLA Ops``. This module reads them once per process:
+of the device's ``XLA Ops``. This module reads them once per process, from
+the trace as ``xplane.load`` keeps it (parsed once, shared with
+``xplane.reduce_trace``, the busy intervals too):
 
 * the **main thread** is the line that holds the benchmark's ``bench_window``
   span; its program spans, clipped to the window, are what idle time is
@@ -16,7 +18,7 @@ of the device's ``XLA Ops``. This module reads them once per process:
 * the device's idle gaps are recomputed as ``xplane._reduce_device`` does
   (union of the ``XLA Ops`` intervals inside the window, on the least busy
   chip), and each gap goes to the **leaf** span that covers most of it
-  (``xplane.attribute_gap``; of equal covers the innermost). A leaf is any
+  (``xplane.attribute_gaps``; of equal covers the innermost). A leaf is any
   program span but the ones that only enclose others (``ENCLOSING``). A gap
   counts as attributed when leaf spans cover more than half of it; otherwise
   it falls to the enclosing span over it, or to ``host_untraced``.
@@ -31,6 +33,7 @@ its result line.
 """
 from __future__ import annotations
 
+import bisect
 import statistics
 from collections import defaultdict
 from typing import Dict, List, Optional, Tuple
@@ -58,30 +61,23 @@ def registry():
     return SPANS
 
 
-def collect(data, names) -> Tuple[Optional[Tuple[float, float]], List[Span],
-                                  List[Span]]:
+def collect(trace: xplane.Trace, names
+            ) -> Tuple[Optional[Tuple[float, float]], List[Span], List[Span]]:
     """(window, main-thread spans, other threads' spans) of a loaded trace;
     the window is None where no ``bench_window`` span was recorded."""
     want = set(names)
     lines = []
     window, main = None, None
-    for plane in data.planes:
-        if not plane.name.startswith("/host:"):
-            continue
-        for line in plane.lines:
-            found: List[Span] = []
-            for e in line.events:
-                if e.name == WINDOW:
-                    s, t = float(e.start_ns), float(e.start_ns
-                                                    + e.duration_ns)
-                    window = (s, t) if window is None else (
-                        min(window[0], s), max(window[1], t))
-                    main = found
-                elif e.name in want:
-                    found.append((e.name, float(e.start_ns),
-                                  float(e.start_ns + e.duration_ns),
-                                  dict(e.stats)))
-            lines.append(found)
+    for line in trace.host_lines:
+        found: List[Span] = []
+        for name, s, t, event in line:
+            if name == WINDOW:
+                window = (s, t) if window is None else (
+                    min(window[0], s), max(window[1], t))
+                main = found
+            elif name in want:
+                found.append((name, s, t, dict(event.stats)))
+        lines.append(found)
     others = [sp for found in lines if found is not main for sp in found]
     return window, list(main or []), others
 
@@ -92,42 +88,59 @@ def clip_spans(spans: List[Span], window) -> List[Span]:
             if min(e, hi) > max(s, lo)]
 
 
-def device_idle(data, window) -> Tuple[Optional[int], List[xplane.Interval]]:
+def device_idle(trace: xplane.Trace, window
+                ) -> Tuple[Optional[int], List[xplane.Interval]]:
     """(chip, its idle gaps inside the window) for the least busy chip, as
     ``xplane.reduce_trace`` judges the idle share; (None, []) where no chip
     ran an op."""
     best = None
-    for plane in data.planes:
-        m = xplane.DEVICE_PLANE.match(plane.name)
-        if not m:
-            continue
-        ops = [ln for ln in plane.lines if ln.name == "XLA Ops"]
-        if not ops:
-            continue
-        busy = xplane.union(xplane.clip(
-            [(s, e) for _, s, e in xplane._events(ops[0])], window))
+    for chip in trace.chips:
+        busy, idle = trace.busy_idle(chip, window)
         if not busy:
             continue
         if best is None or xplane.total(busy) < best[1]:
-            best = (int(m.group(1)), xplane.total(busy), busy)
+            best = (chip, xplane.total(busy), idle)
     if best is None:
         return None, []
-    return best[0], xplane.gaps(best[2], window)
+    return best[0], best[2]
 
 
 def attribute(gaps, main: List[Span]) -> List[Tuple[float, str, bool]]:
     """Per gap: (its seconds, the span it goes to, whether leaf spans cover
     more than half of it)."""
+    return _attribute(gaps, main)[0]
+
+
+def _attribute(gaps, main: List[Span]):
+    """``attribute`` and the number of (gap, span) pairs it looked at: a
+    bisect into the leaves' disjoint cover for the share that has a name,
+    then one sweep of the named gaps over the leaves and one of the others
+    over the enclosing spans (``xplane._sweep``)."""
     leaves = [(n, s, e) for n, s, e, _ in main if n not in ENCLOSING]
     outer = [(n, s, e) for n, s, e, _ in main if n in ENCLOSING]
     leaf_cover = xplane.union((s, e) for _, s, e in leaves)
-    out = []
-    for g in gaps:
-        covered = xplane.total(xplane.clip(leaf_cover, g))
-        named = 2 * covered > g[1] - g[0]
-        name = xplane.attribute_gap(g, leaves if named else outer)
-        out.append(((g[1] - g[0]) * 1e-9, name, named))
-    return out
+    ends = [e for _, e in leaf_cover]
+    compared = 0
+    named = []
+    for lo, hi in gaps:
+        # the cover's intervals that reach into the gap, in their order:
+        # what ``clip(leaf_cover, gap)`` keeps of the whole list
+        k = bisect.bisect_right(ends, lo)
+        part = []
+        while k < len(leaf_cover) and leaf_cover[k][0] < hi:
+            part.append(leaf_cover[k])
+            k += 1
+        compared += len(part)
+        named.append(2 * xplane.total(xplane.clip(part, (lo, hi))) > hi - lo)
+    names: List[Optional[str]] = [None] * len(gaps)
+    for flag, spans in ((True, leaves), (False, outer)):
+        index = [i for i, f in enumerate(named) if f is flag]
+        got, n = xplane._sweep([gaps[i] for i in index], spans)
+        compared += n
+        for i, name in zip(index, got):
+            names[i] = name
+    return [((g[1] - g[0]) * 1e-9, name, flag)
+            for g, name, flag in zip(gaps, names, named)], compared
 
 
 def reduce_spans(window, main: List[Span], others: List[Span], gaps) -> dict:
@@ -196,11 +209,11 @@ def read(run: dict) -> Optional[dict]:
 
 
 def _reduce_file(path: str, names) -> Optional[dict]:
-    data = xplane.load(path)
-    window, main, others = collect(data, names)
+    trace = xplane.load(path)
+    window, main, others = collect(trace, names)
     if window is None or not main:
         return None
-    chip, gaps = device_idle(data, window)
+    chip, gaps = device_idle(trace, window)
     out = reduce_spans(window, main, others, gaps)
     out["chip"] = chip
     for line in describe(out):

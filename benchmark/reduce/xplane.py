@@ -99,20 +99,57 @@ def exposed(collectives: Iterable[Interval],
     return total(subtract(union(collectives), union(compute)))
 
 
+def attribute_gaps(gaps: Sequence[Interval],
+                   spans: Sequence[Tuple[str, float, float]]) -> List[str]:
+    """Per gap, the name of the host span that covers most of it; of spans
+    that cover it equally the shortest (the innermost), of those the first in
+    ``spans``. ``host_untraced`` where none touches it."""
+    return _sweep(gaps, spans)[0]
+
+
 def attribute_gap(gap: Interval, spans: Sequence[Tuple[str, float, float]]
                   ) -> str:
-    """Name of the host span that covers most of ``gap``; of spans that
-    cover it equally the shortest (the innermost). ``host_untraced`` where
-    none touches it."""
-    best, best_key = "host_untraced", (0.0, 0.0)
-    for name, s, e in spans:
-        cover = min(e, gap[1]) - max(s, gap[0])
-        if cover <= 0:
-            continue
-        key = (cover, -(e - s))
-        if key > best_key:
-            best, best_key = name, key
-    return best
+    """``attribute_gaps`` for one gap."""
+    return attribute_gaps([gap], spans)[0]
+
+
+def _sweep(gaps, spans) -> Tuple[List[str], int]:
+    """``attribute_gaps`` and the number of (gap, span) pairs it looked at.
+
+    One sweep over gaps and spans, both in order of their start: a gap's
+    candidates are the spans that began before it ends and had not ended
+    when it began. A span that ended before a gap began has ended before
+    every later one and is dropped, so where spans nest and gaps are short
+    (a host thread's annotations, a device's idle gaps) a gap meets the few
+    spans open around it and not the whole list: the cost follows gaps plus
+    spans, not their product (a traced run of a program with a faster step
+    holds more of both). The winner is the one a loop over all spans picks
+    (``tests/reduce_oracle.py`` keeps that loop), digit for digit: the cover
+    is the same difference of the same floats."""
+    by_start = sorted(range(len(spans)), key=lambda j: spans[j][1])
+    names = ["host_untraced"] * len(gaps)
+    active: List[int] = []
+    nxt = compared = 0
+    for i in sorted(range(len(gaps)), key=lambda i: gaps[i][0]):
+        lo, hi = gaps[i]
+        while nxt < len(by_start) and spans[by_start[nxt]][1] < hi:
+            active.append(by_start[nxt])
+            nxt += 1
+        best_key, best_j, still = (0.0, 0.0), -1, []
+        compared += len(active)
+        for j in active:
+            name, s, e = spans[j]
+            if e <= lo:
+                continue
+            still.append(j)
+            cover = min(e, hi) - max(s, lo)
+            if cover <= 0:
+                continue
+            key = (cover, -(e - s))
+            if key > best_key or (key == best_key and j < best_j):
+                names[i], best_key, best_j = name, key, j
+        active = still
+    return names, compared
 
 
 # ------------------------------------------------------------------- naming
@@ -174,29 +211,74 @@ def node_scope(op_name: str) -> Optional[str]:
 
 
 # ---------------------------------------------------------------- reduction
+def _event(e) -> Tuple[str, float, float]:
+    start = e.start_ns
+    return e.name, float(start), float(start + e.duration_ns)
+
+
 def _events(line) -> List[Tuple[str, float, float]]:
-    return [(e.name, float(e.start_ns), float(e.start_ns + e.duration_ns))
-            for e in line.events]
+    return [_event(e) for e in line.events]
 
 
-def load(path: str):
-    import jax
+class Trace:
+    """One ``.xplane.pb``, read once: every host thread's events and, per
+    chip that ran an op, its three lines as ``(name, start ns, end ns)``
+    lists. ``reduce_trace`` and ``program_spans`` both work on this, so the
+    file is parsed and its events are walked once per process, whatever
+    reads it."""
 
-    return jax.profiler.ProfileData.from_file(path)
+    def __init__(self, data):
+        self.data = data
+        # per host line: (name, start, end, the event) in the line's order
+        self.host_lines = [
+            [_event(e) + (e,) for e in line.events]
+            for plane in data.planes if plane.name.startswith("/host:")
+            for line in plane.lines]
+        self.chips: Dict[int, Dict[str, List[Tuple[str, float, float]]]] = {}
+        for plane in data.planes:
+            m = DEVICE_PLANE.match(plane.name)
+            if not m:
+                continue
+            lines = {ln.name: ln for ln in plane.lines}
+            if "XLA Ops" not in lines:
+                continue
+            self.chips[int(m.group(1))] = {
+                key: _events(lines[name]) if name in lines else []
+                for key, name in (("ops", "XLA Ops"),
+                                  ("async_ops", "Async XLA Ops"),
+                                  ("modules", "XLA Modules"))}
+        self._busy_idle: Dict[Tuple[int, Interval], Tuple[list, list]] = {}
+
+    def busy_idle(self, chip: int, window: Interval
+                  ) -> Tuple[List[Interval], List[Interval]]:
+        """The disjoint intervals inside ``window`` in which an op ran on
+        ``chip``, and the gaps between them (kept: both reductions ask for
+        the same window)."""
+        key = (chip, window)
+        if key not in self._busy_idle:
+            busy = union(clip([(s, e) for _, s, e in self.chips[chip]["ops"]],
+                              window))
+            self._busy_idle[key] = (busy, gaps(busy, window))
+        return self._busy_idle[key]
 
 
-def host_spans(data, names: Iterable[str]) -> List[Tuple[str, float, float]]:
+_TRACES: Dict[str, Trace] = {}
+
+
+def load(path: str) -> Trace:
+    """The trace at ``path``, parsed on the first call and kept."""
+    if path not in _TRACES:
+        import jax
+
+        _TRACES[path] = Trace(jax.profiler.ProfileData.from_file(path))
+    return _TRACES[path]
+
+
+def host_spans(trace: Trace, names: Iterable[str]
+               ) -> List[Tuple[str, float, float]]:
     want = set(names)
-    out = []
-    for plane in data.planes:
-        if not plane.name.startswith("/host:"):
-            continue
-        for line in plane.lines:
-            for e in line.events:
-                if e.name in want:
-                    out.append((e.name, float(e.start_ns),
-                                float(e.start_ns + e.duration_ns)))
-    return out
+    return [(n, s, e) for line in trace.host_lines for n, s, e, _ in line
+            if n in want]
 
 
 def reduce_trace(path: str, span_names: Iterable[str] = (),
@@ -210,9 +292,9 @@ def reduce_trace(path: str, span_names: Iterable[str] = (),
     runs from the first to the last device op. ``scopes`` is ``scope_map`` of
     the programs that ran, for the breakdown's grouping.
     """
-    data = load(path)
-    spans = host_spans(data, set(span_names) | ({window_span} if window_span
-                                                 else set()))
+    trace = load(path)
+    spans = host_spans(trace, set(span_names) | ({window_span} if window_span
+                                                  else set()))
     window = None
     if window_span:
         ws = [(s, e) for n, s, e in spans if n == window_span]
@@ -220,22 +302,14 @@ def reduce_trace(path: str, span_names: Iterable[str] = (),
             window = (min(s for s, _ in ws), max(e for _, e in ws))
     spans = [s for s in spans if s[0] != window_span]
     devices = {}
-    for plane in data.planes:
-        m = DEVICE_PLANE.match(plane.name)
-        if not m:
-            continue
-        lines = {ln.name: ln for ln in plane.lines}
-        if "XLA Ops" not in lines:
-            continue
-        ops = _events(lines["XLA Ops"])
+    for chip, lines in trace.chips.items():
+        ops = lines["ops"]
         if not ops:
             continue
-        async_ops = (_events(lines["Async XLA Ops"])
-                     if "Async XLA Ops" in lines else [])
-        modules = (_events(lines["XLA Modules"])
-                   if "XLA Modules" in lines else [])
-        devices[int(m.group(1))] = _reduce_device(
-            ops, async_ops, modules, window, spans, scopes or {}, top)
+        w = window or (min(s for _, s, _ in ops), max(e for _, _, e in ops))
+        devices[chip] = _reduce_device(
+            ops, lines["async_ops"], lines["modules"], w, spans,
+            scopes or {}, top, *trace.busy_idle(chip, w))
     if not devices:
         return {"devices": {}, "n_devices": 0}
     # the chip that was least busy is the one the idle share is judged by
@@ -250,14 +324,16 @@ def reduce_trace(path: str, span_names: Iterable[str] = (),
     return out
 
 
-def _reduce_device(ops, async_ops, modules, window, spans, scopes, top):
-    if window is None:
-        window = (min(s for _, s, _ in ops), max(e for _, _, e in ops))
+def _reduce_device(ops, async_ops, modules, window, spans, scopes, top, busy,
+                   idle):
     lo, hi = window
     by_class = {"kernel": defaultdict(float), "collective": defaultdict(float),
                 "xla": defaultdict(float)}
     groups = defaultdict(float)
-    intervals, coll_iv, other_iv = [], [], []
+    coll_iv, other_iv = [], []
+    # an op's text is read once: (kind, its class's group, the breakdown's
+    # group), and the text comes back in every step
+    named: Dict[str, Tuple[str, str, str]] = {}
     module_of = _module_lookup(modules)
     per_module = defaultdict(lambda: {"count": 0, "busy_s": 0.0,
                                       "kernel_s": defaultdict(float),
@@ -266,38 +342,41 @@ def _reduce_device(ops, async_ops, modules, window, spans, scopes, top):
         s, e = max(s, lo), min(e, hi)
         if e <= s:
             continue
-        kind, group = classify(text)
+        if text not in named:
+            kind, group = classify(text)
+            name = instruction(text)[0]
+            # the node's scope where the compiled text gave one, else the
+            # instruction's stem (``convert_reduce_fusion.3`` -> that fusion)
+            named[text] = (kind, group, group if kind != "xla" else
+                           scopes.get(name) or f"xla:{kernel_name(name)}")
+        kind, group, shown = named[text]
         dur = (e - s) * 1e-9
         by_class[kind][group] += dur
-        intervals.append((s, e))
         (coll_iv if kind == "collective" else other_iv).append((s, e))
         mod = per_module[module_of(s)]
         mod["busy_s"] += dur
+        groups[shown] += dur
         if kind == "kernel":
             mod["kernel_s"][group] += dur
-            groups[group] += dur
         elif kind == "collective":
             mod["collective_s"] += dur
-            groups[group] += dur
         else:
             mod["xla_s"] += dur
-            # the node's scope where the compiled text gave one, else the
-            # instruction's stem (``convert_reduce_fusion.3`` -> that fusion)
-            name = instruction(text)[0]
-            groups[scopes.get(name) or f"xla:{kernel_name(name)}"] += dur
+    collective: Dict[str, bool] = {}
     for text, s, e in async_ops:
-        kind, _ = classify(text)
-        if kind == "collective" and min(e, hi) > max(s, lo):
+        if text not in collective:
+            collective[text] = classify(text)[0] == "collective"
+        if collective[text] and min(e, hi) > max(s, lo):
             coll_iv.append((max(s, lo), min(e, hi)))
     for name, s, e in modules:
         if lo <= s < hi:
             per_module[_module_name(name)]["count"] += 1
-    busy = union(intervals)
-    idle = gaps(busy, window)
+    names = attribute_gaps(idle, spans)
     by_span = defaultdict(float)
-    for g in idle:
-        by_span[attribute_gap(g, spans)] += (g[1] - g[0]) * 1e-9
-    longest = sorted(idle, key=lambda g: g[0] - g[1])[:5]
+    for g, name in zip(idle, names):
+        by_span[name] += (g[1] - g[0]) * 1e-9
+    longest = sorted(range(len(idle)),
+                     key=lambda i: idle[i][0] - idle[i][1])[:5]
     return {
         "busy_s": total(busy) * 1e-9,
         "window_s": (hi - lo) * 1e-9,
@@ -315,8 +394,8 @@ def _reduce_device(ops, async_ops, modules, window, spans, scopes, top):
                              key=lambda kv: -kv[1])[:top],
         "idle_gaps": sorted(([k, v] for k, v in by_span.items()),
                             key=lambda kv: -kv[1])[:top],
-        "longest_gaps": [[attribute_gap(g, spans), (g[1] - g[0]) * 1e-9]
-                         for g in longest],
+        "longest_gaps": [[names[i], (idle[i][1] - idle[i][0]) * 1e-9]
+                         for i in longest],
     }
 
 
@@ -357,7 +436,7 @@ def _module_lookup(modules):
 def describe(path: str, max_names: int = 40) -> str:
     """A by-hand view of a trace: every plane and line, and per line the
     event names with count, total and first start, plus one event's stats."""
-    data = load(path)
+    data = load(path).data
     out = []
     for plane in data.planes:
         out.append(f"PLANE {plane.name!r} stats={dict(plane.stats)}")

@@ -15,7 +15,9 @@ The last line of standard output is the result: one JSON object with
 ``breakdown``. Everything else goes to earlier lines (``[bench] ...``; the
 checks' verdicts and every compared number beside its limit also to the last
 lines of standard error) and, where ``chiprun_out/`` exists, to
-``chiprun_out/bench/<cell>.seed<n>.trace<t>.json``.
+``chiprun_out/bench/<cell>.seed<n>.trace<t>.json``. Where the run's seconds
+went is on standard error: one ``[bench] wall: <phase> <s>`` line as each
+phase ends, and the table ``[bench] walls: {...}`` among the last lines.
 
 It measures on the platform the cell's file names (``tpu`` unless the file
 says otherwise — only the ``*-tiny`` rehearsal cells under ``tests/cells``
@@ -73,6 +75,9 @@ class Context:
         self.traffic = load_json(self._find("traffic",
                                             f"{self.cell['traffic']}.json"))
         self.t_start = T_START
+        from benchmark.drivers.common import Walls
+
+        self.walls = Walls(T_START)  # where the run's seconds go
         self.trace_dir = os.path.join(ROOT, ".bench_trace", args.workload)
         self.peaks = None
         self.devices = []
@@ -200,6 +205,8 @@ def main() -> None:
     sys.path.insert(0, ROOT)
     ctx = Context(args, os.path.abspath(args.cells))
     check_devices(ctx)
+    walls = ctx.walls
+    walls.add("imports_devices", ctx.since_start())
 
     driver = load_module(os.path.join(HERE, "drivers",
                                       f"{ctx.cell['kind']}.py"),
@@ -208,16 +215,24 @@ def main() -> None:
 
     if ctx.trace and facts.get("trace_file"):
         from benchmark import spans
-        from benchmark.reduce import xplane
+        from benchmark.reduce import program_spans, xplane
 
-        facts["trace"] = xplane.reduce_trace(
-            facts["trace_file"], span_names=spans.NAMES,
-            window_span=spans.WINDOW, scopes=facts.get("scopes"))
+        with walls.phase("load_trace"):  # once: both reductions share it
+            xplane.load(facts["trace_file"])
+        with walls.phase("xplane_reduce"):
+            facts["trace"] = xplane.reduce_trace(
+                facts["trace_file"], span_names=spans.NAMES,
+                window_span=spans.WINDOW, scopes=facts.get("scopes"))
+        with walls.phase("program_spans"):
+            program_spans.read(facts)  # kept per path: the readers find it
     device = device_block(ctx, facts)
     facts["memory_peak_bytes"] = device["memory_peak_bytes"]
-    metrics = (layer_metrics(ctx, facts) if ctx.trace
-               else {k: {"value": float(v[0]), "unit": v[1]}
-                     for k, v in facts["end_to_end"].items()})
+    if ctx.trace:
+        with walls.phase("layer_readers"):
+            metrics = layer_metrics(ctx, facts)
+    else:
+        metrics = {k: {"value": float(v[0]), "unit": v[1]}
+                   for k, v in facts["end_to_end"].items()}
     result = {"correct": bool(facts["correct"]),
               "attempted": int(facts["attempted"]),
               "failed": int(facts["failed"]),
@@ -233,7 +248,9 @@ def main() -> None:
                       for name, value, limit in facts.get("compared", [])]
     for line in verdict_lines:
         log(line)
-    for k, v in facts.get("info", {}).items():
+    facts.setdefault("info", {})["walls_s"] = walls.table()
+    facts["info"]["run_wall_s"] = facts["info"]["walls_s"]["run_wall_s"]
+    for k, v in facts["info"].items():
         log(f"{k}: {v}")
     side = os.path.join(ROOT, "chiprun_out")
     if os.path.isdir(side):
@@ -250,7 +267,8 @@ def main() -> None:
     # standard error, and of standard output the last line's keys alone
     # (the benchmark's contract; PERF.md, correct): so the verdicts and each
     # compared number beside its limit are standard error's last lines
-    print("\n".join(f"[bench] {line}" for line in verdict_lines),
+    print("\n".join(f"[bench] {line}"
+                    for line in [walls.line()] + verdict_lines),
           file=sys.stderr, flush=True)
 
 

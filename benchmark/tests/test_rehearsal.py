@@ -1,8 +1,11 @@
 """run.py end to end on the CPU at the rehearsal size (cells under
 tests/cells, platform "cpu" by their own files), one process per run as the
 driver makes them; and the refusal to measure a real cell without a TPU."""
+import functools
 import json
 import os
+import re
+import shutil
 import subprocess
 import sys
 
@@ -12,6 +15,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)
 CELLS = os.path.join("benchmark", "tests", "cells")
 
 
+@functools.lru_cache(maxsize=None)  # tests that read one run share it
 def run(workload, trace, cells=CELLS, devices=1, seconds="1.5", seed=3):
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
@@ -69,8 +73,6 @@ def test_four_device_cell_with_the_search():
 
 def test_an_empty_checked_set_stops_the_run(tmp_path):
     """A reference that names no parameter group does not pass quietly."""
-    import shutil
-
     cells = tmp_path / "cells"
     shutil.copytree(os.path.join(ROOT, CELLS), cells)
     ref = cells / "reference" / "bert-large-proxy.py"
@@ -105,3 +107,76 @@ def test_a_real_cell_refuses_the_cpu():
 def test_wrong_chip_count_is_refused():
     proc = run("bert-tiny-4dev", 0, devices=2)
     assert proc.returncode != 0 and "asks for 4 chip" in proc.stderr
+
+
+SERVING_READERS = {"batch_occupancy", "host_overhead_share", "queue_p90_ms",
+                   "tpot_p99_ms", "generator_lag_p99_ms",
+                   "window_tokens_per_s", "ttft_p90_ms", "prefill_tick_share",
+                   "decode_tick_ms_p50", "check_logit_gap_max", "compile_s"}
+
+
+def logged(proc, key):
+    return float(re.search(rf"^\[bench\] {key}: ([0-9.]+)$", proc.stdout,
+                           re.M).group(1))
+
+
+def test_trace_steps_close_the_traced_window(tmp_path):
+    """A cell whose file gives ``trace_steps`` traces that many decode steps
+    and no more (or ``trace_seconds``, whichever comes first), and still
+    reports every serving reader; without the key the window is the
+    ``trace_seconds`` it was."""
+    plain = run("gpt2-tiny-chat", 1, seconds="2")
+    assert SERVING_READERS <= set(result(plain)["metrics"])
+    assert logged(plain, "window_decode_steps") > 30
+    assert logged(plain, "measured_window_s") == pytest.approx(1.0, abs=0.1)
+
+    cells = tmp_path / "cells"
+    shutil.copytree(os.path.join(ROOT, CELLS), cells)
+    path = cells / "workloads" / "gpt2-tiny-chat.json"
+    cell = json.loads(path.read_text())
+    path.write_text(json.dumps(dict(cell, trace_steps=8)))
+    proc = run("gpt2-tiny-chat", 1, cells=str(cells), seconds="2")
+    assert SERVING_READERS <= set(result(proc)["metrics"])
+    assert 8 <= logged(proc, "window_decode_steps") <= 9
+    assert logged(proc, "measured_window_s") < 0.5 * cell["trace_seconds"]
+    # the untraced run does not read the key
+    untraced = run("gpt2-tiny-chat", 0, cells=str(cells), seconds="2")
+    assert logged(untraced, "measured_window_s") == pytest.approx(2.0, abs=0.1)
+    assert result(untraced)["metrics"].keys() == {"tpot_p50_ms", "setup_s"}
+
+
+SETUP = {"train": ["build_compile_init", "data", "first_step",
+                   "reference_check", "warm_fit", "step_text"],
+         "serve": ["build_compile_init", "warm_wave", "reference_check",
+                   "step_text", "pre_roll"]}
+AFTER = {"train": [], "serve": ["drain", "loop_finish"]}
+
+
+@pytest.mark.parametrize("workload,kind,seconds", [
+    ("bert-tiny", "train", "1.5"), ("gpt2-tiny-chat", "serve", "2")])
+@pytest.mark.parametrize("trace", [0, 1])
+def test_a_run_says_where_its_seconds_went(workload, kind, seconds, trace):
+    """One ``[bench] wall:`` line on standard error as each phase ends, in
+    the run's order, and the table among standard error's last lines."""
+    proc = run(workload, trace, seconds=seconds)
+    result(proc)
+    err = proc.stderr.splitlines()
+    phases = [m.group(1) for line in err for m in [
+        re.match(r"\[bench\] wall: (\w+) [0-9.]+ \(since start [0-9.]+\)$",
+                 line)] if m]
+    want = ["imports_devices"] + SETUP[kind] + ["window"] + AFTER[kind]
+    if trace:
+        want += ["start_trace", "stop_trace", "load_trace", "xplane_reduce",
+                 "program_spans", "layer_readers"]
+        want += ["loss_after_32_steps"] if kind == "train" else [
+            "request_records"]
+    assert sorted(set(phases)) == sorted(want)
+    order = [p for p in phases if p in SETUP[kind] + ["window"] + AFTER[kind]]
+    assert order == [p for p in want if p in order]
+    table = [line for line in err[-20:] if line.startswith("[bench] walls: ")]
+    assert len(table) == 1
+    walls = json.loads(table[0][len("[bench] walls: "):])
+    assert set(walls) == set(want) | {"run_wall_s"}
+    assert walls["run_wall_s"] >= walls["window"] > 0
+    # beside the compared numbers, which stay standard error's last lines too
+    assert any(line.startswith("[bench] compared ") for line in err[-20:])
