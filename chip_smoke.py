@@ -167,40 +167,99 @@ def check_flash_decode() -> None:
     import numpy as np
 
     from flexflow_tpu.kernels.flash_decode import (_reference_decode,
-                                                   flash_decode)
-    from flexflow_tpu.serving.kvcache import quantize_kv
+                                                   flash_decode_pool)
+    from flexflow_tpu.serving.kvcache import new_kv_pool, scatter_prefill_kv
 
-    slots, heads, hd, bs, mb = 8, 12, 64, 16, 16
-    n_blocks = slots * mb + 1
+    slots, heads, hd, extent = 8, 12, 64, 256
     kq, kk, kv = jax.random.split(jax.random.PRNGKey(1), 3)
     q = jax.random.normal(kq, (slots, heads, hd), jnp.bfloat16)
-    kpool = jax.random.normal(kk, (n_blocks, heads, bs, hd), jnp.bfloat16)
-    vpool = jax.random.normal(kv, (n_blocks, heads, bs, hd), jnp.bfloat16)
-    rng = np.random.default_rng(1)
-    # every slot its own shuffled run of blocks; lengths from 1 key to full
-    tables = jnp.asarray(
-        1 + rng.permutation(slots * mb).reshape(slots, mb), jnp.int32)
+    # lengths from 1 key to a slot's full extent
     n_keys = jnp.asarray([1, 15, 16, 17, 100, 200, 255, 256], jnp.int32)
     scale = 1.0 / np.sqrt(hd)
     ref = _reference_decode()
-    pools = {"native": (kpool, vpool, None, None)}
-    k8, ks = quantize_kv(kpool)
-    v8, vs = quantize_kv(vpool)
-    pools["int8"] = (k8, v8, ks, vs)
-    for label, (kp, vp, ksc, vsc) in pools.items():
-        fn = jax.jit(lambda q, kp, vp, t, n, ksc=ksc, vsc=vsc: flash_decode(
-            q, kp, vp, t, n, sm_scale=scale, kscale=ksc, vscale=vsc))
-        text = fn.lower(q, kp, vp, tables, n_keys).compile().as_text()
+    # the reader's gate asks for whole lanes and 8-row sublanes, not for
+    # the dtype's whole tile: an int8 pool at the default block 16 and a
+    # bf16 pool at block 8 read through the kernel too
+    for label, bs in (("native", 16), ("int8", 16), ("native", 8),
+                      ("int8", 32)):
+        mb = extent // bs
+        n_blocks = slots * mb + 1
+        # the pool as the slot writer builds it: one contiguous K and V
+        # scattered over every block, K | V side by side on 128 lanes
+        flat = tuple(jax.random.normal(k, (1, heads, n_blocks * bs, hd),
+                                       jnp.bfloat16) for k in (kk, kv))
+        every_block = jnp.arange(n_blocks, dtype=jnp.int32)
+        # every slot its own shuffled run of blocks
+        tables = jnp.asarray(1 + np.random.default_rng(1).permutation(
+            slots * mb).reshape(slots, mb), jnp.int32)
+        entry = scatter_prefill_kv(new_kv_pool(flat, n_blocks, bs, label),
+                                   flat, every_block, bs)
+        pool, scales = entry if label == "int8" else (entry, None)
+        fn = jax.jit(lambda q, p, t, n, sc=scales: flash_decode_pool(
+            q, p, t, n, sm_scale=scale, scales=sc))
+        text = fn.lower(q, pool, tables, n_keys).compile().as_text()
         check("flash_decode" in mosaic_calls(text),
-              f"flash_decode ({label} pool, {heads} heads x {hd}, block "
-              f"{bs}) compiles to a Mosaic kernel")
-        out = fn(q, kp, vp, tables, n_keys)
+              f"flash_decode ({label} pool, {heads} heads x {2 * hd} "
+              f"lanes, block {bs}) compiles to a Mosaic kernel")
+        out = fn(q, pool, tables, n_keys)
         with jax.default_matmul_precision("highest"):
-            want = ref(q, kp, vp, tables, n_keys, scale, ksc, vsc)
+            want = ref(q, pool, tables, n_keys, scale, scales)
         e = rel_err(out, want)
         check(e <= FLASH_DECODE_TOL,
-              f"flash_decode ({label} pool) vs masked-gather reference: "
-              f"{e:.2e} <= {FLASH_DECODE_TOL:.2e}")
+              f"flash_decode ({label} pool, block {bs}) vs masked-gather "
+              f"reference: {e:.2e} <= {FLASH_DECODE_TOL:.2e}")
+
+
+def check_kv_write() -> None:
+    """The pool's in-place write against the scatter it replaces, on the
+    chip — where alone the aliased call's hazard exists (it fetches the
+    next grid step's block before this one's is written back): a chunk
+    whose rows fill three blocks, one with pad rows, and the decode
+    step's one row a slot with free slots meeting in the garbage block.
+    A move: the stored values are compared bit for bit."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from flexflow_tpu.serving.kvcache import GARBAGE_BLOCK, write_kv_rows
+
+    heads, bs, lanes, n_blocks = 12, 16, 128, 40
+    kp, kr = jax.random.split(jax.random.PRNGKey(2))
+    pool = jax.random.normal(kp, (n_blocks, heads, bs, lanes), jnp.bfloat16)
+    table = np.asarray([7, 3, 31, 12, 25, 9], np.int32)
+
+    def chunk(chunk_len, start, n_new):
+        pos = start + np.arange(chunk_len)
+        bi = np.where(np.arange(chunk_len) < n_new,
+                      table[np.clip(pos // bs, 0, len(table) - 1)],
+                      GARBAGE_BLOCK)
+        return bi, pos % bs, True
+
+    cases = {
+        "token, free slots in the garbage block": (
+            np.asarray([5, 6, 8, 0, 0, 0, 11, 0]),
+            np.asarray([0, bs - 1, 3, 0, 0, 0, bs - 1, 0]), False),
+        "chunk over three blocks": chunk(2 * bs, bs - 5, 2 * bs),
+        "chunk with pad rows": chunk(4 * bs, 2 * bs, 2 * bs + 3),
+        "chunk inside one block": chunk(bs // 2, 3, bs // 2),
+    }
+    live = np.arange(n_blocks) != GARBAGE_BLOCK
+    for label, (bi, off, consecutive) in cases.items():
+        rows = jax.random.normal(kr, (len(bi), heads, lanes), jnp.bfloat16)
+        bi, off = jnp.asarray(bi, jnp.int32), jnp.asarray(off, jnp.int32)
+        fn = jax.jit(lambda p, r, b, o, c=consecutive: write_kv_rows(
+            p, r, b, o, consecutive=c), donate_argnums=(0,))
+        text = fn.lower(pool, rows, bi, off).compile().as_text()
+        check("kv_write" in mosaic_calls(text),
+              f"kv_write ({label}) compiles to a Mosaic kernel")
+        want = np.asarray(pool.at[bi, :, off].set(rows))
+        got = np.asarray(fn(jnp.copy(pool), rows, bi, off))
+        check(np.array_equal(got[live].view(np.uint16),
+                             want[live].view(np.uint16)),
+              f"kv_write ({label}) equals the scatter bit for bit outside "
+              "the garbage block")
+        check(bool(np.isfinite(got[GARBAGE_BLOCK].astype(np.float32)).all()),
+              f"kv_write ({label}) leaves the garbage block finite")
 
 
 # ------------------------------------------------------------------ trainer
@@ -394,6 +453,7 @@ def main() -> None:
 
     check_flash_attention()
     check_flash_decode()
+    check_kv_write()
 
     n_chips = device["count"]
     bert = BertConfig(batch_size=8 * n_chips, seq_len=512, hidden=1024,
@@ -415,8 +475,8 @@ def main() -> None:
                       num_layers=12, intermediate=3072, vocab_size=50257)
     _streams, eng, serve_cold_s = serve_phase(gpt2, MAX_NEW_TOKENS)
     calls = mosaic_calls(decode_step_text(eng))
-    check("flash_decode" in calls,
-          f"decode step runs the flash_decode Mosaic kernel "
+    check({"flash_decode", "kv_write"} <= calls,
+          f"decode step runs the flash_decode and kv_write Mosaic kernels "
           f"({sorted(calls)})")
 
     info(f"compile seconds: trainer first step {train_compile_s:.1f}, "

@@ -1,5 +1,5 @@
-"""Shared routing/tiling helpers for the row-wise Pallas kernels
-(softmax, top-k)."""
+"""Shared routing/tiling helpers for the Pallas kernels (softmax, top-k,
+and the paged KV pool's reader and writer)."""
 from __future__ import annotations
 
 from typing import Optional

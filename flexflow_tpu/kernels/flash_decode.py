@@ -1,10 +1,9 @@
 """Pallas split-K flash-decode kernel: single-token paged attention.
 
 The serving decode step advances every slot one token; its attention read
-is the decode hot loop's HBM bill. The ring layout paid O(max_len) per
-slot per token (position-masked attention over the full ring); with the
-paged layout (serving/kvcache.py) this kernel gathers ONLY the blocks a
-slot actually occupies, so per-token traffic is O(true_length):
+is the decode hot loop's HBM bill. Over the paged pool
+(serving/kvcache.py) this kernel reads ONLY the blocks a slot actually
+occupies, so per-token traffic is O(true_length):
 
 * grid = (n_slots, max_blocks_per_slot): the KV-block axis is the
   **split-K** dimension — each grid step folds one (heads, block_size)
@@ -17,8 +16,14 @@ slot actually occupies, so per-token traffic is O(true_length):
   last occupied block: Pallas skips the DMA when the resolved index is
   unchanged, so dead steps move no HBM bytes, and the body masks them
   out by global key position anyway (the loaded data is never used).
-* int8 KV (``kscale``/``vscale``): blocks are dequantized in-VMEM from
-  the block-paged per-(token, head) scales — HBM moves ~1/el of the fp
+* ONE block a step: a pool row holds a head's K and V side by side on
+  the lanes (kvcache.py), so the kernel never slices lanes. The query
+  rides zero-padded over V's lanes — ``0 * finite`` is exactly 0, and
+  every stored value is finite (the garbage block's invariant) — so
+  ``q . row`` is the K score; ``p . block`` is accumulated at full
+  width and V's lanes are taken once, from the output.
+* int8 KV (``scales``): blocks are dequantized in-VMEM from the
+  block-paged per-(token, head) scales — HBM moves ~1/el of the fp
   bytes plus the f32 scale vectors (the bandwidth the serving search's
   ``kv_dtype`` axis prices).
 
@@ -26,7 +31,7 @@ Off-TPU the op layer never routes here (the masked gather path keeps
 tier-1 CPU-green); tests run the kernel in interpret mode.
 
 Mosaic wants a free (row) dimension on the left operand of a matmul, so
-the one-token query rides as a unit row: q is ``(heads, 1, head_dim)`` and
+the one-token query rides as a unit row: q is ``(heads, 1, lanes)`` and
 the two products are ordinary head-batched matmuls ``hqd,hkd->hqk`` and
 ``hqk,hkd->hqd`` — the (m, l, acc) state and the output block carry the
 same unit row.
@@ -39,26 +44,31 @@ from typing import Optional
 NEG_INF = -1e30
 
 
-def use_flash_decode(head_dim: int, block_size: int) -> bool:
-    """Routing gate for the serving attention op: real-TPU platform and
-    MXU/VPU-friendly dims (lane-padded head_dim, whole-sublane blocks).
-    The CPU path (gather + masked einsum) is the correctness path —
-    this kernel is the bandwidth path."""
+def use_flash_decode(lanes: int, block_size: int) -> bool:
+    """Routing gate for the serving attention op, from what it can see
+    of the pool: a TPU, ``kd + vd`` a multiple of 128 lanes and
+    ``block_size`` of 8 sublanes — what the READER needs, whatever the
+    pool's dtype (an int8 pool at block 16 and a bf16 pool at block 8
+    read through the kernel, and are written by scatter: ``kv_write``
+    asks for whole tiles, kernels/kv_write.py). The CPU path (gather +
+    masked einsum) is the correctness path — this kernel is the
+    bandwidth path."""
     from ._common import on_tpu
 
-    if block_size < 8 or block_size % 8 != 0 or head_dim % 64 != 0:
-        return False
-    return on_tpu()
+    return lanes % 128 == 0 and block_size % 8 == 0 and on_tpu()
 
 
-def _decode_kernel(tab_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
-                   m_ref, l_ref, acc_ref, *, block_size, n_blocks_grid,
-                   kv_dtype, ks_ref=None, vs_ref=None):
+def _decode_kernel(tab_ref, len_ref, q_ref, kv_ref, *rest, block_size,
+                   n_blocks_grid, kd, int8):
     """One (slot, kv-block) grid step of the split-K recurrence."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
 
+    if int8:
+        sc_ref, o_ref, m_ref, l_ref, acc_ref = rest
+    else:
+        o_ref, m_ref, l_ref, acc_ref = rest
     s = pl.program_id(0)
     j = pl.program_id(1)
 
@@ -72,17 +82,15 @@ def _decode_kernel(tab_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
 
     @pl.when(j * block_size < n_keys)
     def _step():
-        q = q_ref[0].astype(jnp.float32)          # (h, 1, hd), pre-scaled
-        k = k_ref[0]                              # (h, bs, kd)
-        v = v_ref[0]                              # (h, bs, vd)
-        if kv_dtype == "int8":
-            k = k.astype(jnp.float32) * ks_ref[0][..., None]
-            v = v.astype(jnp.float32) * vs_ref[0][..., None]
-        else:
-            k = k.astype(jnp.float32)
-            v = v.astype(jnp.float32)
+        # (h, 1, lanes): pre-scaled, zero over V's lanes
+        q = q_ref[0].astype(jnp.float32)
+        kv = kv_ref[0].astype(jnp.float32)        # (h, bs, lanes): K | V
+        if int8:
+            lane = jax.lax.broadcasted_iota(jnp.int32, kv.shape, 2)
+            kv = kv * jnp.where(lane < kd, sc_ref[0, 0][..., None],
+                                sc_ref[0, 1][..., None])
         # (h, 1, bs) score tile: per-head q row against the block's keys
-        s_tile = jnp.einsum("hqd,hkd->hqk", q, k,
+        s_tile = jnp.einsum("hqd,hkd->hqk", q, kv,
                             preferred_element_type=jnp.float32)
         kpos = j * block_size + jax.lax.broadcasted_iota(
             jnp.int32, s_tile.shape, 2)
@@ -92,8 +100,9 @@ def _decode_kernel(tab_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
                                             keepdims=True))
         p = jnp.exp(s_tile - m_new)               # (h, 1, bs)
         corr = jnp.exp(m_prev - m_new)            # (h, 1, 1)
-        pv = jnp.einsum("hqk,hkd->hqd", p, v,
-                        preferred_element_type=jnp.float32)  # (h, 1, vd)
+        # (h, 1, lanes): V's lanes are the output, K's are never read
+        pv = jnp.einsum("hqk,hkd->hqd", p, kv,
+                        preferred_element_type=jnp.float32)
         acc_ref[:] = acc_ref[:] * corr + pv
         l_new = l_ref[:, :, :1] * corr + jnp.sum(p, axis=-1, keepdims=True)
         m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
@@ -104,15 +113,16 @@ def _decode_kernel(tab_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
         o_ref[0] = (acc_ref[:] / l_ref[:, :, :1]).astype(o_ref.dtype)
 
 
-def flash_decode(q, kpool, vpool, block_tables, n_keys, *,
-                 sm_scale: Optional[float] = None, kscale=None,
-                 vscale=None, interpret: bool = False):
-    """Single-token paged attention over a KV block pool.
+def flash_decode_pool(q, pool, block_tables, n_keys, *,
+                      sm_scale: Optional[float] = None, scales=None,
+                      interpret: bool = False):
+    """Single-token paged attention over one KV block pool.
 
-    q            (n_slots, heads, head_dim) — this step's query rows
-    kpool/vpool  (n_blocks, heads, block_size, kd|vd) — model dtype, or
-                 int8 with ``kscale``/``vscale`` (n_blocks, heads,
-                 block_size) f32 per-(token, head) scales
+    q            (n_slots, heads, kd) — this step's query rows
+    pool         (n_blocks, heads, block_size, kd + vd) — a row is one
+                 head's K then V (serving/kvcache.py); model dtype, or
+                 int8 with ``scales`` (n_blocks, 2, heads, block_size)
+                 f32, K's per-(token, head) scales then V's
     block_tables (n_slots, max_blocks_per_slot) int32
     n_keys       (n_slots,) int32 — keys each slot attends (position + 1)
 
@@ -126,16 +136,16 @@ def flash_decode(q, kpool, vpool, block_tables, n_keys, *,
 
     from ._common import resolve_interpret
 
-    n_slots, heads, head_dim = q.shape
-    n_blocks, _h, block_size, kd = kpool.shape
-    vd = vpool.shape[-1]
+    n_slots, heads, kd = q.shape
+    _n_blocks, _h, block_size, lanes = pool.shape
     mb = block_tables.shape[1]
-    kv_dtype = "int8" if kpool.dtype == jnp.int8 else "native"
-    if kv_dtype == "int8" and (kscale is None or vscale is None):
-        raise ValueError("flash_decode: int8 pools need kscale/vscale")
-    scale = sm_scale if sm_scale is not None else 1.0 / np.sqrt(head_dim)
+    int8 = pool.dtype == jnp.int8
+    if int8 and scales is None:
+        raise ValueError("flash_decode: an int8 pool needs its scales")
+    scale = sm_scale if sm_scale is not None else 1.0 / np.sqrt(kd)
     out_dtype = q.dtype
-    q = (q.astype(jnp.float32) * jnp.float32(scale))[:, :, None, :]
+    q = q.astype(jnp.float32) * jnp.float32(scale)
+    q = jnp.pad(q, ((0, 0), (0, 0), (0, lanes - kd)))[:, :, None, :]
     tables = block_tables.astype(jnp.int32)
     n_keys = n_keys.astype(jnp.int32)
 
@@ -147,84 +157,75 @@ def flash_decode(q, kpool, vpool, block_tables, n_keys, *,
         jj = jnp.minimum(j, jnp.maximum(used - 1, 0))
         return (tab_ref[s, jj], 0, 0, 0)
 
-    def scale_index(s, j, tab_ref, len_ref):
-        return block_index(s, j, tab_ref, len_ref)[:3]
+    def slot_row(s, j, tab_ref, len_ref):
+        return (s, 0, 0, 0)
 
-    in_specs = [
-        pl.BlockSpec((1, heads, 1, head_dim),
-                     lambda s, j, t, n: (s, 0, 0, 0)),
-        pl.BlockSpec((1, heads, block_size, kd), block_index),
-        pl.BlockSpec((1, heads, block_size, vd), block_index),
-    ]
-    args = [q, kpool, vpool]
-    ks_vs = None
-    if kv_dtype == "int8":
-        in_specs += [pl.BlockSpec((1, heads, block_size), scale_index),
-                     pl.BlockSpec((1, heads, block_size), scale_index)]
-        args += [kscale, vscale]
-        ks_vs = True
-
-    def kernel(tab_ref, len_ref, q_ref, k_ref, v_ref, *rest):
-        if ks_vs:
-            ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref = rest
-        else:
-            (o_ref, m_ref, l_ref, acc_ref) = rest
-            ks_ref = vs_ref = None
-        _decode_kernel(tab_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
-                       m_ref, l_ref, acc_ref, block_size=block_size,
-                       n_blocks_grid=mb, kv_dtype=kv_dtype,
-                       ks_ref=ks_ref, vs_ref=vs_ref)
-
+    in_specs = [pl.BlockSpec((1, heads, 1, lanes), slot_row),
+                pl.BlockSpec((1, heads, block_size, lanes), block_index)]
+    args = [q, pool]
+    if int8:
+        in_specs.append(
+            pl.BlockSpec((1, 2, heads, block_size), block_index))
+        args.append(scales)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(n_slots, mb),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, heads, 1, vd),
-                               lambda s, j, t, n: (s, 0, 0, 0)),
+        out_specs=pl.BlockSpec((1, heads, 1, lanes), slot_row),
         scratch_shapes=[
-            pltpu.VMEM((heads, 1, 128), jnp.float32),  # m
-            pltpu.VMEM((heads, 1, 128), jnp.float32),  # l
-            pltpu.VMEM((heads, 1, vd), jnp.float32),   # acc
+            pltpu.VMEM((heads, 1, 128), jnp.float32),    # m
+            pltpu.VMEM((heads, 1, 128), jnp.float32),    # l
+            pltpu.VMEM((heads, 1, lanes), jnp.float32),  # acc
         ],
     )
     fn = pl.pallas_call(
-        kernel,
+        functools.partial(_decode_kernel, block_size=block_size,
+                          n_blocks_grid=mb, kd=kd, int8=int8),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n_slots, heads, 1, vd), out_dtype),
+        out_shape=jax.ShapeDtypeStruct((n_slots, heads, 1, lanes),
+                                       out_dtype),
         interpret=resolve_interpret(interpret),
         name="flash_decode",
     )
-    return fn(tables, n_keys, *args)[:, :, 0, :]
+    return fn(tables, n_keys, *args)[:, :, 0, kd:]
+
+
+def flash_decode(q, kpool, vpool, block_tables, n_keys, *,
+                 sm_scale: Optional[float] = None, kscale=None,
+                 vscale=None, interpret: bool = False):
+    """:func:`flash_decode_pool` for a caller that holds K and V apart
+    (``(n_blocks, heads, block_size, kd | vd)``, int8 with ``kscale`` /
+    ``vscale`` ``(n_blocks, heads, block_size)``): packs them into the
+    one pool, a copy of both. The serving path stores the pool packed
+    and never comes through here; the benchmark's ahead-of-time check
+    of the kernel at the cell's widths does."""
+    import jax.numpy as jnp
+
+    scales = None
+    if kscale is not None and vscale is not None:
+        scales = jnp.stack([kscale, vscale], axis=1)
+    return flash_decode_pool(
+        q, jnp.concatenate([kpool, vpool], axis=-1), block_tables,
+        n_keys, sm_scale=sm_scale, scales=scales, interpret=interpret)
 
 
 @functools.lru_cache(maxsize=1)
 def _reference_decode():
     """Masked-gather reference (the op layer's CPU path restated) for the
     kernel parity tests."""
+    import jax
     import jax.numpy as jnp
 
-    from ..serving.kvcache import (dequantize_kv, gather_paged_kv,
-                                   gather_paged_scales)
+    from ..serving.kvcache import read_kv
 
-    def ref(q, kpool, vpool, tables, n_keys, sm_scale,
-            kscale=None, vscale=None):
-        if kscale is not None:
-            kc = dequantize_kv(gather_paged_kv(kpool, tables),
-                               gather_paged_scales(kscale, tables),
-                               jnp.float32)
-            vc = dequantize_kv(gather_paged_kv(vpool, tables),
-                               gather_paged_scales(vscale, tables),
-                               jnp.float32)
-        else:
-            kc = gather_paged_kv(kpool, tables).astype(jnp.float32)
-            vc = gather_paged_kv(vpool, tables).astype(jnp.float32)
+    def ref(q, pool, tables, n_keys, sm_scale, scales=None):
+        entry = pool if scales is None else (pool, scales)
+        kc, vc = read_kv(entry, tables, q.shape[-1], jnp.float32)
         logits = jnp.einsum("bhd,bhkd->bhk", q.astype(jnp.float32), kc,
                             preferred_element_type=jnp.float32) * sm_scale
         kpos = jnp.arange(kc.shape[2])
         logits = jnp.where(kpos[None, None, :] < n_keys[:, None, None],
                            logits, NEG_INF)
-        import jax
-
         probs = jax.nn.softmax(logits, axis=-1)
         return jnp.einsum("bhk,bhkd->bhd", probs, vc,
                           preferred_element_type=jnp.float32

@@ -180,11 +180,12 @@ def _serving_attention(name: str, q, k, v, sv, *, causal: bool):
     * prefill: q/k/v carry the whole padded prompt; the causal core runs
       unchanged and k/v land at position 0 of one request's contiguous
       ``max_len`` buffer, which the engine's slot writer scatters into
-      the pool (``scatter_prefill_paged``).
+      the pool (``scatter_prefill_kv``).
     * decode: q/k/v carry ONE token per slot; k/v are written into the
-      block pool at (table[pos // bs], pos % bs) — a scatter with static
-      shapes, no recompile — and q attends under the mask
-      ``key_pos <= position``. The read is either the Pallas flash-decode
+      block pool at (table[pos // bs], pos % bs) — static shapes, no
+      recompile, in place on the chip (``kvcache.write_kv_rows``) — and
+      q attends under the mask ``key_pos <= position``. The read is
+      either the Pallas flash-decode
       kernel (TPU fast path — O(true length) HBM traffic,
       kernels/flash_decode.py) or a pure gather back to position order
       followed by the masked einsums below — gathered rows are bitwise
@@ -194,12 +195,9 @@ def _serving_attention(name: str, q, k, v, sv, *, causal: bool):
     """
     import jax
     import jax.numpy as jnp
-    from jax import lax
 
-    from ..serving.kvcache import (dequantize_kv, gather_paged_kv,
-                                   gather_paged_scales, quantize_kv,
-                                   write_token_kv_paged,
-                                   write_token_scale_paged)
+    from ..serving.kvcache import (prefill_kv_entry, read_kv,
+                                   write_token_kv)
 
     if not causal:
         raise ValueError(
@@ -209,47 +207,18 @@ def _serving_attention(name: str, q, k, v, sv, *, causal: bool):
     if sv.mode == "chunk":
         return _chunk_prefill_attention(name, q, k, v, sv)
     if sv.mode == "prefill":
-        b, h, L, hd = k.shape
-        kbuf = lax.dynamic_update_slice(
-            jnp.zeros((b, h, sv.max_len, hd), k.dtype), k, (0, 0, 0, 0))
-        vbuf = lax.dynamic_update_slice(
-            jnp.zeros((b, h, sv.max_len, v.shape[-1]), v.dtype), v,
-            (0, 0, 0, 0))
-        sv.cache_out[name] = (kbuf, vbuf)
+        sv.cache_out[name] = prefill_kv_entry(k, v, sv.max_len)
         return mha_core(q, k, v, causal=True)
     scale = 1.0 / np.sqrt(q.shape[-1])
-    tables, bs = sv.block_tables, sv.block_size
-    if sv.kv_dtype == "int8":
-        kq, ks, vq, vs = sv.cache_in[name]
-        with jax.named_scope("kv_update"):
-            k_new, ks_new = quantize_kv(k)  # (S,h,1,hd), scale (S,h,1)
-            v_new, vs_new = quantize_kv(v)
-            kq = write_token_kv_paged(kq, k_new, sv.positions, tables, bs)
-            ks = write_token_scale_paged(ks, ks_new, sv.positions,
-                                         tables, bs)
-            vq = write_token_kv_paged(vq, v_new, sv.positions, tables, bs)
-            vs = write_token_scale_paged(vs, vs_new, sv.positions,
-                                         tables, bs)
-        sv.cache_out[name] = (kq, ks, vq, vs)
-        kernel_out = _maybe_flash_decode(
-            q, (kq, ks, vq, vs), tables, sv, scale)
-        if kernel_out is not None:
-            return kernel_out
-        kc = dequantize_kv(gather_paged_kv(kq, tables),
-                           gather_paged_scales(ks, tables), k.dtype)
-        vc = dequantize_kv(gather_paged_kv(vq, tables),
-                           gather_paged_scales(vs, tables), v.dtype)
-    else:
-        kp, vp = sv.cache_in[name]
-        with jax.named_scope("kv_update"):
-            kp = write_token_kv_paged(kp, k, sv.positions, tables, bs)
-            vp = write_token_kv_paged(vp, v, sv.positions, tables, bs)
-        sv.cache_out[name] = (kp, vp)
-        kernel_out = _maybe_flash_decode(q, (kp, vp), tables, sv, scale)
-        if kernel_out is not None:
-            return kernel_out
-        kc = gather_paged_kv(kp, tables)
-        vc = gather_paged_kv(vp, tables)
+    tables = sv.block_tables
+    with jax.named_scope("kv_update"):
+        entry = write_token_kv(sv.cache_in[name], k, v, sv.positions,
+                               tables, sv.block_size)
+    sv.cache_out[name] = entry
+    kernel_out = _maybe_flash_decode(q, entry, tables, sv, scale)
+    if kernel_out is not None:
+        return kernel_out
+    kc, vc = read_kv(entry, tables, q.shape[-1], k.dtype)
     extent = kc.shape[2]  # blocks_per_slot * block_size
     if sv.seq_shards > 1:
         return _seqpar_decode(q, kc, vc, sv, scale, extent)
@@ -328,38 +297,18 @@ def _chunk_prefill_attention(name: str, q, k, v, sv):
     import jax
     import jax.numpy as jnp
 
-    from ..serving.kvcache import (dequantize_kv, gather_paged_kv,
-                                   gather_paged_scales, quantize_kv,
-                                   write_chunk_kv_paged,
-                                   write_chunk_scale_paged)
+    from ..serving.kvcache import read_kv, write_chunk_kv
 
-    tables, bs = sv.block_tables, sv.block_size  # tables: (1, mb)
-    row = tables[0]
+    tables = sv.block_tables  # (1, mb)
     start = sv.positions[0]
     n_new = sv.lengths[0]
     chunk_len = q.shape[2]
     pos = start + jnp.arange(chunk_len, dtype=jnp.int32)
     valid = jnp.arange(chunk_len) < n_new
-    if sv.kv_dtype == "int8":
-        kq, ks, vq, vs = sv.cache_in[name]
-        k_new, ks_new = quantize_kv(k)
-        v_new, vs_new = quantize_kv(v)
-        kq = write_chunk_kv_paged(kq, k_new, pos, valid, row, bs)
-        ks = write_chunk_scale_paged(ks, ks_new, pos, valid, row, bs)
-        vq = write_chunk_kv_paged(vq, v_new, pos, valid, row, bs)
-        vs = write_chunk_scale_paged(vs, vs_new, pos, valid, row, bs)
-        sv.cache_out[name] = (kq, ks, vq, vs)
-        kc = dequantize_kv(gather_paged_kv(kq, tables),
-                           gather_paged_scales(ks, tables), k.dtype)
-        vc = dequantize_kv(gather_paged_kv(vq, tables),
-                           gather_paged_scales(vs, tables), v.dtype)
-    else:
-        kp, vp = sv.cache_in[name]
-        kp = write_chunk_kv_paged(kp, k, pos, valid, row, bs)
-        vp = write_chunk_kv_paged(vp, v, pos, valid, row, bs)
-        sv.cache_out[name] = (kp, vp)
-        kc = gather_paged_kv(kp, tables)
-        vc = gather_paged_kv(vp, tables)
+    entry = write_chunk_kv(sv.cache_in[name], k, v, start, n_new,
+                           tables[0], sv.block_size)
+    sv.cache_out[name] = entry
+    kc, vc = read_kv(entry, tables, q.shape[-1], k.dtype)
     extent = kc.shape[2]
     scale = 1.0 / np.sqrt(q.shape[-1])
     # full-extent score GEMM: chunk q rows scattered at their positions
@@ -383,26 +332,18 @@ def _chunk_prefill_attention(name: str, q, k, v, sv):
 
 def _maybe_flash_decode(q, entry, tables, sv, sm_scale):
     """Route one paged decode read through the Pallas flash-decode kernel
-    when eligible (on-TPU, MXU-friendly dims) —
+    when eligible (on-TPU, a pool of whole lanes and sublanes) —
     returns the (S, h, 1, hd) output or None for the gather path."""
-    from ..kernels.flash_decode import flash_decode, use_flash_decode
+    from ..serving.kvcache import flash_decode_kv
 
-    if (sv.seq_shards > 1
-            or not use_flash_decode(q.shape[-1], sv.block_size)):
-        # seq_shards > 1: the shard decomposition runs the split-K math
-        # per segment over the gathered extent (_seqpar_decode); the
-        # single whole-extent kernel launch would bypass the combine
+    if sv.seq_shards > 1:
+        # the shard decomposition runs the split-K math per segment over
+        # the gathered extent (_seqpar_decode); the single whole-extent
+        # kernel launch would bypass the combine
         return None
-    n_keys = sv.positions + 1
-    if sv.kv_dtype == "int8":
-        kq, ks, vq, vs = entry
-        out = flash_decode(q[:, :, 0, :], kq, vq, tables, n_keys,
-                           sm_scale=sm_scale, kscale=ks, vscale=vs)
-    else:
-        kp, vp = entry
-        out = flash_decode(q[:, :, 0, :], kp, vp, tables, n_keys,
-                           sm_scale=sm_scale)
-    return out[:, :, None, :]
+    out = flash_decode_kv(q[:, :, 0, :], entry, tables, sv.positions + 1,
+                          sm_scale)
+    return None if out is None else out[:, :, None, :]
 
 
 def _dropout_seed(rng):

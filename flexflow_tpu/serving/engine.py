@@ -554,34 +554,18 @@ class ServingEngine:
     def _prefill_fn(self, bucket: int):
         return self.executor.make_prefill_step(bucket, self.max_decode_len)
 
-    @staticmethod
-    def _is_kv_entry(entry) -> bool:
-        """Attention KV entries are (k, v) tuples of 4-D per-request
-        buffers ``(1, h, max_len, hd)`` — the pageable kind; everything
-        else (the LSTM carry ``(1, 2h)``) stays slot-major."""
-        import jax
-
-        leaves = jax.tree_util.tree_leaves(entry)
-        return bool(leaves) and all(
-            getattr(leaf, "ndim", 0) == 4 for leaf in leaves)
-
-    def _write_slot(self, cache, slot: int, length: int, token,
-                    table_row) -> None:
-        """Insert one prefilled request into the decode batch: cache rows,
-        length cursor and the pending first token — one jitted scatter,
-        slot/length/token traced (no per-slot recompiles). KV entries
-        are scattered into the table row's pool blocks (quantizing for
-        int8 layouts), other stateful entries land slot-major, and the
-        slot's block-table row is set — ``table_row`` is a traced int32
-        array, so block choice never recompiles either."""
-        import jax
-        import jax.numpy as jnp
-
-        from .kvcache import scatter_prefill_paged
-
+    def _write_slot_program(self):
+        """The jitted slot writer ``(state, last, cache, slot, length,
+        token, table_row) -> (state, last)``, state and last donated:
+        KV entries are scattered into the table row's pool blocks
+        (quantizing for int8 layouts), other stateful entries land
+        slot-major, and the slot's cursor, table row and pending first
+        token are set. Every index is traced: one compile."""
         if self._write_slot_fn is None:
+            from ..execution.executor import named_jit
+            from .kvcache import scatter_prefill_kv
+
             bs = self.kv_block_size
-            int8 = self.kv_dtype == "int8"
             # the ONE pagedness decision: the entry-name set recorded by
             # _ensure_state when it built the pool (a second structural
             # classifier here could silently disagree for a future
@@ -592,22 +576,9 @@ class ServingEngine:
                 caches = {}
                 for name in state.caches:
                     if name in kv_names:
-                        if int8:
-                            kq, ks, vq, vs = state.caches[name]
-                            kc, vc = cache[name]
-                            kq, ks = scatter_prefill_paged(
-                                kq, kc, table_row, bs, scales=ks)
-                            vq, vs = scatter_prefill_paged(
-                                vq, vc, table_row, bs, scales=vs)
-                            caches[name] = (kq, ks, vq, vs)
-                        else:
-                            kp, vp = state.caches[name]
-                            kc, vc = cache[name]
-                            kp, _ = scatter_prefill_paged(kp, kc,
-                                                          table_row, bs)
-                            vp, _ = scatter_prefill_paged(vp, vc,
-                                                          table_row, bs)
-                            caches[name] = (kp, vp)
+                        caches[name] = scatter_prefill_kv(
+                            state.caches[name], cache[name], table_row,
+                            bs)
                     else:
                         caches[name] = update_slot_entry(
                             state.caches[name], cache[name], slot)
@@ -617,11 +588,19 @@ class ServingEngine:
                 return DecodeState(caches=caches, lengths=lengths,
                                    block_tables=tables), last
 
-            from ..execution.executor import named_jit
-
             self._write_slot_fn = named_jit("write", write,
                                             donate_argnums=(0, 1))
-        self.state, self._last_tokens = self._write_slot_fn(
+        return self._write_slot_fn
+
+    def _write_slot(self, cache, slot: int, length: int, token,
+                    table_row) -> None:
+        """Insert one prefilled request into the decode batch: cache rows,
+        length cursor and the pending first token — one jitted scatter
+        (``_write_slot_program``), slot/length/token and ``table_row``
+        traced, so neither slot nor block choice recompiles."""
+        import jax.numpy as jnp
+
+        self.state, self._last_tokens = self._write_slot_program()(
             self.state, self._last_tokens, cache,
             jnp.int32(slot), jnp.int32(length), jnp.int32(token),
             jnp.asarray(table_row, jnp.int32))
@@ -666,36 +645,41 @@ class ServingEngine:
             int(chunk_shape), self.max_decode_len, self.kv_block_size,
             self.kv_dtype)
 
-    def _cow_clone(self, src: int, dst: int) -> None:
-        """Copy-on-write clone: duplicate pool block ``src`` into the
-        freshly-allocated ``dst`` across every paged cache entry (int8
-        scale arrays included) before the cloner's first divergent
-        write. One tiny donated jit with traced block ids — exactly the
-        ``_clear_slot_tables`` idiom — so COW never recompiles. The
-        sharer's block is read, never written: its rows stay bitwise
-        untouched (tests/test_prefix_cache.py pins the isolation)."""
-        import jax
-        import jax.numpy as jnp
-
-        if self.state is None:
-            return  # no pool yet: nothing to clone from
+    def _cow_clone_program(self):
+        """The jitted copy-on-write clone ``(state, src, dst) -> state``,
+        state donated, block ids traced — the ``_clear_slot_tables``
+        idiom, so COW never recompiles."""
         if getattr(self, "_cow_clone_fn", None) is None:
+            import jax
+
+            from .kvcache import clone_kv_block
+
             paged_names = set(self._paged_entry_names)
 
             def clone(state, src, dst):
-                caches = {}
-                for name, entry in state.caches.items():
-                    if name in paged_names:
-                        caches[name] = tuple(
-                            leaf.at[dst].set(leaf[src]) for leaf in entry)
-                    else:
-                        caches[name] = entry
+                caches = {
+                    name: clone_kv_block(entry, src, dst)
+                    if name in paged_names else entry
+                    for name, entry in state.caches.items()}
                 return DecodeState(caches=caches, lengths=state.lengths,
                                    block_tables=state.block_tables)
 
             self._cow_clone_fn = jax.jit(clone, donate_argnums=(0,))
-        self.state = self._cow_clone_fn(self.state, jnp.int32(src),
-                                        jnp.int32(dst))
+        return self._cow_clone_fn
+
+    def _cow_clone(self, src: int, dst: int) -> None:
+        """Copy-on-write clone: duplicate pool block ``src`` into the
+        freshly-allocated ``dst`` across every paged cache entry (int8
+        scale arrays included) before the cloner's first divergent
+        write. The sharer's block is read, never written: its rows stay
+        bitwise untouched (tests/test_prefix_cache.py pins the
+        isolation)."""
+        import jax.numpy as jnp
+
+        if self.state is None:
+            return  # no pool yet: nothing to clone from
+        self.state = self._cow_clone_program()(
+            self.state, jnp.int32(src), jnp.int32(dst))
 
     def _set_slot_meta(self, slot: int, length: int, token: int,
                        table_row: np.ndarray) -> None:
@@ -766,13 +750,14 @@ class ServingEngine:
         """Allocate the slot-pool DecodeState lazily from the first
         prefill's cache structure (zeros; every slot's rows are fully
         overwritten by its admission prefill before any read): the
-        block POOL per KV entry — ``(kv_pool_blocks, h, block_size,
-        hd)`` (+ f32 scale arrays for int8) — a slot-major entry for
-        every other stateful op, and the all-garbage block tables."""
+        block POOL per KV entry (``kvcache.new_kv_pool``: K and V of a
+        head side by side, + the f32 scales for int8), a slot-major
+        entry for every other stateful op, and the all-garbage block
+        tables."""
         import jax
         import jax.numpy as jnp
 
-        from .kvcache import paged_pool_entry
+        from .kvcache import is_prefill_kv_entry, new_kv_pool
 
         if self.state is not None:
             return
@@ -786,23 +771,11 @@ class ServingEngine:
         caches = {}
         self._paged_entry_names = set()
         for name, entry in prefill_cache.items():
-            if self._is_kv_entry(entry):
+            if is_prefill_kv_entry(entry):
                 self._paged_entry_names.add(name)
-                kc, vc = entry
-                if self.kv_dtype == "int8":
-                    kq, ks = paged_pool_entry(
-                        kc, self.kv_pool_blocks, self.kv_block_size,
-                        "int8")
-                    vq, vs = paged_pool_entry(
-                        vc, self.kv_pool_blocks, self.kv_block_size,
-                        "int8")
-                    caches[name] = (kq, ks, vq, vs)
-                else:
-                    caches[name] = (
-                        paged_pool_entry(kc, self.kv_pool_blocks,
-                                         self.kv_block_size, "native"),
-                        paged_pool_entry(vc, self.kv_pool_blocks,
-                                         self.kv_block_size, "native"))
+                caches[name] = new_kv_pool(
+                    entry, self.kv_pool_blocks, self.kv_block_size,
+                    self.kv_dtype)
             else:
                 caches[name] = jax.tree.map(
                     lambda leaf: jnp.zeros((n,) + leaf.shape[1:],

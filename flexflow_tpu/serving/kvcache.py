@@ -19,35 +19,64 @@ generalizes that pattern into the serving engine's decode state (ISSUE 6):
 
 Static shapes are the design rule (no per-token recompiles). The KV
 layout is paged (ISSUE 12, vLLM-style PagedAttention adapted to
-JAX/TPU): ONE pool of fixed-size KV blocks per stateful node —
-``(n_blocks, heads, block_size, head_dim)`` — plus a per-slot **block
-table** ``(n_slots, max_blocks_per_slot)`` int32 mapping each slot's
-logical positions onto pool blocks. Prefill computes one request's
-contiguous ``(1, heads, max_len, head_dim)`` cache and the slot writer
-scatters it into the request's blocks; each decode step writes ONE token
-at ``lengths[slot]``, and attention masks key positions ``> position``.
+JAX/TPU), and what an entry is — its shape, where K and V sit, how a
+prefill's buffers become blocks, how rows are written and read back —
+is known in this module alone:
+
+* ONE pool of fixed-size KV blocks per attention node, ``(n_blocks,
+  heads, block_size, kd + vd)``: a row is one head's K then V, side by
+  side on the lanes. The width is the point. A TPU array rests in tiles
+  of 128 lanes; a last dimension of 64 (GPT-2's head) is half a tile,
+  so the chip's at-rest layout for a separate ``(.., 64)`` K pool puts
+  the BLOCK axis minor-most, which no program computes in — every step
+  copied the whole pool to the kernel's layout and back. Packed to 128
+  lanes with ``block_size`` a whole sublane tile of the dtype, the
+  at-rest layout IS the kernels' (``{3,2,1,0}``, no padding, the same
+  bytes), and a program that donates the pool holds no copy of it
+  (tests/test_kv_pool_in_place.py reads that from the compiled text).
+  One layout for every head width and dtype; where the blocks do not
+  fill whole tiles (tiny test widths) or off the chip, the same arrays
+  are read by gather and written by scatter. Between the two, a pool of
+  128 lanes whose ``block_size`` is 8 rows but not the dtype's whole
+  tile (int8 at the default block 16, bf16 at block 8) is read by the
+  kernel and written by scatter: the reader's gate asks for less than
+  the writer's (kernels/flash_decode.py, kernels/kv_write.py).
+* a per-slot **block table** ``(n_slots, max_blocks_per_slot)`` int32
+  maps each slot's logical positions onto pool blocks. Prefill computes
+  one request's contiguous ``(1, heads, max_len, kd | vd)`` buffers
+  (:func:`prefill_kv_entry`) and the slot writer scatters them, whole
+  blocks, into the request's blocks (:func:`scatter_prefill_kv`); each
+  decode step writes ONE token at ``lengths[slot]`` and a prefill chunk
+  its C rows, both through :func:`write_kv_rows` — on the chip the
+  aliased Pallas call ``kv_write``, in place — and attention masks key
+  positions ``> position``. Reads are the ``flash_decode`` kernel
+  (:func:`flash_decode_kv`) or the gather :func:`read_kv`.
+
 Pad garbage beyond a prompt's true length is never read: the write
 cursor overwrites it before the mask ever exposes it.
 Slot recycling and prefix sharing are pointer bookkeeping in the
 host-side :class:`~flexflow_tpu.serving.scheduler.BlockAllocator`
 (prefix sharing delivered by ISSUE 14's radix-tree cache,
 serving/prefix.py: shared blocks are refcounted, divergent writes clone
-first — copy-on-write); pool occupancy decouples from ``max_len`` (a
-short request holds few blocks); and the single-compile decode contract
-holds — block tables are just another int32 array in the jitted
-signature. Block index 0 is the reserved GARBAGE block: every unused
-table entry points at it, free slots write their (discarded) tokens into
-it, and the attention mask guarantees it is never read — so its contents
-only ever need to stay FINITE (``0 * garbage`` must be exactly ``0.0``;
-the chaos poisoner deliberately never NaNs it).
+first — copy-on-write, :func:`clone_kv_block`); pool occupancy decouples
+from ``max_len`` (a short request holds few blocks); and the
+single-compile decode contract holds — block tables are just another
+int32 array in the jitted signature. Block index 0 is the reserved
+GARBAGE block: every unused table entry points at it, free slots and a
+chunk's pad rows write their (discarded) tokens into it, and the
+attention mask guarantees it is never read — so its contents only ever
+need to stay FINITE (``0 * garbage`` must be exactly ``0.0``: the
+kernel's zero-padded query leans on the same; the chaos poisoner
+deliberately never NaNs it).
 
-Quantized layout (``kv_dtype="int8"``): pool blocks store symmetric
-per-(token, head) int8 rows with float32 scales in block-paged scale
-arrays ``(n_blocks, heads, block_size)`` — scale = amax/127 over the
-head_dim row, written once with the row and folded back on read. fp
-layouts are held to the whole-sequence forward within the stated
-tolerance (tests/serving_oracle.py); int8 is judged against a pinned
-tolerance band (tests/test_decode_paged.py).
+Quantized layout (``kv_dtype="int8"``): the pool stores symmetric
+per-(token, head) int8 rows, K's and V's quantized apart, and the entry
+is ``(pool, scales)`` with f32 ``scales (n_blocks, 2, heads,
+block_size)``, K's then V's — scale = amax/127 over the head_dim row,
+written once with the row and folded back on read. fp layouts are held
+to the whole-sequence forward within the stated tolerance
+(tests/serving_oracle.py); int8 is judged against a pinned tolerance
+band (tests/test_decode_paged.py).
 """
 from __future__ import annotations
 
@@ -276,123 +305,234 @@ def dequantize_kv(q, scale, dtype):
     return (q.astype(jnp.float32) * scale[..., None]).astype(dtype)
 
 
-def write_token_kv_paged(pool, new, positions, block_tables, block_size):
-    """Scatter one token's k or v (n_slots, h, 1, hd) into the block pool
-    (n_blocks, h, block_size, hd) at each slot's current position: block
+def new_kv_pool(prefill_entry, n_blocks: int, block_size: int,
+                kv_dtype: str):
+    """Zeros-initialized pool entry for one attention node, from the
+    structure of its prefill entry (:func:`prefill_kv_entry`): the one
+    pool ``(n_blocks, h, block_size, kd + vd)`` for "native"; for "int8"
+    ``(pool int8, scales f32 (n_blocks, 2, h, block_size))``."""
+    import jax.numpy as jnp
+
+    kbuf, vbuf = prefill_entry
+    h = kbuf.shape[1]
+    shape = (n_blocks, h, block_size, kbuf.shape[-1] + vbuf.shape[-1])
+    if kv_dtype == "int8":
+        return (jnp.zeros(shape, jnp.int8),
+                jnp.zeros((n_blocks, 2, h, block_size), jnp.float32))
+    return jnp.zeros(shape, kbuf.dtype)
+
+
+def prefill_kv_entry(k, v, max_len: int):
+    """What a prefill hands the slot writer for one attention node: the
+    request's k ``(1, h, L, kd)`` and v ``(1, h, L, vd)`` at position 0
+    of contiguous zeroed ``max_len`` buffers, unquantized —
+    :func:`scatter_prefill_kv` turns them into pool blocks."""
+    import jax.numpy as jnp
+
+    pad = ((0, 0), (0, 0), (0, max_len - k.shape[2]), (0, 0))
+    return jnp.pad(k, pad), jnp.pad(v, pad)
+
+
+def is_prefill_kv_entry(entry) -> bool:
+    """Is a prefill's cache entry attention K and V — the pageable kind,
+    4-D per-request buffers ``(1, h, max_len, kd | vd)``? Everything
+    else (the LSTM carry ``(1, 2h)``) stays slot-major."""
+    import jax
+
+    leaves = jax.tree_util.tree_leaves(entry)
+    return bool(leaves) and all(
+        getattr(leaf, "ndim", 0) == 4 for leaf in leaves)
+
+
+def _pool_scales(entry):
+    """``(pool, scales)`` of a pool entry; ``scales`` is None for a
+    "native" entry, which is the bare pool."""
+    return entry if isinstance(entry, tuple) else (entry, None)
+
+
+def _pack_rows(entry, k, v):
+    """k ``(..., kd)`` and v ``(..., vd)`` as stored rows ``(..., kd +
+    vd)`` in the pool's dtype, and for an int8 entry their f32 scales
+    ``(..., 2)``; fp rows are stored bit-unchanged."""
+    import jax.numpy as jnp
+
+    pool, scales = _pool_scales(entry)
+    if scales is None:
+        return jnp.concatenate([k, v], axis=-1).astype(pool.dtype), None
+    kq, ks = quantize_kv(k)
+    vq, vs = quantize_kv(v)
+    return (jnp.concatenate([kq, vq], axis=-1),
+            jnp.stack([ks, vs], axis=-1))
+
+
+def write_kv_rows(pool, rows, block_ids, offsets, *,
+                  consecutive: bool = False, interpret: bool = False):
+    """THE pool write: ``pool[block_ids[n], :, offsets[n]] = rows[n]``
+    for ``rows (N, h, lanes)`` in the pool's dtype. No arithmetic on
+    stored values, and every other byte of the pool is left as it was.
+
+    On the chip (and under ``interpret``, the tests' way in) it is the
+    aliased Pallas call ``kv_write`` (kernels/kv_write.py), which
+    rewrites whole blocks, one a grid step, and loses the first of two
+    steps' rows when both name the same block. So:
+
+    * ``consecutive=False`` (the decode step: one row a slot) takes one
+      step a row. Live slots never share a writable block (copy-on-
+      write); free slots meet in the garbage block, where any finite
+      row will do. Only the chip shows the loss — the interpreter and
+      the scatter below write both rows — so a caller that could name a
+      real block twice in one call (several tokens of one slot) must
+      take ``consecutive=True`` or its own grouping;
+      tests/test_serving_paths.py reads the engine's calls for it and
+      ``chip_smoke.py::check_kv_write`` holds the call on the chip.
+    * ``consecutive=True`` (a prefill chunk) promises ``offsets[n] ==
+      (offsets[0] + n) % block_size`` — the rows are successive
+      positions of one slot — and takes one step a BLOCK: the rows are
+      staged ``block_size`` at a time and each step merges every row
+      that names its block. Steps left without a row are sent to the
+      garbage block, never to a block another step writes.
+
+    Elsewhere it is the scatter the kernel replaces, which is also the
+    tests' oracle."""
+    import jax.numpy as jnp
+
+    from ..kernels.kv_write import kv_write, use_kv_write
+
+    if not (interpret or use_kv_write(pool)):
+        return pool.at[block_ids, :, offsets].set(rows)
+    if not consecutive:
+        return kv_write(pool, rows[:, :, None, :], block_ids, offsets,
+                        offsets + 1, interpret=interpret)
+    n, bs = rows.shape[0], pool.shape[2]
+    steps = (n + bs - 2) // bs + 1  # blocks n successive rows can touch
+    # r[j, t]: which of the n rows sits at offset t of the j-th block
+    r = (jnp.arange(steps)[:, None] * bs + jnp.arange(bs)[None, :]
+         - offsets[0])
+    rc = jnp.clip(r, 0, n - 1)
+    bid = block_ids[rc[:, 0]]  # the block of a step's first row
+    mine = (r >= 0) & (r < n) & (block_ids[rc] == bid[:, None])
+    lo = jnp.argmax(mine, axis=1)
+    count = jnp.sum(mine, axis=1)
+    staged = jnp.swapaxes(rows[rc], 1, 2)  # (steps, h, bs, lanes)
+    return kv_write(pool, staged,
+                    jnp.where(count > 0, bid, GARBAGE_BLOCK), lo,
+                    lo + count, interpret=interpret)
+
+
+def _write_entry(entry, k, v, block_ids, offsets, consecutive):
+    """Rows k ``(N, h, kd)`` / v ``(N, h, vd)`` into a pool entry at
+    ``(block_ids, offsets)``; int8 entries quantize per (token, head)
+    and write the two scales beside the row."""
+    pool, scales = _pool_scales(entry)
+    rows, srows = _pack_rows(entry, k, v)
+    pool = write_kv_rows(pool, rows, block_ids, offsets,
+                         consecutive=consecutive)
+    if scales is None:
+        return pool
+    # (N, h, 2) -> (N, 2, h) at scales[block, :, :, offset]
+    return pool, scales.at[block_ids, :, :, offsets].set(
+        srows.swapaxes(1, 2))
+
+
+def write_token_kv(entry, k, v, positions, block_tables, block_size):
+    """Write one token's k ``(n_slots, h, 1, kd)`` and v ``(n_slots, h,
+    1, vd)`` into the pool at each slot's current position: block
     ``tables[slot, pos // bs]``, offset ``pos % bs``. Free slots (their
     table rows all GARBAGE_BLOCK, position 0) collide harmlessly in the
-    garbage block — it is never read. No arithmetic on stored values."""
+    garbage block — it is never read."""
     import jax.numpy as jnp
 
     bi = jnp.take_along_axis(
         block_tables, (positions // block_size)[:, None], axis=1)[:, 0]
-    off = positions % block_size
-    return pool.at[bi, :, off].set(new[:, :, 0, :].astype(pool.dtype))
+    return _write_entry(entry, k[:, :, 0, :], v[:, :, 0, :], bi,
+                        positions % block_size, consecutive=False)
 
 
-def write_token_scale_paged(scales, scale_new, positions, block_tables,
-                            block_size):
-    """Scale-array twin of :func:`write_token_kv_paged`:
-    ``scales (n_blocks, h, block_size)``, ``scale_new (n_slots, h, 1)``."""
-    import jax.numpy as jnp
-
-    bi = jnp.take_along_axis(
-        block_tables, (positions // block_size)[:, None], axis=1)[:, 0]
-    off = positions % block_size
-    return scales.at[bi, :, off].set(scale_new[:, :, 0])
-
-
-def write_chunk_kv_paged(pool, new, positions, valid, table_row,
-                         block_size):
-    """Scatter one prefill CHUNK's k or v rows ``(1, h, C, hd)`` into
-    the block pool at ``positions`` (C,) of the single slot owning
+def write_chunk_kv(entry, k, v, start, n_new, table_row, block_size):
+    """Write one prefill CHUNK's k ``(1, h, C, kd)`` and v ``(1, h, C,
+    vd)`` at positions ``start + arange(C)`` of the single slot owning
     ``table_row`` (mb,) — the chunked-prefill / prefix-suffix write
-    (ISSUE 14). Invalid (pad) rows beyond the chunk's real token count
-    are routed to the GARBAGE block (finite garbage, never read); valid
-    rows land at (table[pos // bs], pos % bs) like the decode-step
-    write. No arithmetic on stored values."""
+    (ISSUE 14). Pad rows beyond the chunk's ``n_new`` real tokens are
+    routed to the GARBAGE block (finite garbage, never read); real rows
+    land at (table[pos // bs], pos % bs) like the decode-step write."""
     import jax.numpy as jnp
 
-    mb = table_row.shape[0]
-    blk = jnp.clip(positions // block_size, 0, mb - 1)
-    bi = jnp.where(valid, table_row[blk], GARBAGE_BLOCK)
-    off = positions % block_size
-    rows = jnp.swapaxes(new[0], 0, 1)  # (h, C, hd) -> (C, h, hd)
-    return pool.at[bi, :, off].set(rows.astype(pool.dtype))
+    chunk_len = k.shape[2]
+    pos = start + jnp.arange(chunk_len, dtype=jnp.int32)
+    blk = jnp.clip(pos // block_size, 0, table_row.shape[0] - 1)
+    bi = jnp.where(jnp.arange(chunk_len) < n_new, table_row[blk],
+                   GARBAGE_BLOCK)
+    return _write_entry(entry, jnp.swapaxes(k[0], 0, 1),
+                        jnp.swapaxes(v[0], 0, 1), bi, pos % block_size,
+                        consecutive=True)
 
 
-def write_chunk_scale_paged(scales, scale_new, positions, valid,
-                            table_row, block_size):
-    """Scale-array twin of :func:`write_chunk_kv_paged`:
-    ``scales (n_blocks, h, bs)``, ``scale_new (1, h, C)``."""
+def read_kv(entry, block_tables, kdim: int, dtype):
+    """Materialize each slot's logical KV extent from the pool entry
+    through ``(n_slots, mb)`` tables: ``(k, v)`` as ``(n_slots, h, mb *
+    bs, kd | vd)`` in position order and in ``dtype``. This is the
+    gather read (O(mb * bs) rows — the Pallas flash-decode kernel is
+    the O(true_length) path); fp rows come back bitwise the stored rows,
+    int8 rows with their per-(token, head) scale folded back."""
     import jax.numpy as jnp
 
-    mb = table_row.shape[0]
-    blk = jnp.clip(positions // block_size, 0, mb - 1)
-    bi = jnp.where(valid, table_row[blk], GARBAGE_BLOCK)
-    off = positions % block_size
-    return scales.at[bi, :, off].set(jnp.swapaxes(scale_new[0], 0, 1))
+    pool, scales = _pool_scales(entry)
+    g = jnp.swapaxes(pool[block_tables], 1, 2)   # (S, h, mb, bs, lanes)
+    g = g.reshape(g.shape[0], g.shape[1], -1, g.shape[-1])
+    kc, vc = g[..., :kdim], g[..., kdim:]
+    if scales is None:
+        return kc.astype(dtype), vc.astype(dtype)
+    s = jnp.moveaxis(scales[block_tables], 1, 3)  # (S, 2, h, mb, bs)
+    s = s.reshape(s.shape[:3] + (-1,))
+    return (dequantize_kv(kc, s[:, 0], dtype),
+            dequantize_kv(vc, s[:, 1], dtype))
 
 
-def gather_paged_kv(pool, block_tables):
-    """Materialize each slot's logical KV extent from the pool:
-    ``(n_blocks, h, bs, hd)`` gathered through ``(n_slots, mb)`` tables →
-    ``(n_slots, h, mb * bs, hd)`` in position order. This is the
-    CPU fallback read (O(mb * bs) rows — the
-    Pallas flash-decode kernel is the O(true_length) path); a pure
-    gather, so the materialized rows are bitwise the stored rows."""
+def flash_decode_kv(q, entry, block_tables, n_keys, sm_scale):
+    """The kernel read of a pool entry (kernels/flash_decode.py): q
+    ``(n_slots, h, kd)`` against each slot's ``n_keys`` first keys →
+    ``(n_slots, h, vd)``; None where the gate says the gather read
+    (:func:`read_kv`) is the path — off the chip, or a pool that is not
+    whole lanes (128) and whole sublanes (8 rows a block)."""
+    from ..kernels.flash_decode import flash_decode_pool, use_flash_decode
+
+    pool, scales = _pool_scales(entry)
+    if not use_flash_decode(pool.shape[-1], pool.shape[2]):
+        return None
+    return flash_decode_pool(q, pool, block_tables, n_keys,
+                             sm_scale=sm_scale, scales=scales)
+
+
+def scatter_prefill_kv(entry, prefill_entry, table_row, block_size: int):
+    """Insert one prefilled request's contiguous cache
+    (:func:`prefill_kv_entry`) into its table row's pool blocks: it is
+    padded to whole blocks, packed, reshaped block-major and scattered
+    at ``table_row`` (mb,) int32 — whole blocks, so the scatter is in
+    place whatever the layout. Unused table entries point at
+    GARBAGE_BLOCK and receive the cache's zero pad — harmless, never
+    read. int8 entries quantize the rows here; fp pools store them
+    bit-unchanged."""
     import jax.numpy as jnp
 
-    g = pool[block_tables]                 # (S, mb, h, bs, hd)
-    g = jnp.swapaxes(g, 1, 2)              # (S, h, mb, bs, hd)
-    return g.reshape(g.shape[0], g.shape[1], -1, g.shape[-1])
-
-
-def gather_paged_scales(scales, block_tables):
-    """(n_blocks, h, bs) through (n_slots, mb) → (n_slots, h, mb * bs)."""
-    import jax.numpy as jnp
-
-    g = scales[block_tables]               # (S, mb, h, bs)
-    g = jnp.swapaxes(g, 1, 2)              # (S, h, mb, bs)
-    return g.reshape(g.shape[0], g.shape[1], -1)
-
-
-def paged_pool_entry(prefill_leaf, n_blocks: int, block_size: int,
-                     kv_dtype: str):
-    """Zeros-initialized pool (+ scales for int8) for one KV leaf whose
-    per-request prefill shape is ``(1, h, max_len, hd)``. Returns the pool
-    array for "native", ``(pool int8, scales f32)`` for "int8"."""
-    import jax.numpy as jnp
-
-    _, h, _L, hd = prefill_leaf.shape
-    if kv_dtype == "int8":
-        return (jnp.zeros((n_blocks, h, block_size, hd), jnp.int8),
-                jnp.zeros((n_blocks, h, block_size), jnp.float32))
-    return jnp.zeros((n_blocks, h, block_size, hd), prefill_leaf.dtype)
-
-
-def scatter_prefill_paged(pool, prefill_leaf, table_row, block_size: int,
-                          scales=None):
-    """Insert one prefilled request's contiguous cache ``(1, h, max_len,
-    hd)`` into its table row's pool blocks: it is padded to whole blocks,
-    reshaped block-major and scattered at ``table_row`` (mb,) int32.
-    Unused table entries point at GARBAGE_BLOCK and receive the cache's
-    zero pad — harmless, never read. For int8 pools the rows are
-    quantized here (``scales`` must be the matching scale array); fp
-    pools store the rows bit-unchanged."""
-    import jax.numpy as jnp
-
-    x = prefill_leaf[0]                       # (h, L, hd)
-    h, L, hd = x.shape
+    pool, scales = _pool_scales(entry)
+    kbuf, vbuf = prefill_entry
     mb = int(table_row.shape[0])
-    pad = mb * block_size - L
-    if pad:
-        x = jnp.pad(x, ((0, 0), (0, pad), (0, 0)))
-    if scales is not None:
-        q, s = quantize_kv(x)              # (h, P, hd), (h, P)
-        qb = q.reshape(h, mb, block_size, hd).transpose(1, 0, 2, 3)
-        sb = s.reshape(h, mb, block_size).transpose(1, 0, 2)
-        return (pool.at[table_row].set(qb),
-                scales.at[table_row].set(sb))
-    xb = x.reshape(h, mb, block_size, hd).transpose(1, 0, 2, 3)
-    return pool.at[table_row].set(xb.astype(pool.dtype)), None
+    pad = ((0, 0), (0, mb * block_size - kbuf.shape[2]), (0, 0))
+    rows, srows = _pack_rows(entry, jnp.pad(kbuf[0], pad),
+                             jnp.pad(vbuf[0], pad))  # (h, P, lanes)
+    h, _p, lanes = rows.shape
+    pool = pool.at[table_row].set(
+        rows.reshape(h, mb, block_size, lanes).transpose(1, 0, 2, 3))
+    if scales is None:
+        return pool
+    # (h, P, 2) -> (mb, 2, h, bs)
+    return pool, scales.at[table_row].set(
+        srows.reshape(h, mb, block_size, 2).transpose(1, 3, 0, 2))
+
+
+def clone_kv_block(entry, src, dst):
+    """Copy pool block ``src`` onto ``dst`` (traced ids) in every array
+    of the entry — the copy-on-write clone; ``src`` is read only."""
+    import jax
+
+    return jax.tree.map(lambda a: a.at[dst].set(a[src]), entry)

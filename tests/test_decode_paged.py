@@ -325,53 +325,119 @@ def test_kv_bytes_accounting_paged_below_ring(gpt2):
 
 
 # ------------------------------------------------------ flash-decode kernel
-def test_flash_decode_interpret_matches_reference():
-    """The Pallas split-K kernel (interpret mode on CPU) matches the
-    masked-gather reference for fp and int8 pools, including slots with
-    very different true lengths (the clamp-dead-blocks path)."""
+def _packed_pool(rng, n_blocks, heads, block_size, kd, vd):
+    """A random f32 pool entry and its int8 twin, built the way the
+    slot writer builds them: one request's contiguous K and V scattered
+    over every block of the pool."""
+    import jax.numpy as jnp
+
+    from flexflow_tpu.serving.kvcache import new_kv_pool, scatter_prefill_kv
+
+    kv = tuple(jnp.asarray(rng.normal(
+        size=(1, heads, n_blocks * block_size, d)).astype(np.float32))
+        for d in (kd, vd))
+    row = jnp.arange(n_blocks, dtype=jnp.int32)
+    return tuple(scatter_prefill_kv(
+        new_kv_pool(kv, n_blocks, block_size, dt), kv, row, block_size)
+        for dt in ("native", "int8"))
+
+
+@pytest.mark.parametrize("heads,kd,vd", [(4, 64, 64), (25, 64, 64),
+                                         (3, 128, 128), (2, 16, 48)],
+                         ids=["4x64", "25x64", "3x128-256-lanes",
+                              "kd-not-vd"])
+def test_flash_decode_interpret_matches_reference(heads, kd, vd):
+    """The Pallas split-K kernel (interpret mode on CPU) on the packed
+    pool matches the masked-gather reference for fp and int8 pools,
+    including slots with very different true lengths (the
+    clamp-dead-blocks path), at GPT-2 XL's 25 heads of 64, at a width of
+    256 lanes and where K and V differ in width."""
     import jax.numpy as jnp
 
     from flexflow_tpu.kernels.flash_decode import (_reference_decode,
-                                                   flash_decode)
-    from flexflow_tpu.serving.kvcache import quantize_kv
+                                                   flash_decode_pool)
 
     rng = np.random.default_rng(0)
-    S, H, BS, HD, MB = 3, 4, 8, 64, 4
-    NB = 1 + S * MB
-    kpool = jnp.asarray(rng.normal(size=(NB, H, BS, HD)) .astype(np.float32))
-    vpool = jnp.asarray(rng.normal(size=(NB, H, BS, HD)).astype(np.float32))
+    S, BS, MB = 3, 8, 4
+    pool, (q8, s8) = _packed_pool(rng, 1 + S * MB, heads, BS, kd, vd)
     tables = np.zeros((S, MB), np.int32)
     tables[0, :2] = [1, 2]
     tables[1, :4] = [3, 4, 5, 6]
     tables[2, :1] = [7]
     tables = jnp.asarray(tables)
     n_keys = jnp.asarray([13, 30, 5], jnp.int32)
-    q = jnp.asarray(rng.normal(size=(S, H, HD)).astype(np.float32))
-    out = flash_decode(q, kpool, vpool, tables, n_keys, interpret=True)
-    ref = _reference_decode()(q, kpool, vpool, tables, n_keys,
-                              1.0 / np.sqrt(HD))
+    q = jnp.asarray(rng.normal(size=(S, heads, kd)).astype(np.float32))
+    sm = 1.0 / np.sqrt(kd)
+    out = flash_decode_pool(q, pool, tables, n_keys, interpret=True)
+    assert out.shape == (S, heads, vd)
+    ref = _reference_decode()(q, pool, tables, n_keys, sm)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=2e-6)
-    kq, ks = quantize_kv(kpool)
-    vq, vs = quantize_kv(vpool)
-    out8 = flash_decode(q, kq, vq, tables, n_keys, kscale=ks,
-                        vscale=vs, interpret=True)
-    ref8 = _reference_decode()(q, kq, vq, tables, n_keys,
-                               1.0 / np.sqrt(HD), kscale=ks, vscale=vs)
+    out8 = flash_decode_pool(q, q8, tables, n_keys, scales=s8,
+                             interpret=True)
+    ref8 = _reference_decode()(q, q8, tables, n_keys, sm, scales=s8)
     np.testing.assert_allclose(np.asarray(out8), np.asarray(ref8),
                                atol=2e-6)
     # and int8 sits within a loose band of fp (quantization, not bugs)
     assert float(jnp.max(jnp.abs(out8 - ref))) < 0.1
 
 
-def test_flash_decode_gate_off_tpu():
-    from flexflow_tpu.kernels.flash_decode import use_flash_decode
+def test_flash_decode_of_separate_pools_packs_them():
+    """``flash_decode(q, kpool, vpool, ...)``, the entry for a caller
+    that holds K and V apart (the benchmark's ahead-of-time check),
+    reads what the packed entry reads."""
+    import jax.numpy as jnp
 
-    # CPU process: the gate must refuse regardless of dims
+    from flexflow_tpu.kernels.flash_decode import (flash_decode,
+                                                   flash_decode_pool)
+
+    rng = np.random.default_rng(1)
+    pool, (q8, s8) = _packed_pool(rng, 5, 2, 8, 16, 16)
+    tables = jnp.asarray([[1, 2], [3, 0]], jnp.int32)
+    n_keys = jnp.asarray([11, 4], jnp.int32)
+    q = jnp.asarray(rng.normal(size=(2, 2, 16)).astype(np.float32))
+    for p, sc in ((pool, None), (q8, s8)):
+        kw = {} if sc is None else {"kscale": sc[:, 0], "vscale": sc[:, 1]}
+        apart = flash_decode(q, p[..., :16], p[..., 16:], tables, n_keys,
+                             interpret=True, **kw)
+        packed = flash_decode_pool(q, p, tables, n_keys, scales=sc,
+                                   interpret=True)
+        assert np.array_equal(np.asarray(apart), np.asarray(packed))
+
+
+def test_flash_decode_gate_off_tpu(monkeypatch):
+    import jax
+    import jax.numpy as jnp
+
+    from flexflow_tpu.kernels import _common
+    from flexflow_tpu.kernels.flash_decode import use_flash_decode
+    from flexflow_tpu.kernels.kv_write import use_kv_write
+
+    def pool(lanes, block, dtype):
+        return jax.ShapeDtypeStruct((9, 4, block, lanes), dtype)
+
+    # CPU process: both gates must refuse regardless of dims
+    assert not use_flash_decode(128, 16)
+    assert not use_kv_write(pool(128, 16, jnp.bfloat16))
+    monkeypatch.setattr(_common, "on_tpu", lambda: True)
+    # the reader, from what it can see of the pool: kd + vd a multiple
+    # of 128 lanes, block_size of 8 sublanes, whatever the dtype — the
+    # default block 16 serves an int8 pool through the kernel
+    assert use_flash_decode(128, 16)
+    assert use_flash_decode(128, 8)
+    assert use_flash_decode(256, 32)
     assert not use_flash_decode(64, 16)
-    # and bad dims refuse before the platform probe
-    assert not use_flash_decode(60, 16)
-    assert not use_flash_decode(64, 3)
+    assert not use_flash_decode(120, 16)
+    assert not use_flash_decode(128, 12)
+    # the writer rewrites whole blocks in place, so it asks for whole
+    # tiles: block_size a whole sublane tile of the pool's dtype
+    assert use_kv_write(pool(128, 16, jnp.bfloat16))
+    assert use_kv_write(pool(256, 8, jnp.float32))
+    assert use_kv_write(pool(128, 32, jnp.int8))
+    assert not use_kv_write(pool(128, 8, jnp.bfloat16))
+    assert not use_kv_write(pool(128, 16, jnp.int8))
+    assert not use_kv_write(pool(64, 16, jnp.bfloat16))
+    assert not use_kv_write(pool(120, 16, jnp.float32))
 
 
 # ------------------------------------------------------ satellite: FF006

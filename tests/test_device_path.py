@@ -74,7 +74,7 @@ def test_kernel_gates_propagate_a_failing_device_query(monkeypatch):
     monkeypatch.setattr(jax, "devices", boom)
     q = jnp.zeros((1, 2, 512, 64), jnp.bfloat16)
     x = jnp.zeros((8, 1024), jnp.bfloat16)
-    for gate in (lambda: use_flash_decode(64, 16),
+    for gate in (lambda: use_flash_decode(128, 16),
                  lambda: _should_use_flash("auto", q, q, False),
                  lambda: should_use_pallas_softmax(x, -1, opt_in=True),
                  lambda: should_use_pallas_topk(x, 2, opt_in=True)):

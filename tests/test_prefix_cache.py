@@ -647,6 +647,6 @@ def test_chunk_overhang_past_context_stays_finite_and_bitwise(gpt2):
     assert eng.stats.prefix_hits >= 1
     assert r2 == r1, "overhanging suffix chunk perturbed the warm stream"
     for entry in eng.state.caches.values():
-        for leaf in entry:
+        for leaf in jax.tree_util.tree_leaves(entry):
             assert np.isfinite(np.asarray(jax.device_get(leaf))).all(), \
                 "non-finite rows leaked into the KV pool"

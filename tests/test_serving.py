@@ -52,9 +52,8 @@ def _pool_state(cache, prompt_len, max_len):
     ``ServingEngine._ensure_state`` builds for such a model."""
     import jax.numpy as jnp
 
-    from flexflow_tpu.serving.kvcache import (blocks_per_slot,
-                                              paged_pool_entry,
-                                              scatter_prefill_paged)
+    from flexflow_tpu.serving.kvcache import (blocks_per_slot, new_kv_pool,
+                                              scatter_prefill_kv)
 
     mb = blocks_per_slot(max_len, KV_BLOCK)
     row = jnp.arange(1, mb + 1, dtype=jnp.int32)
@@ -62,10 +61,9 @@ def _pool_state(cache, prompt_len, max_len):
     for name, entry in cache.items():
         if isinstance(entry, tuple) and all(x.ndim == 4 for x in entry):
             paged = True
-            caches[name] = tuple(
-                scatter_prefill_paged(
-                    paged_pool_entry(leaf, mb + 1, KV_BLOCK, "native"),
-                    leaf, row, KV_BLOCK)[0] for leaf in entry)
+            caches[name] = scatter_prefill_kv(
+                new_kv_pool(entry, mb + 1, KV_BLOCK, "native"), entry,
+                row, KV_BLOCK)
         else:
             caches[name] = entry
     tables = row[None] if paged else jnp.zeros((1, mb), jnp.int32)
@@ -204,12 +202,13 @@ def _chunked_next_token_logits(ff, prompt, max_len, chunk, late=0):
 
 def _zero_one_k_row(state):
     """Fault (a): the cached K row of prompt position 2 reads zero in
-    the first attention entry (block ``table[0]``, offset 2)."""
+    the first attention entry (block ``table[0]``, offset 2; K is the
+    first half of a pool row's lanes)."""
     name = sorted(n for n, e in state.caches.items()
-                  if isinstance(e, tuple))[0]
-    kp, vp = state.caches[name]
+                  if getattr(e, "ndim", 0) == 4)[0]
+    pool = state.caches[name]
     caches = dict(state.caches)
-    caches[name] = (kp.at[1, :, 2].set(0.0), vp)
+    caches[name] = pool.at[1, :, 2, :pool.shape[-1] // 2].set(0.0)
     return DecodeState(caches=caches, lengths=state.lengths,
                        block_tables=state.block_tables)
 
