@@ -37,12 +37,14 @@ from .report import Diagnostic
 # kept in sync with the Unity DP's state-preserving set (search/unity.py) —
 # the ops the search itself pins to pass sharded states through unchanged
 _STATE_PRESERVING = {
-    OperatorType.OP_RELU, OperatorType.OP_GELU, OperatorType.OP_TANH,
+    OperatorType.OP_RELU, OperatorType.OP_GELU, OperatorType.OP_SILU,
+    OperatorType.OP_TANH,
     OperatorType.OP_SIGMOID, OperatorType.OP_ELU, OperatorType.OP_IDENTITY,
     OperatorType.OP_DROPOUT, OperatorType.OP_SCALAR_MULTIPLY,
     OperatorType.OP_SCALAR_ADD, OperatorType.OP_SCALAR_SUB,
     OperatorType.OP_SCALAR_TRUE_DIV, OperatorType.OP_CAST,
     OperatorType.OP_EXP, OperatorType.OP_POW, OperatorType.OP_LAYERNORM,
+    OperatorType.OP_RMSNORM,
     OperatorType.OP_SOFTMAX, OperatorType.OP_BATCHNORM,
 }
 _ELEMENTWISE_BINARY = {

@@ -213,7 +213,7 @@ class Executor:
             seq_length=ctx.seq_length, mesh=ctx.mesh,
             profiling=ctx.profiling, aux_losses=ctx.aux_losses,
             cache_in=ctx.cache_in, cache_out=ctx.cache_out,
-            serving=ctx.serving)
+            serving=ctx.serving, stats_out=ctx.stats_out)
         with jax.named_scope(node.name):
             outs = node.op.forward(node_params, inputs, node_ctx)
         # apply the strategy's output sharding constraint (parallel ops and
@@ -320,6 +320,9 @@ class Executor:
                     values = dict(zip(ext_refs, ext_vals))
                     aux: List[Any] = []
                     cache_out: Dict[str, Any] = {}
+                    # counters ops hand out (OpContext.stats_out) leave the
+                    # block the same way
+                    stats: Dict[str, Any] = {}
                     # block-local ctx: _exec_node folds the rng per node,
                     # exactly as the plain forward does (recompute replays
                     # identical dropout masks)
@@ -327,7 +330,8 @@ class Executor:
                                           mesh=mesh, profiling=profiling,
                                           aux_losses=aux,
                                           cache_in=cache_in,
-                                          cache_out=cache_out)
+                                          cache_out=cache_out,
+                                          stats_out=stats)
                     for g in seg:
                         node = self.pcg.nodes[g]
                         inputs = [values[(pg, i)] for pg, i in node.inputs]
@@ -341,7 +345,7 @@ class Executor:
                     # inside jax.checkpoint would leak residual tracers
                     aux_sum = sum(aux) if aux else jnp.zeros((), jnp.float32)
                     return (tuple(values[r] for r in out_refs), aux_sum,
-                            tuple(cache_out[n] for n in cache_names))
+                            tuple(cache_out[n] for n in cache_names), stats)
                 return fn
 
             fn = make_fn()
@@ -362,8 +366,10 @@ class Executor:
             block_params = {n: params[n] for n in names if n in params}
             ext_vals = tuple(values[r] for r in ext_refs)
             with jax.named_scope(f"remat_block_{k}"):
-                outs, aux, cache_vals = fn(block_params, ext_vals, ctx.rng,
-                                           ctx.cache_in)
+                outs, aux, cache_vals, stats = fn(block_params, ext_vals,
+                                                  ctx.rng, ctx.cache_in)
+            if ctx.stats_out is not None:
+                ctx.stats_out.update(stats)
             if ctx.aux_losses is not None:
                 ctx.aux_losses.append(aux)
             if ctx.cache_out is not None:
@@ -393,8 +399,9 @@ class Executor:
         constraint and the barrier are value-identities, and each psum
         happens exactly once on the same mesh — loss, grads, and the
         updated params are bitwise-equal (tests/test_pipeline_schedules).
-        Returns ``((loss, (logits, cache_out)), grads)`` with ``grads``
-        matching the ``params`` pytree (blocks partition the layers)."""
+        Returns ``((loss, (logits, cache_out, stats)), grads)`` with
+        ``grads`` matching the ``params`` pytree (blocks partition the
+        layers)."""
         import jax
         import jax.lax as lax
         import jax.numpy as jnp
@@ -409,6 +416,7 @@ class Executor:
         tapes = []
         aux_primals = []
         cache_out: Dict[str, Any] = {}
+        stats_out: Dict[str, Any] = {}
         for fn, ext_refs, out_refs, names, k, cache_names in program:
             block_params = {n: params[n] for n in names if n in params}
             ext_vals = tuple(values[r] for r in ext_refs)
@@ -419,11 +427,13 @@ class Executor:
                 # float32 master params
                 if cdtype is not None:
                     bp = self._cast_floats(bp, cdtype)
-                return _fn(bp, ev, rng, cache)
+                *diff, stats = _fn(bp, ev, rng, cache)
+                return tuple(diff), stats
 
             with jax.named_scope(f"remat_block_{k}"):
-                (outs, aux, cache_vals), vjp = jax.vjp(
-                    run, block_params, ext_vals)
+                (outs, aux, cache_vals), vjp, stats = jax.vjp(
+                    run, block_params, ext_vals, has_aux=True)
+            stats_out.update(stats)
             aux_primals.append(aux)
             cache_out.update(zip(cache_names, cache_vals))
             values.update(zip(out_refs, outs))
@@ -470,7 +480,7 @@ class Executor:
                 prev = cot.get(r)
                 cot[r] = d if prev is None else jax.tree_util.tree_map(
                     jnp.add, prev, d)
-        return (loss, (logits, cache_out)), grads
+        return (loss, (logits, cache_out, stats_out)), grads
 
     # ----------------------------------------------------------- cache state
     def init_cache(self):
@@ -556,9 +566,11 @@ class Executor:
         def loss_fn(params, xs, labels, rng, cache):
             params_c, xs = self._cast_for_compute(params, xs)
             cache_out = {}
+            stats_out = {}
             ctx = OpContext(training=True, rng=rng, mesh=mesh, aux_losses=[],
                             profiling=profiling,
-                            cache_in=cache, cache_out=cache_out)
+                            cache_in=cache, cache_out=cache_out,
+                            stats_out=stats_out)
             if remat_program is not None:
                 raw = self._forward_remat(params_c, self._bind_inputs(xs),
                                           ctx, remat_program)
@@ -572,16 +584,17 @@ class Executor:
                                   self.repl_labels)
                 for aux in ctx.aux_losses:
                     loss = loss + aux
-            return loss, (logits, cache_out)
+            return loss, (logits, cache_out, stats_out)
 
         def step(params, opt_state, xs, labels, rng, cache=None):
             if overlap:
-                (loss, (logits, cache_out)), grads = \
+                (loss, (logits, cache_out, stats_out)), grads = \
                     self._blockwise_value_and_grad(
                         remat_program, params, xs, labels, rng, cache)
             else:
-                (loss, (logits, cache_out)), grads = jax.value_and_grad(
-                    loss_fn, has_aux=True)(params, xs, labels, rng, cache)
+                (loss, (logits, cache_out, stats_out)), grads = \
+                    jax.value_and_grad(loss_fn, has_aux=True)(
+                        params, xs, labels, rng, cache)
             if guard:
                 import jax.numpy as jnp
 
@@ -603,6 +616,10 @@ class Executor:
                                                        opt_state)
             with jax.named_scope("metrics"):
                 m = self._compute_metrics(logits, labels)
+            if stats_out:
+                # the ops' counters ride with the step's metrics: fit fetches
+                # both once an epoch (FFModel.routing_stats)
+                m = dict(m, op_stats=stats_out)
             out = (new_params, new_state, loss, m)
             if has_cache:
                 out = out + (cache_out,)

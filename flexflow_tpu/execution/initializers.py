@@ -31,10 +31,13 @@ class Initializer:
 
 
 class GlorotUniformInitializer(Initializer):
-    """Xavier/Glorot uniform (reference: initializer.cc GlorotUniform)."""
+    """Xavier/Glorot uniform (reference: initializer.cc GlorotUniform).
+    ``stacked``: the leading dims index independent matrices (one per
+    expert), not a receptive field: the fans are the last two dims alone."""
 
-    def __init__(self, seed: int = 0):
+    def __init__(self, seed: int = 0, stacked: bool = False):
         self.seed = seed
+        self.stacked = stacked
 
     @staticmethod
     def _fans(shape: Tuple[int, ...]) -> Tuple[int, int]:
@@ -51,7 +54,8 @@ class GlorotUniformInitializer(Initializer):
     def __call__(self, key, shape, dtype):
         import jax
 
-        fan_in, fan_out = self._fans(tuple(shape))
+        fan_in, fan_out = self._fans(tuple(shape[-2:] if self.stacked
+                                           else shape))
         limit = float(np.sqrt(6.0 / max(fan_in + fan_out, 1)))
         return jax.random.uniform(self._seeded(key), tuple(shape), dtype,
                                   -limit, limit)

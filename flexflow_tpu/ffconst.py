@@ -17,6 +17,7 @@ class ActiMode(enum.IntEnum):
     AC_MODE_SIGMOID = 12
     AC_MODE_TANH = 13
     AC_MODE_GELU = 14
+    AC_MODE_SILU = 15  # x * sigmoid(x); no reference analog
 
 
 class RegularizerMode(enum.IntEnum):
@@ -205,6 +206,18 @@ class OperatorType(enum.IntEnum):
     # form of the reference's per-expert Linear nodes fed by group_by
     # (src/ops/group_by.cc), shardable over the expert dim for EP
     OP_EXPERTS = 116
+    # decoder-block extensions: x * sigmoid(x); the gated MLP
+    # W_down(silu(W_gate x) * W_up x) as one node
+    OP_SILU = 117
+    OP_GATED_MLP = 118
+    # the dropless routed expert layer (ops/moe_ops.py, beside the
+    # fixed-capacity GroupBy/Experts/Aggregate path): router -> dispatch by
+    # a stable sort on expert id -> grouped products over the experts held
+    # here -> combine
+    OP_MOE_ROUTER = 119
+    OP_MOE_DISPATCH = 120
+    OP_MOE_ROUTED_EXPERTS = 121
+    OP_MOE_COMBINE = 122
 
 
 # --- dtype helpers -------------------------------------------------------------

@@ -127,10 +127,12 @@ def global_bh_indices(b_local: int, total_heads: int, h_local: int,
             + h_base + jnp.arange(h_local)[None, :]).astype(jnp.uint32)
 
 
-def _apply_causal_mask(s, q_start, k_start, offset, block_q, block_k):
+def _apply_causal_mask(s, q_start, k_start, offset, block_q, block_k,
+                       window: Optional[int] = None):
     """Causal mask for one (block_q, block_k) score tile. ``offset`` aligns
     rectangular shapes the same way the einsum core's ``tril(k=sk-sq)`` does:
-    query i attends keys j with j <= i + offset."""
+    query i attends keys j with j <= i + offset. With a sliding ``window``
+    key j is visible to query i iff i - window < j <= i."""
     import jax
     import jax.numpy as jnp
 
@@ -138,7 +140,57 @@ def _apply_causal_mask(s, q_start, k_start, offset, block_q, block_k):
                                                (block_q, block_k), 0)
     k_pos = k_start + jax.lax.broadcasted_iota(jnp.int32,
                                                (block_q, block_k), 1)
-    return jnp.where(q_pos + offset >= k_pos, s, NEG_INF)
+    if window is None:
+        return jnp.where(q_pos + offset >= k_pos, s, NEG_INF)
+    return jnp.where((q_pos >= k_pos) & (q_pos - k_pos < window), s, NEG_INF)
+
+
+# ---- the sliding window's band, in tiles. The windowed kernels walk only
+# the tiles that meet the band: their inner grid dimension has the band's
+# width in tiles (``_band_tiles``), grid step ``j`` stands for tile
+# ``lo + j``, and steps past ``hi`` do nothing — their index maps clamp to
+# ``hi``, so Pallas fetches no new tile for them.
+def _band_kb(q_idx, block_q: int, block_k: int, window: int):
+    """(first, last) key tile that query tile ``q_idx`` sees."""
+    import jax.numpy as jnp
+
+    lo = jnp.maximum(q_idx * block_q - (window - 1), 0) // block_k
+    hi = (q_idx * block_q + block_q - 1) // block_k
+    return lo, hi
+
+
+def _band_qb(kb, block_q: int, block_k: int, window: int, num_qb: int):
+    """(first, last) query tile that sees key tile ``kb``."""
+    import jax.numpy as jnp
+
+    lo = (kb * block_k) // block_q
+    hi = jnp.minimum((kb * block_k + block_k - 1 + window - 1) // block_q,
+                     num_qb - 1)
+    return lo, hi
+
+
+def _band_tiles(outer: int, inner: int, outer_block: int, inner_block: int,
+                window: int, keys_inner: bool) -> int:
+    """Static width of the band in inner tiles: the most inner tiles any
+    outer tile meets (``keys_inner``: outer = query tiles, inner = key
+    tiles; else the reverse)."""
+    widest = 0
+    for t in range(outer):
+        if keys_inner:
+            lo = max(t * outer_block - (window - 1), 0) // inner_block
+            hi = (t * outer_block + outer_block - 1) // inner_block
+        else:
+            lo = (t * outer_block) // inner_block
+            hi = min((t * outer_block + outer_block - 1 + window - 1)
+                     // inner_block, inner - 1)
+        widest = max(widest, hi - lo + 1)
+    return widest
+
+
+def _kv_head(h, group: int):
+    """The K/V head that query head ``h`` reads (grouped-query attention:
+    ``group`` query heads share one)."""
+    return h if group == 1 else h // group
 
 
 def _tile_contributes(q_idx, kb, block_q, block_k, offset):
@@ -159,7 +211,8 @@ def _first_contributing_qb(kb, block_q, block_k, offset):
 def _flash_fwd_kernel(seed_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
                       m_scr, l_scr, acc_scr, *, num_kb: int, causal: bool,
                       causal_offset: int = 0,
-                      dropout: float = 0.0, num_heads: int = 1):
+                      dropout: float = 0.0, num_heads: int = 1,
+                      window: Optional[int] = None):
     """Grid (batch, head, q_block, k_block), k innermost: one (q, k) score
     tile per program, online-softmax state (m, l, acc) carried across the k
     grid dimension in VMEM scratch (m/l lane-replicated to (block_q, 128)
@@ -180,8 +233,14 @@ def _flash_fwd_kernel(seed_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
     bh = pl.program_id(0) * num_heads + pl.program_id(1)
     block_q = q_ref.shape[2]
     block_k = k_ref.shape[2]
+    # ``num_kb`` is the k grid's extent: with a window, the band's width in
+    # tiles, and grid step ``step`` stands for key tile ``lo + step``
+    step = kb
+    if window is not None:
+        lo, hi = _band_kb(q_idx, block_q, block_k, window)
+        kb = lo + step
 
-    if num_kb == 1:
+    if num_kb == 1 and window is None:
         # single k block: the whole softmax row is in registers — skip the
         # scratch round-trip entirely (measured ~0.1 ms/layer at b8 s512)
         q = q_ref[0, 0]
@@ -204,7 +263,7 @@ def _flash_fwd_kernel(seed_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
         lse_ref[0, 0] = (m + jnp.log(l_safe)).astype(lse_ref.dtype)
         return
 
-    @pl.when(kb == 0)
+    @pl.when(step == 0)
     def _init():
         m_scr[...] = jnp.full(m_scr.shape, NEG_INF, jnp.float32)
         l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
@@ -217,7 +276,7 @@ def _flash_fwd_kernel(seed_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
         s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)
         if causal:
             s = _apply_causal_mask(s, q_idx * block_q, kb * block_k,
-                                   causal_offset, block_q, block_k)
+                                   causal_offset, block_q, block_k, window)
         m_prev = m_scr[...]  # (block_q, 128), lanes replicated
         l_prev = l_scr[...]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
@@ -235,7 +294,9 @@ def _flash_fwd_kernel(seed_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
         acc_scr[...] = acc_scr[...] * alpha[:, :1] + jnp.dot(
             p.astype(v.dtype), v, preferred_element_type=jnp.float32)
 
-    if causal:
+    if window is not None:
+        pl.when(kb <= hi)(_tile)
+    elif causal:
         @pl.when(_tile_contributes(q_idx, kb, block_q, block_k,
                                    causal_offset))
         def _run():
@@ -243,7 +304,7 @@ def _flash_fwd_kernel(seed_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
     else:
         _tile()
 
-    @pl.when(kb == num_kb - 1)
+    @pl.when(step == num_kb - 1)
     def _final():
         l = l_scr[:, :1]
         l_safe = jnp.where(l == 0.0, 1.0, l)
@@ -251,6 +312,12 @@ def _flash_fwd_kernel(seed_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
         # lse block is (block_q, 1): TPU tiling wants >=2-D blocks whose
         # minor dim matches the array
         lse_ref[0, 0] = (m_scr[:, :1] + jnp.log(l_safe)).astype(lse_ref.dtype)
+
+
+def _window_suffix(window: Optional[int]) -> str:
+    """The windowed kernels carry their own names, so that a trace tells
+    them from the full-attention ones."""
+    return "" if window is None else "_window"
 
 
 def _compiler_params(interpret: bool, semantics):
@@ -262,7 +329,8 @@ def _compiler_params(interpret: bool, semantics):
 
 
 def _flash_forward(q, k, v, causal: bool, block_q: int, block_k: int,
-                   interpret: bool, dropout: float = 0.0, seed=None):
+                   interpret: bool, dropout: float = 0.0, seed=None,
+                   window: Optional[int] = None):
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
@@ -286,19 +354,28 @@ def _flash_forward(q, k, v, causal: bool, block_q: int, block_k: int,
         seed if seed is not None else 0, jnp.uint32), (1,))
 
     num_kb = seq_k // block_k
+    group = heads // k.shape[1]
+    kv_tile = lambda b, h, i, j: (b, _kv_head(h, group), j, 0)  # noqa: E731
+    if window is not None:
+        num_kb = _band_tiles(seq_q // block_q, num_kb, block_q, block_k,
+                             window, keys_inner=True)
+
+        def kv_tile(b, h, i, j):
+            lo, hi = _band_kb(i, block_q, block_k, window)
+            return (b, _kv_head(h, group), jnp.minimum(lo + j, hi), 0)
     grid = (batch, heads, seq_q // block_q, num_kb)
     kernel = functools.partial(_flash_fwd_kernel, num_kb=num_kb,
                                causal=causal,
                                causal_offset=seq_k - seq_q, dropout=dropout,
-                               num_heads=heads)
+                               num_heads=heads, window=window)
     out, lse = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
             pl.BlockSpec((1,), lambda b, h, i, j: (0,)),
             pl.BlockSpec((1, 1, block_q, d), lambda b, h, i, j: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, block_k, d), lambda b, h, i, j: (b, h, j, 0)),
-            pl.BlockSpec((1, 1, block_k, d), lambda b, h, i, j: (b, h, j, 0)),
+            pl.BlockSpec((1, 1, block_k, d), kv_tile),
+            pl.BlockSpec((1, 1, block_k, d), kv_tile),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, block_q, d), lambda b, h, i, j: (b, h, i, 0)),
@@ -316,7 +393,7 @@ def _flash_forward(q, k, v, causal: bool, block_q: int, block_k: int,
         compiler_params=_compiler_params(
             interpret, ("parallel", "parallel", "parallel", "arbitrary")),
         interpret=interpret,
-        name="flash_attention_fwd",
+        name="flash_attention_fwd" + _window_suffix(window),
     )(seed_arr, q, k, v)
     return out, lse.reshape(batch, heads, seq_q)
 
@@ -326,7 +403,8 @@ def _flash_bwd_fused_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                             block_q: int, seq_q: int, num_kb: int,
                             causal: bool, sm_scale: float,
                             causal_offset: int = 0, dropout: float = 0.0,
-                            num_heads: int = 1):
+                            num_heads: int = 1,
+                            window: Optional[int] = None):
     """Fused one-pass backward, grid (batch, head, k_block): K/V tiles
     stream through the grid while Q/dO/lse/O stay resident per (b, h);
     dq accumulates in a (seq_q, d) f32 scratch carried across the k grid
@@ -377,7 +455,7 @@ def _flash_bwd_fused_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
         s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)
         if causal:
             s = _apply_causal_mask(s, qb * block_q, kb * block_k,
-                                   causal_offset, block_q, block_k)
+                                   causal_offset, block_q, block_k, window)
         p = jnp.exp(s - lse)  # exact softmax probabilities from stored lse
         dp = jnp.dot(do, v.T, preferred_element_type=jnp.float32)
         if dropout > 0.0:
@@ -398,7 +476,11 @@ def _flash_bwd_fused_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                                    preferred_element_type=jnp.float32))
         return dk, dv
 
-    if causal:
+    if window is not None:
+        # only the query tiles of the band
+        qb_lo, qb_hi = _band_qb(kb, block_q, block_k, window, num_qb)
+        dk, dv = jax.lax.fori_loop(qb_lo, qb_hi + 1, body, (dk0, dv0))
+    elif causal:
         # the loop start is traced (depends on kb), so the static unroll
         # below does not apply; masked tiles would vanish numerically
         # (p == 0) but cost full compute, so keep the skip via fori_loop
@@ -425,7 +507,8 @@ def _flash_bwd_dkv_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                           delta_ref, dk_ref, dv_ref, dk_scr, dv_scr, *,
                           num_qb: int, causal: bool,
                           causal_offset: int = 0, dropout: float = 0.0,
-                          num_heads: int = 1):
+                          num_heads: int = 1, window: Optional[int] = None,
+                          total_qb: int = 0):
     """Two-pass schedule, dkv kernel: grid (batch, head, k_block, q_block),
     q innermost. K/V tiles are resident per k block; Q/dO/lse/delta tiles
     stream through the q grid dimension; (dk, dv) accumulate in VMEM scratch
@@ -441,8 +524,14 @@ def _flash_bwd_dkv_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
     v = v_ref[0, 0]
     block_k = k.shape[0]
     block_q = q_ref.shape[2]
+    # ``num_qb`` is the q grid's extent: with a window, the band's width in
+    # tiles, and grid step ``step`` stands for query tile ``lo + step``
+    step = qb
+    if window is not None:
+        lo, hi = _band_qb(kb, block_q, block_k, window, total_qb)
+        qb = lo + step
 
-    @pl.when(qb == 0)
+    @pl.when(step == 0)
     def _init():
         dk_scr[...] = jnp.zeros(dk_scr.shape, jnp.float32)
         dv_scr[...] = jnp.zeros(dv_scr.shape, jnp.float32)
@@ -455,7 +544,7 @@ def _flash_bwd_dkv_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
         s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)
         if causal:
             s = _apply_causal_mask(s, qb * block_q, kb * block_k,
-                                   causal_offset, block_q, block_k)
+                                   causal_offset, block_q, block_k, window)
         p = jnp.exp(s - lse)
         dp = jnp.dot(do, v.T, preferred_element_type=jnp.float32)
         if dropout > 0.0:
@@ -472,14 +561,16 @@ def _flash_bwd_dkv_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
         dk_scr[...] = dk_scr[...] + jnp.dot(
             ds.astype(q.dtype).T, q, preferred_element_type=jnp.float32)
 
-    if causal:
+    if window is not None:
+        pl.when(qb <= hi)(_tile)
+    elif causal:
         @pl.when(_tile_contributes(qb, kb, block_q, block_k, causal_offset))
         def _run():
             _tile()
     else:
         _tile()
 
-    @pl.when(qb == num_qb - 1)
+    @pl.when(step == num_qb - 1)
     def _final():
         dk_ref[0, 0] = dk_scr[...].astype(dk_ref.dtype)
         dv_ref[0, 0] = dv_scr[...].astype(dv_ref.dtype)
@@ -489,7 +580,7 @@ def _flash_bwd_dq_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                          delta_ref, dq_ref, dq_scr, *, num_kb: int,
                          causal: bool, sm_scale: float,
                          causal_offset: int = 0, dropout: float = 0.0,
-                         num_heads: int = 1):
+                         num_heads: int = 1, window: Optional[int] = None):
     """Two-pass schedule, dq kernel: grid (batch, head, q_block, k_block),
     k innermost. Q/dO/lse/delta resident per q block; K/V tiles stream
     through the k grid dimension; dq accumulates in scratch."""
@@ -501,8 +592,12 @@ def _flash_bwd_dq_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
     bh = pl.program_id(0) * num_heads + pl.program_id(1)
     block_q = q_ref.shape[2]
     block_k = k_ref.shape[2]
+    step = kb  # as in the forward kernel: with a window, tile ``lo + step``
+    if window is not None:
+        lo, hi = _band_kb(qb, block_q, block_k, window)
+        kb = lo + step
 
-    @pl.when(kb == 0)
+    @pl.when(step == 0)
     def _init():
         dq_scr[...] = jnp.zeros(dq_scr.shape, jnp.float32)
 
@@ -516,7 +611,7 @@ def _flash_bwd_dq_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
         s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)
         if causal:
             s = _apply_causal_mask(s, qb * block_q, kb * block_k,
-                                   causal_offset, block_q, block_k)
+                                   causal_offset, block_q, block_k, window)
         p = jnp.exp(s - lse)
         dp = jnp.dot(do, v.T, preferred_element_type=jnp.float32)
         if dropout > 0.0:
@@ -527,21 +622,27 @@ def _flash_bwd_dq_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
         dq_scr[...] = dq_scr[...] + jnp.dot(
             ds.astype(k.dtype), k, preferred_element_type=jnp.float32)
 
-    if causal:
+    if window is not None:
+        pl.when(kb <= hi)(_tile)
+    elif causal:
         @pl.when(_tile_contributes(qb, kb, block_q, block_k, causal_offset))
         def _run():
             _tile()
     else:
         _tile()
 
-    @pl.when(kb == num_kb - 1)
+    @pl.when(step == num_kb - 1)
     def _final():
         dq_ref[0, 0] = (dq_scr[...] * sm_scale).astype(dq_ref.dtype)
 
 
 def _flash_backward(q, k, v, out, lse, do, causal: bool, block_q: int,
                     block_k: int, interpret: bool, dropout: float = 0.0,
-                    seed=None, fused: Optional[bool] = None):
+                    seed=None, fused: Optional[bool] = None,
+                    window: Optional[int] = None):
+    """(dq, dk, dv). With fewer K/V heads than query heads the kernels write
+    dk/dv per QUERY head (each reads its group's K/V head), in float32, and
+    the group's sum is taken outside, in XLA."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
@@ -549,6 +650,16 @@ def _flash_backward(q, k, v, out, lse, do, causal: bool, block_q: int,
 
     batch, heads, seq_q, d = q.shape
     seq_k = k.shape[2]
+    group = heads // k.shape[1]
+    dkv_dtype = k.dtype if group == 1 else jnp.float32
+    suffix = _window_suffix(window)
+
+    def group_sum(dk, dv):
+        if group == 1:
+            return dk, dv
+        return tuple(g.reshape(batch, heads // group, group, seq_k, d)
+                     .sum(axis=2).astype(k.dtype) for g in (dk, dv))
+
     sm_scale = 1.0 / np.sqrt(d)
     # q pre-scaled as in the forward: the kernels recompute the identical s
     q = (q * np.float32(sm_scale)).astype(q.dtype)
@@ -572,68 +683,86 @@ def _flash_backward(q, k, v, out, lse, do, causal: bool, block_q: int,
         full_q = pl.BlockSpec((1, 1, seq_q, d), lambda b, h, j: (b, h, 0, 0))
         full_q1 = pl.BlockSpec((1, 1, seq_q, 1), lambda b, h, j: (b, h, 0, 0))
         tile_k = pl.BlockSpec((1, 1, block_k, d), lambda b, h, j: (b, h, j, 0))
+        tile_kv = tile_k if group == 1 else pl.BlockSpec(
+            (1, 1, block_k, d), lambda b, h, j: (b, h // group, j, 0))
         kernel = functools.partial(
             _flash_bwd_fused_kernel, block_q=block_q, seq_q=seq_q,
             num_kb=num_kb, causal=causal, sm_scale=sm_scale,
-            causal_offset=seq_k - seq_q, dropout=dropout, num_heads=heads)
+            causal_offset=seq_k - seq_q, dropout=dropout, num_heads=heads,
+            window=window)
         dq, dk, dv = pl.pallas_call(
             kernel,
             grid=(batch, heads, num_kb),
-            in_specs=[seed_spec, full_q, tile_k, tile_k, full_q, full_q1,
+            in_specs=[seed_spec, full_q, tile_kv, tile_kv, full_q, full_q1,
                       full_q],
             out_specs=[full_q, tile_k, tile_k],
             out_shape=[
                 jax.ShapeDtypeStruct((batch, heads, seq_q, d), q.dtype),
-                jax.ShapeDtypeStruct((batch, heads, seq_k, d), k.dtype),
-                jax.ShapeDtypeStruct((batch, heads, seq_k, d), v.dtype),
+                jax.ShapeDtypeStruct((batch, heads, seq_k, d), dkv_dtype),
+                jax.ShapeDtypeStruct((batch, heads, seq_k, d), dkv_dtype),
             ],
             scratch_shapes=[pltpu.VMEM((seq_q, d), jnp.float32)],
             compiler_params=_compiler_params(
                 interpret, ("parallel", "parallel", "arbitrary")),
             interpret=interpret,
-            name="flash_attention_bwd_fused",
+            name="flash_attention_bwd_fused" + suffix,
         )(seed_arr, q, k, v, dor, lser, out)
-        return dq, dk, dv
+        return (dq,) + group_sum(dk, dv)
 
     # two-pass streaming schedule: O(block) VMEM at any sequence length
     delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
                     axis=-1, keepdims=True)
-    tile_q_kv = pl.BlockSpec((1, 1, block_q, d),
-                             lambda b, h, j, i: (b, h, i, 0))
-    tile_q1_kv = pl.BlockSpec((1, 1, block_q, 1),
-                              lambda b, h, j, i: (b, h, i, 0))
+    q_of = lambda b, h, j, i: (b, h, i, 0)  # noqa: E731
+    kv_of = lambda b, h, i, j: (b, _kv_head(h, group), j, 0)  # noqa: E731
+    band_q, band_k = num_qb, num_kb  # the inner grids' extents
+    if window is not None:
+        band_q = _band_tiles(num_kb, num_qb, block_k, block_q, window,
+                             keys_inner=False)
+        band_k = _band_tiles(num_qb, num_kb, block_q, block_k, window,
+                             keys_inner=True)
+
+        def q_of(b, h, j, i):
+            lo, hi = _band_qb(j, block_q, block_k, window, num_qb)
+            return (b, h, jnp.minimum(lo + i, hi), 0)
+
+        def kv_of(b, h, i, j):
+            lo, hi = _band_kb(i, block_q, block_k, window)
+            return (b, _kv_head(h, group), jnp.minimum(lo + j, hi), 0)
+    tile_q_kv = pl.BlockSpec((1, 1, block_q, d), q_of)
+    tile_q1_kv = pl.BlockSpec((1, 1, block_q, 1), q_of)
     res_k = pl.BlockSpec((1, 1, block_k, d), lambda b, h, j, i: (b, h, j, 0))
+    res_kv = res_k if group == 1 else pl.BlockSpec(
+        (1, 1, block_k, d), lambda b, h, j, i: (b, h // group, j, 0))
     dkv_kernel = functools.partial(
-        _flash_bwd_dkv_kernel, num_qb=num_qb, causal=causal,
+        _flash_bwd_dkv_kernel, num_qb=band_q, causal=causal,
         causal_offset=seq_k - seq_q, dropout=dropout,
-        num_heads=heads)
+        num_heads=heads, window=window, total_qb=num_qb)
     dk, dv = pl.pallas_call(
         dkv_kernel,
-        grid=(batch, heads, num_kb, num_qb),
-        in_specs=[seed_spec, tile_q_kv, res_k, res_k, tile_q_kv, tile_q1_kv,
-                  tile_q1_kv],
+        grid=(batch, heads, num_kb, band_q),
+        in_specs=[seed_spec, tile_q_kv, res_kv, res_kv, tile_q_kv,
+                  tile_q1_kv, tile_q1_kv],
         out_specs=[res_k, res_k],
-        out_shape=[jax.ShapeDtypeStruct((batch, heads, seq_k, d), k.dtype),
-                   jax.ShapeDtypeStruct((batch, heads, seq_k, d), v.dtype)],
+        out_shape=[jax.ShapeDtypeStruct((batch, heads, seq_k, d), dkv_dtype),
+                   jax.ShapeDtypeStruct((batch, heads, seq_k, d), dkv_dtype)],
         scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
                         pltpu.VMEM((block_k, d), jnp.float32)],
         compiler_params=_compiler_params(
             interpret, ("parallel", "parallel", "parallel", "arbitrary")),
         interpret=interpret,
-        name="flash_attention_bwd_dkv",
+        name="flash_attention_bwd_dkv" + suffix,
     )(seed_arr, q, k, v, dor, lser, delta)
 
     res_q = pl.BlockSpec((1, 1, block_q, d), lambda b, h, i, j: (b, h, i, 0))
     res_q1 = pl.BlockSpec((1, 1, block_q, 1), lambda b, h, i, j: (b, h, i, 0))
-    tile_k_q = pl.BlockSpec((1, 1, block_k, d),
-                            lambda b, h, i, j: (b, h, j, 0))
+    tile_k_q = pl.BlockSpec((1, 1, block_k, d), kv_of)
     dq_kernel = functools.partial(
-        _flash_bwd_dq_kernel, num_kb=num_kb, causal=causal,
+        _flash_bwd_dq_kernel, num_kb=band_k, causal=causal,
         sm_scale=sm_scale, causal_offset=seq_k - seq_q, dropout=dropout,
-        num_heads=heads)
+        num_heads=heads, window=window)
     dq = pl.pallas_call(
         dq_kernel,
-        grid=(batch, heads, num_qb, num_kb),
+        grid=(batch, heads, num_qb, band_k),
         in_specs=[seed_spec, res_q, tile_k_q, tile_k_q, res_q, res_q1,
                   res_q1],
         out_specs=res_q,
@@ -642,35 +771,41 @@ def _flash_backward(q, k, v, out, lse, do, causal: bool, block_q: int,
         compiler_params=_compiler_params(
             interpret, ("parallel", "parallel", "parallel", "arbitrary")),
         interpret=interpret,
-        name="flash_attention_bwd_dq",
+        name="flash_attention_bwd_dq" + suffix,
     )(seed_arr, q, k, v, dor, lser, delta)
 
-    return dq, dk, dv
+    return (dq,) + group_sum(dk, dv)
 
 
-def _reference_core(q, k, v, causal: bool):
+def _reference_core(q, k, v, causal: bool, window: Optional[int] = None):
     import jax
     import jax.numpy as jnp
 
     d = q.shape[-1]
+    group = q.shape[1] // k.shape[1]
+    if group > 1:
+        k, v = (jnp.repeat(t, group, axis=1) for t in (k, v))
     s = jnp.einsum("bhqd,bhkd->bhqk", q, k,
                    preferred_element_type=jnp.float32) / np.sqrt(d)
     if causal:
         sq, sk = s.shape[-2], s.shape[-1]
         mask = jnp.tril(jnp.ones((sq, sk), bool), k=sk - sq)
+        if window is not None:
+            mask &= ~jnp.tril(jnp.ones((sq, sk), bool), k=sk - sq - window)
         s = jnp.where(mask, s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bhqk,bhkd->bhqd", p.astype(v.dtype), v,
                       preferred_element_type=jnp.float32).astype(v.dtype)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9, 10))
+@functools.partial(jax.custom_vjp,
+                   nondiff_argnums=(4, 5, 6, 7, 8, 9, 10, 11))
 def _flash_attention_p(q, k, v, seed, causal, block_q, block_k, interpret,
-                       dropout, bwd_block_q, bwd_block_k):
-    _check_causal_shape(q, k, causal)
+                       dropout, bwd_block_q, bwd_block_k, window):
+    _check_shapes(q, k, causal, window)
     out, _ = _flash_forward(q, k, v, causal, block_q, block_k,
                             _resolve_interpret(interpret),
-                            dropout=dropout, seed=seed)
+                            dropout=dropout, seed=seed, window=window)
     return out
 
 
@@ -722,8 +857,16 @@ def flash_attention(q, k, v, causal: bool = False,
                     interpret: Optional[bool] = None,
                     dropout: float = 0.0, seed=None,
                     bwd_block_q: Optional[int] = None,
-                    bwd_block_k: Optional[int] = None):
+                    bwd_block_k: Optional[int] = None,
+                    window: Optional[int] = None):
     """q,k,v: (batch, heads, seq, head_dim) -> (batch, heads, seq_q, head_dim).
+
+    Grouped-query attention: k and v may carry fewer heads than q, a divisor
+    of q's; query head ``n`` reads K/V head ``n // (q heads / kv heads)``,
+    and dk/dv are summed over the group. ``window`` (causal self-attention,
+    seq_q == seq_k): key j is visible to query i iff i - window < j <= i;
+    the kernels walk only the tiles of the band and are named
+    ``flash_attention_*_window``.
 
     seq_q/seq_k must be multiples of the block sizes (the attention op checks
     this before selecting the flash path, ops/attention.py). Causal requires
@@ -740,27 +883,38 @@ def flash_attention(q, k, v, causal: bool = False,
     dropout = float(dropout)
     seed = coerce_dropout_seed("flash_attention", dropout, seed)
     return _flash_attention_p(q, k, v, seed, causal, block_q, block_k,
-                              interpret, dropout, bwd_block_q, bwd_block_k)
+                              interpret, dropout, bwd_block_q, bwd_block_k,
+                              window)
 
 
-def _check_causal_shape(q, k, causal: bool) -> None:
+def _check_shapes(q, k, causal: bool, window: Optional[int] = None) -> None:
     if causal and q.shape[-2] > k.shape[-2]:
         raise ValueError(
             f"flash_attention causal requires seq_q <= seq_k, got "
             f"{q.shape[-2]} > {k.shape[-2]}; use the einsum core instead")
+    if q.shape[1] % k.shape[1]:
+        raise ValueError(
+            f"flash_attention: {q.shape[1]} query heads are no multiple of "
+            f"{k.shape[1]} key/value heads")
+    if window is not None and not (causal and q.shape[-2] == k.shape[-2]
+                                   and window >= 1):
+        raise ValueError(
+            "flash_attention: a sliding window needs causal self-attention "
+            f"(seq_q == seq_k) and window >= 1, got causal={causal}, "
+            f"seq_q={q.shape[-2]}, seq_k={k.shape[-2]}, window={window}")
 
 
 def _fwd(q, k, v, seed, causal, block_q, block_k, interpret, dropout,
-         bwd_block_q, bwd_block_k):
-    _check_causal_shape(q, k, causal)
+         bwd_block_q, bwd_block_k, window):
+    _check_shapes(q, k, causal, window)
     out, lse = _flash_forward(q, k, v, causal, block_q, block_k,
                               _resolve_interpret(interpret),
-                              dropout=dropout, seed=seed)
+                              dropout=dropout, seed=seed, window=window)
     return out, (q, k, v, seed, out, lse)
 
 
 def _bwd(causal, block_q, block_k, interpret, dropout, bwd_block_q,
-         bwd_block_k, res, do):
+         bwd_block_k, window, res, do):
     """Backward by recompute (never materializes the score matrix): blockwise
     Pallas kernels using the flash-attention backward identities, with exact
     probabilities reconstructed from the stored logsumexp (and the dropout
@@ -770,7 +924,7 @@ def _bwd(causal, block_q, block_k, interpret, dropout, bwd_block_q,
                          q.shape[-2], k.shape[-2], q.shape[-1])
     dq, dk, dv = _flash_backward(q, k, v, out, lse, do, causal, bq,
                                  bk, _resolve_interpret(interpret),
-                                 dropout=dropout, seed=seed)
+                                 dropout=dropout, seed=seed, window=window)
     return dq, dk, dv, None
 
 

@@ -84,14 +84,16 @@ class FFModel:
         op = op_class_for(op_type)(layer.name, attrs, dtype,
                                    num_inputs=len(inputs))
         out_shapes = op.infer_output_shapes([t.dims for t in inputs])
-        out_dtype = op.output_dtype([t.dtype for t in inputs])
+        out_dtypes = op.output_dtypes([t.dtype for t in inputs],
+                                      len(out_shapes))
         # surface declared weights as user-visible tensors (reference parity)
         for wname, (shape, wdtype, init) in op.weight_specs(
                 [t.dims for t in inputs]).items():
             layer.add_weight(wname, shape, wdtype, init)
         outs = []
         for i, s in enumerate(out_shapes):
-            t = Tensor(s, out_dtype, owner_layer=layer, owner_idx=i, model=self)
+            t = Tensor(s, out_dtypes[i], owner_layer=layer, owner_idx=i,
+                       model=self)
             t.name = f"{layer.name}:out{i}"
             outs.append(t)
         layer.outputs = outs
@@ -183,14 +185,35 @@ class FFModel:
                             bias: bool = True, add_bias_kv: bool = False,
                             add_zero_attn: bool = False,
                             kernel_initializer=None, causal: bool = False,
-                            name: Optional[str] = None) -> Tensor:
-        return self._add_layer(
-            OperatorType.OP_MULTIHEAD_ATTENTION, [query, key, value],
-            {"embed_dim": embed_dim, "num_heads": num_heads, "kdim": kdim,
-             "vdim": vdim, "dropout": dropout, "bias": bias,
-             "add_bias_kv": add_bias_kv, "add_zero_attn": add_zero_attn,
-             "kernel_initializer": kernel_initializer, "causal": causal},
-            query.dtype, name)
+                            name: Optional[str] = None,
+                            num_kv_heads: Optional[int] = None,
+                            window: Optional[int] = None,
+                            rope_theta: Optional[float] = None,
+                            qk_norm: Optional[float] = None,
+                            gated: bool = False) -> Tensor:
+        """The decoder-block attributes after ``name`` are off by default
+        (ops/attention.py): ``num_kv_heads`` grouped-query K/V heads,
+        ``window`` a causal sliding window, ``rope_theta`` rotary positions,
+        ``qk_norm`` the eps of a per-head RMS norm on q and k, ``gated`` a
+        sigmoid gate on the core's output."""
+        attrs = {"embed_dim": embed_dim, "num_heads": num_heads, "kdim": kdim,
+                 "vdim": vdim, "dropout": dropout, "bias": bias,
+                 "add_bias_kv": add_bias_kv, "add_zero_attn": add_zero_attn,
+                 "kernel_initializer": kernel_initializer, "causal": causal}
+        for attr, given in (("num_kv_heads", num_kv_heads),
+                            ("window", window), ("rope_theta", rope_theta),
+                            ("qk_norm", qk_norm), ("gated", gated)):
+            if given:
+                attrs[attr] = given
+        if window and not causal:
+            raise ValueError("multihead_attention: a sliding window needs "
+                             "causal=True")
+        if num_kv_heads and num_heads % num_kv_heads:
+            raise ValueError(
+                f"multihead_attention: {num_heads} heads are no multiple of "
+                f"{num_kv_heads} key/value heads")
+        return self._add_layer(OperatorType.OP_MULTIHEAD_ATTENTION,
+                               [query, key, value], attrs, query.dtype, name)
 
     # ---- elementwise ----------------------------------------------------------
     def _binary(self, op_type, x, y, name=None, inplace_a=False):
@@ -268,6 +291,17 @@ class FFModel:
 
     def gelu(self, x, name=None):
         return self._unary(OperatorType.OP_GELU, x, name=name)
+
+    def silu(self, x, name=None):
+        return self._unary(OperatorType.OP_SILU, x, name=name)
+
+    def gated_mlp(self, x, intermediate: int, kernel_initializer=None,
+                  name=None) -> Tensor:
+        """``W_down(silu(W_gate x) * W_up x)``, no biases, as one node
+        (ops/linear.py GatedMLPOp)."""
+        return self._unary(OperatorType.OP_GATED_MLP, x,
+                           {"intermediate": intermediate,
+                            "kernel_initializer": kernel_initializer}, name)
 
     def dropout(self, x, rate: float = 0.5, seed: int = 0, name=None):
         return self._unary(OperatorType.OP_DROPOUT, x,
@@ -462,7 +496,82 @@ class FFModel:
         return self.aggregate(topk_values, topk_assign, topk_assign, gate,
                               [exp_out], num_exp, lambda_bal)
 
+    def routed_experts(self, input: Tensor, num_experts: int, k: int,
+                       intermediate: int, held=None, route_norm: bool = True,
+                       route_scale: float = 1.0, kernel_initializer=None,
+                       name: str = "moe") -> Tensor:
+        """The dropless routed expert layer (ops/moe_ops.py): router
+        (sigmoid scores) -> dispatch by a stable sort on expert id -> grouped products over the
+        experts held here -> combine; no token is dropped, whatever the
+        routing. ``held=(first, count)`` names the experts of
+        ``num_experts`` this device holds (default: all); the router ranks
+        all of them and the output is the held experts' partial sum. The
+        four nodes are ``<name>router``, ``<name>dispatch``,
+        ``<name>experts`` and ``<name>combine``."""
+        held = tuple(held) if held is not None else (0, num_experts)
+        if not (0 <= held[0] and held[1] >= 1
+                and held[0] + held[1] <= num_experts):
+            raise ValueError(f"routed_experts: held={held} is no range of "
+                             f"{num_experts} experts")
+        ids = {"num_experts": num_experts, "held": held}
+        weights, chosen = self._add_layer(
+            OperatorType.OP_MOE_ROUTER, [input],
+            dict(ids, k=k, route_norm=route_norm,
+                 route_scale=route_scale,
+                 kernel_initializer=kernel_initializer),
+            input.dtype, f"{name}router")
+        rows, sizes, order = self._add_layer(
+            OperatorType.OP_MOE_DISPATCH, [input, chosen], dict(ids),
+            input.dtype, f"{name}dispatch")
+        out = self._add_layer(
+            OperatorType.OP_MOE_ROUTED_EXPERTS, [rows, sizes],
+            dict(ids, intermediate=intermediate,
+                 kernel_initializer=kernel_initializer),
+            input.dtype, f"{name}experts")
+        return self._add_layer(
+            OperatorType.OP_MOE_COMBINE, [out, order, weights, chosen],
+            dict(ids), input.dtype, f"{name}combine")
+
     # ======================================================== observability ==
+    def routing_stats(self) -> Dict[str, Any]:
+        """The routed expert layers' counters over the last ``fit``, summed
+        over its steps and fetched with its epoch metrics (no sync of their
+        own): per dispatch node ``tokens_per_expert`` (one count per held
+        expert), ``pairs_here`` and ``dropped`` (0: the layer drops none),
+        and ``steps``. Empty for a model with no routed layer."""
+        return {k: dict(v) for k, v in
+                (getattr(self, "_routing_stats", None) or {}).items()}
+
+    def _fold_op_stats(self, stats) -> None:
+        """Add one step's op counters (fetched with its metrics) to the
+        fit's totals."""
+        for name, counters in (stats or {}).items():
+            total = self._routing_stats.setdefault(name, {"steps": 0})
+            total["steps"] += 1
+            for key, value in counters.items():
+                value = np.asarray(value).astype(np.int64)
+                total[key] = total[key] + value if key in total else value
+
+    def _routing_digest(self) -> Dict[str, Any]:
+        """``routing_stats`` as the ints and short strings a span carries
+        (the ``epoch_fold`` span's arguments, docs/observability.md): over
+        the fit so far, the pairs held here, the pairs dropped, the
+        (layer, expert) counters, the most loaded expert's tokens over its
+        layer's mean in thousandths (the largest over the layers), and the
+        held experts' tokens summed over the layers."""
+        layers = list(self._routing_stats.values())
+        tokens = [layer["tokens_per_expert"] for layer in layers]
+        return {
+            "moe_pairs_here": int(sum(layer["pairs_here"]
+                                      for layer in layers)),
+            "moe_dropped": int(sum(layer["dropped"] for layer in layers)),
+            "moe_expert_counters": int(sum(t.size for t in tokens)),
+            "moe_load_max_permille": int(max(
+                1000.0 * t.max() / max(t.mean(), 1e-9) for t in tokens)),
+            "moe_tokens_per_expert": ",".join(
+                str(int(v)) for v in np.sum(tokens, axis=0)),
+        }
+
     def _obs_tracer(self):
         """The process tracer, auto-enabled the first time when the config
         asks for a trace file (obs stays a no-op singleton otherwise)."""
@@ -957,6 +1066,7 @@ class FFModel:
         from .obs.trace import span, step_span
 
         self._input_stats = new_input_stats()
+        self._routing_stats: Dict[str, Dict[str, Any]] = {}
         in_shardings = [self.executor.batch_sharding(a.ndim) for a in xs]
         label_sharding = self.executor.batch_sharding(y.ndim)
 
@@ -1137,9 +1247,12 @@ class FFModel:
                     # ONE host transfer for the whole epoch instead of a blocking
                     # int()/float() per scalar per step
                     if epoch_metrics:
-                        with span("epoch_fold", tracer=tracer):
+                        with span("epoch_fold", tracer=tracer) as fold:
                             for m in jax.device_get(epoch_metrics):
                                 self._perf.update(m)
+                                self._fold_op_stats(m.get("op_stats"))
+                            if self._routing_stats:
+                                fold.set_metadata(**self._routing_digest())
                     if telemetry is not None and not (rolled_back or preempted
                                                       or recompiled):
                         loss_f = (float(loss_val) if loss_val is not None

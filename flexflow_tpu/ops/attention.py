@@ -24,13 +24,19 @@ from .base import Op, OpContext, register_op
 
 def mha_core(q, k, v, *, causal: bool = False, dropout: float = 0.0,
              rng=None, training: bool = False, attn_mask=None,
-             scale: float = None):
+             scale: float = None, window: int = None):
     """q,k,v: (batch, heads, seq, head_dim) -> (batch, heads, seq_q, head_dim).
-    attn_mask: optional additive mask broadcastable to (b, h, seq_q, seq_k)."""
+    attn_mask: optional additive mask broadcastable to (b, h, seq_q, seq_k).
+    k/v may carry fewer heads than q (grouped-query: query head n reads K/V
+    head n // group); ``window`` (causal): key j is visible to query i iff
+    i - window < j <= i."""
     import jax
     import jax.numpy as jnp
 
     head_dim = q.shape[-1]
+    group = q.shape[1] // k.shape[1]
+    if group > 1:
+        k, v = (jnp.repeat(t, group, axis=1) for t in (k, v))
     scale = scale if scale is not None else 1.0 / np.sqrt(head_dim)
     logits = jnp.einsum("bhqd,bhkd->bhqk", q, k,
                         preferred_element_type=jnp.float32) * scale
@@ -43,6 +49,9 @@ def mha_core(q, k, v, *, causal: bool = False, dropout: float = 0.0,
     if causal:
         sq, sk = logits.shape[-2], logits.shape[-1]
         mask = jnp.tril(jnp.ones((sq, sk), dtype=bool), k=sk - sq)
+        if window is not None:
+            mask &= ~jnp.tril(jnp.ones((sq, sk), dtype=bool),
+                              k=sk - sq - window)
         logits = jnp.where(mask, logits, -1e30)
     probs = jax.nn.softmax(logits, axis=-1)
     if training and dropout > 0.0 and rng is not None:
@@ -58,6 +67,13 @@ class MultiHeadAttentionOp(Op):
     """attrs: embed_dim, num_heads, kdim, vdim, dropout, bias, add_bias_kv,
     add_zero_attn, causal, use_flash (builder: FFModel::multihead_attention,
     reference model.h:520-537).
+
+    Decoder-block attributes, each absent by default (the op is then the one
+    above): ``num_kv_heads`` (grouped-query: fewer K/V heads than query
+    heads), ``window`` (causal sliding window), ``rope_theta`` (rotary
+    positions on q and k, rotate-half pairing), ``qk_norm`` (an RMS norm
+    per head on q and on k, one gain vector each; its value is the eps),
+    ``gated`` (the output of the core times sigmoid(x Wg), per head).
 
     inputs: (query, key, value), each (batch, seq, dim).
     output: (batch, seq_q, embed_dim).
@@ -84,17 +100,28 @@ class MultiHeadAttentionOp(Op):
         k_in = input_shapes[1][-1]
         v_in = input_shapes[2][-1]
         init = self.attrs.get("kernel_initializer") or DefaultWeightInitializer()
+        kv_heads = self.attrs.get("num_kv_heads") or heads
         specs = {
             "wq": ((q_in, heads, kdim), self.data_type, init),
-            "wk": ((k_in, heads, kdim), self.data_type, init),
-            "wv": ((v_in, heads, vdim), self.data_type, init),
+            "wk": ((k_in, kv_heads, kdim), self.data_type, init),
+            "wv": ((v_in, kv_heads, vdim), self.data_type, init),
             "wo": ((heads, vdim, embed), self.data_type, init),
         }
         if self.attrs.get("bias", True):
             specs["bo"] = ((embed,), self.data_type, DefaultBiasInitializer())
+        if self.attrs.get("gated"):
+            specs["wg"] = ((q_in, heads, vdim), self.data_type, init)
+        if self.attrs.get("qk_norm"):
+            from ..execution.initializers import ConstantInitializer
+
+            specs["q_norm"] = ((kdim,), self.data_type,
+                               ConstantInitializer(1.0))
+            specs["k_norm"] = ((kdim,), self.data_type,
+                               ConstantInitializer(1.0))
         return specs
 
     def forward(self, params, inputs, ctx: OpContext):
+        import jax
         import jax.numpy as jnp
 
         q_in, k_in, v_in = inputs
@@ -106,19 +133,43 @@ class MultiHeadAttentionOp(Op):
         q = jnp.einsum("bsd,dhk->bhsk", q_in, params["wq"])
         k = jnp.einsum("bsd,dhk->bhsk", k_in, params["wk"])
         v = jnp.einsum("bsd,dhk->bhsk", v_in, params["wv"])
+        if self.attrs.get("qk_norm"):
+            eps = float(self.attrs["qk_norm"])
+            q = _head_rms_norm(q, params["q_norm"], eps)
+            k = _head_rms_norm(k, params["k_norm"], eps)
+        if self.attrs.get("rope_theta"):
+            with jax.named_scope(_inner_scope(self.name, "rope")):
+                q, k = (_rotate_half_rope(t, float(self.attrs["rope_theta"]))
+                        for t in (q, k))
+        window = self.attrs.get("window")
         use_flash = self.attrs.get("use_flash", "auto")
         causal = self.attrs.get("causal", False)
         seq_axis = self.attrs.get("sequence_parallel_axis")
         dropout = self.attrs.get("dropout", 0.0)
         live_dropout = _resolve_live_dropout(dropout, ctx)
         seed = _dropout_seed(ctx.rng) if live_dropout else None
+        plain = k.shape[1] == q.shape[1] and window is None
         if ctx.serving is not None:
             # serving engine prefill/decode (ISSUE 6): the KV pool is
             # the execution path, selected before any kernel routing —
             # decode shapes (seq 1) must never reach flash/ring
+            if not plain or self.attrs.get("rope_theta"):
+                raise NotImplementedError(
+                    f"{self.name}: the serving path holds as many K/V heads "
+                    "as query heads, whole-context attention and learned "
+                    "positions; grouped K/V heads, a sliding window and "
+                    "rotary positions run on the training path only "
+                    "(ROADMAP.md, Reach R1)")
             out = _serving_attention(self.name, q, k, v, ctx.serving,
                                      causal=causal)
         elif seq_axis and ctx.mesh is not None and seq_axis in ctx.mesh.shape:
+            if not plain:
+                raise NotImplementedError(
+                    f"{self.name}: sequence-parallel attention (ring, "
+                    "all-to-all) holds as many K/V heads as query heads and "
+                    "whole-context attention; a strategy that shards the "
+                    "sequence of a grouped or windowed attention is refused "
+                    "rather than run unsharded")
             if self.attrs.get("sequence_parallel_mode") == "alltoall":
                 from ..kernels.ulysses_attention import ulysses_attention
 
@@ -134,14 +185,18 @@ class MultiHeadAttentionOp(Op):
         elif _should_use_flash(use_flash, q, k, causal) \
                 and _flash_blocks(q.shape[-2], k.shape[-2]) is not None:
             out = _flash_on_mesh(q, k, v, causal, live_dropout, seed,
-                                 ctx.mesh)
+                                 ctx.mesh, window=window)
         else:
             # the already-resolved live_dropout is the single gate (the r5
             # warning path); rng only rides along when dropout is live, so
             # _resolve_live_dropout cannot be second-guessed downstream
             out = mha_core(q, k, v, causal=causal, dropout=live_dropout,
                            rng=ctx.rng if live_dropout else None,
-                           training=ctx.training)
+                           training=ctx.training, window=window)
+        if "wg" in params:
+            gate = jnp.einsum("bsd,dhk->bhsk", q_in, params["wg"])
+            out = out * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(
+                out.dtype)
         y = jnp.einsum("bhsv,hvd->bsd", out, params["wo"],
                        preferred_element_type=jnp.float32).astype(q_in.dtype)
         if "bo" in params:
@@ -152,18 +207,67 @@ class MultiHeadAttentionOp(Op):
         b, sq, _ = input_shapes[0]
         sk = input_shapes[1][1]
         embed, heads, kdim, vdim = self._dims()
-        proj = 2 * b * sq * input_shapes[0][-1] * heads * kdim * 3 \
+        kv_heads = self.attrs.get("num_kv_heads") or heads
+        q_like = 2 if self.attrs.get("gated") else 1  # wq and the gate's wg
+        proj = 2 * b * sq * input_shapes[0][-1] * (
+            q_like * heads * kdim + kv_heads * (kdim + vdim)) \
             + 2 * b * sq * heads * vdim * embed
-        core = 2 * b * heads * sq * sk * (kdim + vdim)
-        return proj + core
+        window = self.attrs.get("window")
+        if window and self.attrs.get("causal"):
+            w = min(window, sq)  # the band: a triangle, then w keys a row
+            pairs = w * (w + 1) // 2 + (sq - w) * w
+        else:  # a causal mask is not discounted, as before
+            pairs = sq * sk
+        return proj + 2 * b * heads * pairs * (kdim + vdim)
 
     def parallelizable_dims(self, input_shapes):
+        weights = {"wq": 1, "wk": 1, "wv": 1, "wo": 0}
+        if self.attrs.get("gated"):
+            weights["wg"] = 1
         return {
             "batch": True,
             # head (attribute) parallelism: shard heads dim of all projections
-            "heads": {"weights": {"wq": 1, "wk": 1, "wv": 1, "wo": 0},
-                      "reduces_output": True},
+            # (with grouped K/V heads a shard holds whole groups: the degree
+            # divides num_kv_heads)
+            "heads": {"weights": weights, "reduces_output": True},
         }
+
+
+def _inner_scope(node_name: str, what: str) -> str:
+    """A scope inside a node that a trace reads as a node's: ``l3_attn_17``
+    -> ``l3_attnrope`` (the layer prefix kept, so that the breakdown groups
+    it by layer kind as it groups nodes)."""
+    import re
+
+    m = re.match(r"([a-z]+\d+_[a-z][a-z0-9]*)", node_name)
+    return f"{m.group(1)}{what}" if m else what
+
+
+def _head_rms_norm(x, gain, eps: float):
+    """RMS norm over head_dim of (batch, heads, seq, head_dim), in float32,
+    one gain vector for all heads."""
+    import jax
+    import jax.numpy as jnp
+
+    xf = x.astype(jnp.float32)
+    y = xf * jax.lax.rsqrt(jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
+                           + eps)
+    return (y * gain.astype(jnp.float32)).astype(x.dtype)
+
+
+def _rotate_half_rope(x, theta: float):
+    """Rotary positions on (batch, heads, seq, head_dim), rotate-half pairing
+    (dim i with dim i + head_dim/2), positions 0..seq-1, angles in float32."""
+    import jax.numpy as jnp
+
+    seq, d = x.shape[-2], x.shape[-1]
+    inv_freq = 1.0 / (theta ** (np.arange(0, d, 2, dtype=np.float32) / d))
+    ang = jnp.arange(seq, dtype=jnp.float32)[:, None] * inv_freq[None, :]
+    cos = jnp.concatenate([jnp.cos(ang), jnp.cos(ang)], axis=-1)
+    sin = jnp.concatenate([jnp.sin(ang), jnp.sin(ang)], axis=-1)
+    xf = x.astype(jnp.float32)
+    rot = jnp.concatenate([-xf[..., d // 2:], xf[..., :d // 2]], axis=-1)
+    return (xf * cos + rot * sin).astype(x.dtype)
 
 
 def _serving_attention(name: str, q, k, v, sv, *, causal: bool):
@@ -447,7 +551,7 @@ def _should_use_flash(use_flash, q, k, causal) -> bool:
     return False
 
 
-def _flash_on_mesh(q, k, v, causal, dropout, seed, mesh):
+def _flash_on_mesh(q, k, v, causal, dropout, seed, mesh, window=None):
     """The flash kernel on whatever mesh the step runs over. Mosaic kernels
     cannot be partitioned automatically (lowering one with a sharded operand
     raises), so on more than one device the call rides a ``shard_map``.
@@ -465,11 +569,11 @@ def _flash_on_mesh(q, k, v, causal, dropout, seed, mesh):
     bq, bk = _flash_blocks(q.shape[-2], k.shape[-2])
     if mesh is None or mesh.devices.size == 1:
         return flash_attention(q, k, v, causal, bq, bk, dropout=dropout,
-                               seed=seed)
+                               seed=seed, window=window)
     from jax.sharding import PartitionSpec as P
 
     batch_axes, head_axes = [], []
-    b, h = q.shape[0], q.shape[1]
+    b, h = q.shape[0], k.shape[1]  # heads split in whole K/V groups
     for axis, size in mesh.shape.items():
         if axis == "data" and b % size == 0:
             batch_axes.append(axis)
@@ -487,7 +591,7 @@ def _flash_on_mesh(q, k, v, causal, dropout, seed, mesh):
                     jax.lax.axis_index(axis).astype(jnp.uint32)
             seed = seed + shard * jnp.uint32(0x9E3779B1)
         return flash_attention(q, k, v, causal, bq, bk, dropout=dropout,
-                               seed=seed if dropout else None)
+                               seed=seed if dropout else None, window=window)
 
     return jax.shard_map(
         local, mesh=mesh, in_specs=(spec, spec, spec, P()), out_specs=spec,

@@ -56,6 +56,11 @@ class OpContext:
     # and plain whole-sequence inference, which is the only cost the
     # existing paths pay.
     serving: Any = None
+    # counters an op hands out of the step with no sync (the routed expert
+    # layer's tokens per held expert): {op_name: {counter: array}}, filled
+    # during the forward, returned with the step's metrics and fetched where
+    # ``fit`` fetches those (FFModel.routing_stats). None outside training.
+    stats_out: Any = None
 
 
 # registry: OperatorType -> Op subclass

@@ -102,6 +102,7 @@ SigmoidOp = _make_unary(OperatorType.OP_SIGMOID, "jnn.sigmoid", "SigmoidOp")
 TanhOp = _make_unary(OperatorType.OP_TANH, "jnp.tanh", "TanhOp")
 EluOp = _make_unary(OperatorType.OP_ELU, "jnn.elu", "EluOp")
 GeluOp = _make_unary(OperatorType.OP_GELU, "jnn.gelu", "GeluOp")
+SiluOp = _make_unary(OperatorType.OP_SILU, "jnn.silu", "SiluOp")
 ExpOp = _make_unary(OperatorType.OP_EXP, "jnp.exp", "ExpOp")
 LogOp = _make_unary(OperatorType.OP_LOG, "jnp.log", "LogOp")
 SinOp = _make_unary(OperatorType.OP_SIN, "jnp.sin", "SinOp")

@@ -68,6 +68,8 @@ def apply_activation(x, activation: ActiMode):
         return jnp.tanh(x)
     if activation == ActiMode.AC_MODE_GELU:
         return jnn.gelu(x)
+    if activation == ActiMode.AC_MODE_SILU:
+        return jnn.silu(x)
     raise ValueError(f"unknown activation {activation}")
 
 
@@ -141,5 +143,48 @@ class LinearOp(Op):
                             "weights": {"kernel": 1, "bias": 0}},
             # shard in_dim (row-parallel): kernel dim 0; output unreduced -> psum
             "channel_in": {"input_dim": ndim - 1, "weights": {"kernel": 0},
+                           "reduces_output": True},
+        }
+
+
+@register_op(OperatorType.OP_GATED_MLP)
+class GatedMLPOp(Op):
+    """The gated (SwiGLU) MLP as one node: ``W_down(silu(W_gate x) * W_up x)``,
+    no biases. attrs: intermediate, kernel_initializer. Weights ``gate`` and
+    ``up`` (in_dim, intermediate), ``down`` (intermediate, in_dim); the
+    product with the gate is taken in float32."""
+
+    def infer_output_shapes(self, input_shapes):
+        return [tuple(input_shapes[0])]
+
+    def weight_specs(self, input_shapes):
+        from ..execution.initializers import DefaultWeightInitializer
+
+        h, i = input_shapes[0][-1], self.attrs["intermediate"]
+        init = self.attrs.get("kernel_initializer") \
+            or DefaultWeightInitializer()
+        return {"gate": ((h, i), self.data_type, init),
+                "up": ((h, i), self.data_type, init),
+                "down": ((i, h), self.data_type, init)}
+
+    def forward(self, params, inputs, ctx: OpContext):
+        import jax.numpy as jnp
+
+        (x,) = inputs
+        g = jnp.dot(x, params["gate"], preferred_element_type=jnp.float32)
+        u = jnp.dot(x, params["up"], preferred_element_type=jnp.float32)
+        a = (jax.nn.silu(g) * u).astype(x.dtype)
+        return [jnp.dot(a, params["down"],
+                        preferred_element_type=jnp.float32).astype(x.dtype)]
+
+    def flops(self, input_shapes, output_shapes):
+        return 6 * int(np.prod(input_shapes[0])) * self.attrs["intermediate"]
+
+    def parallelizable_dims(self, input_shapes):
+        return {
+            "batch": True,
+            # tensor parallelism over the intermediate width: gate/up by
+            # columns, down by rows; the output is a partial sum
+            "channel_in": {"weights": {"gate": 1, "up": 1, "down": 0},
                            "reduces_output": True},
         }

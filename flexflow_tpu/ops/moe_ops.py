@@ -307,3 +307,283 @@ class CacheOp(Op):
                 return [jnp.where(use_cache, cached.astype(fresh.dtype),
                                   fresh)]
         return [fresh]
+
+
+# ------------------------------------------------- the dropless routed layer
+# Beside the fixed-capacity path above (which drops a token when its
+# expert's buffer is full), a routed layer that drops none, as four nodes:
+#
+#   router   x -> (weights (.., k) f32, chosen (.., k) int32): sigmoid or
+#            softmax scores over ALL ``num_experts``, top-k of score + bias
+#            (the bias selects and takes no gradient), the chosen scores
+#            normalised and scaled
+#   dispatch (x, chosen) -> rows sorted by expert id (a stable sort of the
+#            (token, slot) pairs), the held experts' group sizes, and the
+#            sort's permutation with its inverse
+#   experts  (rows, group sizes) -> a gated MLP per held expert, as grouped
+#            matrix products (``jax.lax.ragged_dot``)
+#   combine  (expert rows, permutation, weights, chosen) -> (.., d)
+#
+# ``held=(first, count)``: the layer is told which experts of ``num_experts``
+# this device holds (expert parallelism's share). The router ranks all of
+# them and normalises over all k chosen; dispatch, experts and combine see
+# only the pairs whose expert is held, and the layer's output is that
+# partial sum — the other shares are other devices'. The row buffer has
+# tokens * k rows, the case of every pair landing here, so no routing can
+# overflow it; rows past the held pairs belong to no group and are masked
+# wherever they are read.
+def _held(attrs):
+    first, count = attrs["held"]
+    return int(first), int(count)
+
+
+def _held_pairs(chosen_flat, first: int, count: int):
+    """(local expert id or ``count`` for a pair not held here, held mask)."""
+    import jax.numpy as jnp
+
+    local = chosen_flat - first
+    here = (local >= 0) & (local < count)
+    return jnp.where(here, local, count), here
+
+
+def _gather_rows(x, idx, back, fan: int):
+    """``x[idx // fan]`` whose backward is a gather too: ``idx`` is a
+    permutation of the (row, slot) pairs and ``back`` its inverse, so
+    d x = sum over a row's ``fan`` slots of dy[back]. (XLA's own transpose
+    of a gather is a scatter-add, which the TPU serialises.)"""
+    import functools
+
+    import jax
+
+    @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+    def f(x, idx, back, fan):
+        return x[idx // fan] if fan > 1 else x[idx]
+
+    def fwd(x, idx, back, fan):
+        return f(x, idx, back, fan), back
+
+    def bwd(fan, back, dy):
+        dx = dy[back]
+        if fan > 1:
+            dx = dx.reshape(-1, fan, dx.shape[-1]).sum(axis=1)
+        return dx.astype(dy.dtype), None, None
+
+    f.defvjp(fwd, bwd)
+    return f(x, idx, back, fan)
+
+
+def _mask_cotangent(rows, n_valid):
+    """Identity whose backward zeroes the cotangent of rows >= n_valid."""
+    import jax
+    import jax.numpy as jnp
+
+    @jax.custom_vjp
+    def f(rows, n_valid):
+        return rows
+
+    def bwd(n_valid, dy):
+        valid = jnp.arange(dy.shape[0])[:, None] < n_valid
+        return jnp.where(valid, dy, 0), None
+
+    f.defvjp(lambda rows, n_valid: (rows, n_valid), bwd)
+    return f(rows, n_valid)
+
+
+@register_op(OperatorType.OP_MOE_ROUTER)
+class MoERouterOp(Op):
+    """attrs: num_experts, k, route_norm, route_scale, kernel_initializer.
+    Scores are ``sigmoid`` of the logits in float32. Weights: ``kernel`` (d, num_experts, no
+    bias) and ``expert_bias`` (num_experts,), a buffer added to the scores
+    for the SELECTION only: the weights are the plain scores of the chosen,
+    and no gradient reaches the buffer.
+
+    input (.., d) -> outputs (weights (.., k) float32, chosen (.., k) int32).
+    """
+
+    def infer_output_shapes(self, input_shapes):
+        out = tuple(input_shapes[0][:-1]) + (self.attrs["k"],)
+        return [out, out]
+
+    def output_dtypes(self, input_dtypes, num_outputs):
+        from ..ffconst import DataType
+
+        return [DataType.DT_FLOAT, DataType.DT_INT32]
+
+    def weight_specs(self, input_shapes):
+        from ..execution.initializers import (DefaultWeightInitializer,
+                                              UniformInitializer)
+
+        d, n = input_shapes[0][-1], self.attrs["num_experts"]
+        return {"kernel": ((d, n), self.data_type,
+                           self.attrs.get("kernel_initializer")
+                           or DefaultWeightInitializer()),
+                "expert_bias": ((n,), self.data_type,
+                                UniformInitializer(min_val=-0.01,
+                                                   max_val=0.01))}
+
+    def forward(self, params, inputs, ctx: OpContext):
+        import jax
+        import jax.numpy as jnp
+
+        (x,) = inputs
+        logits = jnp.dot(x, params["kernel"],
+                         preferred_element_type=jnp.float32)
+        scores = jax.nn.sigmoid(logits)
+        bias = jax.lax.stop_gradient(
+            params["expert_bias"].astype(jnp.float32))
+        _, chosen = jax.lax.top_k(scores + bias, self.attrs["k"])
+        weights = jnp.take_along_axis(scores, chosen, axis=-1)
+        if self.attrs.get("route_norm", True):
+            weights = weights / (jnp.sum(weights, axis=-1, keepdims=True)
+                                 + 1e-20)
+        weights = weights * float(self.attrs.get("route_scale", 1.0))
+        return [weights, chosen.astype(jnp.int32)]
+
+    def flops(self, input_shapes, output_shapes):
+        return 2 * int(np.prod(input_shapes[0])) * self.attrs["num_experts"]
+
+
+@register_op(OperatorType.OP_MOE_DISPATCH)
+class MoEDispatchOp(Op):
+    """attrs: num_experts, held. inputs (x (.., d), chosen (.., k)) ->
+    (rows (tokens * k, d) sorted by expert id with the pairs not held here
+    last, group_sizes (count,) int32, order (2, tokens * k) int32: the
+    sort's permutation and its inverse).
+
+    Publishes the layer's routing counters (``ctx.stats_out``): the tokens
+    each held expert received, the pairs held here, and the pairs that were
+    held and reached no group (dropped: 0 by construction, counted from the
+    two sides)."""
+
+    def infer_output_shapes(self, input_shapes):
+        x, chosen = input_shapes
+        pairs = int(np.prod(chosen))
+        return [(pairs, x[-1]), (_held(self.attrs)[1],), (2, pairs)]
+
+    def output_dtypes(self, input_dtypes, num_outputs):
+        from ..ffconst import DataType
+
+        return [input_dtypes[0], DataType.DT_INT32, DataType.DT_INT32]
+
+    def forward(self, params, inputs, ctx: OpContext):
+        import jax.numpy as jnp
+
+        x, chosen = inputs
+        first, count = _held(self.attrs)
+        k = chosen.shape[-1]
+        key, here = _held_pairs(chosen.reshape(-1), first, count)
+        perm = jnp.argsort(key, stable=True).astype(jnp.int32)
+        inv = jnp.zeros_like(perm).at[perm].set(
+            jnp.arange(perm.shape[0], dtype=jnp.int32))
+        group_sizes = jnp.bincount(key, length=count + 1)[:count].astype(
+            jnp.int32)
+        rows = _gather_rows(x.reshape(-1, x.shape[-1]), perm, inv, k)
+        # rows past the held pairs belong to no group: their cotangent is
+        # whatever the grouped products left there, never a gradient
+        n_here = jnp.sum(here.astype(jnp.int32))
+        rows = _mask_cotangent(rows, n_here)
+        if ctx.stats_out is not None:
+            ctx.stats_out[self.name] = {
+                "tokens_per_expert": group_sizes,
+                "pairs_here": n_here,
+                "dropped": n_here - jnp.sum(group_sizes)}
+        return [rows, group_sizes, jnp.stack([perm, inv])]
+
+    def parallelizable_dims(self, input_shapes):
+        return {"batch": False, "expert": True}
+
+
+@register_op(OperatorType.OP_MOE_ROUTED_EXPERTS)
+class MoERoutedExpertsOp(Op):
+    """attrs: held, intermediate, kernel_initializer. inputs (rows
+    (pairs, d) sorted by expert, group_sizes (count,)) -> (pairs, d): per
+    held expert ``W_down(silu(W_gate x) * W_up x)`` over its rows, three
+    grouped products. Weights ``gate``/``up`` (count, d, intermediate),
+    ``down`` (count, intermediate, d): the held experts' alone, each
+    initialised as a matrix of its own (the default initialiser would read
+    the expert dim as a receptive field and scale every expert down by
+    sqrt(count): an expert's output by count^1.5). Rows of no group come out
+    undefined (zero off-TPU) and are the consumer's to mask."""
+
+    def infer_output_shapes(self, input_shapes):
+        return [tuple(input_shapes[0])]
+
+    def weight_specs(self, input_shapes):
+        from ..execution.initializers import GlorotUniformInitializer
+
+        d, i = input_shapes[0][-1], self.attrs["intermediate"]
+        n = _held(self.attrs)[1]
+        init = self.attrs.get("kernel_initializer") \
+            or GlorotUniformInitializer(stacked=True)
+        return {"gate": ((n, d, i), self.data_type, init),
+                "up": ((n, d, i), self.data_type, init),
+                "down": ((n, i, d), self.data_type, init)}
+
+    def forward(self, params, inputs, ctx: OpContext):
+        import jax
+        import jax.numpy as jnp
+
+        rows, group_sizes = inputs
+
+        def grouped(lhs, rhs):
+            return jax.lax.ragged_dot(lhs, rhs, group_sizes,
+                                      preferred_element_type=jnp.float32)
+
+        a = (jax.nn.silu(grouped(rows, params["gate"]))
+             * grouped(rows, params["up"])).astype(rows.dtype)
+        return [grouped(a, params["down"]).astype(rows.dtype)]
+
+    def flops(self, input_shapes, output_shapes):
+        """Of the rows expected here under uniform routing (pairs * held /
+        num_experts), not of the buffer: the grouped products skip rows of
+        no group."""
+        pairs, d = input_shapes[0]
+        share = _held(self.attrs)[1] / float(self.attrs["num_experts"])
+        return int(6 * pairs * share * d * self.attrs["intermediate"])
+
+    def memory_bytes(self, input_shapes, output_shapes):
+        from ..ffconst import size_of_datatype
+
+        el = size_of_datatype(self.data_type)
+        pairs, d = input_shapes[0]
+        share = _held(self.attrs)[1] / float(self.attrs["num_experts"])
+        weights = 3 * _held(self.attrs)[1] * d * self.attrs["intermediate"]
+        return int(el * (weights + 2 * pairs * share * d))
+
+    def parallelizable_dims(self, input_shapes):
+        # expert parallelism: the expert dim of the three weights is the
+        # shardable one, as ExpertsOp says
+        return {"batch": False, "expert": True}
+
+
+@register_op(OperatorType.OP_MOE_COMBINE)
+class MoECombineOp(Op):
+    """attrs: num_experts, held. inputs (expert rows (pairs, d), order
+    (2, pairs), weights (.., k), chosen (.., k)) -> (.., d): each token's
+    sum over its pairs held here of weight * expert row (the rows gathered
+    back by the sort's inverse; pairs not held here contribute nothing)."""
+
+    def infer_output_shapes(self, input_shapes):
+        rows, _order, weights, _chosen = input_shapes
+        return [tuple(weights[:-1]) + (rows[-1],)]
+
+    def forward(self, params, inputs, ctx: OpContext):
+        import jax.numpy as jnp
+
+        rows, order, weights, chosen = inputs
+        first, count = _held(self.attrs)
+        k = chosen.shape[-1]
+        _, here = _held_pairs(chosen.reshape(-1, k), first, count)
+        slots = _gather_rows(rows, order[1], order[0], 1)
+        slots = slots.reshape(-1, k, rows.shape[-1])
+        slots = jnp.where(here[..., None], slots, 0).astype(jnp.float32)
+        w = jnp.where(here, weights.reshape(-1, k), 0.0)
+        y = jnp.einsum("tk,tkd->td", w, slots)
+        return [y.reshape(chosen.shape[:-1] + (rows.shape[-1],)).astype(
+            rows.dtype)]
+
+    def flops(self, input_shapes, output_shapes):
+        return 2 * int(np.prod(input_shapes[0]))
+
+    def parallelizable_dims(self, input_shapes):
+        return {"batch": False, "expert": True}

@@ -105,7 +105,8 @@ def _validate_node_specs(pcg: PCG, node, ns: NodeStrategy, axis_sizes) -> None:
 def _transitive_producer(pcg: PCG, node) -> Optional[int]:
     """Walk back through unary/elementwise ops to the producing heavy op."""
     passthrough = {
-        OperatorType.OP_RELU, OperatorType.OP_GELU, OperatorType.OP_TANH,
+        OperatorType.OP_RELU, OperatorType.OP_GELU, OperatorType.OP_SILU,
+        OperatorType.OP_TANH,
         OperatorType.OP_SIGMOID, OperatorType.OP_ELU, OperatorType.OP_DROPOUT,
         OperatorType.OP_IDENTITY, OperatorType.OP_SCALAR_MULTIPLY,
         OperatorType.OP_SCALAR_ADD, OperatorType.OP_CAST,

@@ -60,7 +60,8 @@ _log = RecursiveLogger("unity")
 # state-preserving ops (elementwise etc.): pass R through; pass S/Q through
 # when the sharded dim divides
 _STATE_PRESERVING = {
-    OperatorType.OP_RELU, OperatorType.OP_GELU, OperatorType.OP_TANH,
+    OperatorType.OP_RELU, OperatorType.OP_GELU, OperatorType.OP_SILU,
+    OperatorType.OP_TANH,
     OperatorType.OP_SIGMOID, OperatorType.OP_ELU, OperatorType.OP_IDENTITY,
     OperatorType.OP_DROPOUT, OperatorType.OP_SCALAR_MULTIPLY,
     OperatorType.OP_SCALAR_ADD, OperatorType.OP_SCALAR_SUB,
@@ -255,9 +256,12 @@ def node_options(node: PCGNode, tp: int,
         if space.sequence and in_shapes and q_ok(in_shapes[0]) and q_ok(out):
             opts.append(("none", "Q", "Q"))  # dense is per-token
     elif ot == OperatorType.OP_MULTIHEAD_ATTENTION:
-        if space.attribute and a["num_heads"] % tp == 0:
+        kv_heads = a.get("num_kv_heads") or a["num_heads"]
+        if space.attribute and a["num_heads"] % tp == 0 \
+                and kv_heads % tp == 0:
             opts.append(("heads", "R", "R"))
         if space.sequence and in_shapes and q_ok(in_shapes[0]) \
+                and kv_heads == a["num_heads"] and not a.get("window") \
                 and len(node.inputs) == 3 \
                 and len({g for g, _ in node.inputs}) == 1:
             # self-attention only; dropout is fine — ring/Ulysses share the
