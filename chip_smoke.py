@@ -180,11 +180,14 @@ def check_flash_decode() -> None:
                                                    flash_decode_pool)
     from flexflow_tpu.serving.kvcache import new_kv_pool, scatter_prefill_kv
 
-    slots, heads, hd, extent = 8, 12, 64, 256
+    slots, heads, hd, extent = 10, 12, 64, 512
     kq, kk, kv = jax.random.split(jax.random.PRNGKey(1), 3)
     q = jax.random.normal(kq, (slots, heads, hd), jnp.bfloat16)
-    # lengths from 1 key to a slot's full extent
-    n_keys = jnp.asarray([1, 15, 16, 17, 100, 200, 255, 256], jnp.int32)
+    # lengths from no key (a free slot: exact zeros back) over the edges
+    # of a block and of the kernel's 256-key tile to a slot's full extent
+    n_keys = jnp.asarray([0, 1, 15, 16, 17, 100, 255, 256, 257, 512],
+                         jnp.int32)
+    live = np.asarray(n_keys) > 0
     scale = 1.0 / np.sqrt(hd)
     ref = _reference_decode()
     # the reader's gate asks for whole lanes and 8-row sublanes, not for
@@ -212,9 +215,13 @@ def check_flash_decode() -> None:
               f"flash_decode ({label} pool, {heads} heads x {2 * hd} "
               f"lanes, block {bs}) compiles to a Mosaic kernel")
         out = fn(q, pool, tables, n_keys)
+        check(bool(jnp.all(out[~live] == 0)) and bool(jnp.all(
+            jnp.isfinite(out))),
+              f"flash_decode ({label} pool, block {bs}): a slot of no keys "
+              f"is exact zeros, every value finite")
         with jax.default_matmul_precision("highest"):
             want = ref(q, pool, tables, n_keys, scale, scales)
-        e = rel_err(out, want)
+        e = rel_err(out[live], want[live])
         check(e <= FLASH_DECODE_TOL,
               f"flash_decode ({label} pool, block {bs}) vs masked-gather "
               f"reference: {e:.2e} <= {FLASH_DECODE_TOL:.2e}")

@@ -1074,7 +1074,7 @@ class Executor:
             logits = self._logits_f32(
                 values[self.final_guid][self.final_out_idx])[:, 0]
             new_state = DecodeState(caches=sv.cache_out,
-                                    lengths=state.lengths + 1,
+                                    lengths=state.advanced_lengths(),
                                     block_tables=state.block_tables)
             if guard:
                 ok = jnp.all(jnp.isfinite(logits), axis=-1)

@@ -2,30 +2,46 @@
 
 The serving decode step advances every slot one token; its attention read
 is the decode hot loop's HBM bill. Over the paged pool
-(serving/kvcache.py) this kernel reads ONLY the blocks a slot actually
-occupies, so per-token traffic is O(true_length):
+(serving/kvcache.py) this kernel does work in proportion to the keys that
+are LIVE — grid steps, bytes and arithmetic:
 
-* grid = (n_slots, max_blocks_per_slot): the KV-block axis is the
-  **split-K** dimension — each grid step folds one (heads, block_size)
-  score tile into an online-softmax accumulator (m, l, acc scratch),
-  exactly the FlashAttention recurrence restricted to a 1-row q.
-* the pool block each step reads is resolved through the slot's block
-  table by the BlockSpec index map (``PrefetchScalarGridSpec`` — the
-  tables and per-slot key counts are scalar-prefetched, available before
-  the kernel body). Steps past a slot's last occupied block CLAMP to the
-  last occupied block: Pallas skips the DMA when the resolved index is
-  unchanged, so dead steps move no HBM bytes, and the body masks them
-  out by global key position anyway (the loaded data is never used).
-* ONE block a step: a pool row holds a head's K and V side by side on
-  the lanes (kvcache.py), so the kernel never slices lanes. The query
-  rides zero-padded over V's lanes — ``0 * finite`` is exactly 0, and
-  every stored value is finite (the garbage block's invariant) — so
-  ``q . row`` is the K score; ``p . block`` is accumulated at full
-  width and V's lanes are taken once, from the output.
-* int8 KV (``scales``): blocks are dequantized in-VMEM from the
-  block-paged per-(token, head) scales — HBM moves ~1/el of the fp
-  bytes plus the f32 scale vectors (the bandwidth the serving search's
-  ``kv_dtype`` axis prices).
+* grid = (n_slots, tiles of a table row): a grid step folds one KEY TILE
+  — ``P = tile_blocks(...)`` consecutive entries of the slot's block
+  table, ``P * block_size`` keys (256 where the row is that long and
+  VMEM allows) — into an online-softmax accumulator (m, l, acc scratch),
+  exactly the FlashAttention recurrence restricted to a 1-row q. The
+  tile axis is the **split-K** dimension. A table whose width is no
+  multiple of ``P`` is padded with the garbage block.
+* the pool stays in HBM and the kernel gathers a tile's blocks itself:
+  ``P`` async copies, one a table entry, resolved through the
+  scalar-prefetched table into a double-buffered VMEM tile — the next
+  tile of the slot is in flight while this one is folded. No
+  ``BlockSpec`` names the pool, so a DEAD step (a tile past the slot's
+  last key; every step of a slot handed ``n_keys`` 0) resolves no block
+  index, starts no copy and runs no arithmetic: it costs the grid's own
+  step and nothing else (PERF.md section 6, PR 36, has the numbers).
+  Entries of a live tile past the slot's last block point at blocks the
+  slot does not read — the row's garbage padding — and are copied like
+  the others: their keys are masked out by global key position, and the
+  masked probability, exactly 0, meets finite stored values.
+* a slot of ``n_keys`` 0 — what the decode step hands a free slot
+  (kvcache.flash_decode_kv) — comes back as exact zeros: ``acc / l`` is
+  guarded where ``l`` is 0, because a NaN there would reach the garbage
+  block through that slot's K/V write and break the invariant below.
+* ONE pool: a pool row holds a head's K and V side by side on the lanes
+  (kvcache.py), so the kernel never slices lanes. The query rides
+  zero-padded over V's lanes — ``0 * finite`` is exactly 0, and every
+  stored value is finite (the garbage block's invariant) — so
+  ``q . row`` is the K score; ``p . tile`` is accumulated at full width
+  and V's lanes are taken once, from the output.
+* int8 KV (``scales``): a tile is dequantized in-VMEM from the
+  block-paged per-(token, head) scales — HBM moves ~1/el of the fp bytes
+  plus the f32 scale vectors (the bandwidth the serving search's
+  ``kv_dtype`` axis prices). The scales' rows are narrower than a lane
+  tile, which Mosaic does not let a hand-written copy slice, so they
+  come through ``P`` ``BlockSpec``s instead, each clamped to the slot's
+  last occupied block so that a dead step repeats an index and moves
+  nothing.
 
 Off-TPU the op layer never routes here (the masked gather path keeps
 tier-1 CPU-green); tests run the kernel in interpret mode.
@@ -42,6 +58,13 @@ import functools
 from typing import Optional
 
 NEG_INF = -1e30
+# keys a grid step folds, where the table row is that long and VMEM
+# allows: on the v5e at GPT-2 XL's widths 256 beat 64, 128 and 512 at 6,
+# 38 and 64 live slots of 64 (PERF.md section 6, PR 36)
+TILE_KEYS = 256
+# what a tile may take of the 16 MiB of scoped VMEM: the two halves of
+# the gather buffer in the pool's dtype and two f32 tiles of the body
+TILE_VMEM_BYTES = 12 * 2 ** 20
 
 
 def use_flash_decode(lanes: int, block_size: int) -> bool:
@@ -58,19 +81,41 @@ def use_flash_decode(lanes: int, block_size: int) -> bool:
     return lanes % 128 == 0 and block_size % 8 == 0 and on_tpu()
 
 
-def _decode_kernel(tab_ref, len_ref, q_ref, kv_ref, *rest, block_size,
-                   n_blocks_grid, kd, int8):
-    """One (slot, kv-block) grid step of the split-K recurrence."""
+def tile_blocks(pool_shape, itemsize: int, table_width: int) -> int:
+    """``P``: the table entries one grid step folds, from the pool's
+    shape ``(n_blocks, heads, block_size, lanes)`` and element size —
+    ``TILE_KEYS`` keys' worth of blocks or what fits ``TILE_VMEM_BYTES``,
+    at least one block, at most the row."""
+    _n_blocks, heads, block_size, lanes = pool_shape
+    fits = TILE_VMEM_BYTES // (heads * lanes * (2 * itemsize + 8))
+    return max(1, min(min(TILE_KEYS, fits) // block_size, table_width))
+
+
+def _decode_kernel(tab_ref, len_ref, q_ref, pool_ref, *rest, block_size,
+                   tile_blocks, n_tiles_grid, kd, int8):
+    """One (slot, key tile) grid step of the split-K recurrence."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
+    P = tile_blocks
+    sc_refs = ()
     if int8:
-        sc_ref, o_ref, m_ref, l_ref, acc_ref = rest
-    else:
-        o_ref, m_ref, l_ref, acc_ref = rest
+        sc_refs, rest = rest[:P], rest[P:]
+    o_ref, kv_buf, sems, m_ref, l_ref, acc_ref = rest
+    tile_keys = P * block_size
     s = pl.program_id(0)
     j = pl.program_id(1)
+    n_keys = len_ref[s]
+    n_tiles = (n_keys + tile_keys - 1) // tile_keys   # the slot's live ones
+
+    def gather(tile, buf):
+        """The copies of one tile's blocks into half ``buf`` of the
+        buffer: started once, waited once."""
+        return [pltpu.make_async_copy(
+            pool_ref.at[tab_ref[s, tile * P + i]], kv_buf.at[buf, i],
+            sems.at[buf, i]) for i in range(P)]
 
     @pl.when(j == 0)
     def _init():
@@ -78,27 +123,46 @@ def _decode_kernel(tab_ref, len_ref, q_ref, kv_ref, *rest, block_size,
         l_ref[:] = jnp.zeros_like(l_ref)
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    n_keys = len_ref[s]
+    @pl.when(jnp.logical_and(j == 0, n_tiles > 0))
+    def _first():
+        for copy in gather(0, 0):
+            copy.start()
 
-    @pl.when(j * block_size < n_keys)
+    @pl.when(j < n_tiles)
     def _step():
+        buf = j % 2
+
+        @pl.when(j + 1 < n_tiles)
+        def _next():
+            for copy in gather(j + 1, 1 - buf):
+                copy.start()
+
+        for copy in gather(j, buf):
+            copy.wait()
         # (h, 1, lanes): pre-scaled, zero over V's lanes
         q = q_ref[0].astype(jnp.float32)
-        kv = kv_ref[0].astype(jnp.float32)        # (h, bs, lanes): K | V
-        if int8:
-            lane = jax.lax.broadcasted_iota(jnp.int32, kv.shape, 2)
-            kv = kv * jnp.where(lane < kd, sc_ref[0, 0][..., None],
-                                sc_ref[0, 1][..., None])
-        # (h, 1, bs) score tile: per-head q row against the block's keys
+
+        def block(i):                             # (h, bs, lanes): K | V
+            kv = kv_buf[buf, i].astype(jnp.float32)
+            if int8:
+                lane = jax.lax.broadcasted_iota(jnp.int32, kv.shape, 2)
+                sc = sc_refs[i][0]
+                kv = kv * jnp.where(lane < kd, sc[0][..., None],
+                                    sc[1][..., None])
+            return kv
+
+        kv = jnp.concatenate([block(i) for i in range(P)],
+                             axis=1)              # (h, tile, lanes)
+        # (h, 1, tile) score tile: per-head q row against the tile's keys
         s_tile = jnp.einsum("hqd,hkd->hqk", q, kv,
                             preferred_element_type=jnp.float32)
-        kpos = j * block_size + jax.lax.broadcasted_iota(
+        kpos = j * tile_keys + jax.lax.broadcasted_iota(
             jnp.int32, s_tile.shape, 2)
         s_tile = jnp.where(kpos < n_keys, s_tile, NEG_INF)
         m_prev = m_ref[:, :, :1]                  # (h, 1, 1)
         m_new = jnp.maximum(m_prev, jnp.max(s_tile, axis=-1,
                                             keepdims=True))
-        p = jnp.exp(s_tile - m_new)               # (h, 1, bs)
+        p = jnp.exp(s_tile - m_new)               # (h, 1, tile)
         corr = jnp.exp(m_prev - m_new)            # (h, 1, 1)
         # (h, 1, lanes): V's lanes are the output, K's are never read
         pv = jnp.einsum("hqk,hkd->hqd", p, kv,
@@ -108,9 +172,13 @@ def _decode_kernel(tab_ref, len_ref, q_ref, kv_ref, *rest, block_size,
         m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
         l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
 
-    @pl.when(j == n_blocks_grid - 1)
+    @pl.when(j == n_tiles_grid - 1)
     def _finish():
-        o_ref[0] = (acc_ref[:] / l_ref[:, :, :1]).astype(o_ref.dtype)
+        # a slot of no keys folded nothing: l and acc are 0, and 0 / 1
+        # is the exact zero that 0 / 0 is not
+        l = l_ref[:, :, :1]
+        o_ref[0] = (acc_ref[:] / jnp.where(l > 0.0, l, 1.0)
+                    ).astype(o_ref.dtype)
 
 
 def flash_decode_pool(q, pool, block_tables, n_keys, *,
@@ -124,16 +192,41 @@ def flash_decode_pool(q, pool, block_tables, n_keys, *,
                  int8 with ``scales`` (n_blocks, 2, heads, block_size)
                  f32, K's per-(token, head) scales then V's
     block_tables (n_slots, max_blocks_per_slot) int32
-    n_keys       (n_slots,) int32 — keys each slot attends (position + 1)
+    n_keys       (n_slots,) int32 — keys each slot attends: position + 1
+                 for a live slot, 0 for one that attends nothing (a free
+                 slot: no live step, no bytes, an output of exact zeros)
 
-    Returns (n_slots, heads, vd) in q's dtype. ``interpret=True`` runs
-    the Mosaic interpreter (the CPU test path; refused on a TPU)."""
+    The grid is ``(n_slots, ceil(max_blocks_per_slot / P))``, ``P =
+    tile_blocks(pool.shape, itemsize, max_blocks_per_slot)`` table
+    entries a step; the table is padded to whole tiles with the garbage
+    block. Returns (n_slots, heads, vd) in q's dtype. ``interpret=True``
+    runs the Mosaic interpreter (the CPU test path; refused on a TPU).
+
+    The call is a ``jax.jit`` of its own: a decode step calls it once a
+    layer with the same shapes, and the kernel is then traced and
+    lowered once a program and not once a layer (48 times at GPT-2 XL,
+    seconds of every set-up)."""
+    return _jitted_pool()(q, pool, block_tables, n_keys, sm_scale=sm_scale,
+                          scales=scales, interpret=interpret)
+
+
+@functools.lru_cache(maxsize=1)
+def _jitted_pool():
+    import jax
+
+    return jax.jit(_flash_decode_pool,
+                   static_argnames=("sm_scale", "interpret"))
+
+
+def _flash_decode_pool(q, pool, block_tables, n_keys, *, sm_scale, scales,
+                       interpret):
     import jax
     import jax.numpy as jnp
     import numpy as np
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
+    from ..serving.kvcache import GARBAGE_BLOCK
     from ._common import resolve_interpret
 
     n_slots, heads, kd = q.shape
@@ -146,33 +239,42 @@ def flash_decode_pool(q, pool, block_tables, n_keys, *,
     out_dtype = q.dtype
     q = q.astype(jnp.float32) * jnp.float32(scale)
     q = jnp.pad(q, ((0, 0), (0, 0), (0, lanes - kd)))[:, :, None, :]
-    tables = block_tables.astype(jnp.int32)
+    P = tile_blocks(pool.shape, pool.dtype.itemsize, mb)
+    n_tiles = -(-mb // P)
+    tables = jnp.pad(block_tables.astype(jnp.int32),
+                     ((0, 0), (0, n_tiles * P - mb)),
+                     constant_values=GARBAGE_BLOCK)
     n_keys = n_keys.astype(jnp.int32)
-
-    def block_index(s, j, tab_ref, len_ref):
-        # clamp steps past the slot's last occupied block to the last
-        # occupied one: the resolved index repeats, Pallas skips the DMA,
-        # and the body's position mask ignores the data
-        used = (len_ref[s] + block_size - 1) // block_size
-        jj = jnp.minimum(j, jnp.maximum(used - 1, 0))
-        return (tab_ref[s, jj], 0, 0, 0)
 
     def slot_row(s, j, tab_ref, len_ref):
         return (s, 0, 0, 0)
 
+    def scale_block(i):
+        def index(s, j, tab_ref, len_ref):
+            # clamp entries past the slot's last occupied block to the
+            # last occupied one: the resolved index repeats, Pallas
+            # skips the DMA, and the position mask ignores the data
+            used = (len_ref[s] + block_size - 1) // block_size
+            jj = jnp.minimum(j * P + i, jnp.maximum(used - 1, 0))
+            return (tab_ref[s, jj], 0, 0, 0)
+        return index
+
     in_specs = [pl.BlockSpec((1, heads, 1, lanes), slot_row),
-                pl.BlockSpec((1, heads, block_size, lanes), block_index)]
+                pl.BlockSpec(memory_space=pltpu.HBM)]
     args = [q, pool]
     if int8:
-        in_specs.append(
-            pl.BlockSpec((1, 2, heads, block_size), block_index))
-        args.append(scales)
+        in_specs += [pl.BlockSpec((1, 2, heads, block_size), scale_block(i))
+                     for i in range(P)]
+        args += [scales] * P
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(n_slots, mb),
+        grid=(n_slots, n_tiles),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, heads, 1, lanes), slot_row),
         scratch_shapes=[
+            # two tiles of P blocks: one folded, the next in flight
+            pltpu.VMEM((2, P, heads, block_size, lanes), pool.dtype),
+            pltpu.SemaphoreType.DMA((2, P)),
             pltpu.VMEM((heads, 1, 128), jnp.float32),    # m
             pltpu.VMEM((heads, 1, 128), jnp.float32),    # l
             pltpu.VMEM((heads, 1, lanes), jnp.float32),  # acc
@@ -180,7 +282,8 @@ def flash_decode_pool(q, pool, block_tables, n_keys, *,
     )
     fn = pl.pallas_call(
         functools.partial(_decode_kernel, block_size=block_size,
-                          n_blocks_grid=mb, kd=kd, int8=int8),
+                          tile_blocks=P, n_tiles_grid=n_tiles, kd=kd,
+                          int8=int8),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n_slots, heads, 1, lanes),
                                        out_dtype),

@@ -119,6 +119,11 @@ class StepTelemetry:
         # seq_shards) — the recorded number behind "KV provably exceeds
         # one chip"
         self.serving_kv_hbm_per_chip_bytes: Optional[int] = None
+        # the decode attention kernel's grid (PR 36): grid steps of one
+        # layer's call summed over decode steps, and the tiles among them
+        # that held a live slot's key (ServingStats.kv_tiles_grid / _live)
+        self.serving_kv_tiles_grid: int = 0
+        self.serving_kv_tiles_live: int = 0
         # serving-resilience counters (ISSUE 9): the outcome ledger of a
         # serve() run (every request under exactly one of ok |
         # deadline_exceeded | shed | decode_fault | preempted) plus the
@@ -320,6 +325,12 @@ class StepTelemetry:
             if self.serving_kv_hbm_per_chip_bytes is not None:
                 sv["kv_hbm_per_chip_bytes"] = \
                     int(self.serving_kv_hbm_per_chip_bytes)
+            if self.serving_kv_tiles_grid:
+                sv["kv_tiles_grid"] = self.serving_kv_tiles_grid
+                sv["kv_tiles_live"] = self.serving_kv_tiles_live
+                sv["decode_grid_live_share"] = round(
+                    self.serving_kv_tiles_live
+                    / self.serving_kv_tiles_grid, 4)
             out["serving"] = sv
         if self.fleet_replicas:
             total = max(sum(self.fleet_outcomes.values()), 1)

@@ -94,6 +94,13 @@ class ServingStats:
     # decode attention reads, accumulated per step host-side: each live
     # slot's OCCUPIED blocks; bench's bytes-read/token column
     kv_bytes_read: int = 0
+    # the flash_decode grid, counted in the same loop (PR 36): the grid
+    # steps of ONE layer's call, summed over decode steps (n_slots x the
+    # tiles of a table row, kernels/flash_decode.py), and those of them
+    # that hold a key of a live slot; decode_grid_live_share() is their
+    # ratio — how much of the kernel's grid has work
+    kv_tiles_grid: int = 0
+    kv_tiles_live: int = 0
     # prefix cache + chunked prefill ledger (ISSUE 14,
     # serving/prefix.py): admissions that mapped a cached prefix, the
     # prompt tokens whose prefill compute was skipped vs actually
@@ -154,6 +161,14 @@ class ServingStats:
         if not self.tokens_generated or not self.kv_bytes_read:
             return None
         return self.kv_bytes_read / self.tokens_generated
+
+    def decode_grid_live_share(self) -> Optional[float]:
+        """Live tiles over grid steps of the decode attention kernel —
+        the fixed-cost term a serving search needs beside the bytes.
+        None before a decode step ran."""
+        if not self.kv_tiles_grid:
+            return None
+        return self.kv_tiles_live / self.kv_tiles_grid
 
     def acceptance_rate(self) -> Optional[float]:
         if not self.spec_proposed:
@@ -234,6 +249,11 @@ class ServingStats:
         kvpt = self.kv_bytes_per_token()
         if kvpt is not None:
             out["kv_bytes_per_token"] = round(kvpt, 1)
+        if self.kv_tiles_grid:
+            out["kv_tiles_grid"] = self.kv_tiles_grid
+            out["kv_tiles_live"] = self.kv_tiles_live
+            out["decode_grid_live_share"] = round(
+                self.decode_grid_live_share(), 4)
         acc = self.acceptance_rate()
         if acc is not None:
             out["spec_acceptance"] = round(acc, 4)
@@ -1072,17 +1092,40 @@ class ServingEngine:
             self._kv_row_bytes_cache = total
         return self._kv_row_bytes_cache
 
-    def _decode_kv_bytes(self, live) -> int:
-        """Analytic KV bytes this decode step's attention reads: each
-        live slot's OCCUPIED blocks (the flash-decode kernel's actual
-        traffic, O(true_length))."""
-        row = self._kv_row_bytes()
+    def _kv_tile_blocks(self) -> int:
+        """Table entries one grid step of the decode attention kernel
+        folds (kernels/flash_decode.py ``tile_blocks``), from the first
+        KV pool of the decode state; a whole row for a model without
+        one."""
+        if getattr(self, "_kv_tile_blocks_cache", None) is None:
+            from ..kernels.flash_decode import tile_blocks
+            from .kvcache import _pool_scales
+
+            p = self.max_blocks_per_slot
+            if self._paged_entry_names:
+                pool, _scales = _pool_scales(
+                    self.state.caches[min(self._paged_entry_names)])
+                p = tile_blocks(pool.shape, pool.dtype.itemsize, p)
+            self._kv_tile_blocks_cache = p
+        return self._kv_tile_blocks_cache
+
+    def _count_decode_kv(self, stats: ServingStats, live) -> None:
+        """One decode step's attention read, counted: the analytic KV
+        bytes — each live slot's OCCUPIED blocks (the flash-decode
+        kernel's actual traffic, O(true_length)) — and the kernel's
+        grid, per layer's call: every slot's tiles, and the tiles that
+        hold a key of a live slot."""
         bs = self.kv_block_size
+        tile_blocks = self._kv_tile_blocks()
+        tile = tile_blocks * bs
         toks = 0
         for _slot, req in live:
             keys = req.effective_len + 1
             toks += -(-keys // bs) * bs
-        return toks * row
+            stats.kv_tiles_live += -(-keys // tile)
+        stats.kv_bytes_read += toks * self._kv_row_bytes()
+        stats.kv_tiles_grid += self.n_slots * -(
+            -self.max_blocks_per_slot // tile_blocks)
 
     def _sweep_deadlines(self, sched, res, tracer) -> None:
         """Deadline enforcement at the iteration boundary: expired queued
@@ -1213,6 +1256,9 @@ class ServingEngine:
         # step measured the fill
         tel.serving_kv_hbm_per_chip_bytes = \
             stats.kv_hbm_per_chip_bytes or None
+        # the decode attention kernel's grid and how much of it had work
+        tel.serving_kv_tiles_grid = stats.kv_tiles_grid
+        tel.serving_kv_tiles_live = stats.kv_tiles_live
         # serving_resilience block (ISSUE 9): the outcome ledger + event
         # counters, mirroring the resilience/strategy_safety blocks
         tel.serving_outcomes = dict(stats.outcomes)
@@ -1765,7 +1811,7 @@ class _ServeLoop:
         stats, tracer = self.stats, self.tracer
         stats.decode_steps += 1
         self.step_no += 1
-        stats.kv_bytes_read += eng._decode_kv_bytes(live)
+        eng._count_decode_kv(stats, live)
         if self.res_active:
             res.controller.observe_step(
                 wall, len(live),

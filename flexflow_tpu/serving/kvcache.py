@@ -172,6 +172,16 @@ class DecodeState:
     def n_slots(self) -> int:
         return int(self.lengths.shape[0])
 
+    def advanced_lengths(self):
+        """The cursors after one decode step: a live slot's grows by
+        one, a free slot's stays 0 (:func:`live_slots`) — so a free
+        slot never indexes the position table past its end, and its
+        discarded token lands at offset 0 of the garbage block however
+        long the slot stays free."""
+        import jax.numpy as jnp
+
+        return jnp.where(live_slots(self.block_tables), self.lengths + 1, 0)
+
 
 def _decode_state_flatten(s: "DecodeState"):
     names = tuple(sorted(s.caches))
@@ -488,17 +498,34 @@ def read_kv(entry, block_tables, kdim: int, dtype):
             dequantize_kv(vc, s[:, 1], dtype))
 
 
+def live_slots(block_tables):
+    """``(n_slots,)`` bool: the slots whose table row maps a pool block.
+    A row that is all GARBAGE_BLOCK is a free slot — freed, never
+    admitted, or still being chunk-prefilled (its row is set when the
+    last chunk lands). Read from the row, which every slot-freeing path
+    clears at once, and not from the cursor, which the one-deep
+    pipeline may have advanced past the clear."""
+    import jax.numpy as jnp
+
+    return jnp.any(block_tables != GARBAGE_BLOCK, axis=1)
+
+
 def flash_decode_kv(q, entry, block_tables, n_keys, sm_scale):
     """The kernel read of a pool entry (kernels/flash_decode.py): q
     ``(n_slots, h, kd)`` against each slot's ``n_keys`` first keys →
     ``(n_slots, h, vd)``; None where the gate says the gather read
     (:func:`read_kv`) is the path — off the chip, or a pool that is not
-    whole lanes (128) and whole sublanes (8 rows a block)."""
+    whole lanes (128) and whole sublanes (8 rows a block). A free slot
+    (:func:`live_slots`) is handed ``n_keys`` 0: the kernel runs no live
+    step for it, moves no bytes and writes exact zeros."""
+    import jax.numpy as jnp
+
     from ..kernels.flash_decode import flash_decode_pool, use_flash_decode
 
     pool, scales = _pool_scales(entry)
     if not use_flash_decode(pool.shape[-1], pool.shape[2]):
         return None
+    n_keys = jnp.where(live_slots(block_tables), n_keys, 0)
     return flash_decode_pool(q, pool, block_tables, n_keys,
                              sm_scale=sm_scale, scales=scales)
 

@@ -235,6 +235,10 @@ def summarize_telemetry(data, top: int) -> None:
             size = (f"{b / 2 ** 20:.1f} MiB" if b >= 2 ** 20
                     else f"{b / 2 ** 10:.1f} KiB")
             print(f"  kv per shard chip: {size} at measured fill")
+        if srv.get("decode_grid_live_share") is not None:
+            print(f"  decode attention grid: {srv['kv_tiles_live']} of "
+                  f"{srv['kv_tiles_grid']} tiles live "
+                  f"({100 * srv['decode_grid_live_share']:.1f}%)")
 
     _block(data, "serving", _srv)
 

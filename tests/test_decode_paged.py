@@ -382,6 +382,109 @@ def test_flash_decode_interpret_matches_reference(heads, kd, vd):
     assert float(jnp.max(jnp.abs(out8 - ref))) < 0.1
 
 
+@pytest.mark.parametrize("kv_dtype", ["native", "int8"])
+@pytest.mark.parametrize("block_size", [8, 16, 32])
+@pytest.mark.parametrize("table_width", [3, 8, 20, 40])
+def test_flash_decode_interpret_matches_reference_over_tiles(
+        table_width, block_size, kv_dtype):
+    """The kernel's key tile (``tile_blocks`` table entries a grid
+    step) against the masked-gather reference, in interpret mode: table
+    widths that are and are not a multiple of the tile (the wrapper pads
+    the row with the garbage block), every block size the gate lets
+    through, fp and int8 pools, and slots of 0 keys, 1, one less than /
+    exactly / one more than a tile, and the full extent. A slot of 0
+    keys — what the decode step hands a free slot — comes back as exact
+    zeros, and every value is finite."""
+    import jax.numpy as jnp
+
+    from flexflow_tpu.kernels.flash_decode import (_reference_decode,
+                                                   flash_decode_pool,
+                                                   tile_blocks)
+
+    rng = np.random.default_rng(table_width * 100 + block_size)
+    heads, kd = 2, 64
+    extent = table_width * block_size
+    tile = block_size * tile_blocks(
+        (1, heads, block_size, 2 * kd), 1 if kv_dtype == "int8" else 4,
+        table_width)
+    assert 0 < tile <= extent
+    n_keys = np.asarray(sorted({0, 1, max(tile - 1, 1), tile,
+                                min(tile + 1, extent), extent}), np.int32)
+    S = len(n_keys)
+    entries = _packed_pool(rng, 1 + S * table_width, heads, block_size,
+                           kd, kd)
+    pool, scales = (entries[0], None) if kv_dtype == "native" \
+        else entries[1]
+    tables = np.zeros((S, table_width), np.int32)
+    for s_, n in enumerate(n_keys):
+        used = -(-int(n) // block_size)
+        tables[s_, :used] = 1 + s_ * table_width + np.arange(used)
+    tables, n_keys_dev = jnp.asarray(tables), jnp.asarray(n_keys)
+    q = jnp.asarray(rng.normal(size=(S, heads, kd)).astype(np.float32))
+    out = np.asarray(flash_decode_pool(q, pool, tables, n_keys_dev,
+                                       scales=scales, interpret=True))
+    assert np.all(np.isfinite(out))
+    assert np.all(out[n_keys == 0] == 0.0), "a slot of no keys is zeros"
+    ref = np.asarray(_reference_decode()(
+        q, pool, tables, n_keys_dev, 1.0 / np.sqrt(kd), scales=scales))
+    live = n_keys > 0
+    np.testing.assert_allclose(out[live], ref[live], atol=2e-6)
+
+
+def test_free_slot_cursor_stays_zero(gpt2):
+    """A free slot costs the decode step nothing that grows: while one
+    slot serves request after request for more decode steps than
+    ``max_decode_len``, the other's cursor stays 0 (it never indexes the
+    position table past its end), its table row stays all garbage, the
+    garbage block it writes its discarded tokens into stays finite, and
+    the live streams equal the same requests served alone."""
+    import jax
+
+    ff, cfg = gpt2
+    rng = np.random.default_rng(11)
+    prompts = [rng.integers(0, cfg.vocab_size, size=n).tolist()
+               for n in (5, 9, 3, 12)]
+    new = 14
+    alone = [ServingEngine(ff, n_slots=1, max_decode_len=cfg.seq_len,
+                           kv_block_size=8, prefix_cache="off").generate(
+                               [p], max_new_tokens=new)[0] for p in prompts]
+    eng = ServingEngine(ff, n_slots=2, max_decode_len=cfg.seq_len,
+                        kv_block_size=8, prefix_cache="off")
+    sched = ContinuousBatchScheduler(n_slots=2, max_queue=8,
+                                     max_len=cfg.seq_len,
+                                     buckets=eng.buckets)
+    loop = eng.start_serve(sched)
+    streams, steps = [], 0
+    for i, p in enumerate(prompts):   # one at a time: a slot stays free
+        req = Request(prompt=np.asarray(p, np.int32), max_new_tokens=new,
+                      rng_tag=i)
+        eng.admit(sched, req)
+        while loop.tick():
+            free = [s for s, r in enumerate(sched.slots) if r is None]
+            assert free, "one request at a time leaves a slot free"
+            lengths = np.asarray(eng.state.lengths)
+            tables = np.asarray(eng.state.block_tables)
+            assert np.all(lengths[free] == 0), (steps, lengths)
+            assert np.all(tables[free] == 0), (steps, tables)
+        streams.append(list(req.generated))
+        steps = loop.stats.decode_steps
+    loop.finish()
+    assert steps > cfg.seq_len, "more decode steps than max_decode_len"
+    assert streams == alone
+    for entry in eng.state.caches.values():
+        for leaf in jax.tree_util.tree_leaves(entry):
+            if leaf.ndim >= 3:
+                assert np.all(np.isfinite(np.asarray(leaf[0], np.float32)))
+    # the counted grid: every step's tiles, and the live ones among them
+    st = loop.stats
+    assert st.kv_tiles_grid == st.decode_steps * 2 * 1   # one tile a row
+    assert 0 < st.kv_tiles_live <= st.decode_steps
+    assert st.decode_grid_live_share() == \
+        st.kv_tiles_live / st.kv_tiles_grid
+    assert st.summary()["decode_grid_live_share"] == round(
+        st.decode_grid_live_share(), 4)
+
+
 def test_flash_decode_of_separate_pools_packs_them():
     """``flash_decode(q, kpool, vpool, ...)``, the entry for a caller
     that holds K and V apart (the benchmark's ahead-of-time check),
