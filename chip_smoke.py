@@ -227,6 +227,79 @@ def check_flash_decode() -> None:
               f"reference: {e:.2e} <= {FLASH_DECODE_TOL:.2e}")
 
 
+def check_flash_decode_latent() -> None:
+    """The kernel's latent read at the ``openpangu-ultra-docqa-8k`` cell's
+    row (128 heads against ONE 576-number row a key, padded to 640 lanes,
+    the value its first 512) and its chunk read (4 positions a grid step,
+    one shared table row), against a float32 gather."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from flexflow_tpu.kernels.flash_decode import flash_decode_pool
+
+    slots, heads, width, v_lanes, bs, extent = 10, 128, 576, 512, 16, 512
+    mb = extent // bs
+    kq, kp = jax.random.split(jax.random.PRNGKey(3))
+    q = jax.random.normal(kq, (slots, heads, width), jnp.bfloat16)
+    pool = jnp.pad(jax.random.normal(
+        kp, (slots * mb + 1, 1, bs, width), jnp.bfloat16),
+        ((0, 0), (0, 0), (0, 0), (0, 64)))
+    tables = jnp.asarray(1 + np.random.default_rng(1).permutation(
+        slots * mb).reshape(slots, mb), jnp.int32)
+    n_keys = jnp.asarray([0, 1, 15, 16, 17, 100, 255, 256, 257, 512],
+                         jnp.int32)
+    scale = 1.0 / np.sqrt(192.0)
+
+    def gather(q, table_rows, seen):
+        """q (n, heads, width) f32, each row of q its own table row and
+        count of keys seen."""
+        ext = pool[table_rows][:, :, 0].reshape(
+            len(table_rows), extent, -1).astype(jnp.float32)
+        s = jnp.einsum("nhw,nkw->nhk", q, ext[..., :width]) * scale
+        s = jnp.where(jnp.arange(extent)[None, None] < seen[:, None, None],
+                      s, -1e30)
+        return jnp.einsum("nhk,nkv->nhv", jax.nn.softmax(s, axis=-1),
+                          ext[..., :v_lanes])
+
+    fn = jax.jit(lambda q, p, t, n: flash_decode_pool(
+        q, p, t, n, sm_scale=scale, v_lanes=v_lanes))
+    check("flash_decode" in mosaic_calls(
+        fn.lower(q, pool, tables, n_keys).compile().as_text()),
+        "flash_decode (latent pool, 128 heads a 640-lane row) compiles to "
+        "a Mosaic kernel")
+    out = fn(q, pool, tables, n_keys)
+    live = np.asarray(n_keys) > 0
+    check(bool(jnp.all(out[~live] == 0)) and bool(jnp.all(
+        jnp.isfinite(out))), "flash_decode (latent): a slot of no keys is "
+        "exact zeros, every value finite")
+    with jax.default_matmul_precision("highest"):
+        want = gather(q.astype(jnp.float32), tables, n_keys)
+    e = rel_err(out[live], want[live])
+    check(e <= FLASH_DECODE_TOL, f"flash_decode (latent) vs float32 gather: "
+          f"{e:.2e} <= {FLASH_DECODE_TOL:.2e}")
+    # the chunk read: 64 positions from 131 on, the last 13 of them pad
+    c, t, start, n_new = 64, 4, 131, 51
+    qc = jax.random.normal(kq, (c, heads, width), jnp.bfloat16)
+    first = jnp.arange(c // t, dtype=jnp.int32) * t
+    nk = jnp.where(first < n_new, start + first + 1, 0)
+    fn = jax.jit(lambda q, p, t_, n: flash_decode_pool(
+        q.reshape(c // t, t * heads, width), p, t_, n, sm_scale=scale,
+        v_lanes=v_lanes, tokens=t).reshape(c, heads, v_lanes))
+    check("latent_chunk_attention" in mosaic_calls(
+        fn.lower(qc, pool, tables[:1], nk).compile().as_text()),
+        "the chunk read compiles to the Mosaic kernel latent_chunk_attention")
+    out = fn(qc, pool, tables[:1], nk)
+    with jax.default_matmul_precision("highest"):
+        want = gather(qc.astype(jnp.float32),
+                      jnp.broadcast_to(tables[:1], (c, mb)),
+                      start + jnp.arange(c) + 1)
+    e = rel_err(out[:n_new], want[:n_new])
+    check(e <= FLASH_DECODE_TOL and bool(jnp.all(jnp.isfinite(out))),
+          f"latent_chunk_attention vs float32 gather over the live rows: "
+          f"{e:.2e} <= {FLASH_DECODE_TOL:.2e}")
+
+
 def check_kv_write() -> None:
     """The pool's in-place write against the scatter it replaces, on the
     chip — where alone the aliased call's hazard exists (it fetches the
@@ -240,7 +313,18 @@ def check_kv_write() -> None:
 
     from flexflow_tpu.serving.kvcache import GARBAGE_BLOCK, write_kv_rows
 
-    heads, bs, lanes, n_blocks = 12, 16, 128, 40
+    for heads, lanes in ((12, 128), (1, 640)):   # K | V heads; a latent row
+        _check_kv_write(heads, lanes)
+
+
+def _check_kv_write(heads: int, lanes: int) -> None:
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from flexflow_tpu.serving.kvcache import GARBAGE_BLOCK, write_kv_rows
+
+    bs, n_blocks = 16, 40
     kp, kr = jax.random.split(jax.random.PRNGKey(2))
     pool = jax.random.normal(kp, (n_blocks, heads, bs, lanes), jnp.bfloat16)
     table = np.asarray([7, 3, 31, 12, 25, 9], np.int32)
@@ -262,6 +346,7 @@ def check_kv_write() -> None:
     }
     live = np.arange(n_blocks) != GARBAGE_BLOCK
     for label, (bi, off, consecutive) in cases.items():
+        label = f"{heads} x {lanes} lanes, {label}"
         rows = jax.random.normal(kr, (len(bi), heads, lanes), jnp.bfloat16)
         bi, off = jnp.asarray(bi, jnp.int32), jnp.asarray(off, jnp.int32)
         fn = jax.jit(lambda p, r, b, o, c=consecutive: write_kv_rows(
@@ -386,6 +471,105 @@ def check_routed_layer(tokens: int = 8192, d: int = 2048,
     check(errs[worst] <= ROUTED_TOL,
           f"routed layer: output and every gradient within {ROUTED_TOL} of "
           f"the float32 dense loop (worst: {worst} {errs[worst]:.4f})")
+
+
+def check_routed_layer_decode(rows: int = 64, d: int = 7680,
+                              inter: int = 2048) -> None:
+    """The same four nodes at the DECODE shapes of the benchmark's
+    ``openpangu-ultra-docqa-8k`` cell (64 rows of 7,680, one a slot; 256
+    experts, top-8 of the sigmoid scores alone scaled 2.5, experts 0-15
+    held at width 2,048; bf16), forward, against the float32 dense loop at
+    the routing the program chose. The cell's own comparison sees a fault
+    of the held experts only where it moves a served token off the
+    reference's best, and a three-mantissa-bit rounding of the grouped
+    products' operands moved none (PERF.md section 6, PR 37: control c
+    reads 0): the same rounding is planted here and must read over the
+    limit, the sound products under it."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from flexflow_tpu.ffconst import DataType
+    from flexflow_tpu.ops import moe_ops
+    from flexflow_tpu.ops.base import OpContext
+
+    n, k, held, scale = 256, 8, (0, 16), 2.5
+    ids = {"num_experts": n, "held": held}
+    bf16 = DataType.DT_BFLOAT16
+    router_op = moe_ops.MoERouterOp(
+        "r", dict(ids, k=k, route_scale=scale, selection_bias=False), bf16)
+    dispatch_op = moe_ops.MoEDispatchOp("d", ids, bf16, 2)
+    experts_op = moe_ops.MoERoutedExpertsOp(
+        "e", dict(ids, intermediate=inter), bf16, 2)
+    combine_op = moe_ops.MoECombineOp("c", ids, bf16, 4)
+    keys = jax.random.split(jax.random.PRNGKey(5), 5)
+
+    def normal(key, shape, std):
+        return (std * jax.random.normal(key, shape, jnp.float32)).astype(
+            jnp.bfloat16)
+
+    x = normal(keys[0], (rows, 1, d), 1.0)         # as an RMS norm leaves it
+    # a router that sends the held experts rows: their columns lead
+    kernel = normal(keys[1], (d, n), (2.0 / (d + n)) ** 0.5)
+    kernel = kernel.at[:, :held[1]].multiply(4.0)
+    std = (2.0 / (d + inter)) ** 0.5
+    experts = {"gate": normal(keys[2], (held[1], d, inter), std),
+               "up": normal(keys[3], (held[1], d, inter), std),
+               "down": normal(keys[4], (held[1], inter, d), std)}
+
+    def system(x, experts, product):
+        ctx = OpContext(stats_out={})
+        weights, chosen = router_op.forward({"kernel": kernel}, [x], ctx)
+        rows_, sizes, order = dispatch_op.forward({}, [x, chosen], ctx)
+        plain = jax.lax.ragged_dot
+        jax.lax.ragged_dot = product
+        try:
+            (out,) = experts_op.forward(experts, [rows_, sizes], ctx)
+        finally:
+            jax.lax.ragged_dot = plain
+        (y,) = combine_op.forward({}, [out, order, weights, chosen], ctx)
+        return y, weights, chosen, ctx.stats_out["d"]
+
+    def round3(a):
+        m, e = jnp.frexp(a.astype(jnp.float32))
+        return jnp.ldexp(jnp.round(m * 16.0) / 16.0, e).astype(a.dtype)
+
+    ragged = jax.lax.ragged_dot
+    y, w, chosen, stats = jax.jit(
+        lambda x, e: system(x, e, ragged))(x, experts)
+    y3 = jax.jit(lambda x, e: system(
+        x, e, lambda lhs, rhs, g, **kw: ragged(round3(lhs), round3(rhs), g,
+                                               **kw))[0])(x, experts)
+    xf = x.astype(jnp.float32)
+    wf = w.astype(jnp.float32)
+    with jax.default_matmul_precision("highest"):
+        y_ref = jnp.zeros_like(xf)
+        for m in range(held[1]):
+            w_e = jnp.sum(jnp.where(chosen == held[0] + m, wf, 0.0), axis=-1)
+            g, u, dn = (experts[name][m].astype(jnp.float32)
+                        for name in ("gate", "up", "down"))
+            y_ref = y_ref + w_e[..., None] * (
+                (jax.nn.silu(xf @ g) * (xf @ u)) @ dn)
+    here = int(np.isin(np.asarray(chosen), np.arange(*held)).sum())
+    live = int((np.asarray(stats["tokens_per_expert"]) > 0).sum())
+    check(int(stats["pairs_here"]) == here > rows
+          and int(stats["dropped"]) == 0,
+          f"routed layer at decode shapes: {here} of {rows * k} (row, "
+          f"expert) pairs are held here, {live} of {held[1]} held experts "
+          "got a row, none dropped")
+    def rel_l2(got):
+        got = np.asarray(got, np.float32)
+        return float(np.linalg.norm(got - np.asarray(y_ref))
+                     / np.linalg.norm(np.asarray(y_ref)))
+
+    sound, planted = rel_l2(y), rel_l2(y3)
+    info(f"routed layer at decode shapes vs float32 at the program's "
+         f"routing, relative L2: sound {sound:.4f}, grouped products in "
+         f"three mantissa bits {planted:.4f} (limit {ROUTED_TOL})")
+    check(sound <= ROUTED_TOL < planted,
+          f"routed layer at decode shapes: within {ROUTED_TOL} of the "
+          f"float32 dense loop ({sound:.4f}), and the grouped products in "
+          f"three mantissa bits are not ({planted:.4f})")
 
 
 # ------------------------------------------------------------------ trainer
@@ -579,8 +763,10 @@ def main() -> None:
 
     check_flash_attention()
     check_flash_decode()
+    check_flash_decode_latent()
     check_kv_write()
     check_routed_layer()
+    check_routed_layer_decode()
 
     n_chips = device["count"]
     bert = BertConfig(batch_size=8 * n_chips, seq_len=512, hidden=1024,

@@ -260,6 +260,7 @@ def check_serving_graph(pcg) -> List[Diagnostic]:
             continue
         for sub in getattr(node.op, "sub_ops", ()):
             stateful = sub.op_type in (OperatorType.OP_MULTIHEAD_ATTENTION,
+                                       OperatorType.OP_LATENT_ATTENTION,
                                        OperatorType.OP_LSTM)
             positional = (sub.op_type == OperatorType.OP_CONSTANT
                           and is_position_constant(sub.attrs.get("value")))
@@ -416,6 +417,13 @@ def check_paged_kv(pcg, *, block_size: int, pool_blocks: int,
             fix_hint=hint))
     if kv_layout == "sharded" and tp > 1 and pcg is not None:
         for node in pcg.compute_nodes():
+            if node.op.op_type == OperatorType.OP_LATENT_ATTENTION:
+                out.append(Diagnostic(
+                    rule_id="FF006", node=node.name,
+                    message=("paged KV: a latent pool holds one row a "
+                             "token for all heads; a heads-sharded "
+                             "layout has no axis to split it by"),
+                    fix_hint="use the replicated KV layout"))
             if node.op.op_type != OperatorType.OP_MULTIHEAD_ATTENTION:
                 continue
             heads = int(node.op.attrs.get("num_heads", 1))

@@ -345,6 +345,13 @@ class FFConfig:
     # tensor dtypes. Master weights, loss, and normalization stay float32 —
     # the standard TPU mixed-precision recipe (bf16 on the MXU).
     compute_dtype: DataType = DataType.DT_NONE
+    # the dtype floating-point parameters REST in, stated once
+    # (``--param-dtype``). DT_NONE = each weight's own (float32 masters,
+    # what training wants). ``bf16`` is a serving deployment's choice: the
+    # tree is initialised leaf by leaf and each leaf is cast before the
+    # next is made, so no float32 copy of the whole ever exists
+    # (Executor.init_params).
+    param_dtype: DataType = DataType.DT_NONE
     seed: int = 42
 
     iteration_config: FFIterationConfig = dataclasses.field(
@@ -473,6 +480,10 @@ class FFConfig:
                 from .ffconst import str_to_dtype
 
                 self.compute_dtype = str_to_dtype(_next())
+            elif a == "--param-dtype":
+                from .ffconst import str_to_dtype
+
+                self.param_dtype = str_to_dtype(_next())
             elif a == "--enable-propagation":
                 pass  # legacy MCMC propagation; accepted for compatibility
             elif a == "--disable-control-replication":

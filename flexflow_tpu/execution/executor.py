@@ -138,6 +138,10 @@ class Executor:
         import jax
 
         entries = self.weight_entries()
+        rest = getattr(self.config, "param_dtype", None)
+        if rest is not None and rest != DataType.DT_NONE:
+            return self._init_params_at_rest(entries, seed,
+                                             dtype_to_jnp(rest))
 
         def init_fn(key):
             params: Dict[str, Dict[str, Any]] = {}
@@ -152,6 +156,36 @@ class Executor:
             shardings = self.param_shardings()
             return jax.jit(init_fn, out_shardings=shardings)(key)
         return jax.jit(init_fn)(key)
+
+    def _init_params_at_rest(self, entries, seed: int, rest):
+        """The tree with every floating-point leaf resting in ``rest``
+        (``--param-dtype``): one small program a leaf — drawn in the
+        weight's own dtype, as the one-program init draws it, and cast
+        before the next leaf is made — so the peak is the tree at rest
+        plus ONE leaf in float32, never a float32 tree."""
+        import jax
+        import jax.numpy as jnp
+
+        key = jax.random.PRNGKey(seed)
+        shardings = self.param_shardings() if self.mesh is not None else None
+        programs: Dict[Tuple, Any] = {}
+        params: Dict[str, Dict[str, Any]] = {}
+        for i, (node, wname, shape, dtype, init) in enumerate(entries):
+            drawn = dtype_to_jnp(dtype)
+            to = rest if jnp.issubdtype(drawn, jnp.floating) else drawn
+            out = shardings[node.name][wname] if shardings else None
+            # one compile for the leaves that share shape and initialiser
+            # (the layers' twins), whatever their place in the tree
+            pkey = (type(init), tuple(sorted(vars(init).items())),
+                    tuple(shape), drawn, out)
+            if pkey not in programs:
+                programs[pkey] = jax.jit(
+                    lambda k, init=init, shape=shape, drawn=drawn, to=to:
+                    init(k, shape, drawn).astype(to), out_shardings=out)
+            leaf = programs[pkey](jax.random.fold_in(key, i))
+            params.setdefault(node.name, {})[wname] = leaf
+            leaf.block_until_ready()  # the f32 draw is gone before the next
+        return params
 
     # --------------------------------------------------------- mixed precision
     def _compute_jnp_dtype(self):
@@ -1026,7 +1060,10 @@ class Executor:
         step additionally returns ``ok`` — ``isfinite`` of each slot's
         logits reduced to a (n_slots,) bool vector — fused into the same
         program, so the only extra host traffic is that one bool vector
-        per step. The logits themselves are untouched: a poisoned slot's
+        per step. A graph with routed expert layers returns one more
+        value, last: the step's routing counters ``(3,)`` int32 (see
+        below), counted on the device. The logits themselves are
+        untouched: a poisoned slot's
         quarantine decision is the HOST's (serving/resilience.py), and
         every healthy slot's values stay bitwise-identical to the
         unguarded step's.
@@ -1065,8 +1102,10 @@ class Executor:
                               block_size=int(block_size),
                               kv_dtype=str(kv_dtype),
                               seq_shards=int(seq_shards))
+            stats_out: Dict[str, Any] = {}
             ctx = OpContext(training=False, rng=None, mesh=mesh,
-                            profiling=profiling, serving=sv)
+                            profiling=profiling, serving=sv,
+                            stats_out=stats_out)
             values = self.forward_outputs(
                 params, self._bind_inputs(xs), ctx,
                 overrides=self._serving_overrides(
@@ -1076,10 +1115,24 @@ class Executor:
             new_state = DecodeState(caches=sv.cache_out,
                                     lengths=state.advanced_lengths(),
                                     block_tables=state.block_tables)
+            out = (logits, new_state)
             if guard:
-                ok = jnp.all(jnp.isfinite(logits), axis=-1)
-                return logits, new_state, ok
-            return logits, new_state
+                out += (jnp.all(jnp.isfinite(logits), axis=-1),)
+            routed = [v["tokens_per_expert"] for v in stats_out.values()
+                      if "tokens_per_expert" in v]
+            if routed:
+                # a graph with routed expert layers hands its step's
+                # counters out LAST: [pairs held here, held experts that
+                # got a row, the fullest expert's rows over its layer's
+                # mean in thousandths (the largest over the layers)]
+                out += (jnp.stack([
+                    sum(jnp.sum(t) for t in routed),
+                    sum(jnp.sum(t > 0) for t in routed),
+                    jnp.max(jnp.stack([
+                        1000 * jnp.max(t) * t.shape[0]
+                        // jnp.maximum(jnp.sum(t), 1) for t in routed])),
+                ]).astype(jnp.int32),)
+            return out
 
         fn = named_jit("decode", decode, donate_argnums=(2,))
         self._serving_jits[key] = fn

@@ -218,6 +218,10 @@ class OperatorType(enum.IntEnum):
     OP_MOE_DISPATCH = 120
     OP_MOE_ROUTED_EXPERTS = 121
     OP_MOE_COMBINE = 122
+    # multi-head latent attention (ops/latent_attention.py): keys and values
+    # up-projected from one compressed row a token, which is what serving
+    # caches
+    OP_LATENT_ATTENTION = 123
 
 
 # --- dtype helpers -------------------------------------------------------------

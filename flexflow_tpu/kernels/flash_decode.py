@@ -92,8 +92,16 @@ def tile_blocks(pool_shape, itemsize: int, table_width: int) -> int:
 
 
 def _decode_kernel(tab_ref, len_ref, q_ref, pool_ref, *rest, block_size,
-                   tile_blocks, n_tiles_grid, kd, int8):
-    """One (slot, key tile) grid step of the split-K recurrence."""
+                   tile_blocks, n_tiles_grid, kd, int8, latent=False,
+                   tokens=1, shared_table=False):
+    """One (slot, key tile) grid step of the split-K recurrence. ``latent``:
+    the query block is a group's heads against one stored row a key, and the
+    two products take their operands as stored (the pool's dtype) and
+    accumulate in float32. ``tokens`` > 1 (latent): the block's rows are
+    that many successive positions of one sequence, heads innermost, and
+    ``len_ref[s]`` counts the keys of the FIRST of them — token ``t``
+    sees ``t`` more (a prefill chunk's causal mask); ``shared_table``:
+    every slot reads the table's one row."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
@@ -108,13 +116,18 @@ def _decode_kernel(tab_ref, len_ref, q_ref, pool_ref, *rest, block_size,
     s = pl.program_id(0)
     j = pl.program_id(1)
     n_keys = len_ref[s]
-    n_tiles = (n_keys + tile_keys - 1) // tile_keys   # the slot's live ones
+    row = 0 if shared_table else s
+    if tokens > 1:   # the last token's keys decide the slot's live tiles
+        n_tiles = jnp.where(n_keys > 0, (n_keys + tokens - 1 + tile_keys
+                                         - 1) // tile_keys, 0)
+    else:
+        n_tiles = (n_keys + tile_keys - 1) // tile_keys   # the live ones
 
     def gather(tile, buf):
         """The copies of one tile's blocks into half ``buf`` of the
         buffer: started once, waited once."""
         return [pltpu.make_async_copy(
-            pool_ref.at[tab_ref[s, tile * P + i]], kv_buf.at[buf, i],
+            pool_ref.at[tab_ref[row, tile * P + i]], kv_buf.at[buf, i],
             sems.at[buf, i]) for i in range(P)]
 
     @pl.when(j == 0)
@@ -140,10 +153,12 @@ def _decode_kernel(tab_ref, len_ref, q_ref, pool_ref, *rest, block_size,
         for copy in gather(j, buf):
             copy.wait()
         # (h, 1, lanes): pre-scaled, zero over V's lanes
-        q = q_ref[0].astype(jnp.float32)
+        q = q_ref[0] if latent else q_ref[0].astype(jnp.float32)
 
         def block(i):                             # (h, bs, lanes): K | V
-            kv = kv_buf[buf, i].astype(jnp.float32)
+            kv = kv_buf[buf, i]
+            if not latent:
+                kv = kv.astype(jnp.float32)
             if int8:
                 lane = jax.lax.broadcasted_iota(jnp.int32, kv.shape, 2)
                 sc = sc_refs[i][0]
@@ -158,14 +173,18 @@ def _decode_kernel(tab_ref, len_ref, q_ref, pool_ref, *rest, block_size,
                             preferred_element_type=jnp.float32)
         kpos = j * tile_keys + jax.lax.broadcasted_iota(
             jnp.int32, s_tile.shape, 2)
-        s_tile = jnp.where(kpos < n_keys, s_tile, NEG_INF)
+        seen = n_keys
+        if tokens > 1:   # row r is token r // heads-of-the-group
+            seen = n_keys + jax.lax.broadcasted_iota(
+                jnp.int32, s_tile.shape, 1) // (s_tile.shape[1] // tokens)
+        s_tile = jnp.where(kpos < seen, s_tile, NEG_INF)
         m_prev = m_ref[:, :, :1]                  # (h, 1, 1)
         m_new = jnp.maximum(m_prev, jnp.max(s_tile, axis=-1,
                                             keepdims=True))
         p = jnp.exp(s_tile - m_new)               # (h, 1, tile)
         corr = jnp.exp(m_prev - m_new)            # (h, 1, 1)
         # (h, 1, lanes): V's lanes are the output, K's are never read
-        pv = jnp.einsum("hqk,hkd->hqd", p, kv,
+        pv = jnp.einsum("hqk,hkd->hqd", p.astype(kv.dtype), kv,
                         preferred_element_type=jnp.float32)
         acc_ref[:] = acc_ref[:] * corr + pv
         l_new = l_ref[:, :, :1] * corr + jnp.sum(p, axis=-1, keepdims=True)
@@ -183,7 +202,8 @@ def _decode_kernel(tab_ref, len_ref, q_ref, pool_ref, *rest, block_size,
 
 def flash_decode_pool(q, pool, block_tables, n_keys, *,
                       sm_scale: Optional[float] = None, scales=None,
-                      interpret: bool = False):
+                      interpret: bool = False,
+                      v_lanes: Optional[int] = None, tokens: int = 1):
     """Single-token paged attention over one KV block pool.
 
     q            (n_slots, heads, kd) — this step's query rows
@@ -196,6 +216,25 @@ def flash_decode_pool(q, pool, block_tables, n_keys, *,
                  for a live slot, 0 for one that attends nothing (a free
                  slot: no live step, no bytes, an output of exact zeros)
 
+    ``v_lanes`` names the LATENT layout (kvcache.py): the pool holds one
+    row a key whatever the query heads (``heads`` 1, or the K/V heads of
+    a grouped read), ``q`` is ``(n_slots, heads * group, kd)`` and a
+    grid step scores a group's query rows — a ``(group, lanes)`` block
+    — against the tile's rows in one product; the value is the row's
+    first ``v_lanes`` lanes, so the output is ``(n_slots, heads * group,
+    v_lanes)``. The products take the pool's dtype (bf16 on the chip)
+    and accumulate in float32. Same grid, same gather, same name.
+
+    ``tokens`` > 1 (latent only) is a prefill CHUNK's read through the
+    same kernel: a slot is ``tokens`` successive positions of ONE
+    sequence — ``q`` is ``(n_slots, tokens * heads * group, kd)``, token
+    major — ``block_tables`` is that sequence's one row ``(1, mb)``
+    shared by every slot, and ``n_keys[s]`` counts the keys of the
+    slot's FIRST token (0: a slot of pad rows, which costs nothing);
+    token ``t`` of the slot sees ``t`` more. The call is named
+    ``latent_chunk_attention`` in the compiled program, so that a trace
+    tells the chunk's reads from the decode step's.
+
     The grid is ``(n_slots, ceil(max_blocks_per_slot / P))``, ``P =
     tile_blocks(pool.shape, itemsize, max_blocks_per_slot)`` table
     entries a step; the table is padded to whole tiles with the garbage
@@ -207,7 +246,8 @@ def flash_decode_pool(q, pool, block_tables, n_keys, *,
     lowered once a program and not once a layer (48 times at GPT-2 XL,
     seconds of every set-up)."""
     return _jitted_pool()(q, pool, block_tables, n_keys, sm_scale=sm_scale,
-                          scales=scales, interpret=interpret)
+                          scales=scales, interpret=interpret,
+                          v_lanes=v_lanes, tokens=int(tokens))
 
 
 @functools.lru_cache(maxsize=1)
@@ -215,11 +255,12 @@ def _jitted_pool():
     import jax
 
     return jax.jit(_flash_decode_pool,
-                   static_argnames=("sm_scale", "interpret"))
+                   static_argnames=("sm_scale", "interpret", "v_lanes",
+                                    "tokens"))
 
 
 def _flash_decode_pool(q, pool, block_tables, n_keys, *, sm_scale, scales,
-                       interpret):
+                       interpret, v_lanes=None, tokens=1):
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -235,10 +276,30 @@ def _flash_decode_pool(q, pool, block_tables, n_keys, *, sm_scale, scales,
     int8 = pool.dtype == jnp.int8
     if int8 and scales is None:
         raise ValueError("flash_decode: an int8 pool needs its scales")
+    latent = v_lanes is not None
+    shared_table = tokens > 1
+    if shared_table and not (latent and block_tables.shape[0] == 1
+                             and heads % tokens == 0):
+        raise ValueError(
+            f"flash_decode: tokens={tokens} is the latent chunk read: one "
+            f"shared table row and q rows a multiple of it; got q "
+            f"{q.shape}, tables {block_tables.shape}, v_lanes {v_lanes}")
+    if latent and (int8 or heads % _h or not 0 < v_lanes <= kd <= lanes):
+        raise ValueError(
+            f"flash_decode: the latent read takes an fp pool whose heads "
+            f"divide the query's and a value that is a lane prefix of the "
+            f"key; got q {q.shape}, pool {pool.shape} {pool.dtype}, "
+            f"v_lanes {v_lanes}")
     scale = sm_scale if sm_scale is not None else 1.0 / np.sqrt(kd)
     out_dtype = q.dtype
     q = q.astype(jnp.float32) * jnp.float32(scale)
-    q = jnp.pad(q, ((0, 0), (0, 0), (0, lanes - kd)))[:, :, None, :]
+    rows = 1  # query rows a pool head: the group's heads in the latent form
+    if latent:
+        rows, heads = heads // _h, _h
+        q = jnp.pad(q, ((0, 0), (0, 0), (0, lanes - kd))).reshape(
+            n_slots, heads, rows, lanes).astype(pool.dtype)
+    else:
+        q = jnp.pad(q, ((0, 0), (0, 0), (0, lanes - kd)))[:, :, None, :]
     P = tile_blocks(pool.shape, pool.dtype.itemsize, mb)
     n_tiles = -(-mb // P)
     tables = jnp.pad(block_tables.astype(jnp.int32),
@@ -259,7 +320,7 @@ def _flash_decode_pool(q, pool, block_tables, n_keys, *, sm_scale, scales,
             return (tab_ref[s, jj], 0, 0, 0)
         return index
 
-    in_specs = [pl.BlockSpec((1, heads, 1, lanes), slot_row),
+    in_specs = [pl.BlockSpec((1, heads, rows, lanes), slot_row),
                 pl.BlockSpec(memory_space=pltpu.HBM)]
     args = [q, pool]
     if int8:
@@ -270,27 +331,31 @@ def _flash_decode_pool(q, pool, block_tables, n_keys, *, sm_scale, scales,
         num_scalar_prefetch=2,
         grid=(n_slots, n_tiles),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, heads, 1, lanes), slot_row),
+        out_specs=pl.BlockSpec((1, heads, rows, lanes), slot_row),
         scratch_shapes=[
             # two tiles of P blocks: one folded, the next in flight
             pltpu.VMEM((2, P, heads, block_size, lanes), pool.dtype),
             pltpu.SemaphoreType.DMA((2, P)),
-            pltpu.VMEM((heads, 1, 128), jnp.float32),    # m
-            pltpu.VMEM((heads, 1, 128), jnp.float32),    # l
-            pltpu.VMEM((heads, 1, lanes), jnp.float32),  # acc
+            pltpu.VMEM((heads, rows, 128), jnp.float32),    # m
+            pltpu.VMEM((heads, rows, 128), jnp.float32),    # l
+            pltpu.VMEM((heads, rows, lanes), jnp.float32),  # acc
         ],
     )
     fn = pl.pallas_call(
         functools.partial(_decode_kernel, block_size=block_size,
                           tile_blocks=P, n_tiles_grid=n_tiles, kd=kd,
-                          int8=int8),
+                          int8=int8, latent=latent, tokens=tokens,
+                          shared_table=shared_table),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n_slots, heads, 1, lanes),
+        out_shape=jax.ShapeDtypeStruct((n_slots, heads, rows, lanes),
                                        out_dtype),
         interpret=resolve_interpret(interpret),
-        name="flash_decode",
+        name="latent_chunk_attention" if shared_table else "flash_decode",
     )
-    return fn(tables, n_keys, *args)[:, :, 0, kd:]
+    out = fn(tables, n_keys, *args)
+    if latent:
+        return out[..., :v_lanes].reshape(n_slots, heads * rows, v_lanes)
+    return out[:, :, 0, kd:]
 
 
 def flash_decode(q, kpool, vpool, block_tables, n_keys, *,

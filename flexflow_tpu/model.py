@@ -215,6 +215,28 @@ class FFModel:
         return self._add_layer(OperatorType.OP_MULTIHEAD_ATTENTION,
                                [query, key, value], attrs, query.dtype, name)
 
+    def latent_attention(self, input: Tensor, embed_dim: int,
+                         num_heads: int, q_rank: int, kv_rank: int,
+                         nope_dim: int, rope_dim: int, v_dim: int,
+                         rope_theta: float = 10000.0, eps: float = 1e-6,
+                         kernel_initializer=None,
+                         name: Optional[str] = None) -> Tensor:
+        """Causal multi-head latent attention (ops/latent_attention.py):
+        queries through a ``q_rank`` bottleneck, keys and values
+        up-projected from a ``kv_rank`` row a token that carries one shared
+        rotary key of ``rope_dim`` beside it; heads of ``nope_dim +
+        rope_dim`` for the score and ``v_dim`` for the value. Under a
+        serving context the row is what the KV pool holds."""
+        attrs = {"embed_dim": embed_dim, "num_heads": num_heads,
+                 "q_rank": q_rank, "kv_rank": kv_rank, "nope_dim": nope_dim,
+                 "rope_dim": rope_dim, "v_dim": v_dim,
+                 "rope_theta": rope_theta, "eps": eps, "causal": True,
+                 "kernel_initializer": kernel_initializer}
+        if rope_dim % 2:
+            raise ValueError("latent_attention: rope_dim must be even")
+        return self._add_layer(OperatorType.OP_LATENT_ATTENTION, [input],
+                               attrs, input.dtype, name)
+
     # ---- elementwise ----------------------------------------------------------
     def _binary(self, op_type, x, y, name=None, inplace_a=False):
         return self._add_layer(op_type, [x, y], {}, x.dtype, name)
@@ -499,7 +521,8 @@ class FFModel:
     def routed_experts(self, input: Tensor, num_experts: int, k: int,
                        intermediate: int, held=None, route_norm: bool = True,
                        route_scale: float = 1.0, kernel_initializer=None,
-                       name: str = "moe") -> Tensor:
+                       name: str = "moe",
+                       selection_bias: bool = True) -> Tensor:
         """The dropless routed expert layer (ops/moe_ops.py): router
         (sigmoid scores) -> dispatch by a stable sort on expert id -> grouped products over the
         experts held here -> combine; no token is dropped, whatever the
@@ -507,7 +530,9 @@ class FFModel:
         ``num_experts`` this device holds (default: all); the router ranks
         all of them and the output is the held experts' partial sum. The
         four nodes are ``<name>router``, ``<name>dispatch``,
-        ``<name>experts`` and ``<name>combine``."""
+        ``<name>experts`` and ``<name>combine``. ``selection_bias=False``
+        is a router with no ``expert_bias`` buffer: the 8 largest scores
+        are the choice."""
         held = tuple(held) if held is not None else (0, num_experts)
         if not (0 <= held[0] and held[1] >= 1
                 and held[0] + held[1] <= num_experts):
@@ -518,7 +543,8 @@ class FFModel:
             OperatorType.OP_MOE_ROUTER, [input],
             dict(ids, k=k, route_norm=route_norm,
                  route_scale=route_scale,
-                 kernel_initializer=kernel_initializer),
+                 kernel_initializer=kernel_initializer,
+                 **({} if selection_bias else {"selection_bias": False})),
             input.dtype, f"{name}router")
         rows, sizes, order = self._add_layer(
             OperatorType.OP_MOE_DISPATCH, [input, chosen], dict(ids),

@@ -2,7 +2,9 @@
 AlexNet/CIFAR-10, ResNet-50, ResNeXt-50, InceptionV3, Transformer, BERT-Large,
 GPT-2 (decoder-only causal LM), DLRM, XDL, MLP_Unify, CANDLE-Uno, MoE,
 NMT (LSTM seq2seq); and, with no reference analog, the sliding/global
-grouped-query decoder with a dropless routed expert layer (trinity.py)."""
+grouped-query decoder with a dropless routed expert layer (trinity.py) and
+the latent-attention decoder with sandwich norms and a routed top-8 layer,
+built for the serving path (pangu.py)."""
 from .bert import BertConfig, build_bert, bert_param_count  # noqa: F401
 from .gpt2 import (GPT2Config, build_gpt2,  # noqa: F401
                    gpt2_param_count, gpt2_train_flops_per_step)
@@ -16,3 +18,6 @@ from .misc import (build_mlp_unify, build_xdl,  # noqa: F401
 from .nmt import NMTConfig, build_nmt  # noqa: F401
 from .trinity import (TrinityConfig, build_trinity,  # noqa: F401
                       trinity_param_count, trinity_train_flops_per_token)
+from .pangu import (PanguConfig, build_pangu,  # noqa: F401
+                    pangu_param_count, pangu_prefill_flops_per_token,
+                    pangu_decode_flops_per_token)

@@ -124,6 +124,10 @@ class StepTelemetry:
         # that held a live slot's key (ServingStats.kv_tiles_grid / _live)
         self.serving_kv_tiles_grid: int = 0
         self.serving_kv_tiles_live: int = 0
+        # routed expert layers at decode shapes (ServingStats.moe_*)
+        self.serving_moe_pairs_here: int = 0
+        self.serving_moe_experts_live: int = 0
+        self.serving_moe_load_max_permille: int = 0
         # serving-resilience counters (ISSUE 9): the outcome ledger of a
         # serve() run (every request under exactly one of ok |
         # deadline_exceeded | shed | decode_fault | preempted) plus the
@@ -331,6 +335,11 @@ class StepTelemetry:
                 sv["decode_grid_live_share"] = round(
                     self.serving_kv_tiles_live
                     / self.serving_kv_tiles_grid, 4)
+            if self.serving_moe_pairs_here:
+                sv["moe_pairs_here"] = self.serving_moe_pairs_here
+                sv["moe_experts_live"] = self.serving_moe_experts_live
+                sv["moe_load_max_permille"] = \
+                    self.serving_moe_load_max_permille
             out["serving"] = sv
         if self.fleet_replicas:
             total = max(sum(self.fleet_outcomes.values()), 1)

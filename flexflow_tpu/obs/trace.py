@@ -336,7 +336,10 @@ SPANS: Mapping[str, str] = MappingProxyType({
                              "keeping up",
     # the serve tick (_ServeLoop / _AsyncServeLoop)
     "serve_tick": "one tick(); kind = prefill | prefill_chunk | decode | "
-                  "idle; pipelined=1 when a step was in flight at entry",
+                  "idle; pipelined=1 when a step was in flight at entry; a "
+                  "decode tick of a graph with routed expert layers carries "
+                  "moe_pairs_here, moe_experts_live, moe_load_max_permille "
+                  "(counted on the device, fetched with its tokens)",
     "tick_dispatch": "tick entry -> the device call is issued: deadline "
                      "sweep, next_action(), building ids",
     "prefill": "the bucket's prefill call up to and including the "

@@ -7,6 +7,7 @@ from . import elementwise  # noqa: F401
 from . import normalization  # noqa: F401
 from . import tensor_ops  # noqa: F401
 from . import attention  # noqa: F401
+from . import latent_attention  # noqa: F401
 from . import embedding  # noqa: F401
 from . import moe_ops  # noqa: F401
 from . import noop  # noqa: F401

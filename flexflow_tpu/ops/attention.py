@@ -155,11 +155,14 @@ class MultiHeadAttentionOp(Op):
             # decode shapes (seq 1) must never reach flash/ring
             if not plain or self.attrs.get("rope_theta"):
                 raise NotImplementedError(
-                    f"{self.name}: the serving path holds as many K/V heads "
-                    "as query heads, whole-context attention and learned "
-                    "positions; grouped K/V heads, a sliding window and "
+                    f"{self.name}: multihead_attention on the serving path "
+                    "holds as many K/V heads as query heads, whole-context "
+                    "attention and positions from the graph (a learned "
+                    "table); its grouped K/V heads, sliding window and "
                     "rotary positions run on the training path only "
-                    "(ROADMAP.md, Reach R1)")
+                    "(ROADMAP.md, Reach R3 (a)-(c)). Rotary positions from "
+                    "the serving context are latent_attention's "
+                    "(ops/latent_attention.py)")
             out = _serving_attention(self.name, q, k, v, ctx.serving,
                                      causal=causal)
         elif seq_axis and ctx.mesh is not None and seq_axis in ctx.mesh.shape:

@@ -391,7 +391,8 @@ def _mask_cotangent(rows, n_valid):
 
 @register_op(OperatorType.OP_MOE_ROUTER)
 class MoERouterOp(Op):
-    """attrs: num_experts, k, route_norm, route_scale, kernel_initializer.
+    """attrs: num_experts, k, route_norm, route_scale, kernel_initializer,
+    selection_bias (False: no ``expert_bias``, the scores alone rank).
     Scores are ``sigmoid`` of the logits in float32. Weights: ``kernel`` (d, num_experts, no
     bias) and ``expert_bias`` (num_experts,), a buffer added to the scores
     for the SELECTION only: the weights are the plain scores of the chosen,
@@ -414,12 +415,14 @@ class MoERouterOp(Op):
                                               UniformInitializer)
 
         d, n = input_shapes[0][-1], self.attrs["num_experts"]
-        return {"kernel": ((d, n), self.data_type,
-                           self.attrs.get("kernel_initializer")
-                           or DefaultWeightInitializer()),
-                "expert_bias": ((n,), self.data_type,
-                                UniformInitializer(min_val=-0.01,
-                                                   max_val=0.01))}
+        specs = {"kernel": ((d, n), self.data_type,
+                            self.attrs.get("kernel_initializer")
+                            or DefaultWeightInitializer())}
+        if self.attrs.get("selection_bias", True):
+            specs["expert_bias"] = ((n,), self.data_type,
+                                    UniformInitializer(min_val=-0.01,
+                                                       max_val=0.01))
+        return specs
 
     def forward(self, params, inputs, ctx: OpContext):
         import jax
@@ -429,9 +432,11 @@ class MoERouterOp(Op):
         logits = jnp.dot(x, params["kernel"],
                          preferred_element_type=jnp.float32)
         scores = jax.nn.sigmoid(logits)
-        bias = jax.lax.stop_gradient(
-            params["expert_bias"].astype(jnp.float32))
-        _, chosen = jax.lax.top_k(scores + bias, self.attrs["k"])
+        ranked = scores
+        if "expert_bias" in params:
+            ranked = scores + jax.lax.stop_gradient(
+                params["expert_bias"].astype(jnp.float32))
+        _, chosen = jax.lax.top_k(ranked, self.attrs["k"])
         weights = jnp.take_along_axis(scores, chosen, axis=-1)
         if self.attrs.get("route_norm", True):
             weights = weights / (jnp.sum(weights, axis=-1, keepdims=True)

@@ -153,6 +153,15 @@ class ServingStats:
     # is above a real chip's HBM budget while this per-chip figure is
     # below it. Set at serve-loop finish; 0 until a decode step ran.
     kv_hbm_per_chip_bytes: int = 0
+    # routed expert layers at decode shapes: counted on the device by
+    # the decode step and fetched with its tokens (the sync loop; the
+    # async loop does not fetch them) — the (row, expert) pairs held
+    # here and the held experts that got at least one row, both summed
+    # over layers and decode steps, and the fullest expert's rows over
+    # its layer's mean in thousandths, the largest seen
+    moe_pairs_here: int = 0
+    moe_experts_live: int = 0
+    moe_load_max_permille: int = 0
 
     def record_token(self, wall_s: float) -> None:
         self.token_walls_s.append(wall_s)
@@ -273,6 +282,10 @@ class ServingStats:
             out["host_syncs"] = self.host_syncs
         if self.kv_hbm_per_chip_bytes:
             out["kv_hbm_per_chip_bytes"] = self.kv_hbm_per_chip_bytes
+        for k in ("moe_pairs_here", "moe_experts_live",
+                  "moe_load_max_permille"):
+            if getattr(self, k):
+                out[k] = getattr(self, k)
         return out
 
 
@@ -471,6 +484,8 @@ class ServingEngine:
         self.resilience_clock = None
         self.drained_requests: List[Request] = []
         self._last_guard = False
+        # a routed graph's counters of the newest decode step, on the device
+        self._step_counters = None
         # resilience state accumulated by pre-serve admit() calls (shed
         # counts, deadline arming) — consumed by the next serve() so the
         # ledger never loses events to a throwaway policy object
@@ -729,20 +744,33 @@ class ServingEngine:
     def _ensure_state_bootstrap(self) -> None:
         """A chunk action needs the pool, but the pool structure comes
         from a prefill cache and none has run yet (first-ever admission
-        went straight to the chunk path): derive it from one smallest-
-        bucket prefill on a dummy token — the same program the health
-        probe dispatches, so steady-state this is a warm compile and
-        the cache content is discarded (``_ensure_state`` builds
-        zeroed pools from its STRUCTURE only)."""
+        went straight to the chunk path). The pool needs the cache's
+        STRUCTURE alone (``_ensure_state`` builds zeroed pools from it):
+        trace the smallest bucket's prefill for its shapes and hand over
+        zeros. The program itself is not run — a long-document engine's
+        one bucket covers its longest prompt, no admission of a chunked
+        engine uses it, and at that length it may not fit the chip."""
+        import jax
         import jax.numpy as jnp
 
         if self.state is not None:
             return
         b0 = self.buckets[0]
-        ids = np.zeros((1, b0), np.int32)
-        _lg, _last, cache = self._prefill_fn(b0)(
-            self.model.params, [jnp.asarray(ids)],
-            jnp.asarray([1], jnp.int32))
+        shapes = jax.eval_shape(
+            self._prefill_fn(b0), self.model.params,
+            [jax.ShapeDtypeStruct((1, b0), jnp.int32)],
+            jax.ShapeDtypeStruct((1,), jnp.int32))[2]
+        # placed with the weights (committed; whole on every chip of
+        # their mesh), as a prefill's cache would be: the slot writer's
+        # output below then carries the placement every later step's
+        # state will
+        where = jax.tree.leaves(self.model.params)[0].sharding
+        if isinstance(where, jax.sharding.NamedSharding):
+            where = jax.sharding.NamedSharding(
+                where.mesh, jax.sharding.PartitionSpec())
+        cache = jax.tree.map(
+            lambda s: jax.device_put(jnp.zeros(s.shape, s.dtype), where),
+            shapes)
         self._ensure_state(cache)
         # normalize through the classic slot writer — a value-level
         # no-op (dummy cache scattered at an all-garbage row, slot 0,
@@ -753,6 +781,14 @@ class ServingEngine:
         self._write_slot(cache, 0, 0, 0,
                          table_row=np.zeros((self.max_blocks_per_slot,),
                                             np.int32))
+        if self._prefix is not None:
+            # an engine that admits by chunks meets a shared partial block
+            # as soon as two prompts share a prefix: compile the
+            # copy-on-write clone with the pool, on the garbage block
+            # (copied onto itself), not under the first such admission
+            from .kvcache import GARBAGE_BLOCK
+
+            self._cow_clone(GARBAGE_BLOCK, GARBAGE_BLOCK)
 
     def prefix_peek(self, tokens, cap: Optional[int] = None) -> int:
         """Longest cached-prefix length (tokens) the engine's trie holds
@@ -1071,25 +1107,16 @@ class ServingEngine:
     def _kv_row_bytes(self) -> int:
         """Analytic KV bytes ONE token's row costs across every attention
         node — heads * (kdim + vdim) * element size (int8 layouts add the
-        two f32 per-(token, head) scales). The decode bytes-read/token
+        two f32 per-(token, head) scales); a latent node's one stored row,
+        once a token whatever its heads. The decode bytes-read/token
         bench column and the admission-honesty math both price from
         this."""
         if getattr(self, "_kv_row_bytes_cache", None) is None:
-            from ..ffconst import size_of_datatype
-            from .kvcache import kv_token_bytes
+            from .kvcache import node_token_bytes
 
-            total = 0
-            for node in self.executor.pcg.compute_nodes():
-                if node.op.op_type != OperatorType.OP_MULTIHEAD_ATTENTION:
-                    continue
-                a = node.op.attrs
-                heads = int(a.get("num_heads", 1))
-                kd = int(a.get("kdim") or a["embed_dim"] // heads)
-                vd = int(a.get("vdim") or a["embed_dim"] // heads)
-                total += kv_token_bytes(
-                    heads, kd, vd, size_of_datatype(node.op.data_type),
-                    self.kv_dtype)
-            self._kv_row_bytes_cache = total
+            self._kv_row_bytes_cache = sum(
+                node_token_bytes(node.op, self.kv_dtype)
+                for node in self.executor.pcg.compute_nodes())
         return self._kv_row_bytes_cache
 
     def _kv_tile_blocks(self) -> int:
@@ -1202,13 +1229,13 @@ class ServingEngine:
                     if n is not None:
                         raise DeviceLossError(n)
                 decode = self._decode_fn(guard=guard)
-                if guard:
-                    logits, self.state, ok = decode(
-                        params, [self._last_tokens], self.state)
-                    return logits, ok
-                logits, self.state = decode(params, [self._last_tokens],
-                                            self.state)
-                return logits, None
+                logits, self.state, *rest = decode(
+                    params, [self._last_tokens], self.state)
+                ok = rest.pop(0) if guard else None
+                # a routed graph's counters of this step, still on the
+                # device: the sync loop fetches them with the tokens
+                self._step_counters = rest[0] if rest else None
+                return logits, ok
             except Exception as e:  # noqa: BLE001 — filtered just below
                 if not looks_like_device_loss(e):
                     raise
@@ -1259,6 +1286,9 @@ class ServingEngine:
         # the decode attention kernel's grid and how much of it had work
         tel.serving_kv_tiles_grid = stats.kv_tiles_grid
         tel.serving_kv_tiles_live = stats.kv_tiles_live
+        tel.serving_moe_pairs_here = stats.moe_pairs_here
+        tel.serving_moe_experts_live = stats.moe_experts_live
+        tel.serving_moe_load_max_permille = stats.moe_load_max_permille
         # serving_resilience block (ISSUE 9): the outcome ledger + event
         # counters, mirroring the resilience/strategy_safety blocks
         tel.serving_outcomes = dict(stats.outcomes)
@@ -1430,6 +1460,8 @@ class _ServeLoop:
         # caller-reused scheduler) outlive this run, so finish()
         # reports differences, not totals
         self._chunk_walls: Dict[int, float] = {}
+        # the routing counters the last fetch brought, for its tick's span
+        self._moe_tick: Dict[str, int] = {}
         self._prefix_hits0 = sched.prefix_hits
         self._prefix_reused0 = sched.prefix_tokens_reused
         self._evictions0 = (eng._prefix.evictions
@@ -1470,7 +1502,7 @@ class _ServeLoop:
     def _settle_pending(self) -> None:
         return None
 
-    def _fetch(self, toks, ok_vec):
+    def _fetch(self, toks, ok_vec, counters=None):
         """The ONE blocking host-transfer choke point for decode results
         (ISSUE 17 satellite: the formerly separate guarded/unguarded
         ``device_get`` call sites unified). Both the sync loop and the
@@ -1482,13 +1514,21 @@ class _ServeLoop:
         import jax
 
         self.stats.host_syncs += 1
-        if ok_vec is not None:
-            # the ONE extra transfer of the guarded step: the per-slot
-            # finite verdict rides the same device_get as the tokens —
-            # still a single blocking sync
-            toks_host, ok_host = jax.device_get((toks, ok_vec))
-            return np.asarray(toks_host), np.asarray(ok_host)
-        return np.asarray(jax.device_get(toks)), None
+        # the guarded step's per-slot finite verdict and a routed graph's
+        # step counters ride the same device_get as the tokens — still a
+        # single blocking sync (None fetches as None)
+        toks_host, ok_host, c = jax.device_get((toks, ok_vec, counters))
+        if c is not None:
+            pairs, live, load = (int(v) for v in c)
+            st = self.stats
+            st.moe_pairs_here += pairs
+            st.moe_experts_live += live
+            st.moe_load_max_permille = max(st.moe_load_max_permille, load)
+            self._moe_tick = {"moe_pairs_here": pairs,
+                              "moe_experts_live": live,
+                              "moe_load_max_permille": load}
+        return np.asarray(toks_host), (None if ok_host is None
+                                       else np.asarray(ok_host))
 
     # ----------------------------------------------------------------- tick
     def _acct_tick(self, t_tick: float, t_dev: float,
@@ -1858,7 +1898,9 @@ class _ServeLoop:
             return True
         toks = self._sample(live, logits)
         phase.to("fetch_tokens")
-        toks_host, ok_host = self._fetch(toks, ok_vec)
+        toks_host, ok_host = self._fetch(toks, ok_vec, eng._step_counters)
+        phase.tick_args.update(self._moe_tick)
+        self._moe_tick = {}
         wall = time.perf_counter() - t_d
         phase.to("tick_bookkeep")
         self._commit_arrival(live, None, toks_host, ok_host, wall)

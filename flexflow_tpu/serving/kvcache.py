@@ -52,6 +52,18 @@ is known in this module alone:
   positions ``> position``. Reads are the ``flash_decode`` kernel
   (:func:`flash_decode_kv`) or the gather :func:`read_kv`.
 
+* the LATENT layout (a latent-attention node, ops/latent_attention.py):
+  what is cached per token is ONE row for all heads — the compressed
+  key/value ``c_kv`` and the shared rotary key ``k_r`` side by side —
+  so the pool is ``(n_blocks, 1, block_size, lanes)`` with ``lanes``
+  the row's width padded with zeros to whole 128-lane tiles
+  (:func:`latent_lanes`; 576 -> 640, a ninth of the pool). It is the
+  layout above with one head and no V: the value the decode read
+  accumulates is the row's first lanes (``flash_decode``'s ``v_lanes``),
+  so every function here takes ``v=None`` for it and the block tables,
+  the allocator, the trie and the clone see blocks as before. int8 is
+  not defined for it.
+
 Pad garbage beyond a prompt's true length is never read: the write
 cursor overwrites it before the mask ever exposes it.
 Slot recycling and prefix sharing are pointer bookkeeping in the
@@ -291,6 +303,40 @@ def kv_token_bytes(heads: int, kdim: int, vdim: int, el: int,
     return heads * (kdim + vdim) * el
 
 
+def latent_lanes(width: int) -> int:
+    """Lanes of a latent pool row: ``width`` padded to whole 128-lane
+    tiles, so that the pool rests in the kernels' layout."""
+    return -(-int(width) // 128) * 128
+
+
+def latent_token_bytes(width: int, el: int) -> int:
+    """Bytes ONE token costs in one latent node's pool: its stored row,
+    padding included — once a token and layer, whatever the head count
+    (:func:`kv_token_bytes` of one head whose K is the row and whose V
+    is inside it)."""
+    return kv_token_bytes(1, latent_lanes(width), 0, el)
+
+
+def node_token_bytes(op, kv_dtype: str = "native") -> int:
+    """What ONE cached token costs in the pool of the attention node
+    ``op``, or 0 for an op that caches none: heads x (K + V) for
+    multi-head attention, the one stored row for latent attention. The
+    engine's ``kv_bytes_read`` and the serving search price from here."""
+    from ..ffconst import OperatorType, size_of_datatype
+
+    a, el = op.attrs, size_of_datatype(op.data_type)
+    if op.op_type == OperatorType.OP_LATENT_ATTENTION:
+        return latent_token_bytes(int(a["kv_rank"]) + int(a["rope_dim"]),
+                                  el)
+    if op.op_type != OperatorType.OP_MULTIHEAD_ATTENTION:
+        return 0
+    heads = int(a.get("num_heads", 1))
+    return kv_token_bytes(heads,
+                          int(a.get("kdim") or a["embed_dim"] // heads),
+                          int(a.get("vdim") or a["embed_dim"] // heads),
+                          el, kv_dtype)
+
+
 def quantize_kv(x) -> Tuple[Any, Any]:
     """Symmetric per-(..., token, head)-row int8 quantization over the
     trailing head_dim axis: ``q = round(x / scale)`` with
@@ -323,6 +369,13 @@ def new_kv_pool(prefill_entry, n_blocks: int, block_size: int,
     ``(pool int8, scales f32 (n_blocks, 2, h, block_size))``."""
     import jax.numpy as jnp
 
+    if len(prefill_entry) == 1:  # the latent layout: one row, no V
+        if kv_dtype == "int8":
+            raise NotImplementedError(
+                "kv_dtype='int8' is not defined for a latent KV pool")
+        (buf,) = prefill_entry
+        return jnp.zeros((n_blocks, buf.shape[1], block_size,
+                          latent_lanes(buf.shape[-1])), buf.dtype)
     kbuf, vbuf = prefill_entry
     h = kbuf.shape[1]
     shape = (n_blocks, h, block_size, kbuf.shape[-1] + vbuf.shape[-1])
@@ -340,6 +393,8 @@ def prefill_kv_entry(k, v, max_len: int):
     import jax.numpy as jnp
 
     pad = ((0, 0), (0, 0), (0, max_len - k.shape[2]), (0, 0))
+    if v is None:  # the latent layout: k is the row ``(1, 1, L, width)``
+        return (jnp.pad(k, pad),)
     return jnp.pad(k, pad), jnp.pad(v, pad)
 
 
@@ -367,6 +422,9 @@ def _pack_rows(entry, k, v):
     import jax.numpy as jnp
 
     pool, scales = _pool_scales(entry)
+    if v is None:  # a latent row: zeros over the pool's padding lanes
+        pad = [(0, 0)] * (k.ndim - 1) + [(0, pool.shape[-1] - k.shape[-1])]
+        return jnp.pad(k, pad).astype(pool.dtype), None
     if scales is None:
         return jnp.concatenate([k, v], axis=-1).astype(pool.dtype), None
     kq, ks = quantize_kv(k)
@@ -454,7 +512,8 @@ def write_token_kv(entry, k, v, positions, block_tables, block_size):
 
     bi = jnp.take_along_axis(
         block_tables, (positions // block_size)[:, None], axis=1)[:, 0]
-    return _write_entry(entry, k[:, :, 0, :], v[:, :, 0, :], bi,
+    return _write_entry(entry, k[:, :, 0, :],
+                        None if v is None else v[:, :, 0, :], bi,
                         positions % block_size, consecutive=False)
 
 
@@ -473,8 +532,8 @@ def write_chunk_kv(entry, k, v, start, n_new, table_row, block_size):
     bi = jnp.where(jnp.arange(chunk_len) < n_new, table_row[blk],
                    GARBAGE_BLOCK)
     return _write_entry(entry, jnp.swapaxes(k[0], 0, 1),
-                        jnp.swapaxes(v[0], 0, 1), bi, pos % block_size,
-                        consecutive=True)
+                        None if v is None else jnp.swapaxes(v[0], 0, 1),
+                        bi, pos % block_size, consecutive=True)
 
 
 def read_kv(entry, block_tables, kdim: int, dtype):
@@ -510,12 +569,17 @@ def live_slots(block_tables):
     return jnp.any(block_tables != GARBAGE_BLOCK, axis=1)
 
 
-def flash_decode_kv(q, entry, block_tables, n_keys, sm_scale):
+def flash_decode_kv(q, entry, block_tables, n_keys, sm_scale,
+                    v_lanes=None, tokens: int = 1):
     """The kernel read of a pool entry (kernels/flash_decode.py): q
     ``(n_slots, h, kd)`` against each slot's ``n_keys`` first keys →
     ``(n_slots, h, vd)``; None where the gate says the gather read
     (:func:`read_kv`) is the path — off the chip, or a pool that is not
-    whole lanes (128) and whole sublanes (8 rows a block). A free slot
+    whole lanes (128) and whole sublanes (8 rows a block). ``v_lanes`` names the
+    latent layout: q is every head's row against the one stored row a
+    key, the output its first ``v_lanes`` lanes; ``tokens`` > 1 is a
+    latent prefill chunk's read (``tokens`` positions a slot, one shared
+    table row, the caller's ``n_keys`` as they are). A free slot
     (:func:`live_slots`) is handed ``n_keys`` 0: the kernel runs no live
     step for it, moves no bytes and writes exact zeros."""
     import jax.numpy as jnp
@@ -525,9 +589,11 @@ def flash_decode_kv(q, entry, block_tables, n_keys, sm_scale):
     pool, scales = _pool_scales(entry)
     if not use_flash_decode(pool.shape[-1], pool.shape[2]):
         return None
-    n_keys = jnp.where(live_slots(block_tables), n_keys, 0)
+    if tokens == 1:
+        n_keys = jnp.where(live_slots(block_tables), n_keys, 0)
     return flash_decode_pool(q, pool, block_tables, n_keys,
-                             sm_scale=sm_scale, scales=scales)
+                             sm_scale=sm_scale, scales=scales,
+                             v_lanes=v_lanes, tokens=tokens)
 
 
 def scatter_prefill_kv(entry, prefill_entry, table_row, block_size: int):
@@ -542,11 +608,13 @@ def scatter_prefill_kv(entry, prefill_entry, table_row, block_size: int):
     import jax.numpy as jnp
 
     pool, scales = _pool_scales(entry)
-    kbuf, vbuf = prefill_entry
+    kbuf, vbuf = prefill_entry if len(prefill_entry) == 2 \
+        else (prefill_entry[0], None)
     mb = int(table_row.shape[0])
     pad = ((0, 0), (0, mb * block_size - kbuf.shape[2]), (0, 0))
-    rows, srows = _pack_rows(entry, jnp.pad(kbuf[0], pad),
-                             jnp.pad(vbuf[0], pad))  # (h, P, lanes)
+    rows, srows = _pack_rows(
+        entry, jnp.pad(kbuf[0], pad),
+        None if vbuf is None else jnp.pad(vbuf[0], pad))  # (h, P, lanes)
     h, _p, lanes = rows.shape
     pool = pool.at[table_row].set(
         rows.reshape(h, mb, block_size, lanes).transpose(1, 0, 2, 3))

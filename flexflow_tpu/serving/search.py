@@ -241,14 +241,9 @@ def _pick_kind(node: PCGNode, tp: int,
 
 def _attention_state_bytes(node: PCGNode, slots: int, max_len: int,
                            kv_dtype: str = "native") -> int:
-    from .kvcache import kv_token_bytes
+    from .kvcache import node_token_bytes
 
-    a = node.op.attrs
-    heads = int(a.get("num_heads", 1))
-    kdim = int(a.get("kdim") or a["embed_dim"] // heads)
-    vdim = int(a.get("vdim") or a["embed_dim"] // heads)
-    return slots * max_len * kv_token_bytes(
-        heads, kdim, vdim, size_of_datatype(node.op.data_type), kv_dtype)
+    return slots * max_len * node_token_bytes(node.op, kv_dtype)
 
 
 def _graph_cost(sim, g: PCG, tp: int, kv_div: int, slots: int,
@@ -288,6 +283,10 @@ def _graph_cost(sim, g: PCG, tp: int, kv_div: int, slots: int,
             if node.op.op_type == OperatorType.OP_MULTIHEAD_ATTENTION:
                 kv_bytes += _attention_state_bytes(
                     node, slots, max_len, kv_dtype) // max(kv_div, 1)
+            elif node.op.op_type == OperatorType.OP_LATENT_ATTENTION:
+                # one row a token for all heads: no head axis to divide
+                kv_bytes += _attention_state_bytes(
+                    node, slots, max_len, kv_dtype)
             elif node.op.op_type == OperatorType.OP_LSTM:
                 h = int(node.op.attrs["hidden_size"])
                 kv_bytes += slots * 2 * h * size_of_datatype(

@@ -239,6 +239,12 @@ def summarize_telemetry(data, top: int) -> None:
             print(f"  decode attention grid: {srv['kv_tiles_live']} of "
                   f"{srv['kv_tiles_grid']} tiles live "
                   f"({100 * srv['decode_grid_live_share']:.1f}%)")
+        if srv.get("moe_pairs_here"):
+            print(f"  routed layers at decode: {srv['moe_pairs_here']} "
+                  f"(row, expert) pairs held here over "
+                  f"{srv['moe_experts_live']} expert reads; fullest expert "
+                  f"{srv['moe_load_max_permille'] / 1000:.2f} x its "
+                  f"layer's mean")
 
     _block(data, "serving", _srv)
 
