@@ -17,7 +17,7 @@ from typing import Any, Dict, Iterator, List, Optional, Sequence
 
 import numpy as np
 
-from ..obs.trace import span
+from ..obs.trace import span, timed_span
 
 
 class SingleDataLoader:
@@ -122,8 +122,9 @@ def prefetch_iterator(it: Iterator, shardings: List[Any], depth: int = 2,
     Every region is a span on the profiler's clock (obs/trace.SPANS):
     ``batch_gather`` / ``batch_put`` / ``prefetch_backpressure`` on the
     producer's thread, ``dataloader_wait`` around each ``q.get()`` of the
-    consumer; their sums go into ``stats`` (``new_input_stats``), plain
-    adds, no profiler needed.
+    consumer; the three that ``stats`` (``new_input_stats``) sums are
+    ``timed_span``s: plain adds, no profiler needed, the clock read inside
+    the span's own edges so that a sum never exceeds its spans'.
 
     Abandoning the generator early (e.g. fit breaking out on a dynamic
     recompile) stops the producer promptly and JOINS it — without the stop
@@ -138,7 +139,6 @@ def prefetch_iterator(it: Iterator, shardings: List[Any], depth: int = 2,
 
     if stats is None:
         stats = new_input_stats()
-    clock = time.perf_counter
     q: Queue = Queue(maxsize=depth)
     stop = threading.Event()
     _END = object()
@@ -158,20 +158,17 @@ def prefetch_iterator(it: Iterator, shardings: List[Any], depth: int = 2,
         try:
             source = iter(it)
             while True:
-                t0 = clock()
-                with span("batch_gather"):
+                with timed_span("batch_gather", stats, "gather_s"):
                     batch = next(source, _END)
-                t1 = clock()
-                stats["gather_s"] += t1 - t0
                 if batch is _END:
                     break
                 stats["copied_bytes"] += sum(
                     a.nbytes for a in batch
                     if isinstance(a, np.ndarray) and a.flags.owndata)
-                with span("batch_put",
-                          bytes=sum(getattr(a, "nbytes", 0) for a in batch)):
+                with timed_span("batch_put", stats, "put_s",
+                                bytes=sum(getattr(a, "nbytes", 0)
+                                          for a in batch)):
                     staged = device_put_batch(batch, shardings)
-                stats["put_s"] += clock() - t1
                 with span("prefetch_backpressure"):
                     landed = put_or_stop(staged)
                 if not landed:
@@ -185,10 +182,9 @@ def prefetch_iterator(it: Iterator, shardings: List[Any], depth: int = 2,
         t.start()
     try:
         while True:
-            t0 = clock()
-            with span("dataloader_wait", batch=stats["batches"]):
+            with timed_span("dataloader_wait", stats, "wait_s",
+                            batch=stats["batches"]):
                 item = q.get()
-            stats["wait_s"] += clock() - t0
             if item is _END:
                 break
             if isinstance(item, BaseException):

@@ -27,6 +27,8 @@ from .tensor import Tensor
 from .execution.losses import loss_value
 from .execution.metrics import Metrics, PerfMetrics
 from .execution.optimizers import Optimizer, SGDOptimizer
+from .obs.builds import build_mark, built_since, enter, leave
+from .obs.trace import setup_span
 
 
 class FFModel:
@@ -677,11 +679,14 @@ class FFModel:
                 final_tensor: Optional[Tensor] = None) -> None:
         """Traced wrapper over :meth:`_compile_impl` — the whole lowering
         pipeline (PCG build, strategy search, executor + param init) lands as
-        one "compile" span in the obs trace. The explicit signature is kept
+        one "compile" set-up span (``obs.setup_span``: the profiler's trace,
+        the Chrome tracer's, ``obs.setup_walls()``, and the ``phase`` of the
+        programs built inside it), its parts as spans of their own inside it.
+        The explicit signature is kept
         in sync with ``_compile_impl`` (it IS the public API surface the
         frontends introspect)."""
         tracer = self._obs_tracer()
-        with tracer.span("compile", layers=len(self._layers)):
+        with setup_span("compile", tracer=tracer, layers=len(self._layers)):
             self._compile_impl(optimizer, loss_type, metrics, comp_mode,
                                strategy, strategy_fn, final_tensor)
         if tracer.enabled and self.config.trace_file:
@@ -711,6 +716,7 @@ class FFModel:
         from .utils.compile_cache import ensure_compile_cache
 
         ensure_compile_cache()
+        tracer = self._obs_tracer()
 
         # flag-combination sanity before any expensive work (ISSUE 5;
         # parse-time single-flag checks live in FFConfig.parse_args, this
@@ -730,20 +736,22 @@ class FFModel:
         self._search_result = None
         self._strategy_candidates = []
 
-        # -- create_operators_from_layers (model.cc:2785) -----------------------
-        pcg = self.create_pcg()
+        with setup_span("compile_graph", tracer=tracer):
+            # -- create_operators_from_layers (model.cc:2785) -------------------
+            pcg = self.create_pcg()
 
-        # final op = last compute node (the reference uses the graph's sink)
-        if final_tensor is not None:
-            final = pcg.nodes[self._tensor_to_node[final_tensor.guid]]
-            self.final_out_idx = final_tensor.owner_idx or 0
-        else:
-            sinks = [n for n in pcg.sinks()
-                     if n.op.op_type != OperatorType.OP_INPUT]
-            final = sinks[-1]
-            self.final_out_idx = 0
-        self.final_guid = final.guid
-        repl_labels = final.op.op_type == OperatorType.OP_AGG_SPEC
+            # final op = last compute node (the reference uses the graph's
+            # sink)
+            if final_tensor is not None:
+                final = pcg.nodes[self._tensor_to_node[final_tensor.guid]]
+                self.final_out_idx = final_tensor.owner_idx or 0
+            else:
+                sinks = [n for n in pcg.sinks()
+                         if n.op.op_type != OperatorType.OP_INPUT]
+                final = sinks[-1]
+                self.final_out_idx = 0
+            self.final_guid = final.guid
+            repl_labels = final.op.op_type == OperatorType.OP_AGG_SPEC
 
         # -- mesh + strategy ----------------------------------------------------
         import jax
@@ -797,77 +805,81 @@ class FFModel:
             self.strategy = self._run_search(pcg, n_dev)
             self.mesh = mesh_for_strategy(self.config, self.strategy)
 
-        # --static-analysis strict: ShardLint judges EVERY compiled plan
-        # (explicit, imported, or searched) before the executor exists —
-        # the compile-time analog of cascade stage 0 (ISSUE 7). The
-        # default "on" runs analysis only where it replaces dynamic work
-        # (cascade, search pruning, pre-serve), keeping plain compiles at
-        # zero added cost.
-        if (getattr(self.config, "static_analysis", "on") or "on") == \
-                "strict" and self.strategy is not None:
-            from .analysis import StaticAnalysisError, analyze_model
+        with setup_span("compile_graph", tracer=tracer):
+            # --static-analysis strict: ShardLint judges EVERY compiled plan
+            # (explicit, imported, or searched) before the executor exists —
+            # the compile-time analog of cascade stage 0 (ISSUE 7). The
+            # default "on" runs analysis only where it replaces dynamic work
+            # (cascade, search pruning, pre-serve), keeping plain compiles at
+            # zero added cost.
+            if (getattr(self.config, "static_analysis", "on") or "on") == \
+                    "strict" and self.strategy is not None:
+                from .analysis import StaticAnalysisError, analyze_model
 
-            # the SAME full pass the cascade's stage 0 runs (remat plan
-            # resolved, donation contract included) — one entry point, so
-            # the two paths cannot drift; pcg is passed explicitly
-            # because self.pcg binds later in compile
-            report = analyze_model(self, pcg=pcg)
-            if report.errors:
-                raise StaticAnalysisError(
-                    report, context="compile under --static-analysis "
-                    "strict")
+                # the SAME full pass the cascade's stage 0 runs (remat plan
+                # resolved, donation contract included) — one entry point, so
+                # the two paths cannot drift; pcg is passed explicitly
+                # because self.pcg binds later in compile
+                report = analyze_model(self, pcg=pcg)
+                if report.errors:
+                    raise StaticAnalysisError(
+                        report, context="compile under --static-analysis "
+                        "strict")
 
-        if self.config.export_strategy_file and \
-                not getattr(self, "_exported_search_target", False):
-            with open(self.config.export_strategy_file, "w") as f:
-                f.write(self.strategy.to_json(pcg))
-        if self.config.export_strategy_computation_graph_file:
-            with open(self.config.export_strategy_computation_graph_file,
-                      "w") as f:
-                f.write(pcg.to_dot(
-                    include_costs=self.config.include_costs_dot_graph))
+            if self.config.export_strategy_file and \
+                    not getattr(self, "_exported_search_target", False):
+                with open(self.config.export_strategy_file, "w") as f:
+                    f.write(self.strategy.to_json(pcg))
+            if self.config.export_strategy_computation_graph_file:
+                with open(self.config.export_strategy_computation_graph_file,
+                          "w") as f:
+                    f.write(pcg.to_dot(
+                        include_costs=self.config.include_costs_dot_graph))
 
-        # -- fusion (model.cc:2965-3040, gated by --fusion) ---------------------
-        if self.config.perform_fusion:
-            from .ops.fused import apply_fusion
+            # -- fusion (model.cc:2965-3040, gated by --fusion) -----------------
+            if self.config.perform_fusion:
+                from .ops.fused import apply_fusion
 
-            pcg, n_fused, fusion_remap = apply_fusion(
-                pcg, self.strategy, barrier_guids=(self.final_guid,))
-            if n_fused:
-                if final_tensor is not None:
-                    # the barrier guarantees the anchor is unfused or a
-                    # region tail; follow the remap either way
-                    new_guid, new_idx = fusion_remap[self.final_guid]
-                    self.final_guid = new_guid
-                    if new_idx >= 0:
-                        self.final_out_idx = new_idx
-                    final = pcg.nodes[self.final_guid]
-                else:
-                    sinks = [n for n in pcg.sinks()
-                             if n.op.op_type != OperatorType.OP_INPUT]
-                    final = sinks[-1]
-                    self.final_guid = final.guid
-                    self.final_out_idx = 0
-                repl_labels = final.op.op_type == OperatorType.OP_AGG_SPEC
+                pcg, n_fused, fusion_remap = apply_fusion(
+                    pcg, self.strategy, barrier_guids=(self.final_guid,))
+                if n_fused:
+                    if final_tensor is not None:
+                        # the barrier guarantees the anchor is unfused or a
+                        # region tail; follow the remap either way
+                        new_guid, new_idx = fusion_remap[self.final_guid]
+                        self.final_guid = new_guid
+                        if new_idx >= 0:
+                            self.final_out_idx = new_idx
+                        final = pcg.nodes[self.final_guid]
+                    else:
+                        sinks = [n for n in pcg.sinks()
+                                 if n.op.op_type != OperatorType.OP_INPUT]
+                        final = sinks[-1]
+                        self.final_guid = final.guid
+                        self.final_out_idx = 0
+                    repl_labels = final.op.op_type == OperatorType.OP_AGG_SPEC
 
-        # -- label tensor (model.cc:3090-3124) ----------------------------------
-        out_shape = final.out_shapes[self.final_out_idx]
-        if loss_type == LossType.LOSS_SPARSE_CATEGORICAL_CROSSENTROPY:
-            label_shape = (out_shape[0], 1)
-            label_dtype = DataType.DT_INT32
-        else:
-            label_shape = out_shape
-            label_dtype = final.out_dtypes[self.final_out_idx]
-        self.label_tensor = Tensor(label_shape, label_dtype, name="label",
-                                   model=self)
+            # -- label tensor (model.cc:3090-3124) ------------------------------
+            out_shape = final.out_shapes[self.final_out_idx]
+            if loss_type == LossType.LOSS_SPARSE_CATEGORICAL_CROSSENTROPY:
+                label_shape = (out_shape[0], 1)
+                label_dtype = DataType.DT_INT32
+            else:
+                label_shape = out_shape
+                label_dtype = final.out_dtypes[self.final_out_idx]
+            self.label_tensor = Tensor(label_shape, label_dtype, name="label",
+                                       model=self)
 
         self.pcg = pcg
-        self.executor = Executor(pcg, self.mesh, self.strategy, loss_type,
-                                 self.metrics_obj, self.optimizer, self.config,
-                                 self.final_guid, label_dtype, repl_labels,
-                                 final_out_idx=self.final_out_idx)
-        self.params = self.executor.init_params(self.config.numpy_seed())
-        self.opt_state = self.optimizer.init_state(self.params)
+        with setup_span("compile_executor", tracer=tracer):
+            self.executor = Executor(
+                pcg, self.mesh, self.strategy, loss_type, self.metrics_obj,
+                self.optimizer, self.config, self.final_guid, label_dtype,
+                repl_labels, final_out_idx=self.final_out_idx)
+        # ends where they return: the device may still be filling the leaves
+        with setup_span("param_init", tracer=tracer):
+            self.params = self.executor.init_params(self.config.numpy_seed())
+            self.opt_state = self.optimizer.init_state(self.params)
 
         # searched GPipe pipeline: training routes through PipelineTrainer
         # on a (pp, dp) grid seeded with the SAME initialized params; fit
@@ -882,13 +894,14 @@ class FFModel:
             # schedule: --schedule flag > searched strategy.schedule >
             # classic gpipe (parallel.pipeline.resolve_schedule)
             sched, v = resolve_schedule(self.config, self.strategy)
-            self._pipeline_trainer = PipelineTrainer(
-                self, pp=pp, dp=pdp, n_micro=n_micro,
-                optimizer=self.optimizer, loss_type=loss_type,
-                init_params=False,  # fit() seeds from the live params
-                # stage remat: --remat flag > searched level > GPipe full
-                remat=resolve_stage_remat(self.config, self.strategy),
-                schedule=sched, virtual_stages=v)
+            with setup_span("compile_executor", tracer=tracer):
+                self._pipeline_trainer = PipelineTrainer(
+                    self, pp=pp, dp=pdp, n_micro=n_micro,
+                    optimizer=self.optimizer, loss_type=loss_type,
+                    init_params=False,  # fit() seeds from the live params
+                    # stage remat: --remat flag > searched level > GPipe full
+                    remat=resolve_stage_remat(self.config, self.strategy),
+                    schedule=sched, virtual_stages=v)
 
     def create_pcg(self):
         """Layer graph -> PCG (reference: create_operators_from_layers,
@@ -1051,6 +1064,7 @@ class FFModel:
         import jax
 
         assert self.executor is not None, "call compile() first"
+        built_from = build_mark()  # what this fit builds: obs/builds.py
         if recompile_state is not None:
             self._recompile_state = recompile_state
             recompile_state.ffmodel = self
@@ -1069,7 +1083,12 @@ class FFModel:
                     "chaos injection targets the SPMD fit loop; the GPipe "
                     "pipeline trainer is not covered (see "
                     "docs/fault_tolerance.md)")
-            return self._fit_pipeline(xs, y, batch_size, epochs, shuffle)
+            enter("fit")
+            try:
+                return self._fit_pipeline(xs, y, batch_size, epochs,
+                                          shuffle)
+            finally:
+                leave("fit")
         # strategy-safety cascade (ISSUE 5, docs/strategy_safety.md): when
         # armed (--audit-strategy / --memory-budget-mb / strategy chaos),
         # verify the plan BEFORE the loop — preflight, compile + one probe
@@ -1128,6 +1147,8 @@ class FFModel:
         tracer = self._obs_tracer()
         telemetry = self._make_telemetry(tracer, batch_size, "train")
         self._telemetry = telemetry
+        if telemetry is not None:
+            telemetry.build_mark = built_from
         if cascade is not None:
             # counters are final after preverify; the final strategy the
             # cascade settled on lands in the telemetry record
@@ -1153,6 +1174,10 @@ class FFModel:
         tracing = bool(self.config.profiler_trace_dir)
         if tracing:
             jax.profiler.start_trace(self.config.profiler_trace_dir)
+        # the phase of the programs built from here to fit's end; no wrapper
+        # around fit() sets it: a frame above the step costs build time
+        # (obs/builds.py: enter)
+        enter("fit")
         try:
             epoch = epoch0
             preempted = False
@@ -1307,33 +1332,46 @@ class FFModel:
             if loss_val is not None:
                 with span("fit_sync", tracer=tracer):
                     jax.block_until_ready(loss_val)
+        except BaseException:
+            leave("fit")
+            raise
         finally:
             if tracing:
                 jax.profiler.stop_trace()
             if session is not None:
                 session.close(telemetry)
-        elapsed = time.time() - t0
-        self._last_fit_time = elapsed
-        self._last_fit_samples = executed_steps * batch_size
-        if elapsed > 0:
-            throughput = self._last_fit_samples / elapsed
-            if tracer.enabled:
-                tracer.counter("throughput_samples_per_sec",
-                               round(throughput, 2))
+        try:  # still fit: the memory analysis below builds the step too
+            elapsed = time.time() - t0
+            self._last_fit_time = elapsed
+            self._last_fit_samples = executed_steps * batch_size
+            if elapsed > 0:
+                throughput = self._last_fit_samples / elapsed
+                if tracer.enabled:
+                    tracer.counter("throughput_samples_per_sec",
+                                   round(throughput, 2))
+                if self.config.profiling:
+                    # legacy stdout line (kept verbatim for script
+                    # compatibility)
+                    print(f"THROUGHPUT = {throughput:.2f} samples/s")
             if self.config.profiling:
-                # legacy stdout line (kept verbatim for script compatibility)
-                print(f"THROUGHPUT = {throughput:.2f} samples/s")
-        if telemetry is not None:
-            telemetry.finalize()
-            if self.config.telemetry_file and last_batch is not None:
-                from .obs.telemetry import capture_memory_analysis
+                n_built, build_s, by_name = built_since(built_from)
+                if n_built:  # a fit after the first that prints: it recompiled
+                    print(f"BUILT = {n_built} programs in {build_s:.2f} s: "
+                          f"{by_name}")
+            if telemetry is not None:
+                telemetry.finalize()
+                if self.config.telemetry_file and last_batch is not None:
+                    from .obs.telemetry import capture_memory_analysis
 
-                telemetry.device_memory = capture_memory_analysis(
-                    self.executor, self.params, self.opt_state, *last_batch)
-            if self.config.telemetry_file:
-                telemetry.write(self.config.telemetry_file)
-        if tracer.enabled and self.config.trace_file:
-            tracer.write(self.config.trace_file)
+                    telemetry.device_memory = capture_memory_analysis(
+                        self.executor, self.params, self.opt_state,
+                        *last_batch)
+                if self.config.telemetry_file:
+                    telemetry.write(self.config.telemetry_file)
+            if tracer.enabled and self.config.trace_file:
+                tracer.write(self.config.trace_file)
+        finally:
+            leave("fit")
         return self._perf
 
     def _param_stamp(self):
@@ -1470,13 +1508,18 @@ class FFModel:
         t_eval = time.perf_counter()
         n_batches = 0
         loss_val = None
-        for batch in batch_iterator(xs + [y], batch_size,
-                                    drop_remainder=False):
-            bx, by = batch[:-1], batch[-1]
-            loss_val, m = estep(self.params, bx, by)
-            # one host transfer per batch instead of one per metric scalar
-            perf.update(jax.device_get(m))
-            n_batches += 1
+        enter("eval")
+        try:
+            for batch in batch_iterator(xs + [y], batch_size,
+                                        drop_remainder=False):
+                bx, by = batch[:-1], batch[-1]
+                loss_val, m = estep(self.params, bx, by)
+                # one host transfer per batch instead of one per metric
+                # scalar
+                perf.update(jax.device_get(m))
+                n_batches += 1
+        finally:
+            leave("eval")
         if tracer.enabled:
             tracer.complete("eval", time.perf_counter() - t_eval,
                             batches=n_batches,

@@ -8,7 +8,13 @@ analog, SURVEY §1 L0):
   a no-op singleton — ``enable()`` swaps in a live tracer. ``span`` /
   ``step_span`` / ``SPANS``: the hot loops' spans (``fit`` with its input
   pipeline, the serve tick) as ``jax.profiler`` annotations on the
-  profiler's clock; the profiler session is their switch.
+  profiler's clock; the profiler session is their switch. ``setup_span`` /
+  ``setup_walls``: the same for the phases of the set-up, with their walls
+  kept in a process-wide table.
+* ``builds``: one record per build of a jitted program — its name, the
+  phase it fell in, the seconds tracing, lowering, loading and compiling,
+  hit or miss in the persistent cache (``builds()``, ``build_mark()``,
+  ``build_totals()``); always on, fed by ``jax.monitoring`` listeners.
 * ``telemetry``: per-step training telemetry (wall times, loss history,
   compile-vs-steady split, samples/sec, estimated MFU, XLA peak memory) and
   the Unity/MCMC per-iteration search log.
@@ -25,9 +31,10 @@ analog, SURVEY §1 L0):
 Nothing in this package allocates in the jitted path; all instrumentation is
 host-side and gated on ``get_tracer().enabled``.
 """
+from .builds import build_mark, build_totals, builds  # noqa: F401
 from .trace import (SPANS, NoopTracer, Tracer,  # noqa: F401
                     atomic_write_json, disable, enable, get_tracer,
-                    set_tracer, span, step_span)
+                    set_tracer, setup_span, setup_walls, span, step_span)
 from .reqtrace import (FleetTimeSeries, NoopRequestTrace,  # noqa: F401
                        RequestTrace, disable_reqtrace, enable_reqtrace,
                        get_reqtrace, set_reqtrace)

@@ -1,9 +1,9 @@
 """Step and search telemetry: machine-readable training-run records.
 
 ``StepTelemetry`` is filled by ``FFModel.fit``/``eval``: per-step wall time,
-loss/metric history, samples/sec, the first-step (jit compile) time split
-from steady state, estimated MFU from the analytic cost model, and the
-XLA-compiled peak memory (``Executor.train_step_memory_analysis``). The
+loss/metric history, samples/sec, the programs the run built and their
+seconds (``obs/builds.py``), estimated MFU from the analytic cost model, and
+the XLA-compiled peak memory (``Executor.train_step_memory_analysis``). The
 summary is a plain-JSON dict written to ``--telemetry-file``.
 
 ``SearchLog`` is the Unity/MCMC per-iteration log (candidate cost,
@@ -18,6 +18,7 @@ import json
 import time
 from typing import Any, Dict, List, Optional
 
+from .builds import build_mark, built_since
 from .trace import get_tracer
 
 def detect_peak_flops() -> Optional[float]:
@@ -62,6 +63,13 @@ class StepTelemetry:
         self.peak_flops: Optional[float] = None
         self.device_memory: Optional[Dict[str, int]] = None
         self.total_wall_s: float = 0.0
+        # the programs built while this record was open (obs/builds.py):
+        # from ``build_mark`` (the run's entry: fit() and the serve loop set
+        # theirs) to ``finalize()``
+        self.build_mark: int = build_mark()
+        self.programs_built: int = 0
+        self.build_s: float = 0.0
+        self.built_by_name: Dict[str, int] = {}
         # resilience counters (ISSUE 4): filled by the fit loop's
         # ResilienceSession at close — fault events (non-finite steps,
         # preemption signals), recovery events (resume/rollback/flush),
@@ -209,6 +217,8 @@ class StepTelemetry:
 
     def finalize(self) -> None:
         self.total_wall_s = time.perf_counter() - self._t_start
+        self.programs_built, self.build_s, self.built_by_name = \
+            built_since(self.build_mark)
 
     # -- derived numbers ----------------------------------------------------
     @property
@@ -216,7 +226,8 @@ class StepTelemetry:
         return len(self.step_wall_s)
 
     def first_step_s(self) -> Optional[float]:
-        """First-step wall time — dominated by jit compile."""
+        """First-step wall time — it holds the step program's first build
+        (the second step holds its second: ROADMAP.md S12)."""
         return self.step_wall_s[0] if self.step_wall_s else None
 
     def steady_step_s(self) -> Optional[float]:
@@ -246,14 +257,20 @@ class StepTelemetry:
             "total_wall_s": round(self.total_wall_s, 4),
             "loss_history": self.loss_history,
             "epoch_loss": self.epoch_loss,
+            # what the run built, by the registry (obs/builds.py): not zero
+            # after a warm-up = it recompiled, and by_name says what
+            "programs_built": self.programs_built,
         }
+        if self.programs_built:
+            out["build_s"] = round(self.build_s, 6)
+            out["by_name"] = dict(self.built_by_name)
         if self.step_wall_s:
             out["first_step_s"] = round(self.first_step_s(), 6)
             steady = self.steady_step_s()
             if steady is not None:
                 out["steady_step_s"] = round(steady, 6)
-                out["compile_overhead_s"] = round(
-                    max(self.first_step_s() - steady, 0.0), 6)
+            # build_s under the name it had as a difference of step walls
+            out["compile_overhead_s"] = round(self.build_s, 6)
         sps = self.samples_per_sec()
         if sps is not None:
             out["samples_per_sec"] = round(sps, 2)

@@ -14,7 +14,11 @@ are instrumented with: ``jax.profiler`` annotations, so they land in the
 profiler's trace on the device's clock whenever a profiler session runs
 (``--profiler-trace-dir``, ``obs.start_trace``) and cost about a microsecond
 when none does; with the Chrome ``Tracer`` enabled the same call also records
-its complete event.
+its complete event. ``setup_span`` is the same call for the phases of the
+set-up (``compile()``, the engine's construction): it also adds its wall to
+``setup_walls()`` and names the ``phase`` of the programs built inside it
+(``obs/builds.py``); ``timed_span`` is what both share with the input
+pipeline's counters.
 
 Disabled-by-default design: the module-level singleton starts as a
 ``NoopTracer`` whose ``span()`` returns one shared, reusable null context
@@ -25,12 +29,16 @@ against the tracer's epoch).
 """
 from __future__ import annotations
 
+import functools
 import json
 import os
 import threading
 import time
 from types import MappingProxyType
 from typing import Any, Dict, Mapping, Optional
+
+# (the package's attribute ``builds`` is the function, not the module)
+from .builds import pop_span, push_span
 
 
 def atomic_write_json(path: str, obj) -> str:
@@ -311,11 +319,24 @@ def disable():
 
 
 # ------------------------------------------- hot-loop spans, profiler's clock
-#: Every span of the two hot host loops: name -> what it brackets. ``span()``
-#: refuses a name that is not here, so the registry, docs/observability.md
-#: (scripts/check_trace_events.py reads this mapping) and the benchmark's
-#: readers (benchmark/reduce/program_spans.py imports it) cannot drift apart.
+#: Every span of the two hot host loops and of the set-up before them: name
+#: -> what it brackets. ``span()`` refuses a name that is not here, so the
+#: registry, docs/observability.md (scripts/check_trace_events.py reads this
+#: mapping) and the benchmark's readers (benchmark/reduce/program_spans.py
+#: imports it) cannot drift apart.
 SPANS: Mapping[str, str] = MappingProxyType({
+    # set-up (``setup_span``): each also adds its wall to ``setup_walls()``
+    # and is the ``phase`` of the programs built inside it (obs/builds.py)
+    "compile": "the whole of FFModel.compile()",
+    "compile_graph": "compile(): layers -> PCG, fusion, the label tensor",
+    "search": "the Unity search (inside compile() where it searches)",
+    "compile_executor": "compile(): Executor(...) and, for a searched "
+                        "pipeline, its trainer",
+    "param_init": "compile(): executor.init_params + optimizer.init_state, "
+                  "up to where they return (the device may still be busy)",
+    "engine_build": "ServingEngine.__init__",
+    "kv_pool_alloc": "_ensure_state_bootstrap / _ensure_state: the KV pool "
+                     "and the decode state, once an engine",
     # FFModel.fit, main thread
     "epoch": "one pass of fit's epoch loop, set-up to fold",
     "fit_epoch_setup": "top of the epoch loop to the first q.get(): "
@@ -411,3 +432,89 @@ def step_span(name: str, step_num: int, tracer=None, **args):
     view groups device time by these."""
     return _program_span("StepTraceAnnotation", name, tracer,
                          dict(args, step_num=step_num))
+
+
+# ------------------------------------- spans that also count their own wall
+class _TimedSpan:
+    """A span that adds its wall to ``table[key]``. The clock is read inside
+    the annotation's own two edges, so the sum never exceeds the spans' in a
+    trace, however the thread is scheduled."""
+
+    __slots__ = ("_inner", "_table", "_key", "_t0")
+
+    def __init__(self, inner, table, key):
+        self._inner = inner
+        self._table = table
+        self._key = key
+
+    def __enter__(self):
+        self._inner.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        self._table[self._key] += time.perf_counter() - self._t0
+        return self._inner.__exit__(*exc)
+
+    def set_metadata(self, **args) -> None:
+        self._inner.set_metadata(**args)
+
+
+def timed_span(name: str, table, key, tracer=None, **args):
+    """``span(name, **args)`` that also adds its wall to ``table[key]`` (a
+    plain add; ``FFModel.input_stats()``'s seconds are these)."""
+    return _TimedSpan(span(name, tracer, **args), table, key)
+
+
+#: The set-up's spans, outermost first.
+SETUP_SPANS = ("compile", "engine_build", "kv_pool_alloc", "compile_graph",
+               "search", "compile_executor", "param_init")
+_SETUP_WALLS = dict.fromkeys(SETUP_SPANS, 0.0)
+_SETUP_WALLS_OUTERMOST = dict.fromkeys(SETUP_SPANS, 0.0)
+
+
+class _SetupSpan(_TimedSpan):
+    """A ``_TimedSpan`` into the process-wide set-up table that is also the
+    ``phase`` of the programs built inside it (obs/builds.py)."""
+
+    __slots__ = ("_outermost",)
+
+    def __enter__(self):
+        self._outermost = push_span(self._key) == 0
+        return super().__enter__()
+
+    def __exit__(self, *exc):
+        wall = time.perf_counter() - self._t0
+        self._table[self._key] += wall
+        if self._outermost:
+            _SETUP_WALLS_OUTERMOST[self._key] += wall
+        pop_span()
+        return self._inner.__exit__(*exc)
+
+
+def setup_span(name: str, tracer=None, **args):
+    """``span(name, **args)`` for a phase of the set-up (``SETUP_SPANS``):
+    it also adds its wall to ``setup_walls()`` and names the ``phase`` of
+    every program built before it closes. Never inside a step or a tick."""
+    if name not in _SETUP_WALLS:
+        raise KeyError(name)
+    return _SetupSpan(span(name, tracer, **args), _SETUP_WALLS, name)
+
+
+def in_setup_span(name: str):
+    """Decorator: the whole call is one ``setup_span(name)``."""
+    def decorate(fn):
+        @functools.wraps(fn)
+        def wrapper(*args, **kwargs):
+            with setup_span(name):
+                return fn(*args, **kwargs)
+        return wrapper
+    return decorate
+
+
+def setup_walls(outermost: bool = False) -> Dict[str, float]:
+    """Seconds this process spent inside each set-up span so far, by name
+    (always on, plain adds). ``outermost=True`` counts a span only where no
+    other set-up span was open around it, so the values add up to the
+    set-up's wall with nothing counted twice."""
+    return dict(_SETUP_WALLS_OUTERMOST if outermost else _SETUP_WALLS)

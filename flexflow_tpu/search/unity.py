@@ -1583,9 +1583,8 @@ def unity_search(pcg: PCG, config, n_dev: int,
     # per-iteration search telemetry: JSONL when --search-log is set, tracer
     # events when tracing is on (reference analog: the exported-strategy
     # workflow, but for the search's decision sequence itself)
-    from ..obs import SearchLog, get_tracer
+    from ..obs import SearchLog, setup_span
 
-    tracer = get_tracer()
     slog = SearchLog(getattr(config, "search_log_file", "") or None,
                      kind="unity")
 
@@ -1750,7 +1749,7 @@ def unity_search(pcg: PCG, config, n_dev: int,
     cache0 = (sim.cost_cache_hits, sim.cost_cache_misses,
               sim.table_hits, sim.table_misses)
     with _log.scope("unity_search n_dev=%d" % n_dev), \
-            tracer.span("search", n_dev=n_dev):
+            setup_span("search", n_dev=n_dev):
         best = search_all(lam=1.0)
         if use_hier and selfcheck_enabled() and \
                 n_dev <= multipod.SELFCHECK_MAX_DEV:

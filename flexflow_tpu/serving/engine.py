@@ -29,7 +29,8 @@ from typing import Any, Deque, Dict, List, Optional, Sequence
 import numpy as np
 
 from ..ffconst import OperatorType
-from ..obs.trace import span, step_span
+from ..obs.builds import build_mark, built_since, enter, leave
+from ..obs.trace import in_setup_span, setup_span, span, step_span
 from .kvcache import DecodeState, update_slot_entry
 from .scheduler import (ContinuousBatchScheduler, Request, ServingRejection,
                         bucket_for, default_buckets)
@@ -170,6 +171,13 @@ class ServingStats:
     moe_layer_steps: int = 0
     moe_chunk_bounded_steps: int = 0
     moe_chunk_layer_steps: int = 0
+    # the programs built between start_serve and finish() (obs/builds.py),
+    # their seconds tracing, lowering, loading and compiling, and how many
+    # by name; set by finish(). Not zero after a warm-up: the process
+    # recompiled under traffic, and built_by_name says which program
+    programs_built: int = 0
+    build_s: float = 0.0
+    built_by_name: Dict[str, int] = dataclasses.field(default_factory=dict)
 
     def record_token(self, wall_s: float) -> None:
         self.token_walls_s.append(wall_s)
@@ -250,7 +258,11 @@ class ServingStats:
             "queue_depth_hwm": self.queue_depth_hwm,
             "wall_s": round(self.wall_s, 4),
             "tokens_per_s": round(self.tokens_per_s(), 2),
+            "programs_built": self.programs_built,
         }
+        if self.programs_built:
+            out["build_s"] = round(self.build_s, 4)
+            out["by_name"] = dict(self.built_by_name)
         p50, p99 = self.p50_token_ms(), self.p99_token_ms()
         if p50 is not None:
             out["p50_token_ms"] = round(p50, 3)
@@ -317,6 +329,7 @@ class ServingEngine:
     kv_cache = "paged"
     exact_decode = False
 
+    @in_setup_span("engine_build")
     def __init__(self, model, n_slots: Optional[int] = None,
                  max_decode_len: Optional[int] = None,
                  buckets: Optional[Sequence[int]] = None,
@@ -765,40 +778,42 @@ class ServingEngine:
 
         if self.state is not None:
             return
-        b0 = self.buckets[0]
-        shapes = jax.eval_shape(
-            self._prefill_fn(b0), self.model.params,
-            [jax.ShapeDtypeStruct((1, b0), jnp.int32)],
-            jax.ShapeDtypeStruct((1,), jnp.int32))[2]
-        # placed with the weights (committed; whole on every chip of
-        # their mesh), as a prefill's cache would be: the slot writer's
-        # output below then carries the placement every later step's
-        # state will
-        where = jax.tree.leaves(self.model.params)[0].sharding
-        if isinstance(where, jax.sharding.NamedSharding):
-            where = jax.sharding.NamedSharding(
-                where.mesh, jax.sharding.PartitionSpec())
-        cache = jax.tree.map(
-            lambda s: jax.device_put(jnp.zeros(s.shape, s.dtype), where),
-            shapes)
-        self._ensure_state(cache)
-        # normalize through the classic slot writer — a value-level
-        # no-op (dummy cache scattered at an all-garbage row, slot 0,
-        # length 0, token 0) whose OUTPUT carries the same committed
-        # placement every later step input will: the chunk program then
-        # compiles exactly once per shape (an uncommitted first input
-        # would key a second fastpath entry)
-        self._write_slot(cache, 0, 0, 0,
-                         table_row=np.zeros((self.max_blocks_per_slot,),
-                                            np.int32))
-        if self._prefix is not None:
-            # an engine that admits by chunks meets a shared partial block
-            # as soon as two prompts share a prefix: compile the
-            # copy-on-write clone with the pool, on the garbage block
-            # (copied onto itself), not under the first such admission
-            from .kvcache import GARBAGE_BLOCK
+        with setup_span("kv_pool_alloc"):
+            b0 = self.buckets[0]
+            shapes = jax.eval_shape(
+                self._prefill_fn(b0), self.model.params,
+                [jax.ShapeDtypeStruct((1, b0), jnp.int32)],
+                jax.ShapeDtypeStruct((1,), jnp.int32))[2]
+            # placed with the weights (committed; whole on every chip of
+            # their mesh), as a prefill's cache would be: the slot writer's
+            # output below then carries the placement every later step's
+            # state will
+            where = jax.tree.leaves(self.model.params)[0].sharding
+            if isinstance(where, jax.sharding.NamedSharding):
+                where = jax.sharding.NamedSharding(
+                    where.mesh, jax.sharding.PartitionSpec())
+            cache = jax.tree.map(
+                lambda s: jax.device_put(jnp.zeros(s.shape, s.dtype),
+                                         where),
+                shapes)
+            self._alloc_state(cache)
+            # normalize through the classic slot writer — a value-level
+            # no-op (dummy cache scattered at an all-garbage row, slot 0,
+            # length 0, token 0) whose OUTPUT carries the same committed
+            # placement every later step input will: the chunk program then
+            # compiles exactly once per shape (an uncommitted first input
+            # would key a second fastpath entry)
+            self._write_slot(cache, 0, 0, 0,
+                             table_row=np.zeros((self.max_blocks_per_slot,),
+                                                np.int32))
+            if self._prefix is not None:
+                # an engine that admits by chunks meets a shared partial
+                # block as soon as two prompts share a prefix: compile the
+                # copy-on-write clone with the pool, on the garbage block
+                # (copied onto itself), not under the first such admission
+                from .kvcache import GARBAGE_BLOCK
 
-            self._cow_clone(GARBAGE_BLOCK, GARBAGE_BLOCK)
+                self._cow_clone(GARBAGE_BLOCK, GARBAGE_BLOCK)
 
     def prefix_peek(self, tokens, cap: Optional[int] = None) -> int:
         """Longest cached-prefix length (tokens) the engine's trie holds
@@ -820,13 +835,17 @@ class ServingEngine:
         head side by side, + the f32 scales for int8), a slot-major
         entry for every other stateful op, and the all-garbage block
         tables."""
+        if self.state is not None:
+            return
+        with setup_span("kv_pool_alloc"):
+            self._alloc_state(prefill_cache)
+
+    def _alloc_state(self, prefill_cache) -> None:
         import jax
         import jax.numpy as jnp
 
         from .kvcache import is_prefill_kv_entry, new_kv_pool
 
-        if self.state is not None:
-            return
         if self._prefix is not None and self._prefix.n_blocks:
             # building a FRESH pool (first admission after a device-loss
             # rebuild): every cached block id would dangle into zeroed
@@ -1317,6 +1336,9 @@ class ServingEngine:
         tel.serving_cache_evictions = stats.cache_evictions
         tel.serving_chunked_prefills = stats.chunked_prefills
         tel.finalize()
+        # the record opened here, at the run's end: its builds are the run's
+        tel.programs_built, tel.build_s, tel.built_by_name = \
+            stats.programs_built, stats.build_s, dict(stats.built_by_name)
         if self.model.config.telemetry_file:
             tel.write(self.model.config.telemetry_file)
 
@@ -1481,6 +1503,10 @@ class _ServeLoop:
         self._prefix_reused0 = sched.prefix_tokens_reused
         self._evictions0 = (eng._prefix.evictions
                             if eng._prefix is not None else 0)
+        # what this run builds (obs/builds.py): counted from here, and
+        # "serve" is the phase of a program built at a tick's first call
+        self._built_from = build_mark()
+        enter("serve")
         self.t0 = time.perf_counter()
 
     # ---------------------------------------------------------------- drain
@@ -1961,6 +1987,9 @@ class _ServeLoop:
         if self.finished:
             return stats
         self.finished = True
+        leave("serve")
+        stats.programs_built, stats.build_s, stats.built_by_name = \
+            built_since(self._built_from)
         if self.draining:
             eng.drained_requests = sched.pop_queued()
             if ledger_drained and sched.rt.enabled:

@@ -136,14 +136,19 @@ def summarize_telemetry(data, top: int) -> None:
           f"batch_size: {data.get('batch_size')}")
 
     def _steps(first):
-        line = f"first step (jit compile): {first * 1e3:.1f} ms"
+        line = f"first step: {first * 1e3:.1f} ms"
         if "steady_step_s" in data:
-            line += (f"   steady step: {data['steady_step_s'] * 1e3:.3f} ms"
-                     f"   compile overhead: "
-                     f"{data.get('compile_overhead_s', 0) * 1e3:.1f} ms")
+            line += f"   steady step: {data['steady_step_s'] * 1e3:.3f} ms"
         print(line)
 
     _block(data, "first_step_s", _steps)
+    if "programs_built" in data:
+        # the run's builds by the registry (obs/builds.py)
+        names = ", ".join(f"{n} x{k}" for n, k in sorted(
+            data.get("by_name", {}).items()))
+        print(f"programs built: {data['programs_built']} in "
+              f"{data.get('build_s', 0.0) * 1e3:.1f} ms"
+              + (f" ({names})" if names else ""))
     if "samples_per_sec" in data:
         print(f"throughput: {data['samples_per_sec']} samples/s")
     if "estimated_mfu" in data:

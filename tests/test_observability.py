@@ -180,17 +180,37 @@ def test_fit_telemetry_records(tmp_path):
 
 
 def test_step_telemetry_summary_math():
+    import jax.monitoring as monitoring
+
+    from flexflow_tpu.obs.builds import BACKEND, LOWER, TRACE
+
+    def stage(event, name, start, seconds):
+        """One stage of a build, as JAX reports it (obs/builds.py listens)."""
+        monitoring.record_scalar(event, start, fun_name=name)
+        monitoring.record_event_time_span(event, start, start + seconds,
+                                          fun_name=name)
+
     tel = StepTelemetry(batch_size=10)
-    tel.record_step(1.0, 2.0)   # compile step
+    # the first step holds a build of the step program: 0.2 s tracing,
+    # 0.1 s lowering, 0.6 s in the backend
+    stage(TRACE, "step", 100.0, 0.2)
+    stage(LOWER, "jit(step)", 100.2, 0.1)
+    stage(BACKEND, "jit(step)", 100.3, 0.6)
+    tel.record_step(1.0, 2.0)
     tel.record_step(0.1, 1.0)
     tel.record_step(0.2, 0.5)
     tel.record_step(0.1, 0.4)
     tel.finalize()
+    stage(BACKEND, "jit(later)", 200.0, 5.0)  # after the run: not its own
     assert tel.first_step_s() == 1.0
     assert tel.steady_step_s() == 0.1
     assert tel.samples_per_sec() == pytest.approx(100.0)
     s = tel.summary()
+    # the registry's seconds for the builds inside the run, and no longer
+    # first step less the median of the rest (1.0 - 0.1)
     assert s["compile_overhead_s"] == pytest.approx(0.9)
+    assert s["build_s"] == pytest.approx(0.9)
+    assert s["programs_built"] == 1 and s["by_name"] == {"jit_step": 1}
     assert s["loss_history"] == [2.0, 1.0, 0.5, 0.4]
 
 
