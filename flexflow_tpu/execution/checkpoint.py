@@ -328,25 +328,15 @@ def _host_staged_restore(ckptr, subdir: str, template):
     import numpy as np
     import orbax.checkpoint as ocp
 
-    import warnings
-
     ra = jax.tree_util.tree_map(
         lambda l: (ocp.RestoreArgs(restore_type=np.ndarray)
                    if isinstance(l, jax.Array) else ocp.RestoreArgs()),
         template)
-    with warnings.catch_warnings():
-        warnings.filterwarnings("ignore", message=".*sharding info.*")
-        host = ckptr.restore(subdir, item=template, restore_args=ra)
+    host = ckptr.restore(subdir, item=template, restore_args=ra)
 
     def put(h, t):
         if isinstance(t, jax.Array):
             return jax.device_put(np.asarray(h), t.sharding)
-        if isinstance(h, jax.Array):
-            # scalar leaves the template holds as python numbers (a fresh
-            # optimizer step counter) may come back as device arrays pinned
-            # to the CHECKPOINT's topology — strip the stale placement so
-            # the jitted step re-places them on the new mesh
-            return np.asarray(h)
         return h
 
     return jax.tree_util.tree_map(put, host, template)
@@ -387,8 +377,6 @@ def restore_checkpoint(ffmodel, path: str, mesh=None,
     ckptr = ocp.PyTreeCheckpointer()
     import jax
 
-    import warnings
-
     if same_topology or mesh is not None:
         try:
             for attr, subdir in (("params", "params"),
@@ -396,15 +384,9 @@ def restore_checkpoint(ffmodel, path: str, mesh=None,
                 template = getattr(ffmodel, attr)
                 ra = jax.tree_util.tree_map(
                     lambda l: _leaf_restore_args(l, mesh), template)
-                with warnings.catch_warnings():
-                    # scalar opt-state leaves (a fresh template's python-int
-                    # step vs the saved device scalar) make orbax read the
-                    # sharding from file — correct, just chatty
-                    warnings.filterwarnings(
-                        "ignore", message=".*sharding info.*")
-                    setattr(ffmodel, attr,
-                            ckptr.restore(os.path.join(path, subdir),
-                                          item=template, restore_args=ra))
+                setattr(ffmodel, attr,
+                        ckptr.restore(os.path.join(path, subdir),
+                                      item=template, restore_args=ra))
             return int(meta["step"])
         except (ValueError, KeyError) as e:
             # a mesh= override whose axes don't exist in the saved specs

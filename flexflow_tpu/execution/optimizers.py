@@ -11,9 +11,30 @@ with ``next()`` per-step hyperparameter schedule, optimizer.h:27-96) is kept.
 from __future__ import annotations
 
 
+def _step_counter(params):
+    """The step counter of a fresh state, with the signature the train step
+    hands it back with: an int32 array that is not weakly typed, committed
+    and replicated on the mesh the parameters live on. A bare ``0`` here
+    would make a process's first call of the jitted step one program and
+    every later call another (ROADMAP.md S12). Made on the host and put:
+    no program is built for it."""
+    import jax
+    import numpy as np
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    leaves = jax.tree_util.tree_leaves(params)
+    placed = getattr(leaves[0], "sharding", None) if leaves else None
+    # off a mesh: uncommitted, as a jit over uncommitted parameters returns it
+    replicated = (NamedSharding(placed.mesh, PartitionSpec())
+                  if isinstance(placed, NamedSharding) else None)
+    return jax.device_put(np.zeros((), np.int32), replicated)
+
 
 class Optimizer:
     def init_state(self, params):
+        """A fresh state whose every leaf enters the train step as the step
+        returns it: the moments are ``zeros_like`` their parameter (its
+        sharding and committedness), the counter is ``_step_counter``."""
         raise NotImplementedError
 
     def next(self, state):
@@ -50,10 +71,10 @@ class SGDOptimizer(Optimizer):
         import jax
         import jax.numpy as jnp
 
-        if self.momentum == 0.0:
-            return {"step": 0}
-        return {"step": 0,
-                "velocity": jax.tree_util.tree_map(jnp.zeros_like, params)}
+        state = {"step": _step_counter(params)}
+        if self.momentum != 0.0:
+            state["velocity"] = jax.tree_util.tree_map(jnp.zeros_like, params)
+        return state
 
     def update(self, params, grads, state):
         import jax
@@ -112,7 +133,7 @@ class AdamOptimizer(Optimizer):
             return jnp.zeros_like(p, dtype=dt) if dt is not None \
                 else jnp.zeros_like(p)
 
-        return {"step": 0,
+        return {"step": _step_counter(params),
                 "m": jax.tree_util.tree_map(zeros, params),
                 "v": jax.tree_util.tree_map(zeros, params)}
 

@@ -226,8 +226,7 @@ class StepTelemetry:
         return len(self.step_wall_s)
 
     def first_step_s(self) -> Optional[float]:
-        """First-step wall time — it holds the step program's first build
-        (the second step holds its second: ROADMAP.md S12)."""
+        """First-step wall time — it holds the step program's one build."""
         return self.step_wall_s[0] if self.step_wall_s else None
 
     def steady_step_s(self) -> Optional[float]:

@@ -474,11 +474,10 @@ def _shared_program(rows_fn, *static):
     a differentiated ``cond`` is traced — share ONE traced and lowered
     program: the ``trinity-mini`` cell's train step lowers to the parent's
     1.01 MB of StableHLO, 1.16 MB with twelve nodes traced apiece, and that
-    cell's set-up traces, lowers and hashes it three times — 0.5 s of a
-    warm ``setup_s`` that the ``cond``s leave 2-4 s inside its bound (PERF.md
-    section 6, PR 38; to go when ROADMAP S12's second load of the train
-    step does). Where there is no bound there is no ``cond`` and the
-    function is called as it is."""
+    cell's set-up traces, lowers and hashes it twice (the step's one build
+    and the benchmark's reading of its text): 0.5 s of a warm ``setup_s``
+    when it did so three times (PERF.md section 6, PR 38). Where there is
+    no bound there is no ``cond`` and the function is called as it is."""
     import jax
 
     @functools.wraps(rows_fn)

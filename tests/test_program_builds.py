@@ -14,6 +14,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.sharding import NamedSharding, PartitionSpec
 
 from flexflow_tpu import (ActiMode, AdamOptimizer, FFConfig, FFModel,
                           LossType, MetricsType, SGDOptimizer)
@@ -21,9 +22,18 @@ from flexflow_tpu import obs
 from flexflow_tpu.models.gpt2 import GPT2Config, build_gpt2
 from flexflow_tpu.obs.builds import BACKEND, _register, _unregister
 from flexflow_tpu.obs.trace import SETUP_SPANS
+from flexflow_tpu.parallel.strategies import hybrid_data_tensor_strategy
 from flexflow_tpu.serving import ServingEngine
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+_OPTIMIZERS = {
+    "sgd": lambda ff: SGDOptimizer(ff, lr=0.01),
+    "sgd_momentum": lambda ff: SGDOptimizer(ff, lr=0.01, momentum=0.9),
+    "adam": lambda ff: AdamOptimizer(ff, alpha=0.01),
+    "adam_bf16_moments": lambda ff: AdamOptimizer(
+        ff, alpha=0.01, moment_dtype=jnp.bfloat16),
+}
 
 
 @pytest.fixture(autouse=True)
@@ -67,17 +77,28 @@ def cache_dir(tmp_path, monkeypatch):
         compilation_cache.reset_cache()
 
 
-def _mlp(batch=32, width=64):
+def _mlp(batch=32, width=64, optimizer=None, mesh="default"):
+    """``mesh``: ``"default"`` (what compile() picks over the eight virtual
+    devices), ``"one_device"``, or ``"dp2_tp2"`` — four devices with the
+    dense kernels split over the model axis, so the moments are sharded."""
     config = FFConfig()
     config.batch_size = batch
     config.epochs = 1
+    strategy_fn = None
+    if mesh == "one_device":
+        config.mesh_shape = (1,)
+        config.only_data_parallel = True
+    elif mesh == "dp2_tp2":
+        def strategy_fn(pcg):
+            return hybrid_data_tensor_strategy(pcg, dp=2, tp=2)
     ff = FFModel(config)
     t = ff.create_tensor((batch, width))
     t = ff.dense(t, 32, ActiMode.AC_MODE_RELU)
     t = ff.softmax(ff.dense(t, 4))
-    ff.compile(optimizer=AdamOptimizer(ff, alpha=0.01),
+    ff.compile(optimizer=(optimizer or _OPTIMIZERS["adam"])(ff),
                loss_type=LossType.LOSS_SPARSE_CATEGORICAL_CROSSENTROPY,
-               metrics=[MetricsType.METRICS_ACCURACY])
+               metrics=[MetricsType.METRICS_ACCURACY],
+               strategy_fn=strategy_fn)
     rng = np.random.default_rng(0)
     x = rng.normal(size=(4 * batch, width)).astype(np.float32)
     y = rng.integers(0, 4, size=(4 * batch,)).astype(np.int32)
@@ -140,13 +161,12 @@ def test_compile_and_fit_give_one_record_a_build(cache_dir):
         re.sub(r"[^\w.-]", "_", n).rstrip("_") for n in seen.names]
     names = [r.name for r in recs]
     assert "jit_init_fn" in names
-    # The train step is built TWICE in every process: fit's first call
-    # hands the step counter in as a bare 0, its second as an array, and
-    # the two are two signatures (PERF.md section 7; the cure is ROADMAP.md
-    # S12). This is the test that shows S12 worked: it then reads 1.
-    assert names.count("jit_step") == 2
+    # The train step is built ONCE a process: the optimizer's state leaves
+    # compile() with the signature the step hands it back with, so fit's
+    # first call and every later one are one program (ROADMAP.md S12 (1)).
+    assert names.count("jit_step") == 1
     totals = obs.build_totals(mark)
-    assert totals["by_name"]["jit_step"] == 2
+    assert totals["by_name"]["jit_step"] == 1
     for r in recs:
         assert r.end >= r.start and r.backend_s > 0 and r.lower_s > 0
         assert r.cache == "miss" and r.load_s == 0.0  # an empty directory
@@ -168,12 +188,55 @@ def test_a_second_build_over_the_same_directory_hits(cache_dir):
     ff.fit(x, y)
     recs = obs.builds()[mark:]
     totals = obs.build_totals(mark)
-    assert totals["by_name"]["jit_step"] == 2
+    assert totals["by_name"]["jit_step"] == 1
     assert all(r.cache == "hit" and r.load_s > 0 for r in recs), recs
     assert totals["misses"] == 0 and totals["hits"] == len(recs)
     assert totals["compile_s"] == 0 and totals["load_s"] > 0
     # tracing and lowering are paid warm or cold
     assert totals["trace_s"] > 0 and totals["lower_s"] > 0
+
+
+# ----------------------------------- one signature for the life of a process
+def _signatures(state):
+    """What ``jax.jit`` keys a program on, leaf by leaf."""
+    return jax.tree_util.tree_map(
+        lambda a: (a.dtype, a.aval.weak_type, a.sharding, a.committed),
+        state)
+
+
+@pytest.mark.parametrize("mesh", ["one_device", "dp2_tp2"])
+@pytest.mark.parametrize("optimizer", list(_OPTIMIZERS))
+def test_the_step_is_built_once_a_process(optimizer, mesh, tmp_path):
+    """compile(), fit, fit again and the memory analysis at fit's end (and
+    one asked for by hand) build ``jit_step`` once, because every leaf of
+    the optimizer's state is born as the step hands it back: dtype,
+    ``weak_type``, sharding and committedness (ROADMAP.md S12 (1))."""
+    from flexflow_tpu.obs.telemetry import capture_memory_analysis
+
+    mark = obs.build_mark()
+    ff, x, y = _mlp(optimizer=_OPTIMIZERS[optimizer], mesh=mesh)
+    assert ff.mesh.size == {"one_device": 1, "dp2_tp2": 4}[mesh]
+    born = _signatures(ff.opt_state)
+    assert born["step"] == (jnp.int32, False,
+                            NamedSharding(ff.mesh, PartitionSpec()), True)
+    moments = jax.tree_util.tree_leaves(
+        {k: v for k, v in ff.opt_state.items() if k != "step"})
+    assert bool(moments) == (optimizer != "sgd")
+    if moments and mesh == "dp2_tp2":  # and some of them really are split
+        assert any("model" in m.sharding.spec for m in moments)
+    ff.config.telemetry_file = str(tmp_path / "telemetry.json")
+    ff.fit(x[:32], y[:32])  # ONE step, then the analysis: the benchmark's
+    assert _signatures(ff.opt_state) == born
+    assert ff.get_telemetry().device_memory is not None
+    ff.fit(x, y)
+    assert _signatures(ff.opt_state) == born
+    assert int(ff.opt_state["step"]) == 5
+    xs = [jax.device_put(x[:32], ff.executor.batch_sharding(2))]
+    labels = jax.device_put(y[:32].reshape(32, 1),
+                            ff.executor.batch_sharding(2))
+    assert capture_memory_analysis(ff.executor, ff.params, ff.opt_state,
+                                   xs, labels) is not None
+    assert obs.build_totals(mark)["by_name"]["jit_step"] == 1
 
 
 def test_the_cache_off_reads_off():
@@ -208,21 +271,20 @@ def test_phases_of_compile_fit_and_the_callers_own():
     assert set().union(*by.values()) <= {"param_init", "fit", "eval", None}
 
 
-def test_the_memory_analysis_at_fits_end_is_fits_own_build(tmp_path):
+def test_the_memory_analysis_at_fits_end_finds_fits_own_build(tmp_path):
     """With ``--telemetry-file`` fit() ends by lowering and compiling the
-    step once more for XLA's memory analysis. After a fit of ONE step that
-    is the step's second build (the counter is an array by then; the one
-    call handed it in as a bare 0) — the benchmark's first fit is this
-    shape — and it carries fit's phase: the phase ends where fit() does,
-    not where its loop does."""
+    step once more for XLA's memory analysis. After a fit of ONE step — the
+    benchmark's first fit is this shape — that finds the program the one
+    call built (the counter it is handed is the array the call was handed),
+    and the analysis is there. fit's phase ends where fit() does."""
     ff, x, y = _mlp()
     ff.config.telemetry_file = str(tmp_path / "telemetry.json")
     mark = obs.build_mark()
     ff.fit(x[:32], y[:32])
     step = [r for r in obs.builds()[mark:] if r.name == "jit_step"]
-    assert [r.phase for r in step] == ["fit", "fit"]
-    # the run's own summary was closed before the analysis
+    assert [r.phase for r in step] == ["fit"]
     assert ff.get_telemetry().summary()["by_name"]["jit_step"] == 1
+    assert ff.get_telemetry().device_memory["argument_size_in_bytes"] > 0
     jax.jit(lambda v: v * 11)(_ones(3))
     assert obs.builds()[-1].phase is None  # and fit's phase ended with it
 
@@ -314,7 +376,7 @@ def test_fit_telemetry_carries_what_it_built():
     tel = ff.get_telemetry()
     s = tel.summary()
     totals = obs.build_totals(mark)
-    assert s["programs_built"] == totals["builds"] >= 2
+    assert s["programs_built"] == totals["builds"] >= 1
     assert s["by_name"] == totals["by_name"]
     seconds = sum(totals[k] for k in ("trace_s", "lower_s", "load_s",
                                       "compile_s"))
