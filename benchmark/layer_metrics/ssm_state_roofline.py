@@ -1,0 +1,76 @@
+"""The decode step's state update against the memory roofline: the least time
+is the recurrent state the window's decode ticks read plus wrote
+(``recurrent_state_bytes`` of their ``serve_tick`` spans: every slot the
+program stepped, at ``kvcache.node_slot_bytes`` a node) over 819 GB/s; the
+share is that over the time in which the decode step moved the state — the
+same work whatever implements the update. That time is the union of two kinds
+of interval inside the step's launches: the ops under ``l_ssmscan``, and the
+start-to-done spans of the asynchronous copies that carry a state leaf
+(``f32[rows, N, E]``: the compiler prefetches the state into VMEM in slices
+beside the ops before the update and writes it back beside the ops after it
+— read over the update's ops alone, the share came out at 200%). Other
+traffic moves in those intervals too (the weights of the ops the copies
+overlap), so the share is a floor; it cannot pass 100%. The step's ``x``,
+``dt``, ``B`` and ``C`` are a thousandth of the state and are left out."""
+NAME = "ssm_state_roofline"
+UNIT = "%"
+LAYER = "xla program"
+MOVES = "tpot_p50_ms"
+CELLS = ["jamba2-*", "jamba-*"]
+
+
+def state_seconds(run):
+    """Seconds of the window, inside the step program's launches, in which
+    an op under ``l_ssmscan`` ran or an asynchronous copy of a state leaf
+    was in flight; None where there is nothing to read."""
+    import re
+
+    from benchmark import spans, ssm_flops
+    from benchmark.reduce import cell, xplane
+
+    path, tr = run.get("trace_file"), run.get("trace") or {}
+    module = run.get("step_module")
+    if not path or not module or "worst_device" not in tr:
+        return None
+    trace = xplane.load(path)
+    window = [(s, e) for n, s, e in xplane.host_spans(trace, {spans.WINDOW})]
+    if not window:
+        return None
+    lo, hi = min(s for s, _ in window), max(e for _, e in window)
+    lines = trace.chips[tr["worst_device"]]
+    module_of = xplane._module_lookup(lines["modules"])
+    scopes = run.get("scopes") or {}
+    config = cell.cell_config(run)
+    leaf = re.compile(r"f32\[\d+,%d,%d\]" % (
+        int(config["mamba_d_state"]), ssm_flops.inner_width(config)))
+
+    def scan_op(text):
+        return scopes.get(xplane.instruction(text)[0]) == "l_ssmscan"
+
+    def state_copy(text):
+        return leaf.search(text) is not None
+
+    intervals = []
+    for events, want in ((lines["ops"], scan_op),
+                         (lines["async_ops"], state_copy)):
+        wanted = {}   # an event's text comes back in every step
+        for text, s, e in events:
+            s, e = max(s, lo), min(e, hi)
+            if e <= s or module_of(s) != module:
+                continue
+            if text not in wanted:
+                wanted[text] = want(text)
+            if wanted[text]:
+                intervals.append((s, e))
+    return xplane.total(xplane.union(intervals)) * 1e-9 or None
+
+
+def read(run):
+    from benchmark.reduce import decode_scopes
+    if run.get('kind') != 'serve' or not run.get('peaks'):
+        return None
+    moved = decode_scopes.decode_tick_counters(run, 'recurrent_state_bytes')
+    t = state_seconds(run)
+    if not moved or not t:
+        return None
+    return 100.0 * moved / run['peaks']['hbm_bytes_per_s'] / t

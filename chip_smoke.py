@@ -68,6 +68,24 @@ FLASH_DECODE_TOL = 2.0 ** -7
 ROUTED_TOL = 1.5e-2
 
 
+# One selective state-space mixer (ops/ssm.py) at AI21-Jamba2-3B's widths vs
+# the plain float32 reference: the relative L2 error of the recurrent STATE
+# the node hands on, after a padded prefill's last real token and after 64
+# decode steps from it (SSM_TOL), and of the node's output over those rows
+# (SSM_OUT_TOL). The state is where the precision of the scan shows: the
+# bf16 projections' rounding of each token's input averages out over the
+# state's memory, a rounding of the state itself adds up over it. Set between
+# two readings on the v5e (PERF.md section 6, PR 44): the sound program's, and
+# the control's — the reference itself with its state rounded to bf16 every
+# step, which is what a program that kept ``S`` in bf16 would read at best.
+# Readings (my chip run, PR 44; after the prefill, after the decode steps):
+# sound 0.00289, 0.00232; control 0.00534, 0.00536; the output 0.00393,
+# 0.00317. The sound state's error is the bf16 projections' (x, dt, B), which
+# is why the control reads less than twice it.
+SSM_TOL = 4.0e-3
+SSM_OUT_TOL = 2.0e-2
+
+
 def info(msg: str) -> None:
     print(f"[info] {msg}", flush=True)
 
@@ -593,6 +611,118 @@ def check_routed_layer_decode(rows: int = 64, d: int = 7680,
           f"three mantissa bits are not ({planted:.4f})")
 
 
+def check_ssm_layer(d: int = 2560, inner: int = 5120, state: int = 16,
+                    conv: int = 4, rank: int = 160, bucket: int = 2048,
+                    length: int = 1900, steps: int = 64) -> None:
+    """One mixer at the ``jamba2-3b-reasoning`` cell's widths, bf16: a
+    ``bucket``-row padded prefill of a ``length``-token prompt (the
+    ``selective_scan`` kernel; the state handed on is the one after the last
+    REAL token) and ``steps`` decode steps from that state, against the plain
+    reference's whole-sequence mixer in float32 over prompt + steps tokens.
+    The serving cell's own comparison sees a precision fault only where it
+    moves a served token (PERF.md section 7); this one reads the node's
+    output. The control — the reference with its state rounded to bf16 every
+    step — must read over the limit."""
+    import importlib.util
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from flexflow_tpu.ffconst import DataType, OperatorType
+    from flexflow_tpu.ops.base import OpContext, op_class_for
+    from flexflow_tpu.serving.kvcache import ServingState
+
+    spec = importlib.util.spec_from_file_location(
+        "jamba_reference", os.path.join(
+            os.path.dirname(os.path.abspath(__file__)), "benchmark",
+            "reference", "ai21-jamba2-3b.py"))
+    ref = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(ref)
+    eps = 1e-6
+    op = op_class_for(OperatorType.OP_SSM_MIXER)(
+        "l0_ssm", {"inner_dim": inner, "state_dim": state,
+                   "conv_width": conv, "dt_rank": rank, "conv_bias": True,
+                   "proj_bias": False, "norm_eps": eps},
+        DataType.DT_BFLOAT16)
+    keys = jax.random.split(jax.random.PRNGKey(44), 32)
+    params = {name: init(keys[i], shape, jnp.bfloat16)
+              for i, (name, (shape, _dt, init)) in enumerate(
+                  sorted(op.weight_specs([(1, bucket, d)]).items()))}
+    total = length + steps
+    u = jax.random.normal(keys[-1], (1, total, d), jnp.float32
+                          ).astype(jnp.bfloat16)
+    config = {"mamba_d_state": state, "mamba_d_conv": conv,
+              "mamba_dt_rank": rank, "rms_norm_eps": eps}
+
+    def reference(fault, n):
+        def run(u, params):
+            with jax.default_matmul_precision("highest"):
+                return ref.mamba(u[0, :n].astype(jnp.float32),
+                                 ref.f32(params), config, fault,
+                                 with_state=True)
+
+        return [np.asarray(a) for a in jax.jit(run)(u, params)]
+
+    (_, want_mid), (want, want_end) = (reference(None, n)
+                                       for n in (length, total))
+    (_, ctl_mid), (_, ctl_end) = (reference("bf16_state", n)
+                                  for n in (length, total))
+
+    @jax.jit
+    def prefill(params, u):
+        padded = jnp.pad(u[:, :length], ((0, 0), (0, bucket - length),
+                                         (0, 0)))
+        sv = ServingState(mode="prefill", max_len=bucket,
+                          positions=jnp.zeros((1,), jnp.int32),
+                          lengths=jnp.asarray([length], jnp.int32))
+        out = op.forward(params, [padded],
+                         OpContext(training=False, serving=sv))[0]
+        return out[0, :length], sv.cache_out[op.name]
+
+    @jax.jit
+    def decode(params, u_t, cache):
+        sv = ServingState(mode="decode", max_len=bucket,
+                          positions=jnp.zeros((1,), jnp.int32),
+                          cache_in={op.name: cache},
+                          block_tables=jnp.ones((1, 1), jnp.int32))
+        out = op.forward(params, [u_t],
+                         OpContext(training=False, serving=sv))[0]
+        return out[0, 0], sv.cache_out[op.name]
+
+    text = prefill.lower(params, u).compile().as_text()
+    check("selective_scan" in mosaic_calls(text),
+          f"ssm layer: the prefill runs the selective_scan Mosaic kernel "
+          f"({sorted(mosaic_calls(text))})")
+    rows, cache = prefill(params, u)
+    got_mid = np.asarray(cache[1], np.float32)[0]
+    got = [np.asarray(rows, np.float32)]
+    for t in range(length, total):
+        row, cache = decode(params, u[:, t:t + 1], cache)
+        got.append(np.asarray(row, np.float32)[None])
+    got, got_end = np.concatenate(got), np.asarray(cache[1], np.float32)[0]
+
+    def l2(a, b):
+        return float(np.linalg.norm(a - b) / np.linalg.norm(b))
+
+    sound = (l2(got_mid, want_mid), l2(got_end, want_end))
+    planted = (l2(ctl_mid, want_mid), l2(ctl_end, want_end))
+    out = (l2(got[:length], want[:length]), l2(got[length:], want[length:]))
+    info(f"ssm layer at d {d}, E {inner}, N {state}, R {rank}, K {conv}; "
+         f"{length} of {bucket} prefill rows, {steps} decode steps: relative "
+         f"L2 error of the state (after the prefill, after the decode "
+         f"steps): sound {sound[0]:.5f}, {sound[1]:.5f}; the reference with "
+         f"a bf16 state {planted[0]:.5f}, {planted[1]:.5f} (limit "
+         f"{SSM_TOL}); of the output (prefill rows, decode rows) "
+         f"{out[0]:.5f}, {out[1]:.5f} (limit {SSM_OUT_TOL})")
+    check(max(sound) <= SSM_TOL < min(planted),
+          f"ssm layer: the state handed on is within {SSM_TOL} of the "
+          f"float32 reference's, and the bf16-state control is over it")
+    check(max(out) <= SSM_OUT_TOL,
+          f"ssm layer: the output is within {SSM_OUT_TOL} of the float32 "
+          f"reference's")
+
+
 # ------------------------------------------------------------------ trainer
 def build_trainer(cfg, argv):
     """FFConfig -> FFModel -> build_bert -> compile(), bf16 compute, Adam."""
@@ -776,6 +906,12 @@ def main() -> None:
     device = check_environment()
     import numpy as np
 
+    if sys.argv[1:]:  # the named checks alone: python chip_smoke.py <name>..
+        for name in sys.argv[1:]:
+            globals()[name]()
+        print(json.dumps({"ok": True, "device": device}), flush=True)
+        return
+
     from flexflow_tpu.models.bert import BertConfig
     from flexflow_tpu.models.gpt2 import GPT2Config
     from flexflow_tpu.obs import enable as obs_enable
@@ -790,6 +926,7 @@ def main() -> None:
     check_routed_layer(skewed=True)
     check_routed_layer_decode()
     check_routed_layer_decode(lead=1.0)
+    check_ssm_layer()
 
     n_chips = device["count"]
     bert = BertConfig(batch_size=8 * n_chips, seq_len=512, hidden=1024,

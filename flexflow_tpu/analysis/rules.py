@@ -261,7 +261,8 @@ def check_serving_graph(pcg) -> List[Diagnostic]:
         for sub in getattr(node.op, "sub_ops", ()):
             stateful = sub.op_type in (OperatorType.OP_MULTIHEAD_ATTENTION,
                                        OperatorType.OP_LATENT_ATTENTION,
-                                       OperatorType.OP_LSTM)
+                                       OperatorType.OP_LSTM,
+                                       OperatorType.OP_SSM_MIXER)
             positional = (sub.op_type == OperatorType.OP_CONSTANT
                           and is_position_constant(sub.attrs.get("value")))
             if stateful or positional:

@@ -222,6 +222,10 @@ class OperatorType(enum.IntEnum):
     # up-projected from one compressed row a token, which is what serving
     # caches
     OP_LATENT_ATTENTION = 123
+    # selective state-space (Mamba-1) mixer (ops/ssm.py): a recurrence whose
+    # per-request state is a fixed (state_dim, inner_dim) matrix and the last
+    # conv_width - 1 conv inputs, which is what serving carries a slot
+    OP_SSM_MIXER = 124
 
 
 # --- dtype helpers -------------------------------------------------------------

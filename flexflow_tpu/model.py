@@ -239,6 +239,28 @@ class FFModel:
         return self._add_layer(OperatorType.OP_LATENT_ATTENTION, [input],
                                attrs, input.dtype, name)
 
+    def ssm_mixer(self, input: Tensor, inner_dim: int, state_dim: int,
+                  conv_width: int, dt_rank: int, conv_bias: bool,
+                  proj_bias: bool, norm_eps: float,
+                  kernel_initializer=None,
+                  name: Optional[str] = None) -> Tensor:
+        """Selective state-space (Mamba-1) mixer with RMS norms on ``dt``,
+        ``B`` and ``C`` (ops/ssm.py): ``inner_dim`` channels, each with a
+        ``state_dim``-number recurrent state, after a causal depthwise conv
+        of ``conv_width``; ``dt`` through a ``dt_rank`` bottleneck. Every
+        width is the caller's: no default stands in for one. Under a serving
+        context the state and the conv's last inputs are what a slot
+        holds."""
+        attrs = {"inner_dim": inner_dim, "state_dim": state_dim,
+                 "conv_width": conv_width, "dt_rank": dt_rank,
+                 "conv_bias": bool(conv_bias), "proj_bias": bool(proj_bias),
+                 "norm_eps": norm_eps,
+                 "kernel_initializer": kernel_initializer}
+        if conv_width < 2:
+            raise ValueError("ssm_mixer: conv_width must be at least 2")
+        return self._add_layer(OperatorType.OP_SSM_MIXER, [input], attrs,
+                               input.dtype, name)
+
     # ---- elementwise ----------------------------------------------------------
     def _binary(self, op_type, x, y, name=None, inplace_a=False):
         return self._add_layer(op_type, [x, y], {}, x.dtype, name)

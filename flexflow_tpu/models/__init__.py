@@ -4,7 +4,9 @@ GPT-2 (decoder-only causal LM), DLRM, XDL, MLP_Unify, CANDLE-Uno, MoE,
 NMT (LSTM seq2seq); and, with no reference analog, the sliding/global
 grouped-query decoder with a dropless routed expert layer (trinity.py) and
 the latent-attention decoder with sandwich norms and a routed top-8 layer,
-built for the serving path (pangu.py)."""
+built for the serving path (pangu.py), and the hybrid decoder of selective
+state-space layers with a one-K/V-head attention layer every period
+(jamba.py), built for the serving path too."""
 from .bert import BertConfig, build_bert, bert_param_count  # noqa: F401
 from .gpt2 import (GPT2Config, build_gpt2,  # noqa: F401
                    gpt2_param_count, gpt2_train_flops_per_step)
@@ -21,3 +23,5 @@ from .trinity import (TrinityConfig, build_trinity,  # noqa: F401
 from .pangu import (PanguConfig, build_pangu,  # noqa: F401
                     pangu_param_count, pangu_prefill_flops_per_token,
                     pangu_decode_flops_per_token)
+from .jamba import (JambaConfig, build_jamba,  # noqa: F401
+                    jamba_param_count)

@@ -21,9 +21,11 @@ ranks all ``num_experts`` and normalises over all the chosen, this device
 adds the chosen experts it holds, and embedding and head hold ``vocab_size``
 rows of the vocabulary. Nothing stands in for the other devices.
 
-Serving is not supported: the KV pool, ``flash_decode`` and the serving
-programs know neither grouped K/V heads, nor windows, nor rotary positions
-(ROADMAP.md, Reach R1).
+Serving is not supported: the KV pool and ``flash_decode`` serve grouped K/V
+heads (since PR 44: serving/kvcache.py, the grouped layout), but the serving
+programs know neither sliding windows nor rotary positions, and
+``MultiHeadAttentionOp`` raises on either under a serving context
+(ROADMAP.md, Reach R3 (a)).
 """
 from __future__ import annotations
 

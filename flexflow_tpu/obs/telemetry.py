@@ -138,6 +138,10 @@ class StepTelemetry:
         self.serving_moe_load_max_permille: int = 0
         self.serving_moe_bounded_steps: int = 0
         self.serving_moe_layer_steps: int = 0
+        # recurrent nodes' slot-major state the decode steps read plus
+        # wrote, and the live slots among those stepped (ServingStats)
+        self.serving_recurrent_state_bytes: int = 0
+        self.serving_recurrent_slots_live: int = 0
         # serving-resilience counters (ISSUE 9): the outcome ledger of a
         # serve() run (every request under exactly one of ok |
         # deadline_exceeded | shed | decode_fault | preempted) plus the
@@ -360,6 +364,11 @@ class StepTelemetry:
                     self.serving_moe_load_max_permille
                 sv["moe_bounded_steps"] = self.serving_moe_bounded_steps
                 sv["moe_layer_steps"] = self.serving_moe_layer_steps
+            if self.serving_recurrent_state_bytes:
+                sv["recurrent_state_bytes"] = \
+                    self.serving_recurrent_state_bytes
+                sv["recurrent_slots_live"] = \
+                    self.serving_recurrent_slots_live
             out["serving"] = sv
         if self.fleet_replicas:
             total = max(sum(self.fleet_outcomes.values()), 1)

@@ -361,7 +361,9 @@ SPANS: Mapping[str, str] = MappingProxyType({
                   "decode tick of a graph with routed expert layers carries "
                   "moe_pairs_here, moe_experts_live, moe_load_max_permille, "
                   "moe_bounded_steps, moe_layer_steps (counted on the "
-                  "device, fetched with its tokens)",
+                  "device, fetched with its tokens); one of a graph with a "
+                  "recurrent node recurrent_state_bytes (slot-major state "
+                  "the step read plus wrote) and recurrent_slots_live",
     "tick_dispatch": "tick entry -> the device call is issued: deadline "
                      "sweep, next_action(), building ids",
     "prefill": "the bucket's prefill call up to and including the "

@@ -153,16 +153,15 @@ class MultiHeadAttentionOp(Op):
             # serving engine prefill/decode (ISSUE 6): the KV pool is
             # the execution path, selected before any kernel routing —
             # decode shapes (seq 1) must never reach flash/ring
-            if not plain or self.attrs.get("rope_theta"):
+            if window is not None or self.attrs.get("rope_theta"):
                 raise NotImplementedError(
                     f"{self.name}: multihead_attention on the serving path "
-                    "holds as many K/V heads as query heads, whole-context "
-                    "attention and positions from the graph (a learned "
-                    "table); its grouped K/V heads, sliding window and "
-                    "rotary positions run on the training path only "
-                    "(ROADMAP.md, Reach R3 (a)-(c)). Rotary positions from "
-                    "the serving context are latent_attention's "
-                    "(ops/latent_attention.py)")
+                    "holds whole-context attention (grouped K/V heads "
+                    "included) with positions from the graph or none; its "
+                    "sliding window and rotary positions run on the "
+                    "training path only (ROADMAP.md, Reach R3 (a)-(c)). "
+                    "Rotary positions from the serving context are "
+                    "latent_attention's (ops/latent_attention.py)")
             out = _serving_attention(self.name, q, k, v, ctx.serving,
                                      causal=causal)
         elif seq_axis and ctx.mesh is not None and seq_axis in ctx.mesh.shape:
@@ -306,6 +305,16 @@ def _serving_attention(name: str, q, k, v, sv, *, causal: bool):
     from ..serving.kvcache import (prefill_kv_entry, read_kv,
                                    write_token_kv)
 
+    # fewer K/V heads than query heads: the pool's grouped layout
+    group = q.shape[1] // k.shape[1]
+    grouped = group > 1
+    if grouped and (sv.kv_dtype == "int8" or sv.mode == "chunk"
+                    or sv.seq_shards > 1):
+        raise NotImplementedError(
+            f"{name}: grouped K/V heads on the serving path run the "
+            "one-shot prefill and the decode step over a native-dtype "
+            "pool; an int8 pool, a prefill chunk and sequence-parallel "
+            "decode hold as many K/V heads as query heads")
     if not causal:
         raise ValueError(
             f"{name}: serving prefill/decode requires CAUSAL self-attention "
@@ -314,18 +323,22 @@ def _serving_attention(name: str, q, k, v, sv, *, causal: bool):
     if sv.mode == "chunk":
         return _chunk_prefill_attention(name, q, k, v, sv)
     if sv.mode == "prefill":
-        sv.cache_out[name] = prefill_kv_entry(k, v, sv.max_len)
+        sv.cache_out[name] = prefill_kv_entry(k, v, sv.max_len,
+                                              v_first=grouped)
         return mha_core(q, k, v, causal=True)
     scale = 1.0 / np.sqrt(q.shape[-1])
     tables = sv.block_tables
     with jax.named_scope("kv_update"):
         entry = write_token_kv(sv.cache_in[name], k, v, sv.positions,
-                               tables, sv.block_size)
+                               tables, sv.block_size, v_first=grouped)
     sv.cache_out[name] = entry
-    kernel_out = _maybe_flash_decode(q, entry, tables, sv, scale)
+    kernel_out = _maybe_flash_decode(q, entry, tables, sv, scale,
+                                     v_first=grouped)
     if kernel_out is not None:
         return kernel_out
-    kc, vc = read_kv(entry, tables, q.shape[-1], k.dtype)
+    kc, vc = read_kv(entry, tables, q.shape[-1], k.dtype, v_first=grouped)
+    if grouped:
+        kc, vc = (jnp.repeat(t, group, axis=1) for t in (kc, vc))
     extent = kc.shape[2]  # blocks_per_slot * block_size
     if sv.seq_shards > 1:
         return _seqpar_decode(q, kc, vc, sv, scale, extent)
@@ -437,7 +450,7 @@ def _chunk_prefill_attention(name: str, q, k, v, sv):
     return out.astype(vc.dtype)
 
 
-def _maybe_flash_decode(q, entry, tables, sv, sm_scale):
+def _maybe_flash_decode(q, entry, tables, sv, sm_scale, v_first=False):
     """Route one paged decode read through the Pallas flash-decode kernel
     when eligible (on-TPU, a pool of whole lanes and sublanes) —
     returns the (S, h, 1, hd) output or None for the gather path."""
@@ -449,7 +462,7 @@ def _maybe_flash_decode(q, entry, tables, sv, sm_scale):
         # kernel launch would bypass the combine
         return None
     out = flash_decode_kv(q[:, :, 0, :], entry, tables, sv.positions + 1,
-                          sm_scale)
+                          sm_scale, v_first=v_first)
     return None if out is None else out[:, :, None, :]
 
 

@@ -171,6 +171,12 @@ class ServingStats:
     moe_layer_steps: int = 0
     moe_chunk_bounded_steps: int = 0
     moe_chunk_layer_steps: int = 0
+    # recurrent nodes (an LSTM carry, a state-space mixer's state): the
+    # bytes of slot-major state the decode steps read plus wrote — every
+    # slot the program stepped, live or free, at kvcache.node_slot_bytes
+    # a node — and the live slots among them, both summed over decode steps
+    recurrent_state_bytes: int = 0
+    recurrent_slots_live: int = 0
     # the programs built between start_serve and finish() (obs/builds.py),
     # their seconds tracing, lowering, loading and compiling, and how many
     # by name; set by finish(). Not zero after a warm-up: the process
@@ -305,7 +311,8 @@ class ServingStats:
         for k in ("moe_pairs_here", "moe_experts_live",
                   "moe_load_max_permille", "moe_bounded_steps",
                   "moe_layer_steps", "moe_chunk_bounded_steps",
-                  "moe_chunk_layer_steps"):
+                  "moe_chunk_layer_steps", "recurrent_state_bytes",
+                  "recurrent_slots_live"):
             if getattr(self, k):
                 out[k] = getattr(self, k)
         return out
@@ -416,23 +423,26 @@ class ServingEngine:
         # REJECTS beyond it (the old warn-and-clamp is gone, ISSUE 12
         # satellite)
         self._validate_graph()
-        has_lstm = any(
-            n.op.op_type == OperatorType.OP_LSTM
-            for n in self.executor.pcg.compute_nodes())
-        if has_lstm:
-            # the LSTM carry is a summary, not per-token pool rows:
-            # there is no block to share or chunk (ISSUE 14 scope —
-            # attention-only stateful graphs)
+        from .kvcache import is_recurrent
+
+        recurrent = [f"{n.name}: {n.op.op_type.name}"
+                     for n in self.executor.pcg.compute_nodes()
+                     if is_recurrent(n.op)]
+        if recurrent:
+            # a recurrent state (the LSTM carry, a state-space mixer's
+            # state) is a summary, not per-token pool rows: there is no
+            # block to share or chunk (ISSUE 14 scope — attention-only
+            # stateful graphs; ROADMAP.md Reach R8 has what is missing)
             if self.prefill_chunk_tokens:
                 raise ValueError(
                     "prefill_chunk_tokens: chunked prefill supports "
                     "attention-only stateful graphs; this model has "
-                    "LSTM recurrence")
+                    f"a recurrent node ({recurrent[0]})")
             if prefix_cache == "on":
                 raise ValueError(
                     "prefix_cache='on': prefix caching supports "
                     "attention-only stateful graphs; this model has "
-                    "LSTM recurrence")
+                    f"a recurrent node ({recurrent[0]})")
             prefix_mode = "off"
         self.max_context = position_context_bound(self.executor,
                                                   self.max_decode_len)
@@ -1133,6 +1143,13 @@ class ServingEngine:
         self.block_allocator.reset()
 
     # ------------------------------------------------------ KV accounting
+    def _rest_itemsize(self) -> int:
+        """Bytes of an element of what the serving programs rest between
+        steps (K/V rows, a conv tail): the compute dtype's where the graph
+        computes in a reduced one, else 0 — each node's own dtype."""
+        cd = self.executor._compute_jnp_dtype()
+        return 0 if cd is None else int(np.dtype(cd).itemsize)
+
     def _kv_row_bytes(self) -> int:
         """Analytic KV bytes ONE token's row costs across every attention
         node — heads * (kdim + vdim) * element size (int8 layouts add the
@@ -1144,9 +1161,22 @@ class ServingEngine:
             from .kvcache import node_token_bytes
 
             self._kv_row_bytes_cache = sum(
-                node_token_bytes(node.op, self.kv_dtype)
+                node_token_bytes(node.op, self.kv_dtype,
+                                 self._rest_itemsize())
                 for node in self.executor.pcg.compute_nodes())
         return self._kv_row_bytes_cache
+
+    def _recurrent_slot_bytes(self) -> int:
+        """Bytes of slot-major state ONE slot holds across every
+        recurrent node (``kvcache.node_slot_bytes``); 0 for an
+        attention-only graph."""
+        if getattr(self, "_recurrent_slot_bytes_cache", None) is None:
+            from .kvcache import node_slot_bytes
+
+            self._recurrent_slot_bytes_cache = sum(
+                node_slot_bytes(node.op, self._rest_itemsize())
+                for node in self.executor.pcg.compute_nodes())
+        return self._recurrent_slot_bytes_cache
 
     def _kv_tile_blocks(self) -> int:
         """Table entries one grid step of the decode attention kernel
@@ -1182,6 +1212,21 @@ class ServingEngine:
         stats.kv_bytes_read += toks * self._kv_row_bytes()
         stats.kv_tiles_grid += self.n_slots * -(
             -self.max_blocks_per_slot // tile_blocks)
+
+    def _count_decode_recurrent(self, stats: ServingStats, n_live: int
+                                ) -> Dict[str, int]:
+        """One decode step's recurrent state, counted: the step reads and
+        writes the state of EVERY slot (static shapes: a free slot's zeros
+        too). Returns the step's two numbers, for its ``serve_tick`` span;
+        empty for an attention-only graph."""
+        slot = self._recurrent_slot_bytes()
+        if not slot:
+            return {}
+        moved = 2 * slot * self.n_slots
+        stats.recurrent_state_bytes += moved
+        stats.recurrent_slots_live += n_live
+        return {"recurrent_state_bytes": moved,
+                "recurrent_slots_live": n_live}
 
     def _sweep_deadlines(self, sched, res, tracer) -> None:
         """Deadline enforcement at the iteration boundary: expired queued
@@ -1320,6 +1365,8 @@ class ServingEngine:
         tel.serving_moe_load_max_permille = stats.moe_load_max_permille
         tel.serving_moe_bounded_steps = stats.moe_bounded_steps
         tel.serving_moe_layer_steps = stats.moe_layer_steps
+        tel.serving_recurrent_state_bytes = stats.recurrent_state_bytes
+        tel.serving_recurrent_slots_live = stats.recurrent_slots_live
         # serving_resilience block (ISSUE 9): the outcome ledger + event
         # counters, mirroring the resilience/strategy_safety blocks
         tel.serving_outcomes = dict(stats.outcomes)
@@ -1958,6 +2005,8 @@ class _ServeLoop:
         toks_host, ok_host = self._fetch(toks, ok_vec, eng._step_counters)
         phase.tick_args.update(self._moe_tick)
         self._moe_tick = {}
+        phase.tick_args.update(
+            eng._count_decode_recurrent(self.stats, len(live)))
         wall = time.perf_counter() - t_d
         phase.to("tick_bookkeep")
         self._commit_arrival(live, None, toks_host, ok_host, wall)
@@ -2164,6 +2213,8 @@ class _AsyncServeLoop(_ServeLoop):
         pipelined = self._pending is not None
         if pipelined:
             phase.tick_args["pipelined"] = 1
+        phase.tick_args.update(
+            eng._count_decode_recurrent(stats, len(live)))
         k = self.dispatch_no  # chaos keys on dispatch order
         self._chaos_hooks(k)
         t_d = time.perf_counter()

@@ -64,6 +64,24 @@ is known in this module alone:
   the allocator, the trie and the clone see blocks as before. int8 is
   not defined for it.
 
+* the GROUPED layout (multi-head attention with fewer K/V heads than
+  query heads): the pool is ``(n_blocks, kv_heads, block_size, vd +
+  kd)`` and a row rests a K/V head's **V then K**. The decode read is
+  then the latent read as it stands: a K/V head's whole group of query
+  rows scores the stored row in one grid step under a query that is
+  zero over V's lanes, and the value is the row's first ``vd`` lanes
+  (``flash_decode``'s ``v_lanes``). Every function that packs or
+  splits a row takes ``v_first`` for it; the block tables, the
+  allocator, the trie and the clone see blocks as before. int8 is not
+  defined for it.
+
+* the SLOT-MAJOR kind (a recurrent node: the LSTM carry, a state-space
+  mixer's state and conv tail): a fixed size a REQUEST whatever its
+  context, one row a slot (``(n_slots, ...)`` leaves), overwritten
+  whole at admission (:func:`update_slot_entry`), priced a slot by
+  :func:`node_slot_bytes` as the pool is priced a token by
+  :func:`node_token_bytes`.
+
 Pad garbage beyond a prompt's true length is never read: the write
 cursor overwrites it before the mask ever exposes it.
 Slot recycling and prefix sharing are pointer bookkeeping in the
@@ -317,24 +335,56 @@ def latent_token_bytes(width: int, el: int) -> int:
     return kv_token_bytes(1, latent_lanes(width), 0, el)
 
 
-def node_token_bytes(op, kv_dtype: str = "native") -> int:
+def node_token_bytes(op, kv_dtype: str = "native", el: int = 0) -> int:
     """What ONE cached token costs in the pool of the attention node
-    ``op``, or 0 for an op that caches none: heads x (K + V) for
+    ``op``, or 0 for an op that caches none: K/V heads x (K + V) for
     multi-head attention, the one stored row for latent attention. The
-    engine's ``kv_bytes_read`` and the serving search price from here."""
+    engine's ``kv_bytes_read`` and the serving search price from here.
+    ``el``: bytes of an element as the pool rests it — the compute
+    dtype's where the graph computes in a reduced one (the engine says);
+    the node's own dtype otherwise."""
     from ..ffconst import OperatorType, size_of_datatype
 
-    a, el = op.attrs, size_of_datatype(op.data_type)
+    a, el = op.attrs, el or size_of_datatype(op.data_type)
     if op.op_type == OperatorType.OP_LATENT_ATTENTION:
         return latent_token_bytes(int(a["kv_rank"]) + int(a["rope_dim"]),
                                   el)
     if op.op_type != OperatorType.OP_MULTIHEAD_ATTENTION:
         return 0
     heads = int(a.get("num_heads", 1))
-    return kv_token_bytes(heads,
+    return kv_token_bytes(int(a.get("num_kv_heads") or heads),
                           int(a.get("kdim") or a["embed_dim"] // heads),
                           int(a.get("vdim") or a["embed_dim"] // heads),
                           el, kv_dtype)
+
+
+def is_recurrent(op) -> bool:
+    """Does ``op`` carry a recurrent state — a summary of the whole
+    prefix, one row a slot — rather than per-token pool rows? Such a
+    state has no block to share and no position to resume from: the
+    engine serves a graph that holds one without chunked prefill and
+    without the prefix cache (ROADMAP.md, Reach R8)."""
+    from ..ffconst import OperatorType
+
+    return op.op_type in (OperatorType.OP_LSTM, OperatorType.OP_SSM_MIXER)
+
+
+def node_slot_bytes(op, el: int = 0) -> int:
+    """What ONE slot costs in the slot-major state of the recurrent node
+    ``op``, or 0 for an op that holds none: the LSTM's ``[h, c]``; a
+    state-space mixer's ``(state_dim, inner_dim)`` float32 state and its
+    ``conv_width - 1`` conv inputs at ``el`` bytes an element (as
+    :func:`node_token_bytes`). The engine's ``recurrent_state_bytes`` and
+    the serving search price from here."""
+    from ..ffconst import OperatorType, size_of_datatype
+
+    a, el = op.attrs, el or size_of_datatype(op.data_type)
+    if op.op_type == OperatorType.OP_LSTM:
+        return 2 * int(a["hidden_size"]) * el
+    if op.op_type == OperatorType.OP_SSM_MIXER:
+        e = int(a["inner_dim"])
+        return e * int(a["state_dim"]) * 4 + e * (int(a["conv_width"]) - 1) * el
+    return 0
 
 
 def quantize_kv(x) -> Tuple[Any, Any]:
@@ -385,17 +435,24 @@ def new_kv_pool(prefill_entry, n_blocks: int, block_size: int,
     return jnp.zeros(shape, kbuf.dtype)
 
 
-def prefill_kv_entry(k, v, max_len: int):
+def _stored_order(k, v, v_first: bool):
+    """The two halves of a row in the order they rest on the lanes: K
+    then V, or V then K in the grouped layout."""
+    return (v, k) if v_first else (k, v)
+
+
+def prefill_kv_entry(k, v, max_len: int, v_first: bool = False):
     """What a prefill hands the slot writer for one attention node: the
     request's k ``(1, h, L, kd)`` and v ``(1, h, L, vd)`` at position 0
-    of contiguous zeroed ``max_len`` buffers, unquantized —
-    :func:`scatter_prefill_kv` turns them into pool blocks."""
+    of contiguous zeroed ``max_len`` buffers, unquantized and in stored
+    order — :func:`scatter_prefill_kv` turns them into pool blocks."""
     import jax.numpy as jnp
 
     pad = ((0, 0), (0, 0), (0, max_len - k.shape[2]), (0, 0))
     if v is None:  # the latent layout: k is the row ``(1, 1, L, width)``
         return (jnp.pad(k, pad),)
-    return jnp.pad(k, pad), jnp.pad(v, pad)
+    first, second = _stored_order(k, v, v_first)
+    return jnp.pad(first, pad), jnp.pad(second, pad)
 
 
 def is_prefill_kv_entry(entry) -> bool:
@@ -502,7 +559,8 @@ def _write_entry(entry, k, v, block_ids, offsets, consecutive):
         srows.swapaxes(1, 2))
 
 
-def write_token_kv(entry, k, v, positions, block_tables, block_size):
+def write_token_kv(entry, k, v, positions, block_tables, block_size,
+                   v_first: bool = False):
     """Write one token's k ``(n_slots, h, 1, kd)`` and v ``(n_slots, h,
     1, vd)`` into the pool at each slot's current position: block
     ``tables[slot, pos // bs]``, offset ``pos % bs``. Free slots (their
@@ -510,6 +568,8 @@ def write_token_kv(entry, k, v, positions, block_tables, block_size):
     garbage block — it is never read."""
     import jax.numpy as jnp
 
+    if v is not None:
+        k, v = _stored_order(k, v, v_first)
     bi = jnp.take_along_axis(
         block_tables, (positions // block_size)[:, None], axis=1)[:, 0]
     return _write_entry(entry, k[:, :, 0, :],
@@ -536,10 +596,11 @@ def write_chunk_kv(entry, k, v, start, n_new, table_row, block_size):
                         bi, pos % block_size, consecutive=True)
 
 
-def read_kv(entry, block_tables, kdim: int, dtype):
+def read_kv(entry, block_tables, kdim: int, dtype, v_first: bool = False):
     """Materialize each slot's logical KV extent from the pool entry
     through ``(n_slots, mb)`` tables: ``(k, v)`` as ``(n_slots, h, mb *
-    bs, kd | vd)`` in position order and in ``dtype``. This is the
+    bs, kd | vd)`` in position order and in ``dtype`` (``v_first``: the
+    grouped layout, whose rows rest V then K). This is the
     gather read (O(mb * bs) rows — the Pallas flash-decode kernel is
     the O(true_length) path); fp rows come back bitwise the stored rows,
     int8 rows with their per-(token, head) scale folded back."""
@@ -548,6 +609,9 @@ def read_kv(entry, block_tables, kdim: int, dtype):
     pool, scales = _pool_scales(entry)
     g = jnp.swapaxes(pool[block_tables], 1, 2)   # (S, h, mb, bs, lanes)
     g = g.reshape(g.shape[0], g.shape[1], -1, g.shape[-1])
+    if v_first:
+        vdim = g.shape[-1] - kdim
+        return g[..., vdim:].astype(dtype), g[..., :vdim].astype(dtype)
     kc, vc = g[..., :kdim], g[..., kdim:]
     if scales is None:
         return kc.astype(dtype), vc.astype(dtype)
@@ -570,7 +634,7 @@ def live_slots(block_tables):
 
 
 def flash_decode_kv(q, entry, block_tables, n_keys, sm_scale,
-                    v_lanes=None, tokens: int = 1):
+                    v_lanes=None, tokens: int = 1, v_first: bool = False):
     """The kernel read of a pool entry (kernels/flash_decode.py): q
     ``(n_slots, h, kd)`` against each slot's ``n_keys`` first keys →
     ``(n_slots, h, vd)``; None where the gate says the gather read
@@ -579,7 +643,10 @@ def flash_decode_kv(q, entry, block_tables, n_keys, sm_scale,
     latent layout: q is every head's row against the one stored row a
     key, the output its first ``v_lanes`` lanes; ``tokens`` > 1 is a
     latent prefill chunk's read (``tokens`` positions a slot, one shared
-    table row, the caller's ``n_keys`` as they are). A free slot
+    table row, the caller's ``n_keys`` as they are). ``v_first`` names
+    the grouped layout: the query rides zero-padded over V's lanes in
+    front of it and the read is the latent one, a K/V head's group of
+    query rows a grid step. A free slot
     (:func:`live_slots`) is handed ``n_keys`` 0: the kernel runs no live
     step for it, moves no bytes and writes exact zeros."""
     import jax.numpy as jnp
@@ -591,6 +658,9 @@ def flash_decode_kv(q, entry, block_tables, n_keys, sm_scale,
         return None
     if tokens == 1:
         n_keys = jnp.where(live_slots(block_tables), n_keys, 0)
+    if v_first:
+        v_lanes = pool.shape[-1] - q.shape[-1]
+        q = jnp.pad(q, ((0, 0), (0, 0), (v_lanes, 0)))
     return flash_decode_pool(q, pool, block_tables, n_keys,
                              sm_scale=sm_scale, scales=scales,
                              v_lanes=v_lanes, tokens=tokens)

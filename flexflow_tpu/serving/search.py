@@ -263,8 +263,10 @@ def _graph_cost(sim, g: PCG, tp: int, kv_div: int, slots: int,
     at worst case."""
     from ..search.simulator import OpSharding
 
+    from .kvcache import is_recurrent, node_slot_bytes
+
     t = comm = 0.0
-    mem_w = kv_bytes = 0
+    mem_w = kv_bytes = slot_bytes = 0
     transient = 0
     flip = [True]
     assignment: Dict[int, OpSharding] = {}
@@ -287,13 +289,14 @@ def _graph_cost(sim, g: PCG, tp: int, kv_div: int, slots: int,
                 # one row a token for all heads: no head axis to divide
                 kv_bytes += _attention_state_bytes(
                     node, slots, max_len, kv_dtype)
-            elif node.op.op_type == OperatorType.OP_LSTM:
-                h = int(node.op.attrs["hidden_size"])
-                kv_bytes += slots * 2 * h * size_of_datatype(
-                    node.op.data_type)
-    kv_time = kv_bytes * max(min(kv_fill, 1.0), 0.0) / (
+            elif is_recurrent(node.op):
+                # a recurrent state is priced a SLOT, not a token: every
+                # step reads and writes all of it, whatever the fill
+                slot_bytes += slots * node_slot_bytes(node.op)
+    kv_time = (kv_bytes * max(min(kv_fill, 1.0), 0.0) + 2 * slot_bytes) / (
         m.hbm_bandwidth * m.hbm_efficiency)
-    return t + comm + kv_time, mem_w + kv_bytes + transient, assignment
+    return (t + comm + kv_time, mem_w + kv_bytes + slot_bytes + transient,
+            assignment)
 
 
 def _bucket_seq_shards(pcg: PCG, machine, n_dev: int, slots: int,

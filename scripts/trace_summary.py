@@ -252,6 +252,10 @@ def summarize_telemetry(data, top: int) -> None:
                   f"layer's mean; {srv.get('moe_bounded_steps', 0)} of "
                   f"{srv.get('moe_layer_steps', 0)} layer-steps on the "
                   f"bounded path")
+        if srv.get("recurrent_state_bytes"):
+            print(f"  recurrent state at decode: "
+                  f"{srv['recurrent_state_bytes'] / 1e9:.2f} GB read plus "
+                  f"written, {srv['recurrent_slots_live']} live slot-steps")
 
     _block(data, "serving", _srv)
 
