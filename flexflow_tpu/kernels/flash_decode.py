@@ -5,29 +5,31 @@ is the decode hot loop's HBM bill. Over the paged pool
 (serving/kvcache.py) this kernel does work in proportion to the keys that
 are LIVE — grid steps, bytes and arithmetic:
 
-* grid = (n_slots, tiles of a table row): a grid step folds one KEY TILE
-  — ``P = tile_blocks(...)`` consecutive entries of the slot's block
-  table, ``P * block_size`` keys (256 where the row is that long and
-  VMEM allows) — into an online-softmax accumulator (m, l, acc scratch),
+* grid = (n_slots,): a grid step is one SLOT. It reads the slot's live
+  key count from the scalar prefetch and folds the slot's live KEY TILES,
+  first to last, in a loop whose trip count is that many — a tile is
+  ``P = tile_blocks(...)`` consecutive entries of the slot's block table,
+  ``P * block_size`` keys (256 where the row is that long and VMEM
+  allows) — into an online-softmax accumulator (m, l, acc scratch),
   exactly the FlashAttention recurrence restricted to a 1-row q. The
-  tile axis is the **split-K** dimension. A table whose width is no
+  tile loop is the **split-K** dimension. A table whose width is no
   multiple of ``P`` is padded with the garbage block.
 * the pool stays in HBM and the kernel gathers a tile's blocks itself:
   ``P`` async copies, one a table entry, resolved through the
   scalar-prefetched table into a double-buffered VMEM tile — the next
   tile of the slot is in flight while this one is folded. No
-  ``BlockSpec`` names the pool, so a DEAD step (a tile past the slot's
-  last key; every step of a slot handed ``n_keys`` 0) resolves no block
-  index, starts no copy and runs no arithmetic: it costs the grid's own
-  step and nothing else (PERF.md section 6, PR 36, has the numbers).
+  ``BlockSpec`` names the pool and no step exists for a tile past the
+  slot's last key: what the kernel steps over is the slots and the live
+  tiles, nothing else (PERF.md section 6, PR 45, has what each costs).
   Entries of a live tile past the slot's last block point at blocks the
   slot does not read — the row's garbage padding — and are copied like
   the others: their keys are masked out by global key position, and the
   masked probability, exactly 0, meets finite stored values.
 * a slot of ``n_keys`` 0 — what the decode step hands a free slot
-  (kvcache.flash_decode_kv) — comes back as exact zeros: ``acc / l`` is
-  guarded where ``l`` is 0, because a NaN there would reach the garbage
-  block through that slot's K/V write and break the invariant below.
+  (kvcache.flash_decode_kv) — folds nothing and is written as exact
+  zeros without touching the accumulators (a NaN in its place would
+  reach the garbage block through that slot's K/V write and break the
+  invariant below).
 * ONE pool: a pool row holds a head's K and V side by side on the lanes
   (kvcache.py), so the kernel never slices lanes. The query rides
   zero-padded over V's lanes — ``0 * finite`` is exactly 0, and every
@@ -38,10 +40,16 @@ are LIVE — grid steps, bytes and arithmetic:
   block-paged per-(token, head) scales — HBM moves ~1/el of the fp bytes
   plus the f32 scale vectors (the bandwidth the serving search's
   ``kv_dtype`` axis prices). The scales' rows are narrower than a lane
-  tile, which Mosaic does not let a hand-written copy slice, so they
-  come through ``P`` ``BlockSpec``s instead, each clamped to the slot's
-  last occupied block so that a dead step repeats an index and moves
-  nothing.
+  tile, which Mosaic does not let a hand-written copy slice (a block's
+  whole ``(2, heads, block_size)`` rows included: "slice shape along
+  dimension 3 must be aligned to tiling (128)"), so they come through
+  ``P`` ``BlockSpec``s, and a ``BlockSpec`` is indexed by the grid
+  alone: an int8 pool therefore keeps the tile axis on the grid —
+  ``(n_slots, tiles of a table row)``, the same fold one (slot, key
+  tile) grid step, a dead step (a tile past the slot's last key)
+  costing the grid's own step and nothing else, each scale index
+  clamped to the slot's last occupied block so that a dead step repeats
+  it and moves nothing. Which grid is read off the pool's dtype.
 
 Off-TPU the op layer never routes here (the masked gather path keeps
 tier-1 CPU-green); tests run the kernel in interpret mode.
@@ -58,7 +66,7 @@ import functools
 from typing import Optional
 
 NEG_INF = -1e30
-# keys a grid step folds, where the table row is that long and VMEM
+# keys a tile holds, where the table row is that long and VMEM
 # allows: on the v5e at GPT-2 XL's widths 256 beat 64, 128 and 512 at 6,
 # 38 and 64 live slots of 64 (PERF.md section 6, PR 36)
 TILE_KEYS = 256
@@ -82,7 +90,7 @@ def use_flash_decode(lanes: int, block_size: int) -> bool:
 
 
 def tile_blocks(pool_shape, itemsize: int, table_width: int) -> int:
-    """``P``: the table entries one grid step folds, from the pool's
+    """``P``: the table entries of one key tile, from the pool's
     shape ``(n_blocks, heads, block_size, lanes)`` and element size —
     ``TILE_KEYS`` keys' worth of blocks or what fits ``TILE_VMEM_BYTES``,
     at least one block, at most the row."""
@@ -91,17 +99,32 @@ def tile_blocks(pool_shape, itemsize: int, table_width: int) -> int:
     return max(1, min(min(TILE_KEYS, fits) // block_size, table_width))
 
 
+def tiles_on_grid(pool_dtype) -> bool:
+    """Whether the kernel's grid keeps the key-tile axis — ``(n_slots,
+    tiles of a table row)`` — and does not loop over a slot's live tiles
+    inside a ``(n_slots,)`` grid: so for an int8 pool, whose scales come
+    through ``BlockSpec``s that only a grid axis can index."""
+    import jax.numpy as jnp
+
+    return pool_dtype == jnp.int8
+
+
 def _decode_kernel(tab_ref, len_ref, q_ref, pool_ref, *rest, block_size,
                    tile_blocks, n_tiles_grid, kd, int8, latent=False,
                    tokens=1, shared_table=False):
-    """One (slot, key tile) grid step of the split-K recurrence. ``latent``:
-    the query block is a group's heads against one stored row a key, and the
-    two products take their operands as stored (the pool's dtype) and
-    accumulate in float32. ``tokens`` > 1 (latent): the block's rows are
-    that many successive positions of one sequence, heads innermost, and
-    ``len_ref[s]`` counts the keys of the FIRST of them — token ``t``
-    sees ``t`` more (a prefill chunk's causal mask); ``shared_table``:
-    every slot reads the table's one row."""
+    """One SLOT of the split-K recurrence: a grid step folds the slot's
+    live key tiles, first to last, in a loop whose trip count is read
+    from ``len_ref[s]`` (``n_tiles_grid`` None). An int8 pool's scales
+    come through ``BlockSpec``s, which only a grid axis can index, so
+    there the same fold is one (slot, key tile) grid step of
+    ``n_tiles_grid`` a slot. ``latent``: the query block is a group's
+    heads against one stored row a key, and the two products take their
+    operands as stored (the pool's dtype) and accumulate in float32.
+    ``tokens`` > 1 (latent): the block's rows are that many successive
+    positions of one sequence, heads innermost, and ``len_ref[s]`` counts
+    the keys of the FIRST of them — token ``t`` sees ``t`` more (a
+    prefill chunk's causal mask); ``shared_table``: every slot reads the
+    table's one row."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
@@ -114,7 +137,6 @@ def _decode_kernel(tab_ref, len_ref, q_ref, pool_ref, *rest, block_size,
     o_ref, kv_buf, sems, m_ref, l_ref, acc_ref = rest
     tile_keys = P * block_size
     s = pl.program_id(0)
-    j = pl.program_id(1)
     n_keys = len_ref[s]
     row = 0 if shared_table else s
     if tokens > 1:   # the last token's keys decide the slot's live tiles
@@ -130,19 +152,16 @@ def _decode_kernel(tab_ref, len_ref, q_ref, pool_ref, *rest, block_size,
             pool_ref.at[tab_ref[row, tile * P + i]], kv_buf.at[buf, i],
             sems.at[buf, i]) for i in range(P)]
 
-    @pl.when(j == 0)
-    def _init():
+    def init():
         m_ref[:] = jnp.full_like(m_ref, NEG_INF)
         l_ref[:] = jnp.zeros_like(l_ref)
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    @pl.when(jnp.logical_and(j == 0, n_tiles > 0))
-    def _first():
+    def first():
         for copy in gather(0, 0):
             copy.start()
 
-    @pl.when(j < n_tiles)
-    def _step():
+    def fold(j):
         buf = j % 2
 
         @pl.when(j + 1 < n_tiles)
@@ -191,13 +210,37 @@ def _decode_kernel(tab_ref, len_ref, q_ref, pool_ref, *rest, block_size,
         m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
         l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
 
-    @pl.when(j == n_tiles_grid - 1)
-    def _finish():
+    def finish():
         # a slot of no keys folded nothing: l and acc are 0, and 0 / 1
         # is the exact zero that 0 / 0 is not
         l = l_ref[:, :, :1]
         o_ref[0] = (acc_ref[:] / jnp.where(l > 0.0, l, 1.0)
                     ).astype(o_ref.dtype)
+
+    if n_tiles_grid is None:
+        @pl.when(n_tiles == 0)
+        def _free():
+            # exact zeros, and no accumulator touched: a free slot's
+            # step then costs half of what init and finish make it cost
+            o_ref[0] = jnp.zeros_like(o_ref[0])
+
+        @pl.when(n_tiles > 0)
+        def _live():
+            init()
+            first()
+
+            def step(j, carry):
+                fold(j)
+                return carry
+
+            jax.lax.fori_loop(0, n_tiles, step, 0)
+            finish()
+    else:
+        j = pl.program_id(1)
+        pl.when(j == 0)(init)
+        pl.when(jnp.logical_and(j == 0, n_tiles > 0))(first)
+        pl.when(j < n_tiles)(lambda: fold(j))
+        pl.when(j == n_tiles_grid - 1)(finish)
 
 
 def flash_decode_pool(q, pool, block_tables, n_keys, *,
@@ -219,8 +262,8 @@ def flash_decode_pool(q, pool, block_tables, n_keys, *,
     ``v_lanes`` names the LATENT layout (kvcache.py): the pool holds one
     row a key whatever the query heads (``heads`` 1, or the K/V heads of
     a grouped read), ``q`` is ``(n_slots, heads * group, kd)`` and a
-    grid step scores a group's query rows — a ``(group, lanes)`` block
-    — against the tile's rows in one product; the value is the row's
+    slot's step scores a group's query rows — a ``(group, lanes)`` block
+    — against each tile's rows in one product; the value is the row's
     first ``v_lanes`` lanes, so the output is ``(n_slots, heads * group,
     v_lanes)``. The products take the pool's dtype (bf16 on the chip)
     and accumulate in float32. Same grid, same gather, same name.
@@ -235,10 +278,12 @@ def flash_decode_pool(q, pool, block_tables, n_keys, *,
     ``latent_chunk_attention`` in the compiled program, so that a trace
     tells the chunk's reads from the decode step's.
 
-    The grid is ``(n_slots, ceil(max_blocks_per_slot / P))``, ``P =
-    tile_blocks(pool.shape, itemsize, max_blocks_per_slot)`` table
-    entries a step; the table is padded to whole tiles with the garbage
-    block. Returns (n_slots, heads, vd) in q's dtype. ``interpret=True``
+    The grid is ``(n_slots,)``: a step a slot, the slot's live tiles a
+    loop inside it, ``P = tile_blocks(pool.shape, itemsize,
+    max_blocks_per_slot)`` table entries a tile (an int8 pool:
+    ``(n_slots, ceil(max_blocks_per_slot / P))``, a step a tile); the
+    table is padded to whole tiles with the garbage block. Returns
+    (n_slots, heads, vd) in q's dtype. ``interpret=True``
     runs the Mosaic interpreter (the CPU test path; refused on a TPU).
 
     The call is a ``jax.jit`` of its own: a decode step calls it once a
@@ -307,7 +352,7 @@ def _flash_decode_pool(q, pool, block_tables, n_keys, *, sm_scale, scales,
                      constant_values=GARBAGE_BLOCK)
     n_keys = n_keys.astype(jnp.int32)
 
-    def slot_row(s, j, tab_ref, len_ref):
+    def slot_row(s, *_):
         return (s, 0, 0, 0)
 
     def scale_block(i):
@@ -327,9 +372,10 @@ def _flash_decode_pool(q, pool, block_tables, n_keys, *, sm_scale, scales,
         in_specs += [pl.BlockSpec((1, 2, heads, block_size), scale_block(i))
                      for i in range(P)]
         args += [scales] * P
+    tiled_grid = tiles_on_grid(pool.dtype)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(n_slots, n_tiles),
+        grid=(n_slots, n_tiles) if tiled_grid else (n_slots,),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, heads, rows, lanes), slot_row),
         scratch_shapes=[
@@ -343,7 +389,9 @@ def _flash_decode_pool(q, pool, block_tables, n_keys, *, sm_scale, scales,
     )
     fn = pl.pallas_call(
         functools.partial(_decode_kernel, block_size=block_size,
-                          tile_blocks=P, n_tiles_grid=n_tiles, kd=kd,
+                          tile_blocks=P,
+                          n_tiles_grid=n_tiles if tiled_grid else None,
+                          kd=kd,
                           int8=int8, latent=latent, tokens=tokens,
                           shared_table=shared_table),
         grid_spec=grid_spec,

@@ -127,9 +127,9 @@ class StepTelemetry:
         # seq_shards) — the recorded number behind "KV provably exceeds
         # one chip"
         self.serving_kv_hbm_per_chip_bytes: Optional[int] = None
-        # the decode attention kernel's grid (PR 36): grid steps of one
-        # layer's call summed over decode steps, and the tiles among them
-        # that held a live slot's key (ServingStats.kv_tiles_grid / _live)
+        # the decode attention kernel's steps: slot steps plus live key
+        # tiles of one layer's call summed over decode steps, and the
+        # live tiles among them (ServingStats.kv_tiles_grid / _live)
         self.serving_kv_tiles_grid: int = 0
         self.serving_kv_tiles_live: int = 0
         # routed expert layers at decode shapes (ServingStats.moe_*)

@@ -24,7 +24,7 @@ from __future__ import annotations
 import dataclasses
 import time
 from collections import deque
-from typing import Any, Deque, Dict, List, Optional, Sequence
+from typing import Any, Deque, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -95,11 +95,13 @@ class ServingStats:
     # decode attention reads, accumulated per step host-side: each live
     # slot's OCCUPIED blocks; bench's bytes-read/token column
     kv_bytes_read: int = 0
-    # the flash_decode grid, counted in the same loop (PR 36): the grid
-    # steps of ONE layer's call, summed over decode steps (n_slots x the
-    # tiles of a table row, kernels/flash_decode.py), and those of them
-    # that hold a key of a live slot; decode_grid_live_share() is their
-    # ratio — how much of the kernel's grid has work
+    # the flash_decode kernel's steps, counted in the same loop: the
+    # steps of ONE layer's call, summed over decode steps — a grid step
+    # a slot and a loop iteration a live key tile (an int8 pool's grid
+    # keeps the tile axis: n_slots x the tiles of a table row,
+    # kernels/flash_decode.py) — and those of them that fold a key of a
+    # live slot; decode_grid_live_share() is their ratio — how much of
+    # the kernel's stepping has work
     kv_tiles_grid: int = 0
     kv_tiles_live: int = 0
     # prefix cache + chunked prefill ledger (ISSUE 14,
@@ -194,9 +196,9 @@ class ServingStats:
         return self.kv_bytes_read / self.tokens_generated
 
     def decode_grid_live_share(self) -> Optional[float]:
-        """Live tiles over grid steps of the decode attention kernel —
-        the fixed-cost term a serving search needs beside the bytes.
-        None before a decode step ran."""
+        """Live tiles over the steps of the decode attention kernel
+        (slot steps + live tiles) — the fixed-cost term a serving
+        search needs beside the bytes. None before a decode step ran."""
         if not self.kv_tiles_grid:
             return None
         return self.kv_tiles_live / self.kv_tiles_grid
@@ -1178,40 +1180,46 @@ class ServingEngine:
                 for node in self.executor.pcg.compute_nodes())
         return self._recurrent_slot_bytes_cache
 
-    def _kv_tile_blocks(self) -> int:
-        """Table entries one grid step of the decode attention kernel
-        folds (kernels/flash_decode.py ``tile_blocks``), from the first
-        KV pool of the decode state; a whole row for a model without
-        one."""
-        if getattr(self, "_kv_tile_blocks_cache", None) is None:
-            from ..kernels.flash_decode import tile_blocks
+    def _kv_tiling(self) -> Tuple[int, bool]:
+        """``(P, tiled_grid)``: the table entries one step of the decode
+        attention kernel folds (kernels/flash_decode.py ``tile_blocks``)
+        and whether the tile axis is on its grid (an int8 pool), from
+        the first KV pool of the decode state; a whole row for a model
+        without one."""
+        if getattr(self, "_kv_tiling_cache", None) is None:
+            from ..kernels.flash_decode import tile_blocks, tiles_on_grid
             from .kvcache import _pool_scales
 
-            p = self.max_blocks_per_slot
+            tiling = (self.max_blocks_per_slot, False)
             if self._paged_entry_names:
                 pool, _scales = _pool_scales(
                     self.state.caches[min(self._paged_entry_names)])
-                p = tile_blocks(pool.shape, pool.dtype.itemsize, p)
-            self._kv_tile_blocks_cache = p
-        return self._kv_tile_blocks_cache
+                tiling = (tile_blocks(pool.shape, pool.dtype.itemsize,
+                                      self.max_blocks_per_slot),
+                          tiles_on_grid(pool.dtype))
+            self._kv_tiling_cache = tiling
+        return self._kv_tiling_cache
 
     def _count_decode_kv(self, stats: ServingStats, live) -> None:
         """One decode step's attention read, counted: the analytic KV
         bytes — each live slot's OCCUPIED blocks (the flash-decode
         kernel's actual traffic, O(true_length)) — and the kernel's
-        grid, per layer's call: every slot's tiles, and the tiles that
-        hold a key of a live slot."""
+        steps, per layer's call: a grid step a slot and a loop
+        iteration a tile that holds a key of a live slot (an int8
+        pool: every slot's tiles, the grid it keeps)."""
         bs = self.kv_block_size
-        tile_blocks = self._kv_tile_blocks()
+        tile_blocks, tiled_grid = self._kv_tiling()
         tile = tile_blocks * bs
-        toks = 0
+        toks = tiles_live = 0
         for _slot, req in live:
             keys = req.effective_len + 1
             toks += -(-keys // bs) * bs
-            stats.kv_tiles_live += -(-keys // tile)
+            tiles_live += -(-keys // tile)
         stats.kv_bytes_read += toks * self._kv_row_bytes()
-        stats.kv_tiles_grid += self.n_slots * -(
-            -self.max_blocks_per_slot // tile_blocks)
+        stats.kv_tiles_live += tiles_live
+        stats.kv_tiles_grid += (
+            self.n_slots * -(-self.max_blocks_per_slot // tile_blocks)
+            if tiled_grid else self.n_slots + tiles_live)
 
     def _count_decode_recurrent(self, stats: ServingStats, n_live: int
                                 ) -> Dict[str, int]:

@@ -241,8 +241,8 @@ def summarize_telemetry(data, top: int) -> None:
                     else f"{b / 2 ** 10:.1f} KiB")
             print(f"  kv per shard chip: {size} at measured fill")
         if srv.get("decode_grid_live_share") is not None:
-            print(f"  decode attention grid: {srv['kv_tiles_live']} of "
-                  f"{srv['kv_tiles_grid']} tiles live "
+            print(f"  decode attention kernel: {srv['kv_tiles_live']} of "
+                  f"{srv['kv_tiles_grid']} steps fold a key tile "
                   f"({100 * srv['decode_grid_live_share']:.1f}%)")
         if srv.get("moe_pairs_here"):
             print(f"  routed layers at decode: {srv['moe_pairs_here']} "

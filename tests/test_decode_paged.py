@@ -431,6 +431,101 @@ def test_flash_decode_interpret_matches_reference_over_tiles(
     np.testing.assert_allclose(out[live], ref[live], atol=2e-6)
 
 
+def _dense_decode(q, pool, tables, n_keys, scale, kd, v_lanes, tokens):
+    """Every form of the read, densely in float64: ``q`` ``(slots,
+    tokens * pool heads * group, kd)`` against the slot's whole table row
+    (the one shared row where ``tokens`` > 1), token ``t`` of a slot
+    seeing ``n_keys + t`` keys; the value is the lanes after K's (the
+    plain pool) or the row's first ``v_lanes``. A slot of no keys is
+    zeros."""
+    S, H, _ = q.shape
+    _, h, bs, lanes = pool.shape
+    rows = np.broadcast_to(tables, (S, tables.shape[1]))
+    ext = pool[rows].transpose(0, 2, 1, 3, 4).reshape(S, h, -1, lanes)
+    ext = ext.astype(np.float64)
+    qg = q.astype(np.float64).reshape(S, tokens, h, H // (tokens * h), kd)
+    sc = np.einsum("sthgd,shnd->sthgn", qg, ext[..., :kd]) * scale
+    seen = np.arange(ext.shape[2])[None, None, :] < (
+        n_keys[:, None, None] + np.arange(tokens)[None, :, None])
+    seen = seen[:, :, None, None, :] & (n_keys > 0)[:, None, None, None,
+                                                    None]
+    sc = np.where(seen, sc, -np.inf)
+    p = np.where(seen, np.exp(sc - np.where(
+        seen.any(-1, keepdims=True), sc.max(-1, keepdims=True), 0.0)), 0.0)
+    p = p / np.maximum(p.sum(-1, keepdims=True), 1e-300)
+    val = ext[..., kd:] if v_lanes is None else ext[..., :v_lanes]
+    return np.einsum("sthgn,shnv->sthgv", p, val).reshape(S, H, -1)
+
+
+@pytest.mark.parametrize("table_width", [20, 48],
+                         ids=["row-no-multiple-of-P", "row-of-3-tiles"])
+@pytest.mark.parametrize("form", ["plain", "int8", "grouped", "latent",
+                                  "chunk"])
+def test_flash_decode_every_form_over_slot_states(form, table_width):
+    """Every form of the kernel (interpret mode) over slot states in ONE
+    call — a free slot of 0 keys, 1 key, a whole 256-key tile exactly,
+    one key past it, several tiles, the whole row — at a table width
+    that is and is not a multiple of ``P``: free slots come back exact
+    zeros, every other slot matches the dense read, and the output is
+    the tiled-grid kernel's (the parent's, tests/
+    flash_decode_tiled_oracle.py) to the bit: same tiles, same order,
+    same arithmetic."""
+    import jax.numpy as jnp
+
+    from flash_decode_tiled_oracle import tiled_flash_decode_pool
+    from flexflow_tpu.kernels.flash_decode import (flash_decode_pool,
+                                                   tile_blocks)
+
+    rng = np.random.default_rng(table_width)
+    bs, kd = 16, 64
+    pool_heads, group, lanes, v_lanes, tokens = {
+        "plain": (3, 1, 128, None, 1), "int8": (3, 1, 128, None, 1),
+        "grouped": (2, 4, 128, 64, 1), "latent": (1, 8, 128, 48, 1),
+        "chunk": (1, 8, 128, 48, 4)}[form]
+    extent = table_width * bs
+    n_keys = np.asarray(sorted({0, 1, 256, 257, extent - 19, extent}),
+                        np.int32)
+    if tokens > 1:   # a slot's last token sees tokens - 1 more
+        n_keys = np.minimum(n_keys, extent - tokens + 1).astype(np.int32)
+    S = len(n_keys)
+    n_blocks = 1 + (1 if tokens > 1 else S) * table_width
+    scales = None
+    if form in ("plain", "int8"):
+        fp, (q8, scales) = _packed_pool(rng, n_blocks, pool_heads, bs, kd,
+                                        lanes - kd)
+        pool, scales = (fp, None) if form == "plain" else (q8, scales)
+    else:
+        pool = np.zeros((n_blocks, pool_heads, bs, lanes), np.float32)
+        pool[..., :kd] = rng.standard_normal(pool.shape[:-1] + (kd,))
+        pool = jnp.asarray(pool)
+    assert pool.dtype.itemsize == (1 if form == "int8" else 4)
+    assert bs * tile_blocks(pool.shape, pool.dtype.itemsize,
+                            table_width) == 256
+    if tokens > 1:
+        tables = 1 + rng.permutation(table_width)[None].astype(np.int32)
+    else:
+        tables = np.zeros((S, table_width), np.int32)
+        for s_, n in enumerate(n_keys):
+            used = -(-int(n) // bs)
+            tables[s_, :used] = 1 + s_ * table_width + np.arange(used)
+    q = rng.standard_normal(
+        (S, tokens * pool_heads * group, kd)).astype(np.float32)
+    args = (jnp.asarray(q), pool, jnp.asarray(tables), jnp.asarray(n_keys))
+    kw = dict(sm_scale=0.2, scales=scales, v_lanes=v_lanes, tokens=tokens)
+    out = np.asarray(flash_decode_pool(*args, interpret=True, **kw))
+    assert np.all(np.isfinite(out))
+    assert np.all(out[n_keys == 0] == 0.0), "a slot of no keys is zeros"
+    dense = np.asarray(pool, np.float32)
+    if scales is not None:   # K's lanes by K's scales, V's by V's
+        sc = np.asarray(scales)
+        dense = dense * np.where(np.arange(lanes) < kd, sc[:, 0, ..., None],
+                                 sc[:, 1, ..., None])
+    want = _dense_decode(q, dense, tables, n_keys, 0.2, kd, v_lanes, tokens)
+    np.testing.assert_allclose(out, want, atol=3e-6)
+    np.testing.assert_array_equal(
+        out, np.asarray(tiled_flash_decode_pool(*args, **kw)))
+
+
 def test_free_slot_cursor_stays_zero(gpt2):
     """A free slot costs the decode step nothing that grows: while one
     slot serves request after request for more decode steps than
@@ -475,10 +570,12 @@ def test_free_slot_cursor_stays_zero(gpt2):
         for leaf in jax.tree_util.tree_leaves(entry):
             if leaf.ndim >= 3:
                 assert np.all(np.isfinite(np.asarray(leaf[0], np.float32)))
-    # the counted grid: every step's tiles, and the live ones among them
+    # the kernel's counted steps: a grid step a slot (2 of them) and a
+    # loop iteration a live tile (one tile a row here), and the live
+    # ones among them
     st = loop.stats
-    assert st.kv_tiles_grid == st.decode_steps * 2 * 1   # one tile a row
     assert 0 < st.kv_tiles_live <= st.decode_steps
+    assert st.kv_tiles_grid == st.decode_steps * 2 + st.kv_tiles_live
     assert st.decode_grid_live_share() == \
         st.kv_tiles_live / st.kv_tiles_grid
     assert st.summary()["decode_grid_live_share"] == round(

@@ -367,11 +367,12 @@ def test_flash_decode_chunk_read_is_causal_over_its_rows(start, n_new):
 
 #: sha256 of ``str(jaxpr)`` of ``_flash_decode_pool`` at the ``gpt2-xl-chat``
 #: cell's shapes (64 slots, 25 heads of 64, 1,400 blocks of 16, a 64-wide
-#: table, bf16), taken on the parent (b514449, PR 36) under jax 0.9.0: the
-#: latent form is a specialisation chosen by ``v_lanes``, and a call without
-#: it traces to the program it traced to before.
+#: table, bf16) under jax 0.9.0: the latent and chunk forms are
+#: specialisations chosen by ``v_lanes`` / ``tokens``, and a call without
+#: them must trace to the program GPT-2 XL runs — the kernel of PR 45, a
+#: grid step a slot with the slot's key tiles in a loop.
 GPT2_XL_DECODE_DIGEST = \
-    "4093645081a4eb2b141367e0ea148915cb4d38042055f291ddd5f0e551939451"
+    "6fd336c630c19a601b86a7799c6470258ce27eb584a55979db557afd0a9c5d2e"
 
 
 def test_gpt2_xl_decode_kernel_traces_as_before():
