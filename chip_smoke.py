@@ -84,6 +84,20 @@ ROUTED_TOL = 1.5e-2
 # is why the control reads less than twice it.
 SSM_TOL = 4.0e-3
 SSM_OUT_TOL = 2.0e-2
+# One gated delta-rule mixer (ops/gated_delta.py) at Olmo-Hybrid-7B's widths
+# vs the plain float32 reference, the same two readings: the recurrent STATE
+# after a padded prefill's last real token (the chunked kernel) and after 64
+# decode steps from it (the update kernel), and the node's output over those
+# rows. Set between the sound program's reading and the control's — the
+# reference with its state rounded to bf16 every step (``reduce_precision``).
+# Readings (my chip run, PR 46; after the prefill, after the decode steps):
+# sound 0.00291, 0.00291; control 0.00806, 0.00757; the output 0.00393,
+# 0.00403. The limit is the two readings' geometric mean: 1.6 times the one,
+# 0.62 of the other (a matrix state a head takes a bf16 rounding harder than
+# the state-space mixer's vector state: the control reads 2.7 times the sound
+# program, where SSM_TOL's reads 1.8 times).
+GDN_TOL = 4.7e-3
+GDN_OUT_TOL = 2.0e-2
 
 
 def info(msg: str) -> None:
@@ -611,56 +625,50 @@ def check_routed_layer_decode(rows: int = 64, d: int = 7680,
           f"three mantissa bits are not ({planted:.4f})")
 
 
-def check_ssm_layer(d: int = 2560, inner: int = 5120, state: int = 16,
-                    conv: int = 4, rank: int = 160, bucket: int = 2048,
-                    length: int = 1900, steps: int = 64) -> None:
-    """One mixer at the ``jamba2-3b-reasoning`` cell's widths, bf16: a
-    ``bucket``-row padded prefill of a ``length``-token prompt (the
-    ``selective_scan`` kernel; the state handed on is the one after the last
-    REAL token) and ``steps`` decode steps from that state, against the plain
-    reference's whole-sequence mixer in float32 over prompt + steps tokens.
-    The serving cell's own comparison sees a precision fault only where it
-    moves a served token (PERF.md section 7); this one reads the node's
-    output. The control — the reference with its state rounded to bf16 every
-    step — must read over the limit."""
+def _check_mixer_layer(what: str, op, reference_file: str, mixer: str,
+                       config: dict, d: int, bucket: int, length: int,
+                       steps: int, kernels: dict, key: int, tol: float,
+                       out_tol: float) -> None:
+    """One recurrent mixer node ``op`` at a serving cell's widths, bf16: a
+    ``bucket``-row padded prefill of a ``length``-token prompt (the state
+    handed on is the one after the last REAL token) and ``steps`` decode
+    steps from that state, against the plain reference's whole-sequence
+    mixer (``reference_file``'s function ``mixer``) in float32 over prompt +
+    steps tokens. The serving cells' own comparison sees a precision fault
+    only where it moves a served token (PERF.md section 7); this one reads
+    the node's output and the state it hands on. The control — the reference
+    with its state rounded to bf16 every step — must read over the limit.
+    ``kernels``: the Mosaic calls the compiled ``prefill`` / ``decode``
+    programs must hold."""
     import importlib.util
 
     import jax
     import jax.numpy as jnp
     import numpy as np
 
-    from flexflow_tpu.ffconst import DataType, OperatorType
-    from flexflow_tpu.ops.base import OpContext, op_class_for
+    from flexflow_tpu.ops.base import OpContext
     from flexflow_tpu.serving.kvcache import ServingState
 
     spec = importlib.util.spec_from_file_location(
-        "jamba_reference", os.path.join(
+        "mixer_reference", os.path.join(
             os.path.dirname(os.path.abspath(__file__)), "benchmark",
-            "reference", "ai21-jamba2-3b.py"))
+            "reference", reference_file))
     ref = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(ref)
-    eps = 1e-6
-    op = op_class_for(OperatorType.OP_SSM_MIXER)(
-        "l0_ssm", {"inner_dim": inner, "state_dim": state,
-                   "conv_width": conv, "dt_rank": rank, "conv_bias": True,
-                   "proj_bias": False, "norm_eps": eps},
-        DataType.DT_BFLOAT16)
-    keys = jax.random.split(jax.random.PRNGKey(44), 32)
+    keys = jax.random.split(jax.random.PRNGKey(key), 32)
     params = {name: init(keys[i], shape, jnp.bfloat16)
               for i, (name, (shape, _dt, init)) in enumerate(
                   sorted(op.weight_specs([(1, bucket, d)]).items()))}
     total = length + steps
     u = jax.random.normal(keys[-1], (1, total, d), jnp.float32
                           ).astype(jnp.bfloat16)
-    config = {"mamba_d_state": state, "mamba_d_conv": conv,
-              "mamba_dt_rank": rank, "rms_norm_eps": eps}
 
     def reference(fault, n):
         def run(u, params):
             with jax.default_matmul_precision("highest"):
-                return ref.mamba(u[0, :n].astype(jnp.float32),
-                                 ref.f32(params), config, fault,
-                                 with_state=True)
+                return getattr(ref, mixer)(
+                    u[0, :n].astype(jnp.float32), ref.f32(params), config,
+                    fault, with_state=True)
 
         return [np.asarray(a) for a in jax.jit(run)(u, params)]
 
@@ -690,11 +698,13 @@ def check_ssm_layer(d: int = 2560, inner: int = 5120, state: int = 16,
                          OpContext(training=False, serving=sv))[0]
         return out[0, 0], sv.cache_out[op.name]
 
-    text = prefill.lower(params, u).compile().as_text()
-    check("selective_scan" in mosaic_calls(text),
-          f"ssm layer: the prefill runs the selective_scan Mosaic kernel "
-          f"({sorted(mosaic_calls(text))})")
     rows, cache = prefill(params, u)
+    programs = {"prefill": prefill.lower(params, u),
+                "decode": decode.lower(params, u[:, :1], cache)}
+    for program, kernel in kernels.items():
+        calls = mosaic_calls(programs[program].compile().as_text())
+        check(kernel in calls, f"{what}: the {program} runs the {kernel} "
+                               f"Mosaic kernel ({sorted(calls)})")
     got_mid = np.asarray(cache[1], np.float32)[0]
     got = [np.asarray(rows, np.float32)]
     for t in range(length, total):
@@ -708,19 +718,68 @@ def check_ssm_layer(d: int = 2560, inner: int = 5120, state: int = 16,
     sound = (l2(got_mid, want_mid), l2(got_end, want_end))
     planted = (l2(ctl_mid, want_mid), l2(ctl_end, want_end))
     out = (l2(got[:length], want[:length]), l2(got[length:], want[length:]))
-    info(f"ssm layer at d {d}, E {inner}, N {state}, R {rank}, K {conv}; "
+    widths = ", ".join(f"{k} {v}" for k, v in op.attrs.items()
+                       if isinstance(v, int) and not isinstance(v, bool))
+    info(f"{what} at d {d}, {widths}; "
          f"{length} of {bucket} prefill rows, {steps} decode steps: relative "
          f"L2 error of the state (after the prefill, after the decode "
          f"steps): sound {sound[0]:.5f}, {sound[1]:.5f}; the reference with "
          f"a bf16 state {planted[0]:.5f}, {planted[1]:.5f} (limit "
-         f"{SSM_TOL}); of the output (prefill rows, decode rows) "
-         f"{out[0]:.5f}, {out[1]:.5f} (limit {SSM_OUT_TOL})")
-    check(max(sound) <= SSM_TOL < min(planted),
-          f"ssm layer: the state handed on is within {SSM_TOL} of the "
+         f"{tol}); of the output (prefill rows, decode rows) "
+         f"{out[0]:.5f}, {out[1]:.5f} (limit {out_tol})")
+    check(max(sound) <= tol < min(planted),
+          f"{what}: the state handed on is within {tol} of the "
           f"float32 reference's, and the bf16-state control is over it")
-    check(max(out) <= SSM_OUT_TOL,
-          f"ssm layer: the output is within {SSM_OUT_TOL} of the float32 "
+    check(max(out) <= out_tol,
+          f"{what}: the output is within {out_tol} of the float32 "
           f"reference's")
+
+
+def check_ssm_layer(d: int = 2560, inner: int = 5120, state: int = 16,
+                    conv: int = 4, rank: int = 160, bucket: int = 2048,
+                    length: int = 1900, steps: int = 64) -> None:
+    """One selective state-space mixer at the ``jamba2-3b-reasoning`` cell's
+    widths: the ``selective_scan`` kernel's prefill and the fused one-token
+    update (``_check_mixer_layer``)."""
+    from flexflow_tpu.ffconst import DataType, OperatorType
+    from flexflow_tpu.ops.base import op_class_for
+
+    eps = 1e-6
+    op = op_class_for(OperatorType.OP_SSM_MIXER)(
+        "l0_ssm", {"inner_dim": inner, "state_dim": state,
+                   "conv_width": conv, "dt_rank": rank, "conv_bias": True,
+                   "proj_bias": False, "norm_eps": eps},
+        DataType.DT_BFLOAT16)
+    _check_mixer_layer(
+        "ssm layer", op, "ai21-jamba2-3b.py", "mamba",
+        {"mamba_d_state": state, "mamba_d_conv": conv,
+         "mamba_dt_rank": rank, "rms_norm_eps": eps},
+        d, bucket, length, steps, {"prefill": "selective_scan"}, 44,
+        SSM_TOL, SSM_OUT_TOL)
+
+
+def check_delta_layer(d: int = 3840, heads: int = 30, dk: int = 96,
+                      dv: int = 192, conv: int = 4, bucket: int = 1024,
+                      length: int = 900, steps: int = 64) -> None:
+    """One gated delta-rule mixer at the ``olmo-hybrid-7b-assist`` cell's
+    widths: the ``gated_delta_rule`` kernel's prefill and the
+    ``gated_delta_update`` kernel's decode steps (``_check_mixer_layer``)."""
+    from flexflow_tpu.ffconst import DataType, OperatorType
+    from flexflow_tpu.ops.base import op_class_for
+
+    eps = 1e-6
+    op = op_class_for(OperatorType.OP_GATED_DELTA_MIXER)(
+        "l0_gdn", {"num_heads": heads, "key_dim": dk, "value_dim": dv,
+                   "conv_width": conv, "neg_eigval": True, "norm_eps": eps},
+        DataType.DT_BFLOAT16)
+    _check_mixer_layer(
+        "delta layer", op, "olmo-hybrid-7b.py", "delta_mixer",
+        {"linear_num_key_heads": heads, "linear_key_head_dim": dk,
+         "linear_value_head_dim": dv, "linear_conv_kernel_dim": conv,
+         "linear_allow_neg_eigval": True, "rms_norm_eps": eps},
+        d, bucket, length, steps,
+        {"prefill": "gated_delta_rule", "decode": "gated_delta_update"}, 46,
+        GDN_TOL, GDN_OUT_TOL)
 
 
 # ------------------------------------------------------------------ trainer
@@ -927,6 +986,7 @@ def main() -> None:
     check_routed_layer_decode()
     check_routed_layer_decode(lead=1.0)
     check_ssm_layer()
+    check_delta_layer()
 
     n_chips = device["count"]
     bert = BertConfig(batch_size=8 * n_chips, seq_len=512, hidden=1024,

@@ -259,10 +259,10 @@ def check_serving_graph(pcg) -> List[Diagnostic]:
         if node.op.op_type != OperatorType.OP_FUSED:
             continue
         for sub in getattr(node.op, "sub_ops", ()):
+            # per-token pool rows, or a recurrent state a slot (the op says)
             stateful = sub.op_type in (OperatorType.OP_MULTIHEAD_ATTENTION,
-                                       OperatorType.OP_LATENT_ATTENTION,
-                                       OperatorType.OP_LSTM,
-                                       OperatorType.OP_SSM_MIXER)
+                                       OperatorType.OP_LATENT_ATTENTION) \
+                or sub.slot_state_bytes() > 0
             positional = (sub.op_type == OperatorType.OP_CONSTANT
                           and is_position_constant(sub.attrs.get("value")))
             if stateful or positional:

@@ -226,6 +226,10 @@ class OperatorType(enum.IntEnum):
     # per-request state is a fixed (state_dim, inner_dim) matrix and the last
     # conv_width - 1 conv inputs, which is what serving carries a slot
     OP_SSM_MIXER = 124
+    # gated delta-rule mixer (ops/gated_delta.py): a linear-attention
+    # recurrence whose per-request state is a (key_dim, value_dim) matrix a
+    # head and the last conv_width - 1 inputs of its q, k and v convs
+    OP_GATED_DELTA_MIXER = 125
 
 
 # --- dtype helpers -------------------------------------------------------------

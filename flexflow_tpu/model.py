@@ -192,24 +192,30 @@ class FFModel:
                             window: Optional[int] = None,
                             rope_theta: Optional[float] = None,
                             qk_norm: Optional[float] = None,
-                            gated: bool = False) -> Tensor:
+                            gated: bool = False,
+                            qk_norm_whole: bool = False) -> Tensor:
         """The decoder-block attributes after ``name`` are off by default
         (ops/attention.py): ``num_kv_heads`` grouped-query K/V heads,
         ``window`` a causal sliding window, ``rope_theta`` rotary positions,
         ``qk_norm`` the eps of a per-head RMS norm on q and k, ``gated`` a
-        sigmoid gate on the core's output."""
+        sigmoid gate on the core's output, ``qk_norm_whole`` that norm over
+        the WHOLE q and k width (every head at once, a gain a channel)."""
         attrs = {"embed_dim": embed_dim, "num_heads": num_heads, "kdim": kdim,
                  "vdim": vdim, "dropout": dropout, "bias": bias,
                  "add_bias_kv": add_bias_kv, "add_zero_attn": add_zero_attn,
                  "kernel_initializer": kernel_initializer, "causal": causal}
         for attr, given in (("num_kv_heads", num_kv_heads),
                             ("window", window), ("rope_theta", rope_theta),
-                            ("qk_norm", qk_norm), ("gated", gated)):
+                            ("qk_norm", qk_norm), ("gated", gated),
+                            ("qk_norm_whole", qk_norm_whole)):
             if given:
                 attrs[attr] = given
         if window and not causal:
             raise ValueError("multihead_attention: a sliding window needs "
                              "causal=True")
+        if qk_norm_whole and not qk_norm:
+            raise ValueError("multihead_attention: qk_norm_whole is a form "
+                             "of qk_norm, whose eps it needs")
         if num_kv_heads and num_heads % num_kv_heads:
             raise ValueError(
                 f"multihead_attention: {num_heads} heads are no multiple of "
@@ -260,6 +266,27 @@ class FFModel:
             raise ValueError("ssm_mixer: conv_width must be at least 2")
         return self._add_layer(OperatorType.OP_SSM_MIXER, [input], attrs,
                                input.dtype, name)
+
+    def gated_delta_mixer(self, input: Tensor, num_heads: int, key_dim: int,
+                          value_dim: int, conv_width: int, neg_eigval: bool,
+                          norm_eps: float, kernel_initializer=None,
+                          name: Optional[str] = None) -> Tensor:
+        """Gated delta-rule (Gated DeltaNet) mixer (ops/gated_delta.py):
+        ``num_heads`` heads, each with a ``(key_dim, value_dim)`` matrix
+        state decayed by a gate and corrected by a rank-one delta a token,
+        after causal depthwise convs of ``conv_width`` on q, k and v;
+        ``neg_eigval`` lets the write strength reach 2. Every width is the
+        caller's: no default stands in for one. Under a serving context the
+        state and the convs' last inputs are what a slot holds."""
+        attrs = {"num_heads": num_heads, "key_dim": key_dim,
+                 "value_dim": value_dim, "conv_width": conv_width,
+                 "neg_eigval": bool(neg_eigval), "norm_eps": norm_eps,
+                 "kernel_initializer": kernel_initializer}
+        if conv_width < 2:
+            raise ValueError(
+                "gated_delta_mixer: conv_width must be at least 2")
+        return self._add_layer(OperatorType.OP_GATED_DELTA_MIXER, [input],
+                               attrs, input.dtype, name)
 
     # ---- elementwise ----------------------------------------------------------
     def _binary(self, op_type, x, y, name=None, inplace_a=False):

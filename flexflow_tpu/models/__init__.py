@@ -6,7 +6,9 @@ grouped-query decoder with a dropless routed expert layer (trinity.py) and
 the latent-attention decoder with sandwich norms and a routed top-8 layer,
 built for the serving path (pangu.py), and the hybrid decoder of selective
 state-space layers with a one-K/V-head attention layer every period
-(jamba.py), built for the serving path too."""
+(jamba.py), built for the serving path too, and the hybrid decoder of gated
+delta-rule layers with a full-attention layer closing every period
+(olmo_hybrid.py), for the serving path as well."""
 from .bert import BertConfig, build_bert, bert_param_count  # noqa: F401
 from .gpt2 import (GPT2Config, build_gpt2,  # noqa: F401
                    gpt2_param_count, gpt2_train_flops_per_step)
@@ -25,3 +27,5 @@ from .pangu import (PanguConfig, build_pangu,  # noqa: F401
                     pangu_decode_flops_per_token)
 from .jamba import (JambaConfig, build_jamba,  # noqa: F401
                     jamba_param_count)
+from .olmo_hybrid import (OlmoHybridConfig, build_olmo_hybrid,  # noqa: F401
+                          olmo_hybrid_param_count)

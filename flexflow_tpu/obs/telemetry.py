@@ -142,6 +142,9 @@ class StepTelemetry:
         # wrote, and the live slots among those stepped (ServingStats)
         self.serving_recurrent_state_bytes: int = 0
         self.serving_recurrent_slots_live: int = 0
+        # one-shot prefills: the buckets' rows computed, and the real ones
+        self.serving_prefill_rows: int = 0
+        self.serving_prefill_rows_real: int = 0
         # serving-resilience counters (ISSUE 9): the outcome ledger of a
         # serve() run (every request under exactly one of ok |
         # deadline_exceeded | shed | decode_fault | preempted) plus the
@@ -369,6 +372,9 @@ class StepTelemetry:
                     self.serving_recurrent_state_bytes
                 sv["recurrent_slots_live"] = \
                     self.serving_recurrent_slots_live
+            if self.serving_prefill_rows:
+                sv["prefill_rows"] = self.serving_prefill_rows
+                sv["prefill_rows_real"] = self.serving_prefill_rows_real
             out["serving"] = sv
         if self.fleet_replicas:
             total = max(sum(self.fleet_outcomes.values()), 1)

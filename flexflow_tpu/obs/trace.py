@@ -363,7 +363,9 @@ SPANS: Mapping[str, str] = MappingProxyType({
                   "moe_bounded_steps, moe_layer_steps (counted on the "
                   "device, fetched with its tokens); one of a graph with a "
                   "recurrent node recurrent_state_bytes (slot-major state "
-                  "the step read plus wrote) and recurrent_slots_live",
+                  "the step read plus wrote) and recurrent_slots_live; a "
+                  "prefill tick prefill_rows (the bucket's rows, padding "
+                  "included) and prefill_rows_real",
     "tick_dispatch": "tick entry -> the device call is issued: deadline "
                      "sweep, next_action(), building ids",
     "prefill": "the bucket's prefill call up to and including the "

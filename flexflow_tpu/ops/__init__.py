@@ -13,4 +13,5 @@ from . import moe_ops  # noqa: F401
 from . import noop  # noqa: F401
 from . import recurrent  # noqa: F401
 from . import ssm  # noqa: F401
+from . import gated_delta  # noqa: F401
 from . import fused  # noqa: F401

@@ -73,6 +73,8 @@ class MultiHeadAttentionOp(Op):
     heads), ``window`` (causal sliding window), ``rope_theta`` (rotary
     positions on q and k, rotate-half pairing), ``qk_norm`` (an RMS norm
     per head on q and on k, one gain vector each; its value is the eps),
+    ``qk_norm_whole`` (that norm over the WHOLE q and k width instead —
+    every head at once, a gain a channel: the OLMo family's),
     ``gated`` (the output of the core times sigmoid(x Wg), per head).
 
     inputs: (query, key, value), each (batch, seq, dim).
@@ -114,10 +116,12 @@ class MultiHeadAttentionOp(Op):
         if self.attrs.get("qk_norm"):
             from ..execution.initializers import ConstantInitializer
 
-            specs["q_norm"] = ((kdim,), self.data_type,
-                               ConstantInitializer(1.0))
-            specs["k_norm"] = ((kdim,), self.data_type,
-                               ConstantInitializer(1.0))
+            # a gain a head channel, or a channel of the whole width
+            whole = self.attrs.get("qk_norm_whole")
+            specs["q_norm"] = ((heads, kdim) if whole else (kdim,),
+                               self.data_type, ConstantInitializer(1.0))
+            specs["k_norm"] = ((kv_heads, kdim) if whole else (kdim,),
+                               self.data_type, ConstantInitializer(1.0))
         return specs
 
     def forward(self, params, inputs, ctx: OpContext):
@@ -135,8 +139,10 @@ class MultiHeadAttentionOp(Op):
         v = jnp.einsum("bsd,dhk->bhsk", v_in, params["wv"])
         if self.attrs.get("qk_norm"):
             eps = float(self.attrs["qk_norm"])
-            q = _head_rms_norm(q, params["q_norm"], eps)
-            k = _head_rms_norm(k, params["k_norm"], eps)
+            norm = _whole_rms_norm if self.attrs.get("qk_norm_whole") \
+                else _head_rms_norm
+            q = norm(q, params["q_norm"], eps)
+            k = norm(k, params["k_norm"], eps)
         if self.attrs.get("rope_theta"):
             with jax.named_scope(_inner_scope(self.name, "rope")):
                 q, k = (_rotate_half_rope(t, float(self.attrs["rope_theta"]))
@@ -255,6 +261,18 @@ def _head_rms_norm(x, gain, eps: float):
     y = xf * jax.lax.rsqrt(jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
                            + eps)
     return (y * gain.astype(jnp.float32)).astype(x.dtype)
+
+
+def _whole_rms_norm(x, gain, eps: float):
+    """RMS norm over the WHOLE width — heads and head_dim together — of
+    (batch, heads, seq, head_dim), in float32, gain (heads, head_dim)."""
+    import jax
+    import jax.numpy as jnp
+
+    xf = x.astype(jnp.float32)
+    y = xf * jax.lax.rsqrt(jnp.mean(jnp.square(xf), axis=(1, 3),
+                                    keepdims=True) + eps)
+    return (y * gain.astype(jnp.float32)[None, :, None, :]).astype(x.dtype)
 
 
 def _rotate_half_rope(x, theta: float):

@@ -141,6 +141,20 @@ class Op:
         return el * (sum(int(np.prod(s)) for s in input_shapes)
                      + sum(int(np.prod(s)) for s in output_shapes))
 
+    # -- serving state ----------------------------------------------------------
+    def slot_state_bytes(self, el: int = 0) -> int:
+        """What ONE serving slot costs in this op's slot-major RECURRENT
+        state — a summary of the whole prefix, a fixed size whatever the
+        context — or 0 for an op that carries none (attention's per-token
+        pool rows are priced a token: ``kvcache.node_token_bytes``).
+        ``el``: bytes of an element as the state's reduced-precision leaves
+        rest — the compute dtype's where the graph computes in a reduced
+        one (the engine says); the node's own dtype otherwise. The engine's
+        ``recurrent_state_bytes``, its refusal of chunks and prefix hits,
+        the serving search's price a slot and the fusion rule all ask
+        here."""
+        return 0
+
     # -- parallelization metadata ----------------------------------------------
     def parallelizable_dims(self, input_shapes) -> Dict[str, Any]:
         """Which logical dims of output 0 may be sharded, and how weights follow.

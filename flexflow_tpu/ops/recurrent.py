@@ -42,6 +42,13 @@ class LSTMOp(Op):
             "bias": ((4 * h,), self.data_type, zero),
         }
 
+    def slot_state_bytes(self, el: int = 0) -> int:
+        from ..ffconst import size_of_datatype
+
+        # the carry [h, c]
+        return 2 * int(self.attrs["hidden_size"]) * (
+            el or size_of_datatype(self.data_type))
+
     def forward(self, params, inputs, ctx: OpContext):
         import jax.lax as lax
         import jax.numpy as jnp

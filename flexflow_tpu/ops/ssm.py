@@ -15,9 +15,9 @@ With ``u`` the node's input (the block's pre-norm applied by the graph),
 
 What a sequence carries from token to token is ``S`` — ``(N, E)``, float32
 — and the last ``K - 1`` rows of ``x'``: a fixed size whatever the context,
-which is what a serving engine keeps per SLOT (serving/kvcache.py,
-``node_slot_bytes``), beside the per-token pool rows of the graph's
-attention nodes.
+which is what a serving engine keeps per SLOT
+(``SSMMixerOp.slot_state_bytes``), beside the per-token pool rows of the
+graph's attention nodes.
 
 Three forms of the same mathematics:
 
@@ -76,6 +76,26 @@ class _DtBiasInitializer:
         lo, hi = jnp.log(1e-3), jnp.log(1e-1)
         dt = jnp.exp(jax.random.uniform(key, shape, jnp.float32, lo, hi))
         return (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype)
+
+
+def conv_tail_out(sv, hist, live, width: int):
+    """A causal depthwise conv's tail ``(b, (width - 1) * channels)`` for
+    the slot. ``hist (b, width - 1 + s, channels)`` holds the conv's inputs,
+    row ``j`` that of position ``j - (width - 1)``: a prefill's tail is the
+    rows at ``length .. length + width - 2`` (positions ``length - width + 1
+    .. length - 1``, the leading zero pad standing for positions before the
+    sequence), a decode step's its newest ``width - 1``, zero for a free
+    slot (``live (b, 1, 1)``)."""
+    import jax.numpy as jnp
+
+    if sv.mode == "decode":
+        tail = jnp.where(live, hist[:, 1:], 0)
+    elif sv.lengths is not None:
+        idx = sv.lengths[:, None] + jnp.arange(width - 1)[None, :]
+        tail = jnp.take_along_axis(hist, idx[:, :, None], axis=1)
+    else:
+        tail = hist[:, hist.shape[1] - (width - 1):]
+    return tail.reshape(hist.shape[0], -1)
 
 
 def one_token_update(s, x, dt, b, c, a):
@@ -138,6 +158,14 @@ class SSMMixerOp(Op):
             specs["b_in"] = ((2 * E,), t, zero)
             specs["b_out"] = ((d,), t, zero)
         return specs
+
+    def slot_state_bytes(self, el: int = 0) -> int:
+        from ..ffconst import size_of_datatype
+
+        # the (N, E) float32 state and the conv's last K - 1 inputs
+        E, N, K, _R = self._dims()
+        return E * N * 4 + E * (K - 1) * (
+            el or size_of_datatype(self.data_type))
 
     # ------------------------------------------------------------ the parts
     def _selection(self, params, x):
@@ -206,7 +234,7 @@ class SSMMixerOp(Op):
                 conv = conv + params["conv_b"].astype(f32)
             x = jax.nn.silu(conv).astype(u.dtype)
             if sv is not None:
-                tail = self._tail_out(sv, hist, live)
+                tail = conv_tail_out(sv, hist, live, K)
         with scope("proj"):
             dt, b, c = self._selection(params, x)
         with scope("scan"):
@@ -230,25 +258,6 @@ class SSMMixerOp(Op):
             if "b_out" in params:
                 out = out + params["b_out"]
         return [out]
-
-    def _tail_out(self, sv, hist, live):
-        """The conv tail ``(b, (K - 1) * E)`` for the slot. ``hist (b, K - 1
-        + s, E)`` holds the conv's inputs, row ``j`` that of position ``j -
-        (K - 1)``: a prefill's tail is the rows at ``length .. length + K -
-        2`` (positions ``length - K + 1 .. length - 1``, the leading zero pad
-        standing for positions before the sequence), a decode step's its
-        newest ``K - 1``, zero for a free slot."""
-        import jax.numpy as jnp
-
-        K = self._dims()[2]
-        if sv.mode == "decode":
-            tail = jnp.where(live, hist[:, 1:], 0)
-        elif sv.lengths is not None:
-            idx = sv.lengths[:, None] + jnp.arange(K - 1)[None, :]
-            tail = jnp.take_along_axis(hist, idx[:, :, None], axis=1)
-        else:
-            tail = hist[:, hist.shape[1] - (K - 1):]
-        return tail.reshape(hist.shape[0], -1)
 
     def flops(self, input_shapes, output_shapes):
         b, s, d = input_shapes[0]

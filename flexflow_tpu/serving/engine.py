@@ -173,12 +173,18 @@ class ServingStats:
     moe_layer_steps: int = 0
     moe_chunk_bounded_steps: int = 0
     moe_chunk_layer_steps: int = 0
-    # recurrent nodes (an LSTM carry, a state-space mixer's state): the
-    # bytes of slot-major state the decode steps read plus wrote — every
-    # slot the program stepped, live or free, at kvcache.node_slot_bytes
-    # a node — and the live slots among them, both summed over decode steps
+    # recurrent nodes (an LSTM carry, a state-space mixer's state, a gated
+    # delta-rule mixer's matrix state a head): the bytes of slot-major
+    # state the decode steps read plus wrote — every slot the program
+    # stepped, live or free, at Op.slot_state_bytes a node — and the live
+    # slots among them, both summed over decode steps
     recurrent_state_bytes: int = 0
     recurrent_slots_live: int = 0
+    # the one-shot prefills' rows as the program computed them (the
+    # bucket's, padding included: a chunked recurrence and the matmuls pay
+    # a padded row what they pay a real one) and the real ones among them
+    prefill_rows: int = 0
+    prefill_rows_real: int = 0
     # the programs built between start_serve and finish() (obs/builds.py),
     # their seconds tracing, lowering, loading and compiling, and how many
     # by name; set by finish(). Not zero after a warm-up: the process
@@ -314,7 +320,8 @@ class ServingStats:
                   "moe_load_max_permille", "moe_bounded_steps",
                   "moe_layer_steps", "moe_chunk_bounded_steps",
                   "moe_chunk_layer_steps", "recurrent_state_bytes",
-                  "recurrent_slots_live"):
+                  "recurrent_slots_live", "prefill_rows",
+                  "prefill_rows_real"):
             if getattr(self, k):
                 out[k] = getattr(self, k)
         return out
@@ -425,14 +432,13 @@ class ServingEngine:
         # REJECTS beyond it (the old warn-and-clamp is gone, ISSUE 12
         # satellite)
         self._validate_graph()
-        from .kvcache import is_recurrent
-
         recurrent = [f"{n.name}: {n.op.op_type.name}"
                      for n in self.executor.pcg.compute_nodes()
-                     if is_recurrent(n.op)]
+                     if n.op.slot_state_bytes() > 0]
         if recurrent:
             # a recurrent state (the LSTM carry, a state-space mixer's
-            # state) is a summary, not per-token pool rows: there is no
+            # state, a gated delta-rule mixer's matrix state a head) is a
+            # summary, not per-token pool rows: there is no
             # block to share or chunk (ISSUE 14 scope — attention-only
             # stateful graphs; ROADMAP.md Reach R8 has what is missing)
             if self.prefill_chunk_tokens:
@@ -1170,13 +1176,11 @@ class ServingEngine:
 
     def _recurrent_slot_bytes(self) -> int:
         """Bytes of slot-major state ONE slot holds across every
-        recurrent node (``kvcache.node_slot_bytes``); 0 for an
+        recurrent node (``Op.slot_state_bytes``); 0 for an
         attention-only graph."""
         if getattr(self, "_recurrent_slot_bytes_cache", None) is None:
-            from .kvcache import node_slot_bytes
-
             self._recurrent_slot_bytes_cache = sum(
-                node_slot_bytes(node.op, self._rest_itemsize())
+                node.op.slot_state_bytes(self._rest_itemsize())
                 for node in self.executor.pcg.compute_nodes())
         return self._recurrent_slot_bytes_cache
 
@@ -1375,6 +1379,8 @@ class ServingEngine:
         tel.serving_moe_layer_steps = stats.moe_layer_steps
         tel.serving_recurrent_state_bytes = stats.recurrent_state_bytes
         tel.serving_recurrent_slots_live = stats.recurrent_slots_live
+        tel.serving_prefill_rows = stats.prefill_rows
+        tel.serving_prefill_rows_real = stats.prefill_rows_real
         # serving_resilience block (ISSUE 9): the outcome ledger + event
         # counters, mirroring the resilience/strategy_safety blocks
         tel.serving_outcomes = dict(stats.outcomes)
@@ -1729,6 +1735,10 @@ class _ServeLoop:
             phase.to("tick_bookkeep")
             stats.prefills += 1
             stats.prefill_tokens_computed += eff
+            stats.prefill_rows += bucket
+            stats.prefill_rows_real += eff
+            phase.tick_args.update(prefill_rows=bucket,
+                                   prefill_rows_real=eff)
             stats.record_token(wall)
             stats.tokens_generated += 1
             # first_token_ms is stamped at the commit point

@@ -76,7 +76,8 @@ is known in this module alone:
   defined for it.
 
 * the SLOT-MAJOR kind (a recurrent node: the LSTM carry, a state-space
-  mixer's state and conv tail): a fixed size a REQUEST whatever its
+  mixer's state and conv tail, a gated delta-rule mixer's matrix state a
+  head and conv tails): a fixed size a REQUEST whatever its
   context, one row a slot (``(n_slots, ...)`` leaves), overwritten
   whole at admission (:func:`update_slot_entry`), priced a slot by
   :func:`node_slot_bytes` as the pool is priced a token by
@@ -363,28 +364,20 @@ def is_recurrent(op) -> bool:
     prefix, one row a slot — rather than per-token pool rows? Such a
     state has no block to share and no position to resume from: the
     engine serves a graph that holds one without chunked prefill and
-    without the prefix cache (ROADMAP.md, Reach R8)."""
-    from ..ffconst import OperatorType
-
-    return op.op_type in (OperatorType.OP_LSTM, OperatorType.OP_SSM_MIXER)
+    without the prefix cache (ROADMAP.md, Reach R8). The op says
+    (``Op.slot_state_bytes``)."""
+    return op.slot_state_bytes() > 0
 
 
 def node_slot_bytes(op, el: int = 0) -> int:
     """What ONE slot costs in the slot-major state of the recurrent node
-    ``op``, or 0 for an op that holds none: the LSTM's ``[h, c]``; a
-    state-space mixer's ``(state_dim, inner_dim)`` float32 state and its
-    ``conv_width - 1`` conv inputs at ``el`` bytes an element (as
-    :func:`node_token_bytes`). The engine's ``recurrent_state_bytes`` and
+    ``op``, or 0 for an op that holds none: the op's own answer
+    (``Op.slot_state_bytes``: the LSTM's ``[h, c]``; a state-space mixer's
+    float32 state and conv tail; a gated delta-rule mixer's matrix state a
+    head and three conv tails), ``el`` bytes an element as
+    :func:`node_token_bytes`. The engine's ``recurrent_state_bytes`` and
     the serving search price from here."""
-    from ..ffconst import OperatorType, size_of_datatype
-
-    a, el = op.attrs, el or size_of_datatype(op.data_type)
-    if op.op_type == OperatorType.OP_LSTM:
-        return 2 * int(a["hidden_size"]) * el
-    if op.op_type == OperatorType.OP_SSM_MIXER:
-        e = int(a["inner_dim"])
-        return e * int(a["state_dim"]) * 4 + e * (int(a["conv_width"]) - 1) * el
-    return 0
+    return op.slot_state_bytes(el)
 
 
 def quantize_kv(x) -> Tuple[Any, Any]:

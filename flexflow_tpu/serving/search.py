@@ -263,8 +263,6 @@ def _graph_cost(sim, g: PCG, tp: int, kv_div: int, slots: int,
     at worst case."""
     from ..search.simulator import OpSharding
 
-    from .kvcache import is_recurrent, node_slot_bytes
-
     t = comm = 0.0
     mem_w = kv_bytes = slot_bytes = 0
     transient = 0
@@ -289,10 +287,11 @@ def _graph_cost(sim, g: PCG, tp: int, kv_div: int, slots: int,
                 # one row a token for all heads: no head axis to divide
                 kv_bytes += _attention_state_bytes(
                     node, slots, max_len, kv_dtype)
-            elif is_recurrent(node.op):
-                # a recurrent state is priced a SLOT, not a token: every
-                # step reads and writes all of it, whatever the fill
-                slot_bytes += slots * node_slot_bytes(node.op)
+            else:
+                # a recurrent state is priced a SLOT, not a token (the op
+                # says what a slot of it holds, 0 for an op with none):
+                # every step reads and writes all of it, whatever the fill
+                slot_bytes += slots * node.op.slot_state_bytes()
     kv_time = (kv_bytes * max(min(kv_fill, 1.0), 0.0) + 2 * slot_bytes) / (
         m.hbm_bandwidth * m.hbm_efficiency)
     return (t + comm + kv_time, mem_w + kv_bytes + slot_bytes + transient,

@@ -1,0 +1,92 @@
+"""Microbench: the gated delta rule alone at the ``olmo-hybrid-7b-assist``
+cell's shapes (30 heads, d_k 96, d_v 192), on the host's clock.
+
+* the decode step's one-token update over ``SLOTS`` slots, 12 layers' worth
+  inside one jit with the states donated, in the two forms PR 46 timed: the
+  fused XLA expression (``kernels.gated_delta_rule.one_token_update``, the
+  path off the chip) and the ``gated_delta_update`` kernel (the form the op
+  runs on the chip), both on the state as it rests, ``(slots, H, d_k, d_v)``;
+* the prefill kernel (``gated_delta_rule``, the chunked form) over one
+  sequence of 256 / 512 / 1,024 rows against the ``lax.scan`` form it
+  replaces.
+
+Run manually on the chip; not part of the test suite:
+
+    chiprun --chips 1 -- python scripts/microbench_gdn.py
+"""
+import json
+import os
+import sys
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+import jax
+import jax.numpy as jnp
+
+from microbench_ssm import timed  # scripts/ is sys.path[0]
+
+SLOTS, HEADS, DK, DV, LAYERS = 64, 30, 96, 192, 12
+PROMPTS = (256, 512, 1024)
+
+
+def rule_inputs(key, rows, length):
+    keys = jax.random.split(key, 5)
+    f32 = jnp.float32
+    unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)
+    q = unit(jax.random.normal(keys[0], (rows, length, HEADS, DK), f32)) \
+        * DK ** -0.5
+    k = unit(jax.random.normal(keys[1], (rows, length, HEADS, DK), f32))
+    v = jax.random.normal(keys[2], (rows, length, HEADS, DV), f32)
+    g = -jnp.abs(jax.random.normal(keys[3], (rows, length, HEADS), f32)) * 0.1
+    beta = 2.0 * jax.nn.sigmoid(
+        jax.random.normal(keys[4], (rows, length, HEADS), f32))
+    return q, k, v, g, beta
+
+
+def main() -> None:
+    from flexflow_tpu.kernels.gated_delta_rule import (
+        gated_delta_rule, gated_delta_rule_reference, gated_delta_update,
+        one_token_update)
+
+    if jax.devices()[0].platform != "tpu":
+        sys.exit("microbench_gdn: no TPU; a time from another backend says "
+                 "nothing about the chip")
+    f32 = jnp.float32
+    fresh = lambda: [jnp.zeros((SLOTS, HEADS, DK, DV), f32)
+                     for _ in range(LAYERS)]
+    q, k, v, g, beta = (t[:, 0] for t in rule_inputs(
+        jax.random.PRNGKey(0), SLOTS, 1))
+
+    def stack(update):
+        def run(states, q, k, v, g, beta):
+            vs, out = v, []
+            for s in states:   # each layer's input depends on the one before
+                o, s = update(s, q, k, vs, g, beta)
+                vs = v + 1e-3 * o
+                out.append(s)
+            return out, vs
+
+        return run
+
+    result = {"device": jax.devices()[0].device_kind, "slots": SLOTS,
+              "layers": LAYERS}
+    moved = 2 * LAYERS * SLOTS * HEADS * DK * DV * 4
+    for name, update in (("fused_xla", one_token_update),
+                         ("kernel", gated_delta_update)):
+        wall = timed(jax.jit(stack(update), donate_argnums=(0,)), fresh(),
+                     q, k, v, g, beta, donate_first=True)
+        result[f"decode_update_{name}_ms"] = round(wall * 1e3, 3)
+        result[f"decode_update_{name}_gb_per_s"] = round(
+            moved / wall / 1e9, 1)
+
+    for rows in PROMPTS:
+        args = rule_inputs(jax.random.PRNGKey(rows), 1, rows)
+        for name, fn in (("kernel", gated_delta_rule),
+                         ("lax_scan", gated_delta_rule_reference)):
+            wall = timed(jax.jit(fn), *args)
+            result[f"prefill_{rows}_{name}_ms"] = round(wall * 1e3, 3)
+    print(json.dumps(result), flush=True)
+
+
+if __name__ == "__main__":
+    main()

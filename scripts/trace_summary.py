@@ -256,6 +256,10 @@ def summarize_telemetry(data, top: int) -> None:
             print(f"  recurrent state at decode: "
                   f"{srv['recurrent_state_bytes'] / 1e9:.2f} GB read plus "
                   f"written, {srv['recurrent_slots_live']} live slot-steps")
+        if srv.get("prefill_rows"):
+            print(f"  one-shot prefills: {srv['prefill_rows']} rows "
+                  f"computed, {srv['prefill_rows_real']} of them real "
+                  f"({100 * srv['prefill_rows_real'] / srv['prefill_rows']:.1f}%)")
 
     _block(data, "serving", _srv)
 

@@ -4,7 +4,13 @@ persistent compile cache; the set-up spans beside it (``obs.setup_span`` /
 ``obs.setup_walls``); what ``fit`` and a serve run say they built; and that
 none of it changes a result. On the CPU, against a temporary cache directory.
 The registry is the process's: every test reads it from a ``build_mark()``
-of its own."""
+of its own, and starts with no entry point open (``_no_entry_point_open``:
+a serve loop another file's test abandoned without ``finish()`` — the fleet's
+replicas, the request journal's crashes, an op that raises under
+``generate`` — leaves its ``serve`` entered in this worker's process, and
+the phase tests here then read ``serve`` where they built outside every
+entry point: seen under xdist at PR 46, whenever this file follows one of
+those on a worker)."""
 import os
 import re
 import signal
@@ -34,6 +40,17 @@ _OPTIMIZERS = {
     "adam_bf16_moments": lambda ff: AdamOptimizer(
         ff, alpha=0.01, moment_dtype=jnp.bfloat16),
 }
+
+
+@pytest.fixture(autouse=True)
+def _no_entry_point_open():
+    import sys
+
+    entries = sys.modules["flexflow_tpu.obs.builds"]._ENTRIES
+    kept = list(entries)
+    del entries[:]
+    yield
+    entries[:] = kept
 
 
 @pytest.fixture(autouse=True)
