@@ -628,7 +628,7 @@ def check_routed_layer_decode(rows: int = 64, d: int = 7680,
 def _check_mixer_layer(what: str, op, reference_file: str, mixer: str,
                        config: dict, d: int, bucket: int, length: int,
                        steps: int, kernels: dict, key: int, tol: float,
-                       out_tol: float) -> None:
+                       out_tol: float, state_of=lambda s: s) -> None:
     """One recurrent mixer node ``op`` at a serving cell's widths, bf16: a
     ``bucket``-row padded prefill of a ``length``-token prompt (the state
     handed on is the one after the last REAL token) and ``steps`` decode
@@ -639,7 +639,8 @@ def _check_mixer_layer(what: str, op, reference_file: str, mixer: str,
     the node's output and the state it hands on. The control — the reference
     with its state rounded to bf16 every step — must read over the limit.
     ``kernels``: the Mosaic calls the compiled ``prefill`` / ``decode``
-    programs must hold."""
+    programs must hold. ``state_of``: the state as the slot rests it -> the
+    shape the reference hands back."""
     import importlib.util
 
     import jax
@@ -705,12 +706,13 @@ def _check_mixer_layer(what: str, op, reference_file: str, mixer: str,
         calls = mosaic_calls(programs[program].compile().as_text())
         check(kernel in calls, f"{what}: the {program} runs the {kernel} "
                                f"Mosaic kernel ({sorted(calls)})")
-    got_mid = np.asarray(cache[1], np.float32)[0]
+    got_mid = np.asarray(state_of(cache[1]), np.float32)[0]
     got = [np.asarray(rows, np.float32)]
     for t in range(length, total):
         row, cache = decode(params, u[:, t:t + 1], cache)
         got.append(np.asarray(row, np.float32)[None])
-    got, got_end = np.concatenate(got), np.asarray(cache[1], np.float32)[0]
+    got = np.concatenate(got)
+    got_end = np.asarray(state_of(cache[1]), np.float32)[0]
 
     def l2(a, b):
         return float(np.linalg.norm(a - b) / np.linalg.norm(b))
@@ -763,8 +765,10 @@ def check_delta_layer(d: int = 3840, heads: int = 30, dk: int = 96,
                       length: int = 900, steps: int = 64) -> None:
     """One gated delta-rule mixer at the ``olmo-hybrid-7b-assist`` cell's
     widths: the ``gated_delta_rule`` kernel's prefill and the
-    ``gated_delta_update`` kernel's decode steps (``_check_mixer_layer``)."""
+    ``gated_delta_update`` kernel's decode steps (``_check_mixer_layer``);
+    the state rests two heads a row and is read through ``unpack_state``."""
     from flexflow_tpu.ffconst import DataType, OperatorType
+    from flexflow_tpu.kernels.gated_delta_rule import unpack_state
     from flexflow_tpu.ops.base import op_class_for
 
     eps = 1e-6
@@ -779,7 +783,7 @@ def check_delta_layer(d: int = 3840, heads: int = 30, dk: int = 96,
          "linear_allow_neg_eigval": True, "rms_norm_eps": eps},
         d, bucket, length, steps,
         {"prefill": "gated_delta_rule", "decode": "gated_delta_update"}, 46,
-        GDN_TOL, GDN_OUT_TOL)
+        GDN_TOL, GDN_OUT_TOL, state_of=lambda s: unpack_state(s, dv))
 
 
 # ------------------------------------------------------------------ trainer

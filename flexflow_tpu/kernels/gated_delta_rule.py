@@ -36,7 +36,12 @@ write strength. Three forms of it live here:
 * :func:`one_token_update` — the decode step's update of every slot's state,
   written so that the state is read ONCE: ``S^T k`` and ``S^T q`` come from
   the same pass, ``u = b (v - a S^T k)`` and ``o = a S^T q + (k . q) u``,
-  then ``S <- a S + k u^T``.
+  then ``S <- a S + k u^T``. It and its kernel form,
+  :func:`gated_delta_update`, take and return the state AS IT RESTS in a
+  serving slot: ``p`` heads side by side on the lanes of one row, ``p``
+  the least count that makes ``p * d_v`` whole 128-lane tiles
+  (:func:`state_heads_a_row`, :func:`pack_state`, :func:`unpack_state`:
+  the packing is defined there and nowhere else).
 
 A row at or past its sequence's ``length`` must leave the state as it is (a
 right-padded prompt: the state handed on is the one after the last REAL
@@ -104,29 +109,79 @@ def gated_delta_rule_reference(q, k, v, g, beta, *, s0=None, lengths=None):
     return jnp.swapaxes(o, 0, 1), s_last
 
 
-def one_token_update(s, q, k, v, g, beta):
-    """One step of the recurrence for every row with ONE read of the state:
-    ``s (rows, H, d_k, d_v)`` f32, ``q, k (rows, H, d_k)``, ``v (rows, H,
-    d_v)``, ``g, beta (rows, H)`` -> ``(o (rows, H, d_v), s_new)``."""
+#: what one grid step of the one-token update may hold of the state: the
+#: block is double-buffered on its way in and on its way out
+UPDATE_BLOCK_BYTES = 3 * 2 ** 19
+
+#: the most heads that share a row of the state at rest
+MAX_HEADS_A_ROW = 4
+
+
+def state_heads_a_row(heads: int, dv: int) -> int:
+    """``p``, the heads whose ``(d_k, d_v)`` matrices rest side by side in one
+    row of the state: the least count for which ``p * d_v`` is whole
+    128-lane tiles (2 at ``d_v`` 192; 1 at 128 or 256), taken when it
+    divides ``heads`` and is at most ``MAX_HEADS_A_ROW``, else 1."""
+    import math
+
+    p = 128 // math.gcd(dv, 128)
+    return p if p <= MAX_HEADS_A_ROW and heads % p == 0 else 1
+
+
+def pack_state(s, p: Optional[int] = None):
+    """``(..., H, d_k, d_v)``, a matrix a head, as it rests: ``(..., H / p,
+    d_k, p * d_v)`` with ``p = state_heads_a_row(H, d_v)`` unless given; head
+    ``r * p + i`` lies on row ``r``, lanes ``[i * d_v, (i + 1) * d_v)``. The
+    inverse of :func:`unpack_state`; ``s`` itself at ``p = 1``."""
     import jax.numpy as jnp
 
+    *lead, H, dk, dv = s.shape
+    p = p or state_heads_a_row(H, dv)
+    if p == 1:
+        return s
+    n = len(lead)
+    s = s.reshape(*lead, H // p, p, dk, dv)
+    return jnp.swapaxes(s, n + 1, n + 2).reshape(*lead, H // p, dk, p * dv)
+
+
+def unpack_state(s, dv: int):
+    """The state at rest ``(..., H / p, d_k, p * d_v)`` as a matrix a head,
+    ``(..., H, d_k, d_v)``: the inverse of :func:`pack_state`."""
+    import jax.numpy as jnp
+
+    *lead, R, dk, W = s.shape
+    p = W // dv
+    if p == 1:
+        return s
+    n = len(lead)
+    s = s.reshape(*lead, R, dk, p, dv)
+    return jnp.swapaxes(s, n + 1, n + 2).reshape(*lead, R * p, dk, dv)
+
+
+def one_token_update(s, q, k, v, g, beta):
+    """One step of the recurrence for every row with ONE read of the state:
+    ``s (rows, H / p, d_k, p * d_v)`` f32, the state as it rests
+    (:func:`pack_state`), ``q, k (rows, H, d_k)``, ``v (rows, H, d_v)``,
+    ``g, beta (rows, H)`` -> ``(o (rows, H, d_v), s_new)``, ``s_new`` as
+    ``s`` rests."""
+    import jax.numpy as jnp
+
+    p = s.shape[-1] // v.shape[-1]
+    s = unpack_state(s, v.shape[-1])
     a = jnp.exp(g)[..., None]                              # (rows, H, 1)
     sk = jnp.einsum("rhkv,rhk->rhv", s, k)
     sq = jnp.einsum("rhkv,rhk->rhv", s, q)
     u = beta[..., None] * (v - a * sk)
     o = a * sq + jnp.sum(k * q, axis=-1, keepdims=True) * u
-    return o, a[..., None] * s + k[..., :, None] * u[..., None, :]
-
-
-#: what one grid step of the one-token update may hold of the state: the
-#: block is double-buffered on its way in and on its way out
-UPDATE_BLOCK_BYTES = 3 * 2 ** 19
+    return o, pack_state(a[..., None] * s + k[..., :, None] * u[..., None, :],
+                         p)
 
 
 def update_heads(heads: int, dk: int, dv: int) -> int:
-    """Heads a grid step of :func:`gated_delta_update` owns: the most that
-    divide ``heads`` with their (lane-padded) float32 state inside
-    ``UPDATE_BLOCK_BYTES``, at least one."""
+    """Rows of heads a grid step of :func:`gated_delta_update` owns: the most
+    that divide ``heads`` (the state's rows) with their (lane-padded)
+    float32 state of ``dv`` lanes inside ``UPDATE_BLOCK_BYTES``, at least
+    one."""
     lanes = -(-dv // 128) * 128
     fit = max(1, UPDATE_BLOCK_BYTES // (dk * lanes * 4))
     return max(h for h in range(1, heads + 1)
@@ -134,19 +189,22 @@ def update_heads(heads: int, dk: int, dv: int) -> int:
 
 
 def _update_kernel(q_ref, k_ref, v_ref, a_ref, b_ref, kq_ref, s_ref, o_ref,
-                   s_out_ref, *, heads):
-    """One (row, head tile) grid step of the one-token update: each head's
-    ``(d_k, d_v)`` state is read once, contracted with ``k`` and with ``q``
-    down the sublanes, and written once."""
+                   s_out_ref, *, rows, pack):
+    """One (slot, tile of rows) grid step of the one-token update: each
+    row's ``(d_k, p * d_v)`` state — ``p`` heads side by side — is read
+    once, contracted with its heads' ``k`` and ``q`` down the sublanes, and
+    written once."""
     import jax
     import jax.lax as lax
     import jax.numpy as jnp
 
     f32 = jnp.float32
-    dk = k_ref.shape[-1]
+    dk, width = s_ref.shape[-2:]
+    dv = width // pack
     row = jax.lax.broadcasted_iota(jnp.int32, (dk, dk), 0)
     col = jax.lax.broadcasted_iota(jnp.int32, (dk, dk), 1)
     eye = jnp.where(row == col, 1.0, 0.0).astype(f32)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, width), 1)
 
     def columns(ref):
         # (heads, d_k) rows -> (d_k, heads) columns: I @ X^T, the matrix
@@ -155,27 +213,39 @@ def _update_kernel(q_ref, k_ref, v_ref, a_ref, b_ref, kq_ref, s_ref, o_ref,
                                preferred_element_type=f32,
                                precision=lax.Precision.HIGHEST)
 
+    def over_lanes(c, j):
+        # row j's p columns, each over its own head's d_v lanes: a select a
+        # head past the first, never a slice of lanes; (d_k, 1) at p = 1
+        out = c[:, j * pack:j * pack + 1]
+        for i in range(1, pack):
+            out = jnp.where(lane >= i * dv,
+                            c[:, j * pack + i:j * pack + i + 1], out)
+        return out
+
     kc, qc = columns(k_ref), columns(q_ref)
     v, a, b, kq = v_ref[0, 0], a_ref[0, 0], b_ref[0, 0], kq_ref[0, 0]
-    for i in range(heads):
-        s = s_ref[0, i]                                     # (d_k, d_v)
-        k_i, q_i = kc[:, i:i + 1], qc[:, i:i + 1]           # (d_k, 1)
-        a_i = a[i:i + 1]                                    # (1, d_v)
-        sk = jnp.sum(s * k_i, axis=0, keepdims=True)
-        sq = jnp.sum(s * q_i, axis=0, keepdims=True)
-        u = b[i:i + 1] * (v[i:i + 1] - a_i * sk)
-        o_ref[0, 0, i:i + 1, :] = a_i * sq + kq[i:i + 1] * u
-        s_out_ref[0, i] = a_i * s + k_i * u
+    for j in range(rows):
+        s = s_ref[0, j]                                     # (d_k, p d_v)
+        k_j, q_j = over_lanes(kc, j), over_lanes(qc, j)
+        a_j = a[j:j + 1]                                    # (1, p d_v)
+        sk = jnp.sum(s * k_j, axis=0, keepdims=True)
+        sq = jnp.sum(s * q_j, axis=0, keepdims=True)
+        u = b[j:j + 1] * (v[j:j + 1] - a_j * sk)
+        o_ref[0, 0, j:j + 1, :] = a_j * sq + kq[j:j + 1] * u
+        s_out_ref[0, j] = a_j * s + k_j * u
 
 
 def gated_delta_update(s, q, k, v, g, beta, *,
                        interpret: Optional[bool] = None):
     """The kernel form of :func:`one_token_update`: same arguments, same
     results, the state updated IN PLACE (its buffer is aliased onto the
-    result's). A grid step owns :func:`update_heads` heads of one row;
-    the per-head scalars (``exp(g)``, ``beta``, ``k . q``) ride spread
-    over ``d_v`` lanes, a thousandth of the state. The call is named
-    ``gated_delta_update`` in the compiled program."""
+    result's). ``p``, the heads a row of the state holds, is read from the
+    shapes (``s.shape[-1] / d_v``). A grid step owns :func:`update_heads`
+    rows of one slot; ``v``, the output and the per-head scalars
+    (``exp(g)``, ``beta``, ``k . q``, each spread over its head's ``d_v``
+    lanes, a thousandth of the state) ride ``p * d_v`` lanes a row, head-major
+    as the projections lay them. The call is named ``gated_delta_update`` in
+    the compiled program."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
@@ -184,34 +254,39 @@ def gated_delta_update(s, q, k, v, g, beta, *,
     from ._common import resolve_interpret
 
     f32 = jnp.float32
-    rows, H, dk, dv = s.shape
-    hb = update_heads(H, dk, dv)
-    n_t = H // hb
+    slots, R, dk, width = s.shape
+    H, dv = v.shape[1:]
+    p = width // dv
+    if (R * p, p * dv) != (H, width):
+        raise ValueError(f"gated_delta_update: a state {s.shape} does not "
+                         f"hold {H} heads of {dv} lanes")
+    rb = update_heads(R, dk, width)
+    n_t = R // rb
     q, k, v, g, beta = (t.astype(f32) for t in (q, k, v, g, beta))
-    spread = lambda t: jnp.broadcast_to(
-        t[..., None], (rows, H, dv)).reshape(rows, n_t, hb, dv)
-    tiles = lambda t: t.reshape(rows, n_t, hb, t.shape[-1])
+    lanes = lambda t: t.reshape(slots, n_t, rb, width)
+    spread = lambda t: lanes(jnp.broadcast_to(t[..., None], (slots, H, dv)))
+    heads = lambda t: t.reshape(slots, n_t, rb * p, dk)
 
-    def per_head(width):
-        return pl.BlockSpec((1, 1, hb, width), lambda r, t: (r, t, 0, 0))
+    def tile(height, w):
+        return pl.BlockSpec((1, 1, height, w), lambda r, t: (r, t, 0, 0))
 
-    state = pl.BlockSpec((1, hb, dk, dv), lambda r, t: (r, t, 0, 0))
+    state = pl.BlockSpec((1, rb, dk, width), lambda r, t: (r, t, 0, 0))
     o, s_new = pl.pallas_call(
-        functools.partial(_update_kernel, heads=hb),
-        grid=(rows, n_t),
-        in_specs=[per_head(dk), per_head(dk), per_head(dv), per_head(dv),
-                  per_head(dv), per_head(dv), state],
-        out_specs=[per_head(dv), state],
-        out_shape=[jax.ShapeDtypeStruct((rows, n_t, hb, dv), f32),
-                   jax.ShapeDtypeStruct((rows, H, dk, dv), f32)],
+        functools.partial(_update_kernel, rows=rb, pack=p),
+        grid=(slots, n_t),
+        in_specs=[tile(rb * p, dk), tile(rb * p, dk)]
+        + [tile(rb, width)] * 4 + [state],
+        out_specs=[tile(rb, width), state],
+        out_shape=[jax.ShapeDtypeStruct((slots, n_t, rb, width), f32),
+                   jax.ShapeDtypeStruct(s.shape, f32)],
         input_output_aliases={6: 1},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=resolve_interpret(interpret),
         name="gated_delta_update",
-    )(tiles(q), tiles(k), tiles(v), spread(jnp.exp(g)), spread(beta),
+    )(heads(q), heads(k), lanes(v), spread(jnp.exp(g)), spread(beta),
       spread(jnp.sum(k * q, axis=-1)), s.astype(f32))
-    return o.reshape(rows, H, dv), s_new
+    return o.reshape(slots, H, dv), s_new
 
 
 def _chunk_kernel(q_ref, k_ref, kt_ref, v_ref, yc_ref, yr_ref, b_ref, s0_ref,

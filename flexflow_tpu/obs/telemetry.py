@@ -142,6 +142,10 @@ class StepTelemetry:
         # wrote, and the live slots among those stepped (ServingStats)
         self.serving_recurrent_state_bytes: int = 0
         self.serving_recurrent_slots_live: int = 0
+        # that state as the chip rests it (lane and sublane padding in),
+        # once, and the most heads a row of it holds
+        self.serving_recurrent_state_bytes_at_rest: int = 0
+        self.serving_state_heads_a_row: int = 0
         # one-shot prefills: the buckets' rows computed, and the real ones
         self.serving_prefill_rows: int = 0
         self.serving_prefill_rows_real: int = 0
@@ -372,6 +376,9 @@ class StepTelemetry:
                     self.serving_recurrent_state_bytes
                 sv["recurrent_slots_live"] = \
                     self.serving_recurrent_slots_live
+                sv["recurrent_state_bytes_at_rest"] = \
+                    self.serving_recurrent_state_bytes_at_rest
+                sv["state_heads_a_row"] = self.serving_state_heads_a_row
             if self.serving_prefill_rows:
                 sv["prefill_rows"] = self.serving_prefill_rows
                 sv["prefill_rows_real"] = self.serving_prefill_rows_real

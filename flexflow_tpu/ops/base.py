@@ -155,6 +155,14 @@ class Op:
         here."""
         return 0
 
+    def slot_state_heads_a_row(self) -> int:
+        """Heads that share one row of this op's recurrent state AS IT
+        RESTS (1: a head, or no head at all, a row). The bytes do not
+        change with it (:meth:`slot_state_bytes` is the logical count);
+        what the chip pads does — the engine's
+        ``recurrent_state_bytes_at_rest`` and ``state_heads_a_row``."""
+        return 1
+
     # -- parallelization metadata ----------------------------------------------
     def parallelizable_dims(self, input_shapes) -> Dict[str, Any]:
         """Which logical dims of output 0 may be sharded, and how weights follow.

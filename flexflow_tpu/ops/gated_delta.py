@@ -33,10 +33,10 @@ Three forms of the same mathematics (as ops/ssm.py):
   gathered at ``length - K + 1 .. length - 1`` (``ssm.conv_tail_out``): the
   state handed to the slot is the state after the last REAL token, the
   ``LSTMOp`` contract.
-* **decode**: one token a slot from ``cache_in[name] = (conv_tail, S)``;
-  the one-token update reads the state once (``S^T k`` and ``S^T q`` from
-  one pass: kernels/gated_delta_rule.py). A FREE slot's state is held at
-  zero (``kvcache.live_slots``).
+* **decode**: one token a slot from ``cache_in[name] = (conv_tail, S)``,
+  ``S`` as it rests (below); the one-token update reads the state once
+  (``S^T k`` and ``S^T q`` from one pass: kernels/gated_delta_rule.py). A
+  FREE slot's state is held at zero (``kvcache.live_slots``).
 * **chunk** raises: a chunk would have to start from a carried state and a
   prefix hit from a snapshot of one, which the engine does not keep
   (ROADMAP.md, Reach R8).
@@ -46,15 +46,25 @@ accumulation; ``g``, ``a``, ``b``, the L2 norms, ``S``, ``S^T k`` and ``S^T
 q`` are float32, and ``S`` rests in the slot in float32. The conv tails rest
 in the graph's dtype.
 
-At rest ``S`` is ``(n_slots, H, d_k, d_v)``, the shape the update computes
-on: ``d_v`` rides the lanes (192 pads to 256 on the chip, a third more
-bytes), a head's ``d_k`` rows are whole sublane tiles. The two 3-D forms that
-would rest unpadded do not stay where they are put: the chip's runtime rests
-``(n_slots, H * d_k, d_v)`` with ``H * d_k`` on the lanes (less padding), so
-every step re-lays the whole state on its way into the update and out of it,
-and ``(n_slots, d_k, H * d_v)`` has to split its lanes a head, which re-lays
-it too (PERF.md section 6, PR 46: read off the compiled text). The tails are
-``(n_slots, (K - 1) * (2 H d_k + H d_v))``.
+At rest ``S`` is ``(n_slots, H / p, d_k, p * d_v)``: ``p`` heads side by side
+on the lanes of one row, ``p`` the least count for which ``p * d_v`` is whole
+128-lane tiles (``kernels.gated_delta_rule.state_heads_a_row``: 2 at ``d_v``
+192, where 384 = 3 x 128; 1 at 128 or 256) — the shape the update computes on,
+with no lane the chip pads (a head a row, ``(n_slots, H, d_k, 192)``, rests
+every row in 256 lanes, a third more bytes that every decode step read and
+wrote: PR 46's shape), a row's ``d_k`` sublanes whole tiles, and no lane split
+anywhere: the update carries every per-head vector spread over its head's
+``d_v`` lanes, so a row's ``v``, ``exp(g)``, ``beta``, ``k . q`` and output
+are ``p * d_v`` adjacent lanes of the head-major rows the projections hand
+over. The packing is ``pack_state`` / ``unpack_state`` there and nowhere else;
+a prefill packs its last state once as it hands it to the slot. This form
+stays where it is put (PERF.md section 6, PR 49: read off the compiled text).
+The two 3-D forms that would also rest unpadded do not: the chip's runtime
+rests ``(n_slots, H * d_k, d_v)`` with ``H * d_k`` on the lanes (less
+padding), so every step re-lays the whole state on its way into the update and
+out of it, and ``(n_slots, d_k, H * d_v)`` has to split its lanes a head,
+which re-lays it too (PERF.md section 6, PR 46). The tails are ``(n_slots,
+(K - 1) * (2 H d_k + H d_v))``.
 """
 from __future__ import annotations
 
@@ -133,6 +143,12 @@ class GatedDeltaMixerOp(Op):
         el = el or size_of_datatype(self.data_type)
         return H * dk * dv * 4 + (2 * H * dk + H * dv) * (K - 1) * el
 
+    def slot_state_heads_a_row(self) -> int:
+        from ..kernels.gated_delta_rule import state_heads_a_row
+
+        H, _dk, dv, _K = self._dims()
+        return state_heads_a_row(H, dv)
+
     def forward(self, params, inputs, ctx: OpContext):
         import jax
         import jax.numpy as jnp
@@ -141,6 +157,7 @@ class GatedDeltaMixerOp(Op):
                                                 gated_delta_rule_reference,
                                                 gated_delta_update,
                                                 one_token_update,
+                                                pack_state,
                                                 use_gated_delta_rule)
 
         u = inputs[0]                                   # (b, s, d)
@@ -217,6 +234,9 @@ class GatedDeltaMixerOp(Op):
                 rule = gated_delta_rule if use_gated_delta_rule() \
                     else gated_delta_rule_reference
                 o, s_last = rule(q, k, v, g, beta, lengths=lengths)
+                if sv is not None:
+                    # the state as the slot rests it, packed once a prompt
+                    s_last = pack_state(s_last)
         if sv is not None:
             sv.cache_out[self.name] = (tail, s_last)
         with scope("out"):

@@ -180,6 +180,13 @@ class ServingStats:
     # slots among them, both summed over decode steps
     recurrent_state_bytes: int = 0
     recurrent_slots_live: int = 0
+    # that state as the chip RESTS it, all slots, once (no sum): the
+    # leaves' bytes with the last dimension rounded up to 128 lanes and the
+    # one before to a sublane tile — equal to n_slots x Op.slot_state_bytes
+    # where nothing pads — and the most heads a row of it holds
+    # (Op.slot_state_heads_a_row)
+    recurrent_state_bytes_at_rest: int = 0
+    state_heads_a_row: int = 0
     # the one-shot prefills' rows as the program computed them (the
     # bucket's, padding included: a chunked recurrence and the matmuls pay
     # a padded row what they pay a real one) and the real ones among them
@@ -320,7 +327,8 @@ class ServingStats:
                   "moe_load_max_permille", "moe_bounded_steps",
                   "moe_layer_steps", "moe_chunk_bounded_steps",
                   "moe_chunk_layer_steps", "recurrent_state_bytes",
-                  "recurrent_slots_live", "prefill_rows",
+                  "recurrent_slots_live", "recurrent_state_bytes_at_rest",
+                  "state_heads_a_row", "prefill_rows",
                   "prefill_rows_real"):
             if getattr(self, k):
                 out[k] = getattr(self, k)
@@ -1184,6 +1192,26 @@ class ServingEngine:
                 for node in self.executor.pcg.compute_nodes())
         return self._recurrent_slot_bytes_cache
 
+    def _recurrent_at_rest(self) -> Tuple[int, int]:
+        """``(bytes, heads a row)`` of the slot-major state as the chip
+        rests it: every leaf's bytes in whole ``(sublanes, 128)`` tiles
+        of its dtype, and the most heads a recurrent node packs into a
+        row (``Op.slot_state_heads_a_row``)."""
+        if getattr(self, "_recurrent_at_rest_cache", None) is None:
+            import jax
+
+            from .kvcache import tiled_bytes
+
+            slot_major = [entry for name, entry in self.state.caches.items()
+                          if name not in self._paged_entry_names]
+            self._recurrent_at_rest_cache = (
+                sum(tiled_bytes(leaf.shape, leaf.dtype.itemsize)
+                    for leaf in jax.tree.leaves(slot_major)),
+                max((node.op.slot_state_heads_a_row()
+                     for node in self.executor.pcg.compute_nodes()
+                     if node.op.slot_state_bytes() > 0), default=0))
+        return self._recurrent_at_rest_cache
+
     def _kv_tiling(self) -> Tuple[int, bool]:
         """``(P, tiled_grid)``: the table entries one step of the decode
         attention kernel folds (kernels/flash_decode.py ``tile_blocks``)
@@ -1237,6 +1265,8 @@ class ServingEngine:
         moved = 2 * slot * self.n_slots
         stats.recurrent_state_bytes += moved
         stats.recurrent_slots_live += n_live
+        stats.recurrent_state_bytes_at_rest, stats.state_heads_a_row = \
+            self._recurrent_at_rest()
         return {"recurrent_state_bytes": moved,
                 "recurrent_slots_live": n_live}
 
@@ -1379,6 +1409,9 @@ class ServingEngine:
         tel.serving_moe_layer_steps = stats.moe_layer_steps
         tel.serving_recurrent_state_bytes = stats.recurrent_state_bytes
         tel.serving_recurrent_slots_live = stats.recurrent_slots_live
+        tel.serving_recurrent_state_bytes_at_rest = \
+            stats.recurrent_state_bytes_at_rest
+        tel.serving_state_heads_a_row = stats.state_heads_a_row
         tel.serving_prefill_rows = stats.prefill_rows
         tel.serving_prefill_rows_real = stats.prefill_rows_real
         # serving_resilience block (ISSUE 9): the outcome ledger + event

@@ -359,6 +359,19 @@ def node_token_bytes(op, kv_dtype: str = "native", el: int = 0) -> int:
                           el, kv_dtype)
 
 
+def tiled_bytes(shape, itemsize: int) -> int:
+    """Bytes an array of ``shape`` rests in on the chip: its last dimension
+    in whole 128-lane tiles and the one before in whole sublane tiles (8
+    rows of 4 bytes; 16 of 2; 32 of 1), the padding counted."""
+    dims = [int(d) for d in shape]
+    if dims:
+        dims[-1] = latent_lanes(dims[-1])
+    if len(dims) > 1:
+        sub = 8 * max(1, 4 // int(itemsize))
+        dims[-2] = -(-dims[-2] // sub) * sub
+    return int(np.prod(dims, dtype=np.int64)) * int(itemsize)
+
+
 def is_recurrent(op) -> bool:
     """Does ``op`` carry a recurrent state — a summary of the whole
     prefix, one row a slot — rather than per-token pool rows? Such a

@@ -2,10 +2,15 @@
 cell's shapes (30 heads, d_k 96, d_v 192), on the host's clock.
 
 * the decode step's one-token update over ``SLOTS`` slots, 12 layers' worth
-  inside one jit with the states donated, in the two forms PR 46 timed: the
-  fused XLA expression (``kernels.gated_delta_rule.one_token_update``, the
-  path off the chip) and the ``gated_delta_update`` kernel (the form the op
-  runs on the chip), both on the state as it rests, ``(slots, H, d_k, d_v)``;
+  inside one jit with the states donated, in three forms: the
+  ``gated_delta_update`` kernel on the state as it rests, two heads a row
+  (``packed``: ``(slots, H / 2, d_k, 2 d_v)``, whole lane tiles); the same
+  kernel handed a head a row (``padded``: ``(slots, H, d_k, d_v)``, the shape
+  PR 46 rested, whose 192 lanes the chip pads to 256); and the fused XLA
+  expression (``kernels.gated_delta_rule.one_token_update``, the path off the
+  chip) on the packed state. Each prints the bytes its states rest in on the
+  chip (``kvcache.tiled_bytes``) and the GB/s it moved, counted on the
+  matrices' own bytes (``logical``) and on the bytes at rest;
 * the prefill kernel (``gated_delta_rule``, the chunked form) over one
   sequence of 256 / 512 / 1,024 rows against the ``lax.scan`` form it
   replaces.
@@ -46,14 +51,15 @@ def rule_inputs(key, rows, length):
 def main() -> None:
     from flexflow_tpu.kernels.gated_delta_rule import (
         gated_delta_rule, gated_delta_rule_reference, gated_delta_update,
-        one_token_update)
+        one_token_update, pack_state)
+    from flexflow_tpu.serving.kvcache import tiled_bytes
 
     if jax.devices()[0].platform != "tpu":
         sys.exit("microbench_gdn: no TPU; a time from another backend says "
                  "nothing about the chip")
     f32 = jnp.float32
-    fresh = lambda: [jnp.zeros((SLOTS, HEADS, DK, DV), f32)
-                     for _ in range(LAYERS)]
+    padded = jax.ShapeDtypeStruct((SLOTS, HEADS, DK, DV), f32)
+    packed = jax.eval_shape(pack_state, padded)
     q, k, v, g, beta = (t[:, 0] for t in rule_inputs(
         jax.random.PRNGKey(0), SLOTS, 1))
 
@@ -69,15 +75,21 @@ def main() -> None:
         return run
 
     result = {"device": jax.devices()[0].device_kind, "slots": SLOTS,
-              "layers": LAYERS}
-    moved = 2 * LAYERS * SLOTS * HEADS * DK * DV * 4
-    for name, update in (("fused_xla", one_token_update),
-                         ("kernel", gated_delta_update)):
-        wall = timed(jax.jit(stack(update), donate_argnums=(0,)), fresh(),
+              "layers": LAYERS, "state_packed": list(packed.shape)}
+    logical = LAYERS * SLOTS * HEADS * DK * DV * 4
+    for name, update, rest in (("kernel_packed", gated_delta_update, packed),
+                               ("kernel_padded", gated_delta_update, padded),
+                               ("fused_xla", one_token_update, packed)):
+        states = [jnp.zeros(rest.shape, f32) for _ in range(LAYERS)]
+        wall = timed(jax.jit(stack(update), donate_argnums=(0,)), states,
                      q, k, v, g, beta, donate_first=True)
+        at_rest = LAYERS * tiled_bytes(rest.shape, 4)
         result[f"decode_update_{name}_ms"] = round(wall * 1e3, 3)
-        result[f"decode_update_{name}_gb_per_s"] = round(
-            moved / wall / 1e9, 1)
+        result[f"decode_update_{name}_at_rest_gb"] = round(at_rest / 1e9, 4)
+        result[f"decode_update_{name}_logical_gb_per_s"] = round(
+            2 * logical / wall / 1e9, 1)
+        result[f"decode_update_{name}_at_rest_gb_per_s"] = round(
+            2 * at_rest / wall / 1e9, 1)
 
     for rows in PROMPTS:
         args = rule_inputs(jax.random.PRNGKey(rows), 1, rows)

@@ -255,7 +255,10 @@ def summarize_telemetry(data, top: int) -> None:
         if srv.get("recurrent_state_bytes"):
             print(f"  recurrent state at decode: "
                   f"{srv['recurrent_state_bytes'] / 1e9:.2f} GB read plus "
-                  f"written, {srv['recurrent_slots_live']} live slot-steps")
+                  f"written, {srv['recurrent_slots_live']} live slot-steps; "
+                  f"{srv.get('recurrent_state_bytes_at_rest', 0) / 1e9:.2f} "
+                  f"GB at rest on the chip, "
+                  f"{srv.get('state_heads_a_row', 1)} head(s) a row")
         if srv.get("prefill_rows"):
             print(f"  one-shot prefills: {srv['prefill_rows']} rows "
                   f"computed, {srv['prefill_rows_real']} of them real "

@@ -275,29 +275,155 @@ def test_chunked_kernel_equals_the_scan(batch, length, heads, dk, dv, short,
     np.testing.assert_allclose(got_s, cut_s, rtol=2e-5, atol=2e-5)
 
 
-@pytest.mark.parametrize("rows,heads,dk,dv", [(3, 2, 16, 32), (4, 6, 8, 32),
-                                              (2, 30, 96, 192)])
-def test_update_kernel_equals_the_one_token_update(rows, heads, dk, dv):
-    """The decode step's kernel against the fused expression and against one
-    step of the scan; a row told it is free (decay 0, beta 0) comes back
-    zero."""
+#: (heads, d_v) -> the heads a row of the state at rest holds
+HEADS_A_ROW = [((30, 192), 2), ((4, 128), 1), ((4, 256), 1), ((4, 64), 2),
+               ((4, 96), 4), ((8, 32), 4), ((3, 192), 1), ((2, 32), 1),
+               ((6, 96), 1), ((8, 16), 1)]
+
+
+@pytest.mark.parametrize("heads,dv,p", [hd + (p,) for hd, p in HEADS_A_ROW])
+def test_pack_state_round_trip(heads, dv, p):
+    """``p`` from the shapes alone (the least heads whose lanes are whole
+    128-lane tiles, when it divides H and is at most 4); head ``r p + i``
+    rests on row ``r``, lanes ``[i d_v, (i + 1) d_v)``; unpacking is the
+    inverse; at ``p = 1`` nothing moves."""
+    from flexflow_tpu.kernels.gated_delta_rule import (pack_state,
+                                                       state_heads_a_row,
+                                                       unpack_state)
+
+    dk = 8
+    assert state_heads_a_row(heads, dv) == p
+    s = jnp.arange(3 * heads * dk * dv, dtype=jnp.float32).reshape(
+        3, heads, dk, dv)
+    packed = pack_state(s)
+    assert packed.shape == (3, heads // p, dk, p * dv)
+    assert p == 1 or (p * dv) % 128 == 0
+    for h in range(heads):
+        r, i = divmod(h, p)
+        np.testing.assert_array_equal(
+            packed[:, r, :, i * dv:(i + 1) * dv], s[:, h])
+    np.testing.assert_array_equal(unpack_state(packed, dv), s)
+    if p == 1:
+        assert packed is s and unpack_state(s, dv) is s
+    # a leading axis more or less: the layers' stack, one slot
+    np.testing.assert_array_equal(pack_state(s[0]), packed[0])
+    np.testing.assert_array_equal(unpack_state(packed[None], dv), s[None])
+
+
+def _pr46_update(s, q, k, v, g, beta):
+    """PR 46's ``gated_delta_update`` — a head a row of the state, ``k`` and
+    ``q`` a ``(d_k, 1)`` column a head — kept here as the oracle of "the same
+    arithmetic on the same numbers in the same order": the packed kernel's
+    results are bit-equal to it."""
+    import functools
+
+    import jax.lax as lax
+    from jax.experimental import pallas as pl
+
+    from flexflow_tpu.kernels.gated_delta_rule import update_heads
+
+    f32 = jnp.float32
+    rows, H, dk, dv = s.shape
+    hb = update_heads(H, dk, dv)
+    n_t = H // hb
+
+    def kernel(q_ref, k_ref, v_ref, a_ref, b_ref, kq_ref, s_ref, o_ref,
+               s_out_ref):
+        row = jax.lax.broadcasted_iota(jnp.int32, (dk, dk), 0)
+        col = jax.lax.broadcasted_iota(jnp.int32, (dk, dk), 1)
+        eye = jnp.where(row == col, 1.0, 0.0).astype(f32)
+        columns = lambda ref: lax.dot_general(
+            eye, ref[0, 0], (((1,), (1,)), ((), ())),
+            preferred_element_type=f32, precision=lax.Precision.HIGHEST)
+        kc, qc = columns(k_ref), columns(q_ref)
+        v, a, b, kq = v_ref[0, 0], a_ref[0, 0], b_ref[0, 0], kq_ref[0, 0]
+        for i in range(hb):
+            s = s_ref[0, i]
+            k_i, q_i, a_i = kc[:, i:i + 1], qc[:, i:i + 1], a[i:i + 1]
+            sk = jnp.sum(s * k_i, axis=0, keepdims=True)
+            sq = jnp.sum(s * q_i, axis=0, keepdims=True)
+            u = b[i:i + 1] * (v[i:i + 1] - a_i * sk)
+            o_ref[0, 0, i:i + 1, :] = a_i * sq + kq[i:i + 1] * u
+            s_out_ref[0, i] = a_i * s + k_i * u
+
+    spread = lambda t: jnp.broadcast_to(
+        t[..., None], (rows, H, dv)).reshape(rows, n_t, hb, dv)
+    tiles = lambda t: t.reshape(rows, n_t, hb, t.shape[-1])
+    per_head = lambda w: pl.BlockSpec((1, 1, hb, w), lambda r, t: (r, t, 0, 0))
+    state = pl.BlockSpec((1, hb, dk, dv), lambda r, t: (r, t, 0, 0))
+    o, s_new = pl.pallas_call(
+        kernel, grid=(rows, n_t),
+        in_specs=[per_head(dk), per_head(dk)] + [per_head(dv)] * 4 + [state],
+        out_specs=[per_head(dv), state],
+        out_shape=[jax.ShapeDtypeStruct((rows, n_t, hb, dv), f32),
+                   jax.ShapeDtypeStruct((rows, H, dk, dv), f32)],
+        interpret=True,
+    )(tiles(q), tiles(k), tiles(v), spread(jnp.exp(g)), spread(beta),
+      spread(jnp.sum(k * q, axis=-1)), s)
+    return o.reshape(rows, H, dv), s_new
+
+
+@pytest.mark.parametrize("rows,heads,dk,dv,p", [
+    (2, 30, 96, 192, 2),     # the published widths: two heads a row
+    (3, 4, 16, 128, 1),      # whole lane tiles already: PR 46's form
+    (3, 4, 16, 64, 2),
+    (3, 4, 16, 96, 4),
+    (2, 3, 16, 192, 1),      # an odd count of heads falls to a head a row
+    (3, 2, 16, 32, 1),       # four would share a row: two heads do not
+    (4, 6, 8, 32, 1),
+])
+def test_update_kernel_on_the_state_at_rest(rows, heads, dk, dv, p):
+    """64 decode steps of the ``gated_delta_update`` kernel on the state as
+    it rests, ``p`` heads a row: bit-equal, output and state, to the same
+    kernel on the unpacked state and to PR 46's kernel; within rounding of
+    the fused expression step by step and of the scan over the 64 tokens; a
+    row told it is free (decay 0, beta 0) stays zero."""
     from flexflow_tpu.kernels.gated_delta_rule import (
         gated_delta_rule_reference, gated_delta_update, one_token_update,
-        update_heads)
+        pack_state, state_heads_a_row, unpack_state, update_heads)
 
-    q, k, v, g, beta, s0 = rule_inputs(rows, 1, heads, dk, dv, rows)
+    steps = 64
+    assert state_heads_a_row(heads, dv) == p
+    q, k, v, g, beta, s0 = rule_inputs(rows, steps, heads, dk, dv, rows)
     g, beta = g.at[0].set(-jnp.inf), beta.at[0].set(0.0)
-    args = (s0, q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0])
-    want_o, want_s = one_token_update(*args)
-    got_o, got_s = gated_delta_update(*args, interpret=True)
-    np.testing.assert_allclose(got_o, want_o, rtol=1e-5, atol=1e-6)
-    np.testing.assert_allclose(got_s, want_s, rtol=1e-5, atol=1e-6)
+    s0 = s0.at[0].set(0.0)
+    kernel = jax.jit(lambda *a: gated_delta_update(*a, interpret=True))
+    fused, pr46 = jax.jit(one_token_update), jax.jit(_pr46_update)
+    s_packed = s_fused = pack_state(s0)
+    s_plain = s_old = s0
+    assert s_packed.shape == (rows, heads // p, dk, p * dv)
+    outs = []
+    for t in range(steps):
+        row = (q[:, t], k[:, t], v[:, t], g[:, t], beta[:, t])
+        o, s_packed = kernel(s_packed, *row)
+        o_plain, s_plain = kernel(s_plain, *row)
+        o_old, s_old = pr46(s_old, *row)
+        o_fused, s_fused = fused(s_fused, *row)
+        for other in (o_plain, o_old):
+            np.testing.assert_array_equal(o, other)
+        np.testing.assert_allclose(o, o_fused, rtol=2e-5, atol=2e-5)
+        outs.append(o)
+    got_s = unpack_state(s_packed, dv)
+    assert s_packed.shape == s_fused.shape == pack_state(s0).shape
+    np.testing.assert_array_equal(got_s, s_plain)
+    np.testing.assert_array_equal(got_s, s_old)
+    np.testing.assert_allclose(s_packed, s_fused, rtol=2e-5, atol=2e-5)
+    got_o = jnp.stack(outs, axis=1)
     assert not np.asarray(got_s[0]).any() and not np.asarray(got_o[0]).any()
-    scan_o, scan_s = gated_delta_rule_reference(q[1:], k[1:], v[1:], g[1:],
-                                                beta[1:], s0=s0[1:])
-    np.testing.assert_allclose(got_o[1:], scan_o[:, 0], rtol=1e-5, atol=1e-6)
-    np.testing.assert_allclose(got_s[1:], scan_s, rtol=1e-5, atol=1e-6)
-    assert heads % update_heads(heads, dk, dv) == 0
+    scan_o, scan_s = gated_delta_rule_reference(
+        q[1:], k[1:], v[1:], g[1:], beta[1:], s0=s0[1:])
+    np.testing.assert_allclose(got_o[1:], scan_o, rtol=2e-5, atol=2e-5)
+    np.testing.assert_allclose(got_s[1:], scan_s, rtol=2e-5, atol=2e-5)
+    assert (heads // p) % update_heads(heads // p, dk, p * dv) == 0
+
+
+def test_a_state_of_another_width_is_refused():
+    from flexflow_tpu.kernels.gated_delta_rule import gated_delta_update
+
+    q, k, v, g, beta, s0 = rule_inputs(2, 1, 4, 16, 64, 0)
+    with pytest.raises(ValueError, match="does not hold 4 heads of 64"):
+        gated_delta_update(s0[:, :3], q[:, 0], k[:, 0], v[:, 0], g[:, 0],
+                           beta[:, 0], interpret=True)
 
 
 # ------------------------------------------------------------ serving path
@@ -391,6 +517,82 @@ def test_a_slot_freed_and_taken_again(system):
     n = len(second)
     rows = np.stack([decode[(0, t)] for t in range(n, len(seq) - 1)])
     assert_matches(rows, want[n:], "the second tenant's rows")
+
+
+@pytest.fixture(scope="module")
+def paired():
+    """The tiny model at ``d_v`` 64: its two heads share a 128-lane row of
+    the state at rest (``system``'s ``d_v`` 32 would put four in a row and
+    has two: a head a row)."""
+    ff = build(olmo_config(linear_value_head_dim=64))
+    return ff, jax.device_get(ff.params), dict(TINY, linear_value_head_dim=64)
+
+
+def test_prefill_hand_off_then_decode_two_heads_a_row(paired):
+    """The prefill packs its last state once as it hands it to the slot and
+    the decode steps update it as it rests: every row equals the
+    reference's FULL forward, and the counters say how the state rests."""
+    ff, params0, config = paired
+    eng = engine(ff)
+    prefill, decode = record_logits(eng)
+    prompts = [[int(t) for t in ids(70 + k, n)]
+               for k, n in enumerate((13, 16, 27))]
+    outs = eng.generate(prompts, max_new_tokens=8)
+    for slot, (prompt, out) in enumerate(zip(prompts, outs)):
+        seq = np.asarray(prompt + out, np.int32)
+        want = ref.logits(params0, seq[:-1], config)
+        n = len(prompt)
+        assert_matches(prefill[slot], want[n - 1], "prefill row")
+        rows = np.stack([decode[(slot, t)] for t in range(n, len(seq) - 1)])
+        assert_matches(rows, want[n:], "decode rows")
+    states = [entry[1] for name, entry in eng.state.caches.items()
+              if name not in eng._paged_entry_names]
+    # H 2, d_k 16, d_v 64: one row of two heads, 128 lanes
+    assert [s.shape for s in states] == [(4, 1, 16, 128)] * 6
+    st = eng.stats
+    assert st.state_heads_a_row == 2
+    # the states rest in whole tiles (the logical bytes); a tail of 3 x
+    # (2 x 2 x 16 + 2 x 64) = 576 numbers a slot rests 4 slots in 8
+    # sublanes and 576 lanes in 640
+    assert st.recurrent_state_bytes_at_rest \
+        == 6 * (4 * 2 * 16 * 64 * 4 + 8 * 640 * 4)
+    assert eng._recurrent_slot_bytes() \
+        == 6 * (2 * 16 * 64 * 4 + 576 * 4)
+    summary = st.summary()
+    assert summary["state_heads_a_row"] == 2
+    assert summary["recurrent_state_bytes_at_rest"] \
+        == st.recurrent_state_bytes_at_rest
+
+
+def test_the_counters_of_the_state_at_rest(system):
+    """``recurrent_state_bytes_at_rest`` counts what the chip pads — a head
+    a row of ``d_v`` 32 rests in 128 lanes, four times its bytes — beside
+    the logical ``recurrent_state_bytes``; both reach the telemetry's
+    serving block, once, not summed over the steps."""
+    from flexflow_tpu.serving.kvcache import tiled_bytes
+
+    ff, _ = system
+    ff._telemetry_requested = True
+    eng = engine(ff)
+    eng.generate([[1, 2, 3], [4, 5]], max_new_tokens=6)
+    st = eng.stats
+    state = 4 * 2 * 16 * 128 * 4          # (4, 2, 16, 32) f32 in 128 lanes
+    tail = 8 * 384 * 4                    # (4, 384) f32 in 8 sublanes
+    assert st.state_heads_a_row == 1
+    assert st.recurrent_state_bytes_at_rest == 6 * (state + tail)
+    assert st.recurrent_state_bytes_at_rest \
+        > eng.n_slots * eng._recurrent_slot_bytes()
+    assert st.recurrent_state_bytes == st.decode_steps * 2 * eng.n_slots \
+        * eng._recurrent_slot_bytes()
+    assert tiled_bytes((64, 15, 96, 384), 4) == 64 * 15 * 96 * 384 * 4
+    assert tiled_bytes((64, 30, 96, 192), 4) == 64 * 30 * 96 * 256 * 4
+    assert tiled_bytes((64, 34560), 2) == 64 * 34560 * 2
+    assert tiled_bytes((5, 100), 2) == 16 * 128 * 2
+    assert tiled_bytes((7,), 4) == 128 * 4
+    block = ff.get_telemetry().summary()["serving"]
+    assert block["recurrent_state_bytes"] == st.recurrent_state_bytes
+    assert block["recurrent_state_bytes_at_rest"] == 6 * (state + tail)
+    assert block["state_heads_a_row"] == 1
 
 
 def test_state_taken_at_the_padded_tail_is_refused(system):
@@ -536,15 +738,21 @@ def test_published_slot_and_token_bytes():
 
 # ----------------------------------------- the compiled programs, for a v5e
 SLOTS, POOL_BLOCKS, DEPTH = 8, 65, 4
-HEADS, DK, DV = 4, 64, 128
+HEADS, DK, DV = 4, 64, 192
+#: the state at rest: two heads of 192 lanes a row, three whole lane tiles
+STATE = (SLOTS, HEADS // 2, DK, 2 * DV)
+#: the cell's: 64 slots, 30 heads, d_k 96, d_v 192
+PUBLISHED = (64, 30, 96, 192)
 
 
 @pytest.fixture(scope="module")
 def programs():
     """The prefill, the decode step and the slot write of a 4-layer model
-    (3 mixers, 1 full-attention layer) at lane-aligned widths (d_v and the
-    attention heads 128), lowered for a described v5e with the kernels'
-    gates answering as on a TPU."""
+    (3 mixers, 1 full-attention layer) at the published lane widths (d_v
+    192: two heads a row of the state; the attention heads 128), lowered
+    for a described v5e with the kernels' gates answering as on a TPU; and
+    the update kernel alone at the cell's shape, on the state as it rests
+    and on a head a row."""
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
     from jax.sharding import SingleDeviceSharding
@@ -587,8 +795,22 @@ def programs():
     row = on(jnp.zeros((eng.max_blocks_per_slot,), jnp.int32))
     params = on(ff.params)
     _common.on_tpu = lambda: True
+
+    def update(state_shape):
+        from flexflow_tpu.kernels.gated_delta_rule import gated_delta_update
+
+        slots, heads, dk, dv = PUBLISHED
+        f32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32,
+                                                  sharding=chip)
+        return (jax.jit(gated_delta_update, donate_argnums=(0,)),
+                (f32(*state_shape), f32(slots, heads, dk),
+                 f32(slots, heads, dk), f32(slots, heads, dv),
+                 f32(slots, heads), f32(slots, heads)))
+
     try:
         yield eng, {
+            "update_at_rest": update((64, 15, 96, 384)),
+            "update_a_head_a_row": update(PUBLISHED),
             "prefill": (ff.executor.make_prefill_step(128, 129),
                         (params, [x], one)),
             "decode_step": (eng._decode_fn(), (
@@ -601,10 +823,45 @@ def programs():
         jax.config.update("jax_enable_compilation_cache", before)
 
 
-def compiled_text(programs, name):
+def compiled(programs, name):
     fn, args = programs[1][name]
-    return fn.trace(*args).lower(
-        lowering_platforms=("tpu",)).compile().as_text()
+    return fn.trace(*args).lower(lowering_platforms=("tpu",)).compile()
+
+
+def compiled_text(programs, name):
+    return compiled(programs, name).as_text()
+
+
+def async_copies_of(text, shape):
+    """The compiled program's asynchronous copies that carry ``shape``."""
+    return [line for line in text.splitlines() if shape in line
+            and re.search(r"\b(copy|slice|dynamic-slice)-start\(", line)]
+
+
+@pytest.mark.parametrize("name,lanes", [("update_at_rest", 384),
+                                        ("update_a_head_a_row", 256)])
+def test_update_kernel_rests_its_state_where_it_is_put(programs, name,
+                                                       lanes):
+    """``gated_delta_update`` alone at the cell's shape, through Mosaic for
+    a described v5e: the state parameter keeps the layout it is handed
+    (``{3,2,1,0}`` in ``(8, 128)`` tiles), is aliased onto the result, and
+    the program holds no temporary. Two heads a row the arguments are the
+    matrices' own bytes; a head a row (PR 46's shape, the form ``p = 1``
+    keeps for an odd count of heads) they are a third more."""
+    c = compiled(programs, name)
+    text, ma = c.as_text(), c.memory_analysis()
+    state = programs[1][name][1][0].shape
+    shape = "f32[%d,%d,%d,%d]" % state
+    assert re.search(re.escape(shape) + r"\{3,2,1,0:T\(8,128\)\} "
+                     r"parameter\(0\)", text)
+    assert "gated_delta_update" in kernels_in(text)
+    assert ma.temp_size_in_bytes == 0
+    at_rest = state[0] * state[1] * state[2] * lanes * 4
+    assert ma.alias_size_in_bytes == at_rest
+    assert (lanes == 384) == (at_rest == 64 * 30 * 96 * 192 * 4)
+    assert at_rest < ma.argument_size_in_bytes < at_rest + 5e6
+    assert not async_copies_of(text, shape) and not re.search(
+        r"= " + re.escape(shape) + r"\{[^}]*\} copy\(", text)
 
 
 @pytest.mark.parametrize("name", ["decode_step", "slot_write"])
@@ -622,13 +879,13 @@ def test_pool_and_state_are_written_in_place(programs, name):
     channels = HEADS * (2 * DK + DV)
     shapes = {
         "pool": f"bf16[{POOL_BLOCKS},4,16,256]",
-        "state": f"f32[{SLOTS},{HEADS},{DK},{DV}]",
+        "state": "f32[%d,%d,%d,%d]" % STATE,
         "tail": f"bf16[{SLOTS},{3 * channels}]",
     }
     want = {"pool": 1, "state": DEPTH - 1, "tail": DEPTH - 1}
     assert sorted(leaf.shape for leaf in jax.tree.leaves(
         eng.state.caches)) == sorted(
-            [(POOL_BLOCKS, 4, 16, 256)] + [(SLOTS, HEADS, DK, DV)] * 3
+            [(POOL_BLOCKS, 4, 16, 256)] + [STATE] * 3
             + [(SLOTS, 3 * channels)] * 3)
     for kind, shape in shapes.items():
         leaves = {int(n) for n in re.findall(
@@ -639,6 +896,111 @@ def test_pool_and_state_are_written_in_place(programs, name):
         ops = set(re.findall(
             r"= " + re.escape(shape) + r"\{[^}]*\} ([\w-]+)\(", text))
         assert "copy" not in ops, f"{name} copies a {kind} leaf: {ops}"
+    # the state rests as the update computes on it, in whole tiles (at
+    # these 1.5 MB a layer the compiler prefetches a leaf into fast memory
+    # beside other ops; at the cell's 142 MB it cannot:
+    # test_the_cells_decode_step_rests_its_state_two_heads_a_row)
+    assert len(re.findall(re.escape(shapes["state"])
+                          + r"\{3,2,1,0:T\(8,128\)\} parameter\(", entry)) \
+        == DEPTH - 1
+
+
+def test_the_cells_decode_step_rests_its_state_two_heads_a_row(programs):
+    """The ``olmo-hybrid-7b-assist`` cell's decode step — its widths, slots,
+    pool and vocabulary at depth 4, three mixers and a full-attention layer,
+    no weight ever made — compiled for a described v5e: each state leaf is
+    ``f32[64,15,96,384]`` in whole ``(8, 128)`` tiles, aliased onto the
+    step's result, and nothing carries it but the update itself — no copy
+    into or out of it and NO asynchronous copy, so the time the benchmark's
+    ``gdn_state_roofline`` reads under ``l_gdnrule`` is the whole of the
+    state's and the share cannot pass 100%."""
+    from flexflow_tpu import FFConfig, FFModel, LossType
+    from flexflow_tpu.execution.executor import Executor
+    from flexflow_tpu.models.olmo_hybrid import (OlmoHybridConfig,
+                                                 build_olmo_hybrid)
+    from flexflow_tpu.serving import ServingEngine
+    from flexflow_tpu.serving.kvcache import DecodeState
+
+    with open(os.path.join(BENCH, "workloads",
+                           "olmo-hybrid-7b-assist.json")) as f:
+        cell = json.load(f)
+    with open(os.path.join(BENCH, "configs", "olmo-hybrid-7b.json")) as f:
+        config = json.load(f)
+    chip = programs[1]["update_at_rest"][1][0].sharding
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                    sharding=chip)
+    kwargs = {field: config[key]
+              for field, key in config["builder"]["fields"].items()}
+    kwargs.update(batch_size=8, layer_types=config["layer_types"][:DEPTH])
+    cfg = OlmoHybridConfig(**kwargs)
+    ffc = FFConfig()
+    ffc.parse_args(["-b", "8"] + config["compile_flags"]
+                   + cell["compile_flags"])
+
+    def shapes(self, seed=0):
+        out = {}
+        for node, wname, shape, _dtype, _init in self.weight_entries():
+            out.setdefault(node.name, {})[wname] = sds(tuple(shape),
+                                                       jnp.bfloat16)
+        return out
+
+    real_init = Executor.init_params
+    Executor.init_params = shapes
+    try:
+        ff = FFModel(ffc)
+        build_olmo_hybrid(ff, cfg)
+        ff.compile(loss_type=LossType.LOSS_SPARSE_CATEGORICAL_CROSSENTROPY)
+    finally:
+        Executor.init_params = real_init
+    e = cell["engine"]
+    eng = ServingEngine(ff, n_slots=e["n_slots"],
+                        max_decode_len=e["max_decode_len"],
+                        kv_pool_blocks=e["kv_pool_blocks"],
+                        buckets=tuple(e["buckets"]))
+    # the decode state, from the shapes a prefill hands the slots
+    b0 = eng.buckets[0]
+    cache = jax.eval_shape(eng._prefill_fn(b0), ff.params,
+                           [sds((1, b0), jnp.int32)],
+                           sds((1,), jnp.int32))[2]
+    state_shape = (e["n_slots"], 15, 96, 384)
+    caches, eng._paged_entry_names = {}, set()
+    for name, entry in cache.items():
+        if "_attn" in name:
+            eng._paged_entry_names.add(name)
+            caches[name] = sds((eng.kv_pool_blocks, cfg.num_heads,
+                                eng.kv_block_size, 2 * cfg.head_dim),
+                               jnp.bfloat16)
+        else:
+            tail, s = entry
+            assert s.shape == (1,) + state_shape[1:]
+            caches[name] = tuple(sds((eng.n_slots,) + leaf.shape[1:],
+                                     leaf.dtype) for leaf in (tail, s))
+    state = DecodeState(
+        caches=caches, lengths=sds((eng.n_slots,), jnp.int32),
+        block_tables=sds((eng.n_slots, eng.max_blocks_per_slot), jnp.int32))
+    c = eng._decode_fn(guard=False).trace(
+        ff.params, [sds((eng.n_slots, 1), jnp.int32)], state).lower(
+            lowering_platforms=("tpu",)).compile()
+    text = c.as_text()
+    entry = text[text.index("\nENTRY "):]
+    header = text[:text.index("\n")]
+    start = header.index("input_output_alias={")
+    aliased = {int(n) for n in re.findall(
+        r"\}: \((\d+), ", header[start:header.index(" }", start)])}
+    shape = "f32[%d,%d,%d,%d]" % state_shape
+    leaves = {int(n) for n in re.findall(
+        r"= " + re.escape(shape) + r"\{3,2,1,0:T\(8,128\)\} "
+        r"parameter\((\d+)\)", entry)}
+    assert len(leaves) == DEPTH - 1 and leaves <= aliased
+    ops = set(re.findall(
+        r"= " + re.escape(shape) + r"\{[^}]*\} ([\w-]+)\(", text))
+    assert ops <= {"parameter", "get-tuple-element"}, ops
+    assert not async_copies_of(text, shape)
+    assert {"gated_delta_update", "flash_decode", "kv_write"} \
+        <= kernels_in(text)
+    # the step's temporaries: the logits and a layer's activations, a
+    # fifth of ONE mixer's state over the slots
+    assert c.memory_analysis().temp_size_in_bytes < 64 * 15 * 96 * 384 * 4 / 4
 
 
 def test_scopes_and_kernels_are_in_the_compiled_programs(programs):
