@@ -38,7 +38,7 @@ ROOT = os.path.dirname(os.path.abspath(__file__))
 TRAIN_STEPS = 8          # first fit(): step 0 compiles
 TRAIN_STEPS_AGAIN = 2    # second fit() on the same model (donated buffers)
 # Adam's first steps move every one of 335M weights by the learning rate: at
-# the 1e-4 bench.py times with, one step saturates the 2-class softmax and the
+# 1e-4 (the seed round's rate) one step saturates the 2-class softmax and the
 # loss sits at the clip (0.75 -> 17.3 on the CPU, 1.15 -> 6.9 on the chip) —
 # the recipe, not the device. At 1e-6 the loss falls step by step.
 TRAIN_LR = 1e-6

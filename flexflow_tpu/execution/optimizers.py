@@ -108,10 +108,10 @@ class AdamOptimizer(Optimizer):
     m/v moments in a reduced dtype (e.g. ``jnp.bfloat16``). The update math
     stays f32 (moments are upcast, the fresh values rounded once at store),
     but the optimizer's HBM traffic drops from ~28 to ~16 bytes/param —
-    Adam is HBM-bound at double-digit % of a BERT-Large step (BASELINE.md
-    breakdown), so this is a measured throughput knob. None (default) keeps
-    exact reference numerics; the bench's headline always uses None and
-    reports the extension as a separate leg."""
+    Adam is fused into the weight-gradient fusions of a BERT-Large step
+    (PERF.md §5), so what the knob buys there is not separable, and no cell
+    of the benchmark sets it. None (default) keeps exact reference numerics,
+    and every cell of the benchmark trains with None."""
 
     def __init__(self, ffmodel=None, alpha: float = 0.001, beta1: float = 0.9,
                  beta2: float = 0.999, weight_decay: float = 0.0,

@@ -469,7 +469,7 @@ class Simulator:
             self._table_cache.popitem(last=False)
 
     def cache_stats(self) -> Dict[str, Any]:
-        """Hit/miss counters for the SearchLog/tracer and bench.py."""
+        """Hit/miss counters for the SearchLog and the tracer."""
         total = self.cost_cache_hits + self.cost_cache_misses
         return {
             "cost_cache_hits": self.cost_cache_hits,

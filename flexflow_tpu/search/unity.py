@@ -168,7 +168,7 @@ class SearchResult:
     remat: str = "none"
     # delta-cost engine telemetry, filled by unity_search: total search wall
     # seconds, number of costed candidates, and the Simulator's cache
-    # hit/miss counters (bench.py's search_wall_s / search_candidates_per_s)
+    # hit/miss counters (the search log's search_wall_s, PERF.md §3 search_s)
     search_wall_s: Optional[float] = None
     candidates: int = 0
     cache_stats: Optional[Dict] = None
@@ -1745,7 +1745,7 @@ def unity_search(pcg: PCG, config, n_dev: int,
     t_search0 = time.perf_counter()
     # snapshot the cache counters: the reported stats must be THIS search's
     # deltas, not the Simulator's lifetime totals (a shared sim arrives
-    # pre-warmed by calibration or baseline costing — bench.py does both)
+    # pre-warmed by calibration or by costing a baseline plan first)
     cache0 = (sim.cost_cache_hits, sim.cost_cache_misses,
               sim.table_hits, sim.table_misses)
     with _log.scope("unity_search n_dev=%d" % n_dev), \
@@ -1924,7 +1924,7 @@ def unity_search(pcg: PCG, config, n_dev: int,
                                 pod_plan=pipe_pods)
 
     # delta-cost engine telemetry: wall time, throughput and cache counters
-    # land on the SearchResult (bench.py's search_wall_s metric) and in the
+    # land on the SearchResult (search_wall_s, the cell's search_s) and in the
     # final SearchLog record
     search_wall_s = time.perf_counter() - t_search0
     candidates = sum(slog.counts.get(k, 0) for k in

@@ -1,8 +1,8 @@
 """Where XLA's persistent compilation cache lives.
 
-``FFModel.compile`` — which every ``ServingEngine``, example and bench leg
-has behind it — and the scripts that jit before they build a model
-(``bench.py``, ``chip_smoke.py``) call :func:`ensure_compile_cache` before
+``FFModel.compile`` — which every ``ServingEngine``, example and benchmark
+cell has behind it — and the script that jits before it builds a model
+(``chip_smoke.py``) call :func:`ensure_compile_cache` before
 the first compile, so a second process, or a second run on the same machine,
 loads BERT-Large's train step and every serving bucket instead of compiling
 them again.

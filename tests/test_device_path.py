@@ -156,21 +156,6 @@ def test_chip_smoke_refuses_the_cpu_within_seconds():
     assert '"ok"' not in r.stdout  # no result line without a chip
 
 
-def test_bench_prints_then_exits_nonzero_when_a_leg_errored(capsys):
-    sys.path.insert(0, _REPO)
-    import bench
-
-    bench._report({"metric": "m", "value": 1.0})  # clean: returns
-    with pytest.raises(SystemExit) as exc:
-        bench._report({"metric": "m", "dlrm_leg_error": "Boom: x",
-                       "mem_check_error_seq4096": "Boom: y"})
-    assert exc.value.code not in (0, None)
-    assert "dlrm_leg_error" in str(exc.value.code)
-    assert "mem_check_error_seq4096" in str(exc.value.code)
-    printed = capsys.readouterr().out.strip().splitlines()
-    assert len(printed) == 2 and '"dlrm_leg_error"' in printed[1]
-
-
 def test_config_lets_a_failing_device_query_propagate(monkeypatch):
     from flexflow_tpu import FFConfig
 

@@ -1,7 +1,7 @@
 """Round-11 housekeeping (ISSUE 9 satellites): the bounded ServingStats
 reservoir, the ServingRejection hierarchy, the new serving-resilience
 flags' parse-time validation, the telemetry serving_resilience block +
-trace_summary digest, and the docs/bench wiring."""
+trace_summary digest, and the docs wiring."""
 import os
 import subprocess
 import sys
@@ -133,8 +133,8 @@ def test_telemetry_block_absent_for_clean_runs():
     assert "serving" in tel.summary()
 
 
-# ------------------------------------------------------------- docs / bench
-def test_docs_and_bench_wiring():
+# -------------------------------------------------------------------- docs
+def test_docs_wiring():
     with open(os.path.join(_REPO, "docs", "serving.md")) as f:
         serving_md = f.read()
     assert "Serving under failure" in serving_md
@@ -143,10 +143,6 @@ def test_docs_and_bench_wiring():
     with open(os.path.join(_REPO, "docs", "fault_tolerance.md")) as f:
         ft_md = f.read()
     assert "poison_decode_at" in ft_md and "serving.md" in ft_md
-    with open(os.path.join(_REPO, "bench.py")) as f:
-        bench = f.read()
-    assert "serving_degraded_tokens_per_s" in bench
-    assert "serving_degraded_vs_clean" in bench
 
 
 def test_check_docs_flags_green():

@@ -3,10 +3,6 @@
 * `--serve-loop` flag: parse-time validation, preflight validation of
   programmatic assignment, documented in python_api.md
   (check_docs_flags stays green).
-* both serving bench legs emit `host_overhead_fraction` for whichever
-  loop ran plus a `serve_loop` key identifying it, and the sync-vs-
-  async comparison keys (static pin — the full legs are too heavy for
-  tier-1, the r14 idiom).
 * host-overhead math with the ISSUE 17 overlap bucket: overlapped host
   work widens the DENOMINATOR only; with no overlap the r16 fraction
   is unchanged.
@@ -49,29 +45,6 @@ def test_serve_loop_flag_documented():
 
     assert check_docs_flags.main([]) == 0
     assert "--serve-loop" in _read("docs/python_api.md")
-
-
-# ----------------------------------------------------------------- bench
-def test_bench_serving_legs_emit_serve_loop_and_hof_keys():
-    """Both serving bench legs identify the loop that ran and carry the
-    sync-vs-async host-overhead comparison (static pin)."""
-    src = _read("bench.py")
-    for key in (
-            # serving leg: headline loop id + comparison sub-leg
-            # (the per-loop keys are f-string emissions over
-            # ("sync", "async") — pinned as templates below)
-            "serving_serve_loop", "serving_host_overhead_fraction",
-            'f"serving_{loop}_tokens_per_s"',
-            "serving_loop_cpu_simulated", "serving_async_hof_vs_sync",
-            "serving_async_hof_below_sync", "serving_async_host_syncs",
-            # fleet leg: loop id + async sub-run
-            "fleet_serve_loop", "fleet_host_overhead_fraction",
-            "fleet_sync_host_overhead_fraction",
-            "fleet_async_host_overhead_fraction",
-            "fleet_async_host_syncs"):
-        assert key in src, f"bench key {key} missing"
-    # the f-string emission covers both loops' hof keys
-    assert 'f"serving_{loop}_host_overhead_fraction"' in src
 
 
 # ------------------------------------------------------------- accounting

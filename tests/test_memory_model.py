@@ -8,11 +8,11 @@ reference: per-device memory validation vs the framebuffer budget,
 activations x2 + weights x4) overshot by 1.78x, biasing every memory-lambda
 feasibility call toward false-infeasible.
 
-The XLA peaks pinned here were measured on a real v5e this round (bench.py's
-mem legs re-measure them live every round — keys mem_analytic_vs_xla{,_
-seq4096,_dlrm} in BENCH_r05); CPU-compiled peaks use a different buffer
-assignment and are NOT comparable, so this test validates the analytic side
-against the recorded chip numbers."""
+The XLA peaks pinned here were measured on a v5e in 2026-07 (round 5) and
+nothing measures the analytic model beside XLA's peak since (ROADMAP S6; the
+benchmark's cells record peak_hbm_gb alone, PERF.md §3); CPU-compiled peaks
+use a different buffer assignment and are NOT comparable, so this test
+validates the analytic side against the recorded chip numbers."""
 import pytest
 
 from flexflow_tpu import AdamOptimizer, DataType, FFConfig, FFModel, LossType

@@ -6,8 +6,8 @@ grounded memory model — infeasible on v5e's 16 GiB. The search must find a
 feasible strategy itself. Activations dominate and shard identically under
 every (dp, tp) factorization, so the escapes are GPipe microbatching (live
 activations / n_micro) and — since ISSUE 3 — activation rematerialization
-(saved bytes x keep-fraction, a few percent recompute); bench.py's
-memsearch leg records the same regime and the dryrun executes a
+(saved bytes x keep-fraction, a few percent recompute); no cell of the
+benchmark sits in this regime yet (ROADMAP S6) and the dryrun executes a
 budget-forced winner end-to-end."""
 from flexflow_tpu import FFConfig, FFModel
 from flexflow_tpu.models.bert import BertConfig, build_bert

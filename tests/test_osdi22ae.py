@@ -26,8 +26,8 @@ def test_protocol_runs_both_modes():
 def test_searched_beats_dp_in_simulation_bert_and_dlrm():
     """The artifact's headline claim (searched >= DP on the same hardware,
     scripts/osdi22ae/bert.sh + dlrm.sh) asserted on the simulator for both
-    workloads; the bench harness repeats it with device-calibrated costs on
-    the real chip (BENCH keys searched_vs_dp_8chip_sim)."""
+    workloads; on chips the comparison is still unmeasured (ROADMAP S6:
+    the one four-chip cell's searched plan is data-parallel)."""
     from flexflow_tpu import FFConfig, FFModel, LossType
     from flexflow_tpu.models import BertConfig, build_bert, build_dlrm
     from flexflow_tpu.search.machine_model import TPUMachineModel
@@ -65,7 +65,7 @@ def test_dlrm_claim_first_principles_envelope():
     bytes/bandwidth envelope so the headline cannot swing with cost-model
     edits (it went 27.5x -> 19.8x -> 7.2x across rounds while unanchored).
 
-    Bench config (bench.py DLRM leg): batch 64, 8 tables x 200000 x 64
+    Config (the seed round's DLRM chip run): batch 64, 8 tables x 200000 x 64
     f32, v5e-8 (ici 50 GB/s/link, (2,4) torus -> 4 concurrent ring links
     for the full 8-chip group; HBM 819 GB/s x 0.8 eff; Adam update moves
     ~7 bytes per weight byte — optimizer_kernel.cu analog).

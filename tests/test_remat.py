@@ -7,8 +7,8 @@ decreasing under `full` remat on a seq-scaled model, cost-model/plan
 plumbing, and the
 λ-remix counter contract with remat-extended keys.
 
-Slow tier (marked): the BERT-Large 8-dev remat × memory-search sweep — the
-bench acceptance leg (dp8+remat beats the pipeline bubble) under the
+Slow tier (marked): the BERT-Large 8-dev remat × memory-search sweep —
+ISSUE 3's acceptance case (dp8+remat beats the pipeline bubble) under the
 FLEXFLOW_TPU_SEARCH_SELFCHECK equivalence gate.
 """
 import numpy as np
@@ -280,12 +280,12 @@ def test_memory_search_with_remat_axis_finds_feasible_cheaper_plan(
 
 @pytest.mark.slow
 def test_bert_large_8dev_remat_beats_pipeline_bubble(monkeypatch):
-    """The bench acceptance leg (ISSUE 3): BERT-Large b512 on 8 v5e chips —
+    """ISSUE 3's acceptance case: BERT-Large b512 on 8 v5e chips —
     dp8 needs 19.45 GiB (infeasible); pre-remat the search fell back to a
-    GPipe plan 1.8x slower than dp8 (memsearch_vs_dp_time 0.547 in
-    BENCH_r05). With the remat axis the winner must be feasible AND
-    markedly closer to dp8 speed, under the selfcheck gate, with the λ
-    sweeps still pure remixes."""
+    GPipe plan 1.8x slower than dp8 (0.547 of its speed in the simulator,
+    the seed round's record). With the remat axis the winner must be
+    feasible AND markedly closer to dp8 speed, under the selfcheck gate,
+    with the λ sweeps still pure remixes."""
     import json
 
     monkeypatch.setenv(SELFCHECK_ENV, "1")
