@@ -98,6 +98,14 @@ SSM_OUT_TOL = 2.0e-2
 # program, where SSM_TOL's reads 1.8 times).
 GDN_TOL = 4.7e-3
 GDN_OUT_TOL = 2.0e-2
+# One latent-attention node (ops/latent_attention.py) at GigaChat3.5's widths
+# (64 heads, YaRN, neighbour pairs, the gate) vs the plain float32 reference
+# at positions past the original context: the relative L2 error of the node's
+# OUTPUT over the chunk rows (the absorbed chunk read) and the decode rows
+# (the absorbed decode read). Set between the sound program's reading and the
+# control's, the reference with ``k_r`` left unrotated (PERF.md section 6,
+# PR 53, has both readings).
+LATENT_TOL = 2.0e-2
 
 
 def info(msg: str) -> None:
@@ -518,7 +526,8 @@ def check_routed_layer(tokens: int = 8192, d: int = 2048,
 
 
 def check_routed_layer_decode(rows: int = 64, d: int = 7680,
-                              inter: int = 2048, lead: float = 4.0) -> None:
+                              inter: int = 2048, lead: float = 4.0,
+                              limit: float = 0.0) -> None:
     """The same four nodes at the DECODE shapes of the benchmark's
     ``openpangu-ultra-docqa-8k`` cell (64 rows of 7,680, one a slot; 256
     experts, top-8 of the sigmoid scores alone scaled 2.5, experts 0-15
@@ -532,7 +541,9 @@ def check_routed_layer_decode(rows: int = 64, d: int = 7680,
     router columns: at 4 they draw more pairs than the layer's row bound
     (128 of the 512) and the whole-buffer fallback is what is held; at 1
     the routing is the cell's kind (32 pairs expected) and both the sound
-    and the planted products run on the bounded path."""
+    and the planted products run on the bounded path. ``limit``: the
+    experts' SwiGLU clamped there (the ``gigachat35-reasoning-2k`` cell's
+    form), with the experts' first two matrices scaled so that it bites."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -548,7 +559,8 @@ def check_routed_layer_decode(rows: int = 64, d: int = 7680,
         "r", dict(ids, k=k, route_scale=scale, selection_bias=False), bf16)
     dispatch_op = moe_ops.MoEDispatchOp("d", ids, bf16, 2)
     experts_op = moe_ops.MoERoutedExpertsOp(
-        "e", dict(ids, intermediate=inter), bf16, 2)
+        "e", dict(ids, intermediate=inter,
+                  **({"limit": limit} if limit else {})), bf16, 2)
     combine_op = moe_ops.MoECombineOp("c", ids, bf16, 4)
     keys = jax.random.split(jax.random.PRNGKey(5), 5)
 
@@ -561,8 +573,11 @@ def check_routed_layer_decode(rows: int = 64, d: int = 7680,
     kernel = normal(keys[1], (d, n), (2.0 / (d + n)) ** 0.5)
     kernel = kernel.at[:, :held[1]].multiply(lead)
     std = (2.0 / (d + inter)) ** 0.5
-    experts = {"gate": normal(keys[2], (held[1], d, inter), std),
-               "up": normal(keys[3], (held[1], d, inter), std),
+    # under a limit: gate and up products of std 8 and 12 against 10
+    experts = {"gate": normal(keys[2], (held[1], d, inter),
+                              std * (8.0 if limit else 1.0)),
+               "up": normal(keys[3], (held[1], d, inter),
+                            std * (12.0 if limit else 1.0)),
                "down": normal(keys[4], (held[1], inter, d), std)}
 
     def system(x, experts, product):
@@ -590,14 +605,18 @@ def check_routed_layer_decode(rows: int = 64, d: int = 7680,
                                                **kw))[0])(x, experts)
     xf = x.astype(jnp.float32)
     wf = w.astype(jnp.float32)
+    bites = []
     with jax.default_matmul_precision("highest"):
         y_ref = jnp.zeros_like(xf)
         for m in range(held[1]):
             w_e = jnp.sum(jnp.where(chosen == held[0] + m, wf, 0.0), axis=-1)
             g, u, dn = (experts[name][m].astype(jnp.float32)
                         for name in ("gate", "up", "down"))
-            y_ref = y_ref + w_e[..., None] * (
-                (jax.nn.silu(xf @ g) * (xf @ u)) @ dn)
+            gx, ux = xf @ g, xf @ u
+            if limit:
+                bites.append(jnp.mean((gx > limit) | (jnp.abs(ux) > limit)))
+                gx, ux = jnp.minimum(gx, limit), jnp.clip(ux, -limit, limit)
+            y_ref = y_ref + w_e[..., None] * ((jax.nn.silu(gx) * ux) @ dn)
     here = int(np.isin(np.asarray(chosen), np.arange(*held)).sum())
     live = int((np.asarray(stats["tokens_per_expert"]) > 0).sum())
     bound = moe_ops._row_bound(rows * k, ids)
@@ -610,6 +629,11 @@ def check_routed_layer_decode(rows: int = 64, d: int = 7680,
           f"expert) pairs are held here, {live} of {held[1]} held experts "
           f"got a row, none dropped; against a bound of {bound} rows they "
           f"took the {path}")
+    if limit:
+        share = float(np.mean([float(b) for b in bites]))
+        check(share > 0.2, f"routed layer at decode shapes: the clamp at "
+              f"{limit:g} bites on {share:.0%} of the gate / up products")
+
     def rel_l2(got):
         got = np.asarray(got, np.float32)
         return float(np.linalg.norm(got - np.asarray(y_ref))
@@ -625,7 +649,20 @@ def check_routed_layer_decode(rows: int = 64, d: int = 7680,
           f"three mantissa bits are not ({planted:.4f})")
 
 
-def _check_mixer_layer(what: str, op, reference_file: str, mixer: str,
+def _load_reference(reference_file: str):
+    """A plain reference of ``benchmark/reference/`` by its file's name."""
+    import importlib.util
+
+    spec = importlib.util.spec_from_file_location(
+        "smoke_reference", os.path.join(
+            os.path.dirname(os.path.abspath(__file__)), "benchmark",
+            "reference", reference_file))
+    ref = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(ref)
+    return ref
+
+
+def _check_mixer_layer(what: str, op, reference_file: str, mixer,
                        config: dict, d: int, bucket: int, length: int,
                        steps: int, kernels: dict, key: int, tol: float,
                        out_tol: float, state_of=lambda s: s) -> None:
@@ -634,15 +671,16 @@ def _check_mixer_layer(what: str, op, reference_file: str, mixer: str,
     handed on is the one after the last REAL token) and ``steps`` decode
     steps from that state, against the plain reference's whole-sequence
     mixer (``reference_file``'s function ``mixer``) in float32 over prompt +
-    steps tokens. The serving cells' own comparison sees a precision fault
+    steps tokens (``mixer``: its name there, called as the olmo-hybrid and
+    jamba references take it, or a callable ``(ref, u, params, config,
+    fault)`` -> (output, last state)). The serving cells' own comparison
+    sees a precision fault
     only where it moves a served token (PERF.md section 7); this one reads
     the node's output and the state it hands on. The control — the reference
     with its state rounded to bf16 every step — must read over the limit.
     ``kernels``: the Mosaic calls the compiled ``prefill`` / ``decode``
     programs must hold. ``state_of``: the state as the slot rests it -> the
     shape the reference hands back."""
-    import importlib.util
-
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -650,12 +688,7 @@ def _check_mixer_layer(what: str, op, reference_file: str, mixer: str,
     from flexflow_tpu.ops.base import OpContext
     from flexflow_tpu.serving.kvcache import ServingState
 
-    spec = importlib.util.spec_from_file_location(
-        "mixer_reference", os.path.join(
-            os.path.dirname(os.path.abspath(__file__)), "benchmark",
-            "reference", reference_file))
-    ref = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(ref)
+    ref = _load_reference(reference_file)
     keys = jax.random.split(jax.random.PRNGKey(key), 32)
     params = {name: init(keys[i], shape, jnp.bfloat16)
               for i, (name, (shape, _dt, init)) in enumerate(
@@ -667,6 +700,9 @@ def _check_mixer_layer(what: str, op, reference_file: str, mixer: str,
     def reference(fault, n):
         def run(u, params):
             with jax.default_matmul_precision("highest"):
+                if callable(mixer):
+                    return mixer(ref, u[0, :n].astype(jnp.float32), params,
+                                 config, fault)
                 return getattr(ref, mixer)(
                     u[0, :n].astype(jnp.float32), ref.f32(params), config,
                     fault, with_state=True)
@@ -762,28 +798,183 @@ def check_ssm_layer(d: int = 2560, inner: int = 5120, state: int = 16,
 
 def check_delta_layer(d: int = 3840, heads: int = 30, dk: int = 96,
                       dv: int = 192, conv: int = 4, bucket: int = 1024,
-                      length: int = 900, steps: int = 64) -> None:
+                      length: int = 900, steps: int = 64,
+                      key_heads: int = 0) -> None:
     """One gated delta-rule mixer at the ``olmo-hybrid-7b-assist`` cell's
     widths: the ``gated_delta_rule`` kernel's prefill and the
     ``gated_delta_update`` kernel's decode steps (``_check_mixer_layer``);
-    the state rests two heads a row and is read through ``unpack_state``."""
+    the state rests two heads a row and is read through ``unpack_state``.
+    ``key_heads``: the grouped form of the ``gigachat35-reasoning-2k``
+    cell (``check_delta_layer_grouped``), against that cell's reference."""
     from flexflow_tpu.ffconst import DataType, OperatorType
     from flexflow_tpu.kernels.gated_delta_rule import unpack_state
     from flexflow_tpu.ops.base import op_class_for
 
     eps = 1e-6
+    attrs = {"num_heads": heads, "key_dim": dk, "value_dim": dv,
+             "conv_width": conv, "neg_eigval": not key_heads,
+             "norm_eps": eps}
+    config = {"linear_num_key_heads": key_heads or heads,
+              "linear_key_head_dim": dk, "linear_value_head_dim": dv,
+              "linear_conv_kernel_dim": conv, "rms_norm_eps": eps}
+    if key_heads:
+        attrs.update(num_key_heads=key_heads, gate="sigmoid2_zero_centered")
+        config.update(linear_num_value_heads=heads,
+                      linear_attn_o_norm_eps=eps, linear_sigmoid_gate_scale=2)
+
+        def mixer(ref, u, params, config, fault):
+            return ref.delta_mixer(
+                u, _no_alternatives(), params, dict(config, fault=fault),
+                u.shape[0], with_state=True)
+
+        what, reference, key = "grouped delta layer", \
+            "gigachat35-432b-a28b.py", 53
+    else:
+        config["linear_allow_neg_eigval"] = True
+        what, reference, mixer, key = "delta layer", "olmo-hybrid-7b.py", \
+            "delta_mixer", 46
     op = op_class_for(OperatorType.OP_GATED_DELTA_MIXER)(
-        "l0_gdn", {"num_heads": heads, "key_dim": dk, "value_dim": dv,
-                   "conv_width": conv, "neg_eigval": True, "norm_eps": eps},
-        DataType.DT_BFLOAT16)
+        "l0_gdn", attrs, DataType.DT_BFLOAT16)
     _check_mixer_layer(
-        "delta layer", op, "olmo-hybrid-7b.py", "delta_mixer",
-        {"linear_num_key_heads": heads, "linear_key_head_dim": dk,
-         "linear_value_head_dim": dv, "linear_conv_kernel_dim": conv,
-         "linear_allow_neg_eigval": True, "rms_norm_eps": eps},
-        d, bucket, length, steps,
-        {"prefill": "gated_delta_rule", "decode": "gated_delta_update"}, 46,
+        what, op, reference, mixer, config, d, bucket, length, steps,
+        {"prefill": "gated_delta_rule", "decode": "gated_delta_update"}, key,
         GDN_TOL, GDN_OUT_TOL, state_of=lambda s: unpack_state(s, dv))
+
+
+def _no_alternatives():
+    """The gigachat reference's ``pos_a`` with no routing alternative."""
+    import jax.numpy as jnp
+
+    return jnp.zeros((0,), jnp.int32)
+
+
+def check_delta_layer_grouped() -> None:
+    """The ``gigachat35-reasoning-2k`` cell's delta-rule mixer: 32 key heads
+    under 64 value heads of (128, 128) — a head a row of the state at rest,
+    the path PR 49 left for 128 lanes — the sigmoid2 gate over the
+    zero-centred norm, a 2,048-row bucket."""
+    check_delta_layer(d=7168, heads=64, dk=128, dv=128, bucket=2048,
+                      length=1900, key_heads=32)
+
+
+def check_latent_layer(d: int = 7168, heads: int = 64, context: int = 36864,
+                       chunk: int = 1024, steps: int = 16,
+                       compare_from: int = 32768) -> None:
+    """One latent-attention node at the ``gigachat35-reasoning-2k`` cell's
+    widths (64 heads, q 1,536, a 512 + 64 row, YaRN factor 8 over 32,768,
+    neighbour pairs, the sigmoid gate), bf16, at positions PAST the original
+    context: ``context`` tokens chunk-prefilled into a latent pool (the
+    absorbed chunk read, ``latent_chunk_attention``), then ``steps`` decode
+    steps (``flash_decode``'s latent read at a 64-head row), against the
+    plain reference's materialised float32 attention over the whole
+    sequence. Compared: the rows at positions 32,768 and on. The control —
+    the reference with ``k_r`` left unrotated — must read over the limit."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from flexflow_tpu.ffconst import DataType, OperatorType
+    from flexflow_tpu.ops.base import OpContext, op_class_for
+    from flexflow_tpu.serving.kvcache import ServingState, new_kv_pool
+
+    ref = _load_reference("gigachat35-432b-a28b.py")
+    yarn = {"type": "yarn", "factor": 8, "beta_fast": 32, "beta_slow": 1,
+            "mscale": 1, "mscale_all_dim": 1,
+            "original_max_position_embeddings": 32768}
+    config = {"num_attention_heads": heads, "q_lora_rank": 1536,
+              "kv_lora_rank": 512, "qk_nope_head_dim": 128,
+              "qk_rope_head_dim": 64, "v_head_dim": 128,
+              "rope_theta": 100000.0, "rope_scaling": yarn,
+              "rope_interleave": True, "gated_attention": True,
+              "use_mla_scaling_factor": True, "rms_norm_eps": 1e-6}
+    op = op_class_for(OperatorType.OP_LATENT_ATTENTION)(
+        "l4_mla", {"embed_dim": d, "num_heads": heads, "q_rank": 1536,
+                   "kv_rank": 512, "nope_dim": 128, "rope_dim": 64,
+                   "v_dim": 128, "rope_theta": 100000.0, "eps": 1e-6,
+                   "causal": True, "rope_scaling": yarn,
+                   "rope_interleave": True, "gated": True},
+        DataType.DT_BFLOAT16)
+    keys = jax.random.split(jax.random.PRNGKey(53), 16)
+    params = {name: init(keys[i], shape, jnp.bfloat16)
+              for i, (name, (shape, _dt, init)) in enumerate(
+                  sorted(op.weight_specs([(1, chunk, d)]).items()))}
+    total, bs = context + steps, 16
+    u = jax.random.normal(keys[-1], (1, total, d), jnp.float32
+                          ).astype(jnp.bfloat16)
+    mb = -(-total // bs)
+    table = jnp.arange(1, mb + 1, dtype=jnp.int32)
+    pool = new_kv_pool(
+        (jnp.zeros((1, 1, bs, op.row_width), jnp.bfloat16),), mb + 1, bs,
+        "native")
+
+    @jax.jit
+    def chunk_step(params, x, pool, start):
+        sv = ServingState(mode="chunk", max_len=total, block_size=bs,
+                          positions=start[None],
+                          lengths=jnp.asarray([chunk], jnp.int32),
+                          cache_in={op.name: pool},
+                          block_tables=table[None])
+        out = op.forward(params, [x], OpContext(training=False,
+                                                serving=sv))[0]
+        return out[0], sv.cache_out[op.name]
+
+    @jax.jit
+    def decode(params, x, pool, pos):
+        sv = ServingState(mode="decode", max_len=total, block_size=bs,
+                          positions=pos[None], cache_in={op.name: pool},
+                          block_tables=table[None])
+        out = op.forward(params, [x], OpContext(training=False,
+                                                serving=sv))[0]
+        return out[0, 0], sv.cache_out[op.name]
+
+    first = compare_from // chunk
+    calls = mosaic_calls(chunk_step.lower(
+        params, u[:, :chunk], pool, jnp.int32(0)).compile().as_text()) \
+        | mosaic_calls(decode.lower(
+            params, u[:, :1], pool, jnp.int32(0)).compile().as_text())
+    check({"latent_chunk_attention", "flash_decode", "kv_write"} <= calls,
+          f"latent layer at {heads} heads: the chunk and decode steps run "
+          f"the Mosaic kernels ({sorted(calls)})")
+    got = []
+    for c in range(context // chunk):
+        rows, pool = chunk_step(params, u[:, c * chunk:(c + 1) * chunk],
+                                pool, jnp.int32(c * chunk))
+        if c >= first:
+            got.append(np.asarray(rows, np.float32))
+    for t in range(context, total):
+        r, pool = decode(params, u[:, t:t + 1], pool, jnp.int32(t))
+        got.append(np.asarray(r, np.float32)[None])
+    got = np.concatenate(got)
+
+    def reference(fault):
+        def run(u, params):
+            with jax.default_matmul_precision("highest"):
+                return ref.attention(
+                    u[0].astype(jnp.float32), _no_alternatives(), params,
+                    dict(config, fault=fault), total)[first * chunk:]
+
+        return np.asarray(jax.jit(run)(u, params))
+
+    want, control = reference(None), reference("k_r_unrotated")
+
+    def l2(a, b):
+        return float(np.linalg.norm(a - b) / np.linalg.norm(b))
+
+    parts = {"chunk rows": slice(0, context - first * chunk),
+             "decode rows": slice(context - first * chunk, None)}
+    sound = {k: l2(got[v], want[v]) for k, v in parts.items()}
+    planted = {k: l2(control[v], want[v]) for k, v in parts.items()}
+    info(f"latent layer at d {d}, {heads} heads, positions "
+         f"{first * chunk}-{total - 1} (YaRN past 32,768, neighbour pairs, "
+         f"gated): relative L2 error of the output: sound "
+         f"{sound['chunk rows']:.5f} (chunk rows), "
+         f"{sound['decode rows']:.5f} (decode rows); the reference with k_r "
+         f"unrotated {planted['chunk rows']:.5f}, "
+         f"{planted['decode rows']:.5f} (limit {LATENT_TOL})")
+    check(max(sound.values()) <= LATENT_TOL < min(planted.values()),
+          f"latent layer: the output past the original context is within "
+          f"{LATENT_TOL} of the float32 reference's, and the unrotated-k_r "
+          f"control is over it")
 
 
 # ------------------------------------------------------------------ trainer
@@ -989,8 +1180,11 @@ def main() -> None:
     check_routed_layer(skewed=True)
     check_routed_layer_decode()
     check_routed_layer_decode(lead=1.0)
+    check_routed_layer_decode(rows=128, d=7168, lead=1.0, limit=10.0)
     check_ssm_layer()
     check_delta_layer()
+    check_delta_layer_grouped()
+    check_latent_layer()
 
     n_chips = device["count"]
     bert = BertConfig(batch_size=8 * n_chips, seq_len=512, hidden=1024,

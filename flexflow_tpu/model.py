@@ -161,9 +161,15 @@ class FFModel:
              "eps": eps}, input.dtype, name)
 
     def rms_norm(self, input: Tensor, axes: Sequence[int] = (-1,),
-                 eps: float = 1e-6, name: Optional[str] = None) -> Tensor:
-        return self._add_layer(OperatorType.OP_RMSNORM, [input],
-                               {"axes": list(axes), "eps": eps},
+                 eps: float = 1e-6, name: Optional[str] = None,
+                 gain: Optional[str] = None) -> Tensor:
+        """``x / rms(x) * g(scale)``: ``g`` the identity, or a form of
+        ``ops.normalization.norm_gain`` (``gain="sigmoid2"``: ``2
+        sigmoid(scale)``, ``scale`` stored about zero)."""
+        attrs = {"axes": list(axes), "eps": eps}
+        if gain:
+            attrs["gain"] = gain
+        return self._add_layer(OperatorType.OP_RMSNORM, [input], attrs,
                                input.dtype, name)
 
     def batch_matmul(self, A: Tensor, B: Tensor,
@@ -228,13 +234,19 @@ class FFModel:
                          nope_dim: int, rope_dim: int, v_dim: int,
                          rope_theta: float = 10000.0, eps: float = 1e-6,
                          kernel_initializer=None,
-                         name: Optional[str] = None) -> Tensor:
+                         name: Optional[str] = None,
+                         rope_scaling: Optional[dict] = None,
+                         rope_interleave: bool = False,
+                         gated: bool = False) -> Tensor:
         """Causal multi-head latent attention (ops/latent_attention.py):
         queries through a ``q_rank`` bottleneck, keys and values
         up-projected from a ``kv_rank`` row a token that carries one shared
         rotary key of ``rope_dim`` beside it; heads of ``nope_dim +
         rope_dim`` for the score and ``v_dim`` for the value. Under a
-        serving context the row is what the KV pool holds."""
+        serving context the row is what the KV pool holds. Off by default:
+        ``rope_scaling`` (a YaRN group: its frequencies, and ``m^2`` on the
+        softmax scale), ``rope_interleave`` (neighbour pairs), ``gated`` (a
+        sigmoid gate a head on the core's output)."""
         attrs = {"embed_dim": embed_dim, "num_heads": num_heads,
                  "q_rank": q_rank, "kv_rank": kv_rank, "nope_dim": nope_dim,
                  "rope_dim": rope_dim, "v_dim": v_dim,
@@ -242,6 +254,16 @@ class FFModel:
                  "kernel_initializer": kernel_initializer}
         if rope_dim % 2:
             raise ValueError("latent_attention: rope_dim must be even")
+        if rope_scaling:
+            kind = rope_scaling.get("type", rope_scaling.get("rope_type"))
+            if kind != "yarn":
+                raise ValueError("latent_attention: rope_scaling of type "
+                                 f"{kind!r} is not built (yarn is)")
+            attrs["rope_scaling"] = dict(rope_scaling)
+        for attr, given in (("rope_interleave", rope_interleave),
+                            ("gated", gated)):
+            if given:
+                attrs[attr] = True
         return self._add_layer(OperatorType.OP_LATENT_ATTENTION, [input],
                                attrs, input.dtype, name)
 
@@ -270,14 +292,21 @@ class FFModel:
     def gated_delta_mixer(self, input: Tensor, num_heads: int, key_dim: int,
                           value_dim: int, conv_width: int, neg_eigval: bool,
                           norm_eps: float, kernel_initializer=None,
-                          name: Optional[str] = None) -> Tensor:
+                          name: Optional[str] = None,
+                          num_key_heads: Optional[int] = None,
+                          gate: str = "silu") -> Tensor:
         """Gated delta-rule (Gated DeltaNet) mixer (ops/gated_delta.py):
         ``num_heads`` heads, each with a ``(key_dim, value_dim)`` matrix
         state decayed by a gate and corrected by a rank-one delta a token,
         after causal depthwise convs of ``conv_width`` on q, k and v;
         ``neg_eigval`` lets the write strength reach 2. Every width is the
         caller's: no default stands in for one. Under a serving context the
-        state and the convs' last inputs are what a slot holds."""
+        state and the convs' last inputs are what a slot holds.
+        ``num_key_heads`` < ``num_heads``: grouped key heads (value head j
+        reads key head ``j // (num_heads / num_key_heads)``); ``gate``: the
+        output gate's form (``ops.gated_delta.GATES``)."""
+        from .ops.gated_delta import GATES
+
         attrs = {"num_heads": num_heads, "key_dim": key_dim,
                  "value_dim": value_dim, "conv_width": conv_width,
                  "neg_eigval": bool(neg_eigval), "norm_eps": norm_eps,
@@ -285,6 +314,17 @@ class FFModel:
         if conv_width < 2:
             raise ValueError(
                 "gated_delta_mixer: conv_width must be at least 2")
+        if num_key_heads and num_key_heads != num_heads:
+            if num_heads % num_key_heads:
+                raise ValueError(
+                    f"gated_delta_mixer: {num_heads} value heads are no "
+                    f"multiple of {num_key_heads} key heads")
+            attrs["num_key_heads"] = int(num_key_heads)
+        if gate not in GATES:
+            raise ValueError(f"gated_delta_mixer: gate {gate!r} is none of "
+                             f"{GATES}")
+        if gate != "silu":
+            attrs["gate"] = gate
         return self._add_layer(OperatorType.OP_GATED_DELTA_MIXER, [input],
                                attrs, input.dtype, name)
 
@@ -369,12 +409,15 @@ class FFModel:
         return self._unary(OperatorType.OP_SILU, x, name=name)
 
     def gated_mlp(self, x, intermediate: int, kernel_initializer=None,
-                  name=None) -> Tensor:
+                  name=None, limit: Optional[float] = None) -> Tensor:
         """``W_down(silu(W_gate x) * W_up x)``, no biases, as one node
-        (ops/linear.py GatedMLPOp)."""
-        return self._unary(OperatorType.OP_GATED_MLP, x,
-                           {"intermediate": intermediate,
-                            "kernel_initializer": kernel_initializer}, name)
+        (ops/linear.py GatedMLPOp). ``limit`` L: the clamped form,
+        ``silu(min(g, L)) * clip(u, -L, L)``."""
+        attrs = {"intermediate": intermediate,
+                 "kernel_initializer": kernel_initializer}
+        if limit:
+            attrs["limit"] = float(limit)
+        return self._unary(OperatorType.OP_GATED_MLP, x, attrs, name)
 
     def dropout(self, x, rate: float = 0.5, seed: int = 0, name=None):
         return self._unary(OperatorType.OP_DROPOUT, x,
@@ -573,7 +616,8 @@ class FFModel:
                        intermediate: int, held=None, route_norm: bool = True,
                        route_scale: float = 1.0, kernel_initializer=None,
                        name: str = "moe",
-                       selection_bias: bool = True) -> Tensor:
+                       selection_bias: bool = True,
+                       limit: Optional[float] = None) -> Tensor:
         """The dropless routed expert layer (ops/moe_ops.py): router
         (sigmoid scores) -> dispatch by a stable sort on expert id -> grouped products over the
         experts held here -> combine; no token is dropped, whatever the
@@ -583,7 +627,8 @@ class FFModel:
         four nodes are ``<name>router``, ``<name>dispatch``,
         ``<name>experts`` and ``<name>combine``. ``selection_bias=False``
         is a router with no ``expert_bias`` buffer: the 8 largest scores
-        are the choice."""
+        are the choice. ``limit``: the experts' gated MLP clamped as
+        ``gated_mlp``'s."""
         held = tuple(held) if held is not None else (0, num_experts)
         if not (0 <= held[0] and held[1] >= 1
                 and held[0] + held[1] <= num_experts):
@@ -603,7 +648,8 @@ class FFModel:
         out = self._add_layer(
             OperatorType.OP_MOE_ROUTED_EXPERTS, [rows, sizes],
             dict(ids, intermediate=intermediate,
-                 kernel_initializer=kernel_initializer),
+                 kernel_initializer=kernel_initializer,
+                 **({"limit": float(limit)} if limit else {})),
             input.dtype, f"{name}experts")
         return self._add_layer(
             OperatorType.OP_MOE_COMBINE, [out, order, weights, chosen],

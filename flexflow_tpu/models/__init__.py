@@ -8,7 +8,10 @@ built for the serving path (pangu.py), and the hybrid decoder of selective
 state-space layers with a one-K/V-head attention layer every period
 (jamba.py), built for the serving path too, and the hybrid decoder of gated
 delta-rule layers with a full-attention layer closing every period
-(olmo_hybrid.py), for the serving path as well."""
+(olmo_hybrid.py), for the serving path as well, and the hybrid decoder that
+holds both cache kinds in one graph: delta-rule layers with grouped key
+heads, one latent-attention layer closing every period, all over a routed
+expert layer under a clamped SwiGLU (gigachat.py), for the serving path."""
 from .bert import BertConfig, build_bert, bert_param_count  # noqa: F401
 from .gpt2 import (GPT2Config, build_gpt2,  # noqa: F401
                    gpt2_param_count, gpt2_train_flops_per_step)
@@ -29,3 +32,5 @@ from .jamba import (JambaConfig, build_jamba,  # noqa: F401
                     jamba_param_count)
 from .olmo_hybrid import (OlmoHybridConfig, build_olmo_hybrid,  # noqa: F401
                           olmo_hybrid_param_count)
+from .gigachat import (GigaChatConfig, build_gigachat,  # noqa: F401
+                       gigachat_param_count)

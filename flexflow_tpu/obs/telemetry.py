@@ -146,6 +146,10 @@ class StepTelemetry:
         # once, and the most heads a row of it holds
         self.serving_recurrent_state_bytes_at_rest: int = 0
         self.serving_state_heads_a_row: int = 0
+        # latent nodes' pool rows the decode steps folded, and the decode
+        # state's allocated bytes by kind of cache (ServingStats)
+        self.serving_latent_rows_read: int = 0
+        self.serving_cache_bytes_by_kind: Dict[str, int] = {}
         # one-shot prefills: the buckets' rows computed, and the real ones
         self.serving_prefill_rows: int = 0
         self.serving_prefill_rows_real: int = 0
@@ -382,6 +386,11 @@ class StepTelemetry:
             if self.serving_prefill_rows:
                 sv["prefill_rows"] = self.serving_prefill_rows
                 sv["prefill_rows_real"] = self.serving_prefill_rows_real
+            if self.serving_latent_rows_read:
+                sv["latent_rows_read"] = self.serving_latent_rows_read
+            if self.serving_cache_bytes_by_kind:
+                sv["cache_bytes_by_kind"] = dict(
+                    self.serving_cache_bytes_by_kind)
             out["serving"] = sv
         if self.fleet_replicas:
             total = max(sum(self.fleet_outcomes.values()), 1)

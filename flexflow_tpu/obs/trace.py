@@ -363,7 +363,9 @@ SPANS: Mapping[str, str] = MappingProxyType({
                   "moe_bounded_steps, moe_layer_steps (counted on the "
                   "device, fetched with its tokens); one of a graph with a "
                   "recurrent node recurrent_state_bytes (slot-major state "
-                  "the step read plus wrote) and recurrent_slots_live; a "
+                  "the step read plus wrote) and recurrent_slots_live; one "
+                  "of a graph with a latent-attention node latent_rows_read "
+                  "(the pool rows its latent reads folded); a "
                   "prefill tick prefill_rows (the bucket's rows, padding "
                   "included) and prefill_rows_real",
     "tick_dispatch": "tick entry -> the device call is issued: deadline "

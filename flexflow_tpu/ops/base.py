@@ -180,6 +180,22 @@ class Op:
         return f"{type(self).__name__}({self.name})"
 
 
+def no_chunk_carry(node: str, what: str, asked: str = "") -> str:
+    """The one sentence with which a graph that holds a recurrent node
+    refuses chunked prefill and the prefix cache: the engine's at
+    construction (``asked`` names the option) and each recurrent op's own
+    under a chunk, whatever else the graph holds (attention or latent
+    attention nodes beside it change nothing: their pool rows could be
+    chunked and shared, the state beside them cannot)."""
+    return (f"{asked + ': ' if asked else ''}chunked prefill and the prefix "
+            "cache support attention-only stateful graphs; this model has a "
+            f"recurrent node ({node}): {what} is a summary of the whole "
+            "prefix, not per-token pool rows, so a chunk would have to start "
+            "from a carried state and a prefix hit from a snapshot of one, "
+            "which the engine does not keep (ROADMAP.md, Reach R8): serve "
+            "without --prefill-chunk-tokens and with --prefix-cache off")
+
+
 def _freeze(v):
     if isinstance(v, (list, tuple)):
         return tuple(_freeze(x) for x in v)

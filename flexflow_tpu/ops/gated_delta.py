@@ -16,6 +16,15 @@ and head:
     y_t  = RMSNorm_{d_v}(o_t; w_n) * silu( (W_g u_t)_h )
     out_t = W_o concat_h y_t
 
+**Grouped key heads** (``num_key_heads`` ``H_k`` < ``H``, ``r = H / H_k``):
+``q'`` and ``k'`` are ``H_k d_k`` wide, value head ``j`` reads ``q^, k^`` of
+key head ``j // r``, and everything else — ``v``, the gate, ``b``, ``g``, the
+state — is a VALUE head's. ``q^`` and ``k^`` are repeated over their ``r``
+value heads after the norms (a few MB a step against the state's GB), so the
+rule and both kernels see ``H`` heads as ever. **The output gate** ``gate``:
+``"silu"`` above, or ``"sigmoid2_zero_centered"``: ``y_t = o_t / rms(o_t) *
+(1 + w_n) * 2 sigmoid((W_g u_t)_h)``, ``w_n`` stored about zero.
+
 What a sequence carries from token to token is ``S`` — ``H`` matrices of
 ``(d_k, d_v)``, float32 — and the last ``K - 1`` rows of ``q'``, ``k'`` and
 ``v'``: a fixed size whatever the context, which is what a serving engine
@@ -70,8 +79,11 @@ from __future__ import annotations
 
 from ..ffconst import OperatorType
 from .attention import _head_rms_norm, _inner_scope
-from .base import Op, OpContext, register_op
+from .base import Op, OpContext, no_chunk_carry, register_op
 from .ssm import _DtBiasInitializer, conv_tail_out
+
+#: the output gate's forms (``gate``)
+GATES = ("silu", "sigmoid2_zero_centered")
 
 
 class _ALogInitializer:
@@ -98,11 +110,13 @@ def l2_normalize(x, eps: float = 1e-6):
 
 @register_op(OperatorType.OP_GATED_DELTA_MIXER)
 class GatedDeltaMixerOp(Op):
-    """attrs: num_heads (H), key_dim (d_k), value_dim (d_v), conv_width (K),
-    neg_eigval, norm_eps. input (batch, seq, dim) -> same shape.
+    """attrs: num_heads (H, the value heads), num_key_heads (H_k, default
+    H), key_dim (d_k), value_dim (d_v), conv_width (K), neg_eigval,
+    norm_eps, gate (``GATES``, default "silu"). input (batch, seq, dim) ->
+    same shape.
 
-    Weights: ``w_q``, ``w_k`` (dim, H d_k), ``w_v``, ``w_g`` (dim, H d_v),
-    ``w_a``, ``w_b`` (dim, H), ``conv_w`` (2 H d_k + H d_v, K), ``a_log``,
+    Weights: ``w_q``, ``w_k`` (dim, H_k d_k), ``w_v``, ``w_g`` (dim, H d_v),
+    ``w_a``, ``w_b`` (dim, H), ``conv_w`` (2 H_k d_k + H d_v, K), ``a_log``,
     ``dt_bias`` (H,), ``norm_w`` (d_v,), ``w_o`` (H d_v, dim)."""
 
     def _dims(self):
@@ -110,30 +124,43 @@ class GatedDeltaMixerOp(Op):
         return (int(a["num_heads"]), int(a["key_dim"]), int(a["value_dim"]),
                 int(a["conv_width"]))
 
+    def _key_heads(self) -> int:
+        return int(self.attrs.get("num_key_heads") or self.attrs["num_heads"])
+
+    def _zero_centered(self) -> bool:
+        return self.attrs.get("gate", "silu") == "sigmoid2_zero_centered"
+
     def infer_output_shapes(self, input_shapes):
         return [tuple(input_shapes[0])]
 
     def weight_specs(self, input_shapes):
         from ..execution.initializers import (ConstantInitializer,
                                               DefaultWeightInitializer,
+                                              NormInitializer,
                                               UniformInitializer)
 
         d = input_shapes[0][-1]
         H, dk, dv, K = self._dims()
+        Hk = self._key_heads()
         init = self.attrs.get("kernel_initializer") \
             or DefaultWeightInitializer()
         t = self.data_type
-        return {"w_q": ((d, H * dk), t, init), "w_k": ((d, H * dk), t, init),
+        # a zero-centred gain rests about 0 (gain 1 + w); drawn, not 0, so
+        # that no seeded test holds it at the constant 1
+        norm_init = NormInitializer(stddev=0.02) if self._zero_centered() \
+            else ConstantInitializer(1.0)
+        return {"w_q": ((d, Hk * dk), t, init),
+                "w_k": ((d, Hk * dk), t, init),
                 "w_v": ((d, H * dv), t, init), "w_g": ((d, H * dv), t, init),
                 "w_a": ((d, H), t, init), "w_b": ((d, H), t, init),
                 # a depthwise conv's fan-in is its K taps
-                "conv_w": ((2 * H * dk + H * dv, K), t, UniformInitializer(
+                "conv_w": ((2 * Hk * dk + H * dv, K), t, UniformInitializer(
                     min_val=-K ** -0.5, max_val=K ** -0.5)),
                 "a_log": ((H,), t, _ALogInitializer()),
                 # softplus^-1(dt), dt log-uniform in [1e-3, 1e-1]: decays
                 # over hundreds of tokens, not two
                 "dt_bias": ((H,), t, _DtBiasInitializer()),
-                "norm_w": ((dv,), t, ConstantInitializer(1.0)),
+                "norm_w": ((dv,), t, norm_init),
                 "w_o": ((H * dv, d), t, init)}
 
     def slot_state_bytes(self, el: int = 0) -> int:
@@ -141,7 +168,8 @@ class GatedDeltaMixerOp(Op):
 
         H, dk, dv, K = self._dims()
         el = el or size_of_datatype(self.data_type)
-        return H * dk * dv * 4 + (2 * H * dk + H * dv) * (K - 1) * el
+        return H * dk * dv * 4 \
+            + (2 * self._key_heads() * dk + H * dv) * (K - 1) * el
 
     def slot_state_heads_a_row(self) -> int:
         from ..kernels.gated_delta_rule import state_heads_a_row
@@ -163,15 +191,11 @@ class GatedDeltaMixerOp(Op):
         u = inputs[0]                                   # (b, s, d)
         batch, seq, _d = u.shape
         H, dk, dv, K = self._dims()
+        Hk = self._key_heads()
         sv = ctx.serving
         if sv is not None and sv.mode == "chunk":
-            raise NotImplementedError(
-                f"{self.name}: chunked/prefix-cached prefill supports "
-                "attention-only stateful graphs; a gated delta-rule mixer "
-                "would have to start a chunk from a carried state and a "
-                "prefix hit from a snapshot of one (ROADMAP.md, Reach R8): "
-                "serve without --prefill-chunk-tokens and with "
-                "--prefix-cache off")
+            raise NotImplementedError(no_chunk_carry(
+                self.name, "a gated delta-rule mixer's matrix state"))
         decode = sv is not None and sv.mode == "decode"
         f32 = jnp.float32
         scope = lambda what: jax.named_scope(_inner_scope(self.name, what))
@@ -205,9 +229,9 @@ class GatedDeltaMixerOp(Op):
             # contractions cancel, and a rounding to bf16 in front of them
             # comes out of the layer more than doubled
             x = jax.nn.silu(conv)
-            q, k, v = (t.reshape(batch, seq, H, -1) for t in (
-                x[..., :H * dk], x[..., H * dk:2 * H * dk],
-                x[..., 2 * H * dk:]))
+            q, k, v = (t.reshape(batch, seq, h, -1) for t, h in (
+                (x[..., :Hk * dk], Hk), (x[..., Hk * dk:2 * Hk * dk], Hk),
+                (x[..., 2 * Hk * dk:], H)))
             if sv is not None:
                 tail = conv_tail_out(sv, hist, live, K)
         with scope("gate"):
@@ -217,6 +241,10 @@ class GatedDeltaMixerOp(Op):
                 a_in + params["dt_bias"].astype(f32))
             q = l2_normalize(q) * dk ** -0.5
             k = l2_normalize(k)
+            if Hk != H:
+                # value head j reads key head j // r: the normalised rows
+                # repeated, so that the rule sees H heads as ever
+                q, k = (jnp.repeat(t, H // Hk, axis=2) for t in (q, k))
         with scope("rule"):
             if decode:
                 # a free slot decays to nothing and writes nothing: a = 0
@@ -241,9 +269,13 @@ class GatedDeltaMixerOp(Op):
             sv.cache_out[self.name] = (tail, s_last)
         with scope("out"):
             # (b, s, H, d_v) float32 in, so float32 out of the norm
-            y = _head_rms_norm(o, params["norm_w"],
-                               float(self.attrs["norm_eps"]))
-            y = y * jax.nn.silu(gate.astype(f32).reshape(y.shape))
+            gain = params["norm_w"]
+            if self._zero_centered():
+                gain = 1.0 + gain.astype(f32)
+            y = _head_rms_norm(o, gain, float(self.attrs["norm_eps"]))
+            z = gate.astype(f32).reshape(y.shape)
+            y = y * (2.0 * jax.nn.sigmoid(z) if self._zero_centered()
+                     else jax.nn.silu(z))
             out = jnp.einsum(
                 "bse,ed->bsd", y.reshape(batch, seq, H * dv).astype(u.dtype),
                 params["w_o"], preferred_element_type=f32).astype(u.dtype)
@@ -252,7 +284,7 @@ class GatedDeltaMixerOp(Op):
     def flops(self, input_shapes, output_shapes):
         b, s, d = input_shapes[0]
         H, dk, dv, K = self._dims()
-        channels = 2 * H * dk + H * dv
+        channels = 2 * self._key_heads() * dk + H * dv
         per_token = 2 * d * (channels + H * dv + 2 * H) + 2 * channels * K \
             + 6 * H * dk * dv + 2 * H * dv * d
         return b * s * per_token

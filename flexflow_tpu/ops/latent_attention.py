@@ -60,22 +60,88 @@ from .base import Op, OpContext, register_op
 #: (``flash_decode_pool(tokens=)``): 4 x 128 heads is a 512-row query block,
 #: 7 MB of the 16 MiB of scoped VMEM with its accumulators at 640 lanes
 CHUNK_TOKENS_A_STEP = 4
+#: the materialised core's float32 score tile, (batch x heads, rows, keys),
+#: is cut into blocks of query rows past this many bytes
+SCORE_TILE_BYTES = 256 << 20
 
 
-def rope_at(x, positions, theta: float):
+def score_blocks(heads: int, rows: int, keys: int) -> int:
+    """Blocks of query rows the materialised core runs in: the least power
+    of two that brings the float32 score tile under ``SCORE_TILE_BYTES``
+    and divides ``rows``; 1 (the whole tile at once) where it fits."""
+    blocks = 1
+    while heads * (rows // blocks) * keys * 4 > SCORE_TILE_BYTES \
+            and rows % (2 * blocks) == 0:
+        blocks *= 2
+    return blocks
+
+
+def yarn_inv_freq(d: int, theta: float, scaling: dict):
+    """YaRN's rotary frequencies for a ``d``-wide rotary part (the
+    DeepSeek-V3 family's closed form): ``theta^(-2j/d)`` kept where a pair
+    turns more than ``beta_fast`` times over the original context, divided
+    by ``factor`` where it turns fewer than ``beta_slow`` times, and a
+    linear ramp between the two correction dims. float32 ``(d / 2,)``."""
+    factor = float(scaling["factor"])
+    orig = float(scaling["original_max_position_embeddings"])
+
+    def correction_dim(rotations):
+        return d * np.log(orig / (rotations * 2 * np.pi)) \
+            / (2 * np.log(theta))
+
+    low = max(np.floor(correction_dim(float(scaling["beta_fast"]))), 0)
+    high = min(np.ceil(correction_dim(float(scaling["beta_slow"]))), d - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(d // 2, dtype=np.float32) - low)
+                   / (high - low), 0, 1)
+    extra = 1.0 / (theta ** (np.arange(0, d, 2, dtype=np.float32) / d))
+    return (extra / factor * ramp + extra * (1 - ramp)).astype(np.float32)
+
+
+def yarn_mscale(factor: float, mscale: float = 1.0) -> float:
+    """``0.1 mscale ln(factor) + 1`` past factor 1: what YaRN multiplies the
+    attention logits' temperature by."""
+    return 0.1 * float(mscale) * np.log(factor) + 1.0 if factor > 1 else 1.0
+
+
+def rope_at(x, positions, theta: float, scaling=None,
+            interleave: bool = False):
     """Rotary positions on ``x (..., seq, d)`` at ``positions`` broadcastable
-    to ``x.shape[:-1]``, rotate-half pairing (dim i with dim i + d/2),
-    angles in float32."""
+    to ``x.shape[:-1]``, angles in float32. The pairing is rotate-half (dim
+    i with dim i + d/2) or, ``interleave``, neighbours (2j with 2j + 1: the
+    same angles, the columns in another order). ``scaling``: a YaRN
+    ``rope_scaling`` group (:func:`yarn_inv_freq`; cos and sin times
+    ``mscale / mscale_all_dim``'s ratio, 1 where the two are equal)."""
     import jax.numpy as jnp
 
     d = x.shape[-1]
-    inv_freq = 1.0 / (theta ** (np.arange(0, d, 2, dtype=np.float32) / d))
+    amp = 1.0
+    if scaling is None:
+        inv_freq = 1.0 / (theta ** (np.arange(0, d, 2, dtype=np.float32) / d))
+    else:
+        inv_freq = yarn_inv_freq(d, theta, scaling)
+        amp = yarn_mscale(scaling["factor"], scaling.get("mscale", 1.0)) \
+            / yarn_mscale(scaling["factor"],
+                          scaling.get("mscale_all_dim", 0.0))
     ang = positions.astype(jnp.float32)[..., None] * inv_freq
-    cos = jnp.concatenate([jnp.cos(ang), jnp.cos(ang)], axis=-1)
-    sin = jnp.concatenate([jnp.sin(ang), jnp.sin(ang)], axis=-1)
+    if scaling is None and not interleave:
+        # the expression as it stood before the two options, in its order:
+        # the programs that set neither trace as they did
+        cos = jnp.concatenate([jnp.cos(ang), jnp.cos(ang)], axis=-1)
+        sin = jnp.concatenate([jnp.sin(ang), jnp.sin(ang)], axis=-1)
+        xf = x.astype(jnp.float32)
+        rot = jnp.concatenate([-xf[..., d // 2:], xf[..., :d // 2]], axis=-1)
+        return (xf * cos + rot * sin).astype(x.dtype)
+    cos, sin = jnp.cos(ang) * amp, jnp.sin(ang) * amp
     xf = x.astype(jnp.float32)
-    rot = jnp.concatenate([-xf[..., d // 2:], xf[..., :d // 2]], axis=-1)
-    return (xf * cos + rot * sin).astype(x.dtype)
+    if interleave:
+        a, b = xf[..., 0::2], xf[..., 1::2]
+        out = jnp.stack([a * cos - b * sin, b * cos + a * sin], axis=-1)
+        return out.reshape(xf.shape).astype(x.dtype)
+    a, b = xf[..., :d // 2], xf[..., d // 2:]
+    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
+                           axis=-1).astype(x.dtype)
 
 
 def _rms(x, gain, eps: float):
@@ -92,7 +158,12 @@ def _rms(x, gain, eps: float):
 class LatentAttentionOp(Op):
     """attrs: embed_dim, num_heads, q_rank, kv_rank, nope_dim, rope_dim,
     v_dim, rope_theta, eps (of the two latent RMS norms), causal (True: the
-    only form). input (batch, seq, dim) -> (batch, seq, embed_dim). No bias.
+    only form); off by default: rope_scaling (a YaRN group: the frequencies
+    of :func:`yarn_inv_freq`, and the softmax scale times ``m^2``, ``m =
+    yarn_mscale(factor, mscale_all_dim)``), rope_interleave (neighbour
+    pairs), gated (``o <- o * sigmoid(x W_g)`` a head, before ``W_o``:
+    weight ``wg`` (dim, heads * v)). input (batch, seq, dim) -> (batch, seq,
+    embed_dim). No bias.
 
     Weights, each a matrix: ``wq_a`` (dim, q_rank), ``q_norm`` (q_rank,),
     ``wq_b`` (q_rank, heads * (nope + rope)), ``wkv_a`` (dim, kv_rank +
@@ -123,12 +194,37 @@ class LatentAttentionOp(Op):
             or DefaultWeightInitializer()
         one = ConstantInitializer(1.0)
         t = self.data_type
-        return {"wq_a": ((d, qr), t, init), "q_norm": ((qr,), t, one),
-                "wq_b": ((qr, h * (nope + rope)), t, init),
-                "wkv_a": ((d, kr + rope), t, init),
-                "kv_norm": ((kr,), t, one),
-                "wkv_b": ((kr, h * (nope + vd)), t, init),
-                "wo": ((h * vd, self.attrs["embed_dim"]), t, init)}
+        specs = {"wq_a": ((d, qr), t, init), "q_norm": ((qr,), t, one),
+                 "wq_b": ((qr, h * (nope + rope)), t, init),
+                 "wkv_a": ((d, kr + rope), t, init),
+                 "kv_norm": ((kr,), t, one),
+                 "wkv_b": ((kr, h * (nope + vd)), t, init),
+                 "wo": ((h * vd, self.attrs["embed_dim"]), t, init)}
+        if self.attrs.get("gated"):
+            specs["wg"] = ((d, h * vd), t, init)
+        return specs
+
+    def _rope(self, x, positions):
+        theta = float(self.attrs["rope_theta"])
+        scaling = self.attrs.get("rope_scaling")
+        interleave = bool(self.attrs.get("rope_interleave"))
+        if scaling is None and not interleave:
+            # as the parent calls it (benchmark/tests/controls_pangu.py
+            # plants a three-argument fault here)
+            return rope_at(x, positions, theta)
+        return rope_at(x, positions, theta, scaling=scaling,
+                       interleave=interleave)
+
+    def _scale(self):
+        """The softmax scale: ``(nope + rope)^-1/2``, times YaRN's ``m^2``
+        under a ``rope_scaling`` that names ``mscale_all_dim``."""
+        _h, _qr, _kr, nope, rope, _vd = self._dims()
+        scale = 1.0 / np.sqrt(nope + rope)
+        sc = self.attrs.get("rope_scaling")
+        if sc and sc.get("mscale_all_dim"):
+            scale = scale * yarn_mscale(sc["factor"],
+                                        sc["mscale_all_dim"]) ** 2
+        return scale
 
     # ------------------------------------------------------------ the parts
     def _queries(self, params, x, positions):
@@ -142,9 +238,8 @@ class LatentAttentionOp(Op):
                        float(self.attrs["eps"]))
             q = jnp.dot(c_q, params["wq_b"]).reshape(
                 x.shape[:2] + (h, nope + rope))
-            q_r = rope_at(jnp.swapaxes(q[..., nope:], 1, 2),
-                          positions[:, None, :],
-                          float(self.attrs["rope_theta"]))
+            q_r = self._rope(jnp.swapaxes(q[..., nope:], 1, 2),
+                             positions[:, None, :])
         return q[..., :nope], jnp.swapaxes(q_r, 1, 2)
 
     def _rows(self, params, x, positions):
@@ -158,8 +253,7 @@ class LatentAttentionOp(Op):
             kv = jnp.dot(x, params["wkv_a"])
             c_kv = _rms(kv[..., :kr], params["kv_norm"],
                         float(self.attrs["eps"]))
-            k_r = rope_at(kv[..., kr:], positions,
-                          float(self.attrs["rope_theta"]))
+            k_r = self._rope(kv[..., kr:], positions)
             return jnp.concatenate([c_kv, k_r], axis=-1)
 
     def _wkv_b(self, params):
@@ -167,11 +261,18 @@ class LatentAttentionOp(Op):
         h, _qr, kr, nope, _rope, vd = self._dims()
         return params["wkv_b"].reshape(kr, h, nope + vd)
 
-    def _out(self, params, o):
-        """o (b, s, h, v) -> (b, s, embed)."""
+    def _out(self, params, o, x):
+        """o (b, s, h, v) -> (b, s, embed); ``gated``: each head's output
+        times ``sigmoid(x W_g)`` first, the product taken in float32."""
         import jax
         import jax.numpy as jnp
 
+        if self.attrs.get("gated"):
+            with jax.named_scope(_inner_scope(self.name, "gate")):
+                z = jnp.dot(x, params["wg"],
+                            preferred_element_type=jnp.float32)
+                o = (o.astype(jnp.float32)
+                     * jax.nn.sigmoid(z).reshape(o.shape)).astype(o.dtype)
         with jax.named_scope(_inner_scope(self.name, "out")):
             return jnp.dot(o.reshape(o.shape[:2] + (-1,)), params["wo"],
                            preferred_element_type=jnp.float32
@@ -188,17 +289,36 @@ class LatentAttentionOp(Op):
         w = self._wkv_b(params)
         with jax.named_scope(_inner_scope(self.name, "up")):
             kv = jnp.einsum("bnc,chd->bnhd", rows[..., :kr], w)
-        scale = 1.0 / np.sqrt(nope + rope)
-        with jax.named_scope(_inner_scope(self.name, "core")):
+        scale = self._scale()
+
+        def core(q_n, q_r, mask):
             s = jnp.einsum("bshd,bnhd->bhsn", q_n, kv[..., :nope],
                            preferred_element_type=jnp.float32)
             s = s + jnp.einsum("bshr,bnr->bhsn", q_r, rows[..., kr:],
                                preferred_element_type=jnp.float32)
             s = jnp.where(mask[:, None], s * scale, -1e30)
             p = jax.nn.softmax(s, axis=-1)
-            o = jnp.einsum("bhsn,bnhd->bshd", p.astype(rows.dtype),
-                           kv[..., nope:],
-                           preferred_element_type=jnp.float32)
+            return jnp.einsum("bhsn,bnhd->bshd", p.astype(rows.dtype),
+                              kv[..., nope:],
+                              preferred_element_type=jnp.float32)
+
+        b, sq, h = q_n.shape[:3]
+        blocks = score_blocks(b * h, sq, rows.shape[1])
+        with jax.named_scope(_inner_scope(self.name, "core")):
+            if blocks == 1:
+                o = core(q_n, q_r, mask)
+            else:
+                # the float32 score tile a block of query rows at a time: whole,
+                # a 2,048-row prompt's is a GB a layer beside a resident engine
+                cut = lambda t, axis: jnp.moveaxis(t.reshape(
+                    t.shape[:axis] + (blocks, -1) + t.shape[axis + 1:]),
+                    axis, 0)
+                o = jax.lax.map(
+                    lambda qm: core(*qm),
+                    (cut(q_n, 1), cut(q_r, 1),
+                     cut(jnp.broadcast_to(mask, (mask.shape[0], sq,
+                                                 mask.shape[2])), 1)))
+                o = jnp.moveaxis(o, 0, 1).reshape(b, sq, h, -1)
         return o.astype(rows.dtype)
 
     def _absorbed(self, params, q_n, q_r, entry, tables, seen,
@@ -217,7 +337,7 @@ class LatentAttentionOp(Op):
 
         h, _qr, kr, nope, rope, _vd = self._dims()
         w = self._wkv_b(params)
-        scale = 1.0 / np.sqrt(nope + rope)
+        scale = self._scale()
         b, c = q_n.shape[:2]
         with jax.named_scope(_inner_scope(self.name, "absorb")):
             qt = jnp.einsum("bshd,chd->bshc", q_n, w[..., :nope])
@@ -266,7 +386,7 @@ class LatentAttentionOp(Op):
                     rows[:, None], None, sv.max_len)
             mask = jnp.tril(jnp.ones((s, s), dtype=bool))[None]
             return [self._out(params, self._materialised(
-                params, q_n, q_r, rows, mask))]
+                params, q_n, q_r, rows, mask), x)]
         if sv.mode == "chunk":
             return [self._chunk(params, x, sv)]
         return [self._decode(params, x, sv)]
@@ -295,7 +415,7 @@ class LatentAttentionOp(Op):
         seen = jnp.where(i < n_new, start + i + 1, 0)[None]
         t = CHUNK_TOKENS_A_STEP if c % CHUNK_TOKENS_A_STEP == 0 else 0
         return self._out(params, self._absorbed(
-            params, q_n, q_r, entry, sv.block_tables, seen, tokens=t))
+            params, q_n, q_r, entry, sv.block_tables, seen, tokens=t), x)
 
     def _decode(self, params, x, sv):
         """One token a slot, absorbed: the row into the pool at the slot's
@@ -317,14 +437,15 @@ class LatentAttentionOp(Op):
                                    sv.block_tables, sv.block_size)
         sv.cache_out[self.name] = entry
         return self._out(params, self._absorbed(
-            params, q_n, q_r, entry, sv.block_tables, pos + 1))
+            params, q_n, q_r, entry, sv.block_tables, pos + 1), x)
 
     # ---------------------------------------------------------- cost model
     def flops(self, input_shapes, output_shapes):
         b, s, d = input_shapes[0]
         h, qr, kr, nope, rope, vd = self._dims()
         proj = d * qr + qr * h * (nope + rope) + d * (kr + rope) \
-            + kr * h * (nope + vd) + h * vd * self.attrs["embed_dim"]
+            + kr * h * (nope + vd) + h * vd * self.attrs["embed_dim"] \
+            + (d * h * vd if self.attrs.get("gated") else 0)
         return 2 * b * s * proj + 2 * b * h * s * s * (nope + rope + vd)
 
     def parallelizable_dims(self, input_shapes):

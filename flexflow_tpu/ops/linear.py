@@ -147,12 +147,25 @@ class LinearOp(Op):
         }
 
 
+def swiglu(g, u, limit=None):
+    """``silu(g) * u`` in the operands' dtype (float32 here); ``limit`` L:
+    the clamped form ``silu(min(g, L)) * clip(u, -L, L)`` — the gate cut
+    from above alone (``silu`` is bounded below), the linear half from both
+    sides."""
+    import jax.numpy as jnp
+
+    if limit:
+        g, u = jnp.minimum(g, limit), jnp.clip(u, -limit, limit)
+    return jax.nn.silu(g) * u
+
+
 @register_op(OperatorType.OP_GATED_MLP)
 class GatedMLPOp(Op):
     """The gated (SwiGLU) MLP as one node: ``W_down(silu(W_gate x) * W_up x)``,
-    no biases. attrs: intermediate, kernel_initializer. Weights ``gate`` and
-    ``up`` (in_dim, intermediate), ``down`` (intermediate, in_dim); the
-    product with the gate is taken in float32."""
+    no biases. attrs: intermediate, kernel_initializer, limit (off by
+    default; L: ``silu(min(g, L)) * clip(u, -L, L)``, :func:`swiglu`).
+    Weights ``gate`` and ``up`` (in_dim, intermediate), ``down``
+    (intermediate, in_dim); the product with the gate is taken in float32."""
 
     def infer_output_shapes(self, input_shapes):
         return [tuple(input_shapes[0])]
@@ -173,7 +186,7 @@ class GatedMLPOp(Op):
         (x,) = inputs
         g = jnp.dot(x, params["gate"], preferred_element_type=jnp.float32)
         u = jnp.dot(x, params["up"], preferred_element_type=jnp.float32)
-        a = (jax.nn.silu(g) * u).astype(x.dtype)
+        a = swiglu(g, u, self.attrs.get("limit")).astype(x.dtype)
         return [jnp.dot(a, params["down"],
                         preferred_element_type=jnp.float32).astype(x.dtype)]
 

@@ -31,6 +31,7 @@ import numpy as np
 
 from ..ffconst import OperatorType
 from .base import Op, OpContext, register_op
+from .linear import swiglu
 
 
 def moe_capacity(k: int, batch: int, alpha: float, n: int) -> int:
@@ -504,10 +505,11 @@ def _dispatch_rows(bound, k, x, order, here, n_here):
                     (order, here, n_here))
 
 
-def _expert_rows(bound, product, rows, gate, up, down, group_sizes):
+def _expert_rows(bound, product, limit, rows, gate, up, down, group_sizes):
     """The gated MLP of every held expert over its rows. ``product``:
     ``jax.lax.ragged_dot`` as the caller finds it (a check that plants a
-    fault in it must not be handed a program traced before). As
+    fault in it must not be handed a program traced before); ``limit``:
+    the clamp of ``linear.swiglu`` (None: none). As
     ``_by_rows``, with one difference: the bounded path's two first
     products leave its forward as residuals of the BOUND's shape, so its
     backward is the six transposed products and computes none again (the
@@ -522,7 +524,7 @@ def _expert_rows(bound, product, rows, gate, up, down, group_sizes):
                        preferred_element_type=jnp.float32)
 
     def gated(g, u):
-        return (jax.nn.silu(g) * u).astype(dtype)
+        return swiglu(g, u, limit).astype(dtype)
 
     def whole(rows, gate, up, down, group_sizes):
         a = gated(grouped(rows, gate, group_sizes),
@@ -798,7 +800,8 @@ class MoEDispatchOp(Op):
 
 @register_op(OperatorType.OP_MOE_ROUTED_EXPERTS)
 class MoERoutedExpertsOp(Op):
-    """attrs: held, intermediate, kernel_initializer. inputs (rows
+    """attrs: held, intermediate, kernel_initializer, limit (off by
+    default: the clamp of ``linear.swiglu``). inputs (rows
     (pairs, d) sorted by expert, group_sizes (count,)) -> (pairs, d): per
     held expert ``W_down(silu(W_gate x) * W_up x)`` over its rows, three
     grouped products. Weights ``gate``/``up`` (count, d, intermediate),
@@ -828,7 +831,8 @@ class MoERoutedExpertsOp(Op):
 
         rows, group_sizes = inputs
         bound = _row_bound(rows.shape[0], self.attrs)
-        return [_shared_program(_expert_rows, bound, jax.lax.ragged_dot)(
+        return [_shared_program(_expert_rows, bound, jax.lax.ragged_dot,
+                                self.attrs.get("limit"))(
             rows, params["gate"], params["up"], params["down"],
             group_sizes)]
 

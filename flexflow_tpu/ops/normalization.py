@@ -51,9 +51,34 @@ class LayerNormOp(Op):
         return [y.astype(x.dtype)]
 
 
+#: the forms of an RMS norm's gain (``gain``); None: the stored scale itself
+NORM_GAINS = ("sigmoid2",)
+
+
+def norm_gain(w, form=None):
+    """The gain an RMS norm multiplies by, from its stored ``scale``, in
+    float32. ``None``: the scale itself (stored about 1). ``"sigmoid2"``
+    (``ZeroCenteredGatedNorm`` with gating weight 2): ``2 sigmoid(w)``, ``w``
+    stored about zero — gain 1 at ``w = 0``, never negative, at most 2. The
+    published keys name the form and the constant, not the formula: this
+    function (and its twin in the plain reference) is the one place another
+    reading changes."""
+    import jax
+    import jax.numpy as jnp
+
+    w = w.astype(jnp.float32)
+    if form is None:
+        return w
+    if form == "sigmoid2":
+        return 2.0 * jax.nn.sigmoid(w)
+    raise ValueError(f"norm_gain: form {form!r} is none of {NORM_GAINS}")
+
+
 @register_op(OperatorType.OP_RMSNORM)
 class RMSNormOp(Op):
-    """attrs: axes, eps. TPU-native extension for LLM blocks."""
+    """attrs: axes, eps, gain (off by default: a form of :func:`norm_gain`,
+    whose scale is drawn N(0, 0.02) and not the constant 1). TPU-native
+    extension for LLM blocks."""
 
     def infer_output_shapes(self, input_shapes):
         return [input_shapes[0]]
@@ -64,6 +89,11 @@ class RMSNormOp(Op):
         ishape = input_shapes[0]
         axes = [a % len(ishape) for a in self.attrs.get("axes", [len(ishape) - 1])]
         nshape = tuple(ishape[a] for a in sorted(axes))
+        if self.attrs.get("gain"):
+            from ..execution.initializers import NormInitializer
+
+            return {"scale": (nshape, self.data_type,
+                              NormInitializer(stddev=0.02))}
         return {"scale": (nshape, self.data_type, ConstantInitializer(1.0))}
 
     def forward(self, params, inputs, ctx: OpContext):
@@ -77,7 +107,10 @@ class RMSNormOp(Op):
         ms = jnp.mean(jnp.square(xf), axis=axes, keepdims=True)
         y = xf / jnp.sqrt(ms + eps)
         bshape = [x.shape[a] if a in axes else 1 for a in range(ndim)]
-        return [(y * params["scale"].reshape(bshape)).astype(x.dtype)]
+        scale = params["scale"]
+        if self.attrs.get("gain"):
+            scale = norm_gain(scale, self.attrs["gain"])
+        return [(y * scale.reshape(bshape)).astype(x.dtype)]
 
 
 @register_op(OperatorType.OP_SOFTMAX)

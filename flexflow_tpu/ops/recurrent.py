@@ -16,7 +16,7 @@ Outputs: [sequence_outputs, final_state(batch, 2*hidden)].
 from __future__ import annotations
 
 from ..ffconst import OperatorType
-from .base import Op, OpContext, register_op
+from .base import Op, OpContext, no_chunk_carry, register_op
 
 
 @register_op(OperatorType.OP_LSTM)
@@ -58,17 +58,10 @@ class LSTMOp(Op):
         h = self.attrs["hidden_size"]
         sv = ctx.serving  # serving engine prefill/decode (ISSUE 6)
         if sv is not None and sv.mode == "chunk":
-            # chunked/prefix-cached prefill (ISSUE 14) is an
-            # attention-only feature: the LSTM carry is a summary, not
-            # per-token pool rows — there is no block to share or chunk.
-            # The engine disables the prefix cache and refuses
-            # --prefill-chunk-tokens for LSTM graphs at construction;
-            # this raise is the defense-in-depth backstop.
-            raise NotImplementedError(
-                f"{self.name}: chunked/prefix-cached prefill supports "
-                "attention-only stateful graphs; LSTM recurrence has no "
-                "chunk path (serve without --prefill-chunk-tokens and "
-                "with --prefix-cache off)")
+            # the engine refuses chunks and the prefix cache for a graph
+            # with a recurrent node at construction; this is the backstop
+            raise NotImplementedError(no_chunk_carry(
+                self.name, "the LSTM's [h, c] carry"))
         if sv is not None and sv.mode == "decode" and sv.cache_in is not None \
                 and self.name in sv.cache_in:
             # the LSTM's recurrent carry IS its decode state: resume from

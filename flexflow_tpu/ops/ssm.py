@@ -51,7 +51,7 @@ from __future__ import annotations
 
 from ..ffconst import OperatorType
 from .attention import _head_rms_norm, _inner_scope
-from .base import Op, OpContext, register_op
+from .base import Op, OpContext, no_chunk_carry, register_op
 
 
 class _ALogInitializer:
@@ -198,12 +198,8 @@ class SSMMixerOp(Op):
         E, N, K, _R = self._dims()
         sv = ctx.serving
         if sv is not None and sv.mode == "chunk":
-            raise NotImplementedError(
-                f"{self.name}: chunked/prefix-cached prefill supports "
-                "attention-only stateful graphs; a state-space mixer would "
-                "have to start a chunk from a carried state and a prefix "
-                "hit from a snapshot of one (ROADMAP.md, Reach R8): serve "
-                "without --prefill-chunk-tokens and with --prefix-cache off")
+            raise NotImplementedError(no_chunk_carry(
+                self.name, "a state-space mixer's state"))
         decode = sv is not None and sv.mode == "decode"
         f32 = jnp.float32
         scope = lambda what: jax.named_scope(_inner_scope(self.name, what))

@@ -157,9 +157,9 @@ class ServingStats:
     # below it. Set at serve-loop finish; 0 until a decode step ran.
     kv_hbm_per_chip_bytes: int = 0
     # routed expert layers at decode shapes: counted on the device by
-    # the decode step and fetched with its tokens (the sync loop; the
-    # async loop does not fetch them) — the (row, expert) pairs held
-    # here and the held experts that got at least one row, both summed
+    # the decode step and fetched with its tokens (the sync loop in its
+    # own tick; the async loop at the step's settle, a tick later) — the
+    # (row, expert) pairs held here and the held experts that got at least one row, both summed
     # over layers and decode steps, and the fullest expert's rows over
     # its layer's mean in thousandths, the largest seen; the layer-steps
     # whose pairs fit the layer's row bound and took the bounded path
@@ -187,6 +187,16 @@ class ServingStats:
     # (Op.slot_state_heads_a_row)
     recurrent_state_bytes_at_rest: int = 0
     state_heads_a_row: int = 0
+    # latent-attention nodes: the pool rows the decode steps' latent reads
+    # fold — each live slot's keys, a latent node's call — summed over
+    # nodes and decode steps (times kvcache.latent_token_bytes: the bytes)
+    latent_rows_read: int = 0
+    # what the engine allocated for its decode state, by kind of cache,
+    # once (no sum): "kv_pool" / "latent_pool" the paged pools of the
+    # attention / latent-attention nodes, "recurrent_state" the slot-major
+    # entries — the two kinds a hybrid graph holds side by side
+    cache_bytes_by_kind: Dict[str, int] = dataclasses.field(
+        default_factory=dict)
     # the one-shot prefills' rows as the program computed them (the
     # bucket's, padding included: a chunked recurrence and the matmuls pay
     # a padded row what they pay a real one) and the real ones among them
@@ -329,9 +339,11 @@ class ServingStats:
                   "moe_chunk_layer_steps", "recurrent_state_bytes",
                   "recurrent_slots_live", "recurrent_state_bytes_at_rest",
                   "state_heads_a_row", "prefill_rows",
-                  "prefill_rows_real"):
+                  "prefill_rows_real", "latent_rows_read"):
             if getattr(self, k):
                 out[k] = getattr(self, k)
+        if self.cache_bytes_by_kind:
+            out["cache_bytes_by_kind"] = dict(self.cache_bytes_by_kind)
         return out
 
 
@@ -449,16 +461,14 @@ class ServingEngine:
             # summary, not per-token pool rows: there is no
             # block to share or chunk (ISSUE 14 scope — attention-only
             # stateful graphs; ROADMAP.md Reach R8 has what is missing)
-            if self.prefill_chunk_tokens:
-                raise ValueError(
-                    "prefill_chunk_tokens: chunked prefill supports "
-                    "attention-only stateful graphs; this model has "
-                    f"a recurrent node ({recurrent[0]})")
-            if prefix_cache == "on":
-                raise ValueError(
-                    "prefix_cache='on': prefix caching supports "
-                    "attention-only stateful graphs; this model has "
-                    f"a recurrent node ({recurrent[0]})")
+            from ..ops.base import no_chunk_carry
+
+            for asked, on in (
+                    ("--prefill-chunk-tokens", self.prefill_chunk_tokens),
+                    ("--prefix-cache on", prefix_cache == "on")):
+                if on:
+                    raise ValueError(no_chunk_carry(
+                        recurrent[0], "its slot-major state", asked))
             prefix_mode = "off"
         self.max_context = position_context_bound(self.executor,
                                                   self.max_decode_len)
@@ -1270,6 +1280,50 @@ class ServingEngine:
         return {"recurrent_state_bytes": moved,
                 "recurrent_slots_live": n_live}
 
+    def _count_decode_latent(self, stats: ServingStats, live,
+                             in_flight: int = 0) -> Dict[str, int]:
+        """One decode step's latent reads, counted: every latent node
+        folds each live slot's rows (its keys: the context and the token
+        the step writes — ``effective_len`` before the step's own token is
+        committed; ``in_flight``: live slots whose previous token is not
+        committed yet, the async loop's). Returns the step's number, for
+        its ``serve_tick`` span; empty for a graph without a latent
+        node."""
+        n_latent = len(self._latent_node_names())
+        if not n_latent:
+            return {}
+        rows = n_latent * (in_flight + sum(
+            req.effective_len for _slot, req in live))
+        stats.latent_rows_read += rows
+        return {"latent_rows_read": rows}
+
+    def _latent_node_names(self) -> frozenset:
+        if getattr(self, "_latent_node_names_cache", None) is None:
+            from ..ffconst import OperatorType
+
+            self._latent_node_names_cache = frozenset(
+                node.name for node in self.executor.pcg.compute_nodes()
+                if node.op.op_type == OperatorType.OP_LATENT_ATTENTION)
+        return self._latent_node_names_cache
+
+    def cache_bytes_by_kind(self) -> Dict[str, int]:
+        """Bytes of the decode state as allocated, by kind of cache (see
+        ``ServingStats.cache_bytes_by_kind``); empty before the state is
+        built."""
+        import jax
+
+        if self.state is None:
+            return {}
+        latent = self._latent_node_names()
+        out: Dict[str, int] = {}
+        for name, entry in self.state.caches.items():
+            kind = "recurrent_state" \
+                if name not in self._paged_entry_names \
+                else "latent_pool" if name in latent else "kv_pool"
+            out[kind] = out.get(kind, 0) + sum(
+                int(leaf.nbytes) for leaf in jax.tree.leaves(entry))
+        return out
+
     def _sweep_deadlines(self, sched, res, tracer) -> None:
         """Deadline enforcement at the iteration boundary: expired queued
         requests are dropped before they cost a prefill; expired in-flight
@@ -1414,6 +1468,8 @@ class ServingEngine:
         tel.serving_state_heads_a_row = stats.state_heads_a_row
         tel.serving_prefill_rows = stats.prefill_rows
         tel.serving_prefill_rows_real = stats.prefill_rows_real
+        tel.serving_latent_rows_read = stats.latent_rows_read
+        tel.serving_cache_bytes_by_kind = dict(stats.cache_bytes_by_kind)
         # serving_resilience block (ISSUE 9): the outcome ledger + event
         # counters, mirroring the resilience/strategy_safety blocks
         tel.serving_outcomes = dict(stats.outcomes)
@@ -2058,6 +2114,7 @@ class _ServeLoop:
         self._moe_tick = {}
         phase.tick_args.update(
             eng._count_decode_recurrent(self.stats, len(live)))
+        phase.tick_args.update(eng._count_decode_latent(self.stats, live))
         wall = time.perf_counter() - t_d
         phase.to("tick_bookkeep")
         self._commit_arrival(live, None, toks_host, ok_host, wall)
@@ -2137,6 +2194,7 @@ class _ServeLoop:
             stats.kv_hbm_per_chip_bytes = int(
                 stats.kv_bytes_read / stats.decode_steps
                 / max(eng.seq_shards, 1))
+        stats.cache_bytes_by_kind = eng.cache_bytes_by_kind()
         if self.publish_telemetry:
             eng._merge_telemetry(sched, stats)
             if tracer.enabled and eng.model.config.trace_file:
@@ -2159,6 +2217,9 @@ class _PendingStep:
     live: List
     epochs: List[int]
     t_d: float
+    # a routed graph's step counters, on the device: fetched with the
+    # tokens at settle, for the NEXT tick's ``serve_tick`` span
+    counters: Any = None
 
 
 class _AsyncServeLoop(_ServeLoop):
@@ -2212,7 +2273,7 @@ class _AsyncServeLoop(_ServeLoop):
         not host work)."""
         t_s = time.perf_counter()
         with span("fetch_tokens", tracer=self.tracer):
-            toks_host, ok_host = self._fetch(p.toks, p.ok_vec)
+            toks_host, ok_host = self._fetch(p.toks, p.ok_vec, p.counters)
         blocked = time.perf_counter() - t_s
         self.stats.host_device_s += blocked
         wall = time.perf_counter() - p.t_d
@@ -2266,6 +2327,8 @@ class _AsyncServeLoop(_ServeLoop):
             phase.tick_args["pipelined"] = 1
         phase.tick_args.update(
             eng._count_decode_recurrent(stats, len(live)))
+        phase.tick_args.update(eng._count_decode_latent(
+            stats, live, len(self._pending.live) if pipelined else 0))
         k = self.dispatch_no  # chaos keys on dispatch order
         self._chaos_hooks(k)
         t_d = time.perf_counter()
@@ -2312,11 +2375,15 @@ class _AsyncServeLoop(_ServeLoop):
                 pass  # backend without async host copies: settle blocks
         prev, self._pending = self._pending, _PendingStep(
             toks=toks, ok_vec=ok_vec, live=list(live),
-            epochs=[self.sched.slot_epoch[s] for s, _ in live], t_d=t_d)
+            epochs=[self.sched.slot_epoch[s] for s, _ in live], t_d=t_d,
+            counters=eng._step_counters)
         self.dispatch_no += 1
         phase.close()
         blocked = (self._settle_step(prev, "tick_overlap")
                    if prev is not None else 0.0)
+        # the settled step's routing counters, a step behind their tick
+        phase.tick_args.update(self._moe_tick)
+        self._moe_tick = {}
         stats.host_overlap_s += max(
             time.perf_counter() - issued - blocked, 0.0)
         stats.host_ticks += 1

@@ -55,6 +55,12 @@ SHAPES = {
         slots=64, q_heads=128, kd=576, pool_heads=1, lanes=640, v_lanes=512,
         table=824, pool_blocks=28000, calls=5, live_keys=9000,
         live=(0, 4, 10, 32, 64)),
+    # the latent read at 64 heads a row: one latent layer, a slot some
+    # 650 prompt tokens and half its 2,400-token answer deep
+    "gigachat35-reasoning-2k": dict(
+        slots=128, q_heads=64, kd=576, pool_heads=1, lanes=640, v_lanes=512,
+        table=512, pool_blocks=32769, calls=1, live_keys=1900,
+        live=(0, 16, 64, 85, 128)),
 }
 
 

@@ -1,5 +1,8 @@
 """Microbench: the gated delta rule alone at the ``olmo-hybrid-7b-assist``
-cell's shapes (30 heads, d_k 96, d_v 192), on the host's clock.
+cell's shapes (30 heads, d_k 96, d_v 192) or, named as argument, the
+``gigachat35-reasoning-2k`` cell's (64 value heads of 128 x 128, 128 slots,
+4 layers: a head a row of the state at rest, so ``packed`` and ``padded`` are
+one shape there), on the host's clock.
 
 * the decode step's one-token update over ``SLOTS`` slots, 12 layers' worth
   inside one jit with the states donated, in three forms: the
@@ -30,8 +33,15 @@ import jax.numpy as jnp
 
 from microbench_ssm import timed  # scripts/ is sys.path[0]
 
-SLOTS, HEADS, DK, DV, LAYERS = 64, 30, 96, 192, 12
-PROMPTS = (256, 512, 1024)
+#: cell: slots, (value) heads, d_k, d_v, delta-rule layers, prompt rows; a
+#: cell's name as argument runs that shape (the first by default). The
+#: kernels see value heads alone: grouped key heads are repeated before them
+SHAPES = {
+    "olmo-hybrid-7b-assist": (64, 30, 96, 192, 12, (256, 512, 1024)),
+    "gigachat35-reasoning-2k": (128, 64, 128, 128, 4, (512, 1024, 2048)),
+}
+SLOTS, HEADS, DK, DV, LAYERS, PROMPTS = SHAPES[
+    sys.argv[1] if sys.argv[1:] else "olmo-hybrid-7b-assist"]
 
 
 def rule_inputs(key, rows, length):
